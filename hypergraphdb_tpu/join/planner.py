@@ -687,8 +687,8 @@ class DeviceJoinPlan:
         cfg = graph.config.query
         # planner duality in the cost model's own unit: if the host can
         # answer for less than one ad-hoc dispatch amortizes
-        # (device_min_batch rows' worth of host bytes — CALIBRATION.md
-        # §2), stay host. Gating on the raw ROW estimate here would
+        # (device_min_batch rows' worth of host bytes — a round-5
+        # constant, core/config.py), stay host. Gating on the raw ROW estimate here would
         # demand anchors so wide the executor's default pads could never
         # hold them — the arm would be unreachable by construction.
         host_cost = host_cost_bytes(graph, self.fallback)

@@ -16,8 +16,11 @@ Key anatomy (see README "Fused BFS kernel & AOT cache"):
   ``<root>/<jax-version>_<backend>/`` — so upgrading jax or moving
   between backends can never replay a stale executable;
 - the **entry file name** is ``<entry>__<sha256 of (entry, arg avals,
-  statics, content_key)>.aot``; avals cover every dynamic argument's
-  shape/dtype (the shape bucket), statics are the jit-static kwargs, and
+  execution devices, statics, content_key)>.aot``; avals cover every
+  dynamic argument's shape/dtype (the shape bucket), the devices are the
+  ones the arguments pin the program to (:func:`execution_devices` — the
+  executable is loaded back onto exactly those), statics are the
+  jit-static kwargs, and
   ``content_key`` is the caller's optional data fingerprint (serving
   passes ``ellbfs.snapshot_fingerprint``-style keys when results must be
   pinned to a snapshot generation);
@@ -162,6 +165,41 @@ def _aval_sig(x: Any) -> str:
     return ";".join(parts)
 
 
+def execution_devices(args: Any) -> tuple:
+    """The devices ``jit_fn(*args)`` executes on, in the program's own
+    device order: the committed arguments' shardings (a mesh keeps its
+    ``devices.flat`` order), else — every argument uncommitted or a host
+    value — the default device, exactly where jit would place it.
+
+    A serialized executable records no devices of its own:
+    ``deserialize_and_load`` binds it to whatever it is handed, and by
+    default to EVERY local device, which turns a one-device program into
+    an n-shard one that fails at its first execute. So the cache derives
+    the devices from the arguments, keys the entry on them and loads the
+    executable onto them."""
+    import jax
+
+    committed: dict = {}
+    uncommitted: dict = {}
+    for leaf in jax.tree_util.tree_leaves(args):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is None:
+            continue
+        mesh = getattr(sharding, "mesh", None)
+        devs = (tuple(mesh.devices.flat) if mesh is not None
+                else sorted(sharding.device_set, key=lambda d: d.id))
+        # a ShapeDtypeStruct that names a sharding pins it like a
+        # committed array does
+        into = committed if getattr(leaf, "committed", True) else uncommitted
+        for d in devs:
+            into.setdefault(d.id, d)
+    found = committed or uncommitted
+    if found:
+        return tuple(found.values())
+    default = jax.config.jax_default_device
+    return (default if isinstance(default, jax.Device) else jax.devices()[0],)
+
+
 @dataclass
 class AOTCache:
     """One fingerprinted cache directory + an in-process compiled memo.
@@ -266,10 +304,14 @@ class AOTCache:
         return removed
 
     # -- keys -----------------------------------------------------------------
-    def key_for(self, entry: str, args: tuple, statics: dict) -> str:
+    def key_for(self, entry: str, args: tuple, statics: dict,
+                devices: Optional[tuple] = None) -> str:
+        if devices is None:
+            devices = execution_devices(args)
         h = hashlib.sha256()
         h.update(entry.encode())
         h.update(_aval_sig(args).encode())
+        h.update(repr([int(d.id) for d in devices]).encode())
         h.update(repr(sorted(statics.items())).encode())
         h.update(self.content_key.encode())
         safe = "".join(c if c.isalnum() or c in "._-" else "_"
@@ -294,13 +336,14 @@ class AOTCache:
         shape generation, synchronously, on a serving thread — and
         nothing evicts the superseded files."""
         statics = statics or {}
-        key = self.key_for(entry, args, statics)
+        devices = execution_devices(args)
+        key = self.key_for(entry, args, statics, devices)
         compiled = self._mem.get(key)
         if compiled is not None:
             self.stats.hits += 1
             self.stats.mem_hits += 1
             return compiled
-        compiled = self._load(key)
+        compiled = self._load(key, devices)
         if compiled is not None:
             self.stats.hits += 1
             self.stats.disk_hits += 1
@@ -324,7 +367,7 @@ class AOTCache:
         return self.stats.hits > before
 
     # -- disk -----------------------------------------------------------------
-    def _load(self, key: str):
+    def _load(self, key: str, devices: tuple):
         path = self._path(key)
         if not os.path.exists(path):
             return None
@@ -350,7 +393,9 @@ class AOTCache:
         try:
             from jax.experimental import serialize_executable as se
 
-            return se.deserialize_and_load(payload, in_tree, out_tree)
+            return se.deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=devices,
+            )
         except Exception as e:  # noqa: BLE001 - runtime rejected the blob
             log.warning("aot cache entry %s failed to deserialize (%s: %s)"
                         " — rebuilding", path, type(e).__name__, e)

@@ -125,9 +125,7 @@ def _scatter_relation(
         jnp.zeros((K, m_dest), dtype=bool),
         jnp.zeros((K,), dtype=jnp.int32),
     )
-    if varying_axis is not None and hasattr(jax.lax, "pcast"):
-        # jax >= 0.6 tracks varying-ness explicitly; 0.4.x shard_map has no
-        # varying type system, so a replicated init is accepted as-is
+    if varying_axis is not None:
         init = jax.lax.pcast(init, (varying_axis,), to="varying")
     (dest, cnt), _ = jax.lax.scan(body, init, (src, dst))
     return pack_bits(dest), cnt

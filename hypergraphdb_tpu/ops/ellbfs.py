@@ -4,8 +4,8 @@ Round 2's ``ops/bitfrontier.py`` made 10M-atom frontiers *fit* (bit-packed
 ``(K, W)`` bitmaps) but not *fast*: its push scan does a ``test_bits``
 gather plus an ``.at[:, d].max`` scatter **per (seed, edge)** — K×E scalar
 probes per hop. Measured on v5e, XLA lowers both to a latency-bound unit
-running ~10⁸ indices/s, which is why BENCH_r02 saw 324 s/run and <1% of
-HBM (VERDICT r2 Weak #2).
+running ~10⁸ indices/s, which is why the round-2 bench saw 324 s/run and
+<1% of HBM (VERDICT r2 Weak #2).
 
 This module keeps the same BFS semantics (``SimpleALGenerator`` neighbor
 rule: frontier atom → incident links → their targets, reference
@@ -202,9 +202,8 @@ def _reduce_level(
     E = idx.shape[0]
     Kw = values.shape[1]
     n_out = E // w
-    if (use_pallas and Kw % 128 == 0 and E >= _pg.MIN_INDICES
-            and _pg.SEG % (_pg.G * w) == 0
-            and _pg._vmem_bytes(w, Kw) <= _pg.VMEM_BUDGET):
+    if (use_pallas and E >= _pg.MIN_INDICES
+            and _pg.declined(w, Kw) is None):
         return _pg.gather_or(values, idx, w)
     if E <= chunk * w:
         g = values[idx]
@@ -839,10 +838,11 @@ def bfs_pull(
                                      count_edges=count_edges)
             )
             continue
-        # wide blocks (k_block % 4096 == 0 → 128-lane rows) run the Pallas
-        # gather when it preflights on this backend; everything else keeps
-        # the XLA gather (same measured descriptor rate, no width limits)
-        use_pallas = len(block) % 4096 == 0 and _pg.pallas_ok()
+        # 4096-seed blocks (128-lane rows, the one width the kernel
+        # compiles at) run the Pallas gather on a TPU; everything else
+        # keeps the XLA gather (no width limits)
+        use_pallas = (len(block) == _pg.ROW_WORDS * WORD
+                      and _pg.pallas_ok())
         blocks.append(
             _bfs_pull_device(
                 dev["levels1"], plans.stage1.widths,

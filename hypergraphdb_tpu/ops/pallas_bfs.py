@@ -40,11 +40,13 @@ Narrow seed blocks (K < 4096) still run fused at 128 lanes; the spare
 words are zero and sliced off on exit.
 
 Fallback contract: everything here is gated — :func:`pallas_bfs_ok`
-probes the backend once (CPU/older toolchains → False), plan builders
-decline geometries whose SMEM/VMEM windows exceed budget, and callers
-(``ellbfs.bfs_pull``, ``ops/serving``) keep the unfused chain as the
-fallback path, so CPU tier-1 exercises the exact same entry points with
-``use_pallas`` resolving to False.
+is False off-TPU (decided from the platform) and on a TPU probes the
+kernel once, RAISING if the chip's compiler refuses it; plan builders
+decline, with a reason, geometries whose SMEM/VMEM windows exceed budget
+and row widths Mosaic refuses; and callers (``ellbfs.bfs_pull``,
+``ops/serving``) keep the unfused chain for what is declined, so CPU
+tier-1 exercises the exact same entry points with ``use_pallas``
+resolving to False.
 """
 
 from __future__ import annotations
@@ -84,8 +86,10 @@ B = 8
 SEG_BLOCKS = 256
 #: in-flight DMA slots (D*W outstanding row copies)
 D = 8
-#: minimum lane width of a visited row (Mosaic VMEM window constraint —
-#: narrower blocks fail to compile; also the 512-byte descriptor lever)
+#: lane width of a visited row — the ONLY one the kernel compiles at:
+#: narrower VMEM windows fail Mosaic, and wider rows fail its tiling check
+#: on the single-row DMA (``plan_supported`` declines them); also the
+#: 512-byte descriptor lever
 KWP_MIN = 128
 #: per-core SMEM budget for the scalar-prefetched chunk plan (matches
 #: hglint HG503's model); we claim at most half, like pallas_gather.SEG
@@ -704,32 +708,33 @@ def first_r_from_bitmap(visited: jax.Array, n1: jax.Array,
 # ----------------------------------------------------------------- gating
 
 
-_PREFLIGHT: dict[str, bool] = {}
+#: backends whose probe has passed (a failed probe raises, so it is never
+#: recorded: the next caller sees the same error, not a quiet fallback)
+_PROBED: set = set()
 
 
 def pallas_bfs_ok() -> bool:
-    """True when the fused hop kernel compiles and runs correctly on the
-    default backend — probed once with a tiny instance, cached. Guarded
-    by ``HG_PALLAS_BFS`` (default on)."""
+    """Does the fused hop kernel serve on the default backend? Decided
+    from the PLATFORM: off anywhere but a TPU (CPU tier-1 runs the same
+    entry points through the unfused chain), vetoed by ``HG_PALLAS_BFS=0``.
+    On a TPU the kernel is probed once with a tiny instance, and a probe
+    that Mosaic refuses or that answers wrong RAISES — a chip that cannot
+    run its own kernel is a fault to surface, never a reason to serve the
+    XLA chain without saying so."""
     if os.environ.get("HG_PALLAS_BFS", "1") in ("0", "false", "no"):
         return False
     backend = jax.default_backend()
-    hit = _PREFLIGHT.get(backend)
-    if hit is not None:
-        return hit
     if backend != "tpu":
-        _PREFLIGHT[backend] = False
         return False
-    try:
-        ok = _probe()
-    except Exception:  # noqa: BLE001 - any compile/runtime failure → fallback
-        ok = False
-    _PREFLIGHT[backend] = ok
-    return ok
+    if backend not in _PROBED:
+        _probe()
+        _PROBED.add(backend)
+    return True
 
 
-def _probe() -> bool:
-    """A 2-block, 1-segment instance with a known OR pattern."""
+def _probe() -> None:
+    """A 2-block, 1-segment instance with a known OR pattern; raises what
+    the compiler raises, or ``RuntimeError`` on a wrong answer."""
     kwp = KWP_MIN
     n_rows = 2 * B
     visited = jnp.zeros((n_rows, kwp), jnp.uint32).at[0, 0].set(
@@ -742,7 +747,11 @@ def _probe() -> bool:
     out = _hop_call(blk_off[0], chunk_rows[0], idx[0], visited, visited,
                     nb=2, w=W, interpret=False)
     res = np.asarray(out)
-    return bool(res[1, 0] == 1 and res[0, 0] == 1 and res[2:].sum() == 0)
+    if not (res[1, 0] == 1 and res[0, 0] == 1 and res[2:].sum() == 0):
+        raise RuntimeError(
+            "pallas_bfs probe: the fused hop kernel compiled but answered "
+            f"wrong on {jax.devices()[0].device_kind}"
+        )
 
 
 def fused_ready(snap: CSRSnapshot, k_block: int) -> bool:
@@ -758,9 +767,16 @@ def plan_supported(snap: CSRSnapshot, k_block: int) -> Optional[str]:
     """None when the fused plan fits the budget model for this block
     width; otherwise the human-readable reason it must fall back."""
     kwp = max(_ceil_to(max(k_block, WORD) // WORD, KWP_MIN), KWP_MIN)
+    if kwp != KWP_MIN:
+        # cheap declines before the O(E) plan build
+        return (f"visited rows of {kwp} words ({k_block} seeds): Mosaic "
+                f"accepts the kernel's single-row DMA only at {KWP_MIN}-"
+                f"word rows — wider is refused with 'Slice shape along "
+                f"dimension 0 must be aligned to tiling (8), but is 1' "
+                f"(v5e, tests/test_tpu_compile.py); block seeds at "
+                f"{KWP_MIN * WORD} or fewer")
     if _vmem_bytes(kwp) > VMEM_BUDGET:
-        # cheap decline before the O(E) plan build; snapshot plans are
-        # always built at the default chunk width W
+        # snapshot plans are always built at the default chunk width W
         return (f"VMEM working set {_vmem_bytes(kwp)} B exceeds the "
                 f"{VMEM_BUDGET} B budget at kwp={kwp}")
     plan = fused_plans_for(snap)

@@ -29,8 +29,9 @@ state to fit wide blocks in HBM. The kernel is kept as the default TPU
 path at supported widths (it edges out XLA slightly and pins the layout),
 with the XLA gather as the fallback everywhere else.
 
-Constraints (Mosaic, this toolchain): rows must be a multiple of 128 lanes
-(Kw % 128 == 0 — narrower VMEM blocks fail to compile), and the
+Constraints (Mosaic, this toolchain): rows must be exactly 128 lanes
+(``ROW_WORDS`` — narrower VMEM blocks fail to compile, and at 256+ the
+single-row DMA fails the tiling check; :func:`declined`), and the
 scalar-prefetched index segment must fit the 1 MB SMEM, so long index
 arrays are processed in ``SEG``-index segments under ``lax.scan``.
 """
@@ -47,15 +48,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from hypergraphdb_tpu import verify as hgverify
-
-try:  # DMA priorities landed after 0.4.x; harmless to drop when absent
-    import inspect
-
-    _COPY_PRIORITY = "priority" in inspect.signature(
-        pltpu.AsyncCopyDescriptor.start
-    ).parameters
-except Exception:  # pragma: no cover - defensive: API moved
-    _COPY_PRIORITY = False
 
 #: per-core SMEM budget the scalar-prefetched index segment must fit
 #: (matches hglint HG503's model of PrefetchScalarGridSpec operands)
@@ -80,6 +72,8 @@ MIN_INDICES = 1 << 15
 #: per-core VMEM budget the kernel's working set must fit (see
 #: ``_vmem_bytes``); matches hglint HG501's default budget
 VMEM_BUDGET = 16 << 20
+#: the one row width (uint32 words; 4096 seeds) the kernel compiles at
+ROW_WORDS = 128
 
 
 def _vmem_bytes(w: int, Kw: int) -> int:
@@ -92,6 +86,28 @@ def _vmem_bytes(w: int, Kw: int) -> int:
     return 4 * Kw * (2 * G + D * w)
 
 
+def declined(w: int, Kw: int) -> str | None:
+    """None when the kernel serves ``w``-wide chunks of ``Kw``-word rows;
+    otherwise the reason callers must take the XLA gather. The ONE gate:
+    ``gather_or`` raises it, ``ellbfs._reduce_level`` routes on it, and
+    ``tests/test_tpu_compile.py`` holds it to what the v5e compiler
+    accepts."""
+    if Kw != ROW_WORDS:
+        return (f"rows of {Kw} words: Mosaic accepts the single-row DMA "
+                f"only at {ROW_WORDS}-word rows (narrower VMEM blocks fail "
+                f"to compile; wider is refused with 'Slice shape along "
+                f"dimension 0 must be aligned to tiling (8), but is 1')")
+    if SEG % (G * w):
+        # segmenting slices idx in SEG blocks of whole G-chunk groups; a
+        # width that doesn't divide them would truncate the grid to zero
+        # and return an unwritten buffer
+        return f"w={w} must divide SEG/G={SEG // G}"
+    if _vmem_bytes(w, Kw) > VMEM_BUDGET:
+        return (f"VMEM working set {_vmem_bytes(w, Kw)} B (w={w}, "
+                f"Kw={Kw}) exceeds the {VMEM_BUDGET} B per-core budget")
+    return None
+
+
 def _kernel(idx_ref, values, out_ref, rows, sems, *, w, Kw):
     g = pl.program_id(0)
 
@@ -99,15 +115,11 @@ def _kernel(idx_ref, values, out_ref, rows, sems, *, w, Kw):
         base = g * G * w + c * w
         rbase = slot * w
         for j in range(w):
-            copy = pltpu.make_async_copy(
+            pltpu.make_async_copy(
                 values.at[pl.ds(idx_ref[base + j], 1), :],
                 rows.at[pl.ds(rbase + j, 1), :],
                 sems.at[slot],
-            )
-            if _COPY_PRIORITY:
-                copy.start(priority=j % 2)
-            else:
-                copy.start()
+            ).start()
 
     for p in range(D):
         start(p, p)
@@ -164,23 +176,15 @@ def gather_or(values: jax.Array, idx: jax.Array, w: int,
               interpret: bool = False) -> jax.Array:
     """``OR over groups of w``: returns ``(len(idx)//w, Kw)`` uint32 where
     row c = OR of ``values[idx[c*w : (c+1)*w]]``. ``len(idx) % w == 0`` and
-    ``Kw % 128 == 0`` required. Trace-safe (callable under jit)."""
+    a shape :func:`declined` admits required. Trace-safe (callable under
+    jit)."""
     E = idx.shape[0]
     Kw = values.shape[1]
-    if E % w or Kw % 128:
-        raise ValueError(f"gather_or: need len(idx) % {w} == 0 and "
-                         f"Kw % 128 == 0, got E={E} Kw={Kw}")
-    if SEG % (G * w):
-        # segmenting slices idx in SEG blocks of whole G-chunk groups; a
-        # width that doesn't divide them would truncate the grid to zero
-        # and return an unwritten buffer
-        raise ValueError(f"gather_or: w={w} must divide SEG/G={SEG // G}")
-    if _vmem_bytes(w, Kw) > VMEM_BUDGET:
-        raise ValueError(
-            f"gather_or: VMEM working set {_vmem_bytes(w, Kw)} B "
-            f"(w={w}, Kw={Kw}) exceeds the {VMEM_BUDGET} B per-core "
-            f"budget; narrow the rows or fall back to the XLA gather"
-        )
+    if E % w:
+        raise ValueError(f"gather_or: need len(idx) % {w} == 0, got E={E}")
+    why = declined(w, Kw)
+    if why is not None:
+        raise ValueError(f"gather_or: {why}")
     n_out = E // w
     # pad to whole G-chunk blocks (pad chunks gather row 0 and are sliced
     # off — chunks are independent, so garbage rows never mix in)
@@ -206,31 +210,36 @@ def _ceil(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-_PREFLIGHT: dict[str, bool] = {}
+#: backends whose probe has passed (a failed probe raises and is never
+#: recorded — see :func:`pallas_ok`)
+_PROBED: set = set()
 
 
 def pallas_ok() -> bool:
-    """True when the kernel compiles and runs on the default backend —
-    probed once with a tiny instance, cached. Guarded by
-    ``HG_PALLAS_GATHER`` (default on)."""
+    """Does the kernel serve on the default backend? Decided from the
+    PLATFORM: off anywhere but a TPU, vetoed by ``HG_PALLAS_GATHER=0``. On
+    a TPU it is probed once with a tiny instance, and a probe that Mosaic
+    refuses or that answers wrong RAISES: the caller asked for the chip's
+    kernel, and running the XLA gather instead without saying so would
+    hide a broken chip path behind correct answers."""
     if os.environ.get("HG_PALLAS_GATHER", "1") in ("0", "false", "no"):
         return False
     backend = jax.default_backend()
-    hit = _PREFLIGHT.get(backend)
-    if hit is not None:
-        return hit
     if backend != "tpu":
-        _PREFLIGHT[backend] = False
         return False
-    try:
-        vals = jnp.arange(8 * 128, dtype=jnp.uint32).reshape(8, 128)
+    if backend not in _PROBED:
+        vals = jnp.arange(8 * ROW_WORDS, dtype=jnp.uint32).reshape(
+            8, ROW_WORDS)
         idx = jnp.asarray(np.tile(np.arange(8, dtype=np.int32), G))
         out = gather_or(vals, idx, 8)
         expect = np.bitwise_or.reduce(
-            np.asarray(vals)[np.asarray(idx)].reshape(-1, 8, 128), axis=1
+            np.asarray(vals)[np.asarray(idx)].reshape(-1, 8, ROW_WORDS),
+            axis=1,
         )
-        ok = bool(np.array_equal(np.asarray(out), expect))
-    except Exception:  # noqa: BLE001 - any compile/runtime failure → XLA path
-        ok = False
-    _PREFLIGHT[backend] = ok
-    return ok
+        if not np.array_equal(np.asarray(out), expect):
+            raise RuntimeError(
+                "pallas_gather probe: the kernel compiled but answered "
+                f"wrong on {jax.devices()[0].device_kind}"
+            )
+        _PROBED.add(backend)
+    return True

@@ -575,10 +575,7 @@ def and_incident_pattern(
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def device_intersect_sorted(arrays: Sequence[np.ndarray]) -> np.ndarray:
@@ -586,8 +583,8 @@ def device_intersect_sorted(arrays: Sequence[np.ndarray]) -> np.ndarray:
     query planner for large intersections (``IntersectPlan``).
 
     On TPU, VMEM-sized inputs take the Pallas tiled-compare kernel
-    (~3× the XLA searchsorted path on-device, see ``ops/pallas_kernels``);
-    everything else falls back to vectorized searchsorted."""
+    (see ``ops/pallas_kernels``); everything else takes the vectorized
+    searchsorted."""
     arrays = sorted(arrays, key=len)
     base = arrays[0]
     if len(base) == 0:
@@ -600,15 +597,9 @@ def device_intersect_sorted(arrays: Sequence[np.ndarray]) -> np.ndarray:
         )
 
         if fits_vmem(len(base), len(arrays) - 1, L):
-            try:
-                return intersect_sorted_pallas(arrays)
-            except Exception:
-                import logging
-
-                logging.getLogger("hypergraphdb_tpu.ops").warning(
-                    "pallas intersection failed; searchsorted fallback",
-                    exc_info=True,
-                )
+            # a kernel the chip refuses raises: fits_vmem is the gate, and
+            # a compiler failure is a fault, not a shape to route around
+            return intersect_sorted_pallas(arrays)
     base_p = pad_sorted(base.astype(np.int32), L)
     others = np.stack([pad_sorted(a.astype(np.int32), L) for a in arrays[1:]])
     mask = np.asarray(intersect_mask_many(jnp.asarray(base_p), jnp.asarray(others)))

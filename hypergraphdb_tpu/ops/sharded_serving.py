@@ -12,7 +12,7 @@ no longer has to fit one chip's HBM:
   per hop, two all-gathers of packed frontier words cross ICI), with the
   result compaction ALSO on the mesh: each device counts + top-``r``'s
   its own row range, counts ``psum`` up, and the per-device candidate
-  windows ``all_gather`` + merge into the global ``top_r`` smallest ids
+  windows gather (``psum`` of slot-placed windows) + merge into the global ``top_r`` smallest ids
   — O(K · n_dev · top_r) ints on ICI however large the graph.
 - :func:`pattern_serve_batch_sharded` — K conjunctive incident patterns,
   CANDIDATE-sharded: the smallest anchor's incidence row (host-gathered
@@ -49,12 +49,10 @@ from hypergraphdb_tpu.ops.bitfrontier import unpack_bits
 from hypergraphdb_tpu.ops.setops import ELL_MAX_WIDTH, SENTINEL, _bucket
 from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 from hypergraphdb_tpu.parallel.sharded import (
-    _SHARD_MAP_KW,
     AXIS,
     ShardedDelta,
     ShardedSnapshot,
     bfs_packed_sharded_delta,
-    shard_map,
 )
 
 
@@ -103,14 +101,29 @@ def mesh_carrier(mesh) -> ShardedSnapshot:
 
 
 def _merge_first_r(local_first: jax.Array, top_r: int) -> jax.Array:
-    """All-gather each device's ascending candidate window and merge to
-    the global ``top_r`` smallest (SENTINEL-padded): the one collective
-    the compaction epilogues share. Runs INSIDE a shard_map region."""
-    cand = jax.lax.all_gather(local_first, AXIS, axis=1, tiled=True)
+    """Gather each device's ascending candidate window and merge to the
+    global ``top_r`` smallest (SENTINEL-padded): the one collective the
+    compaction epilogues share. Runs INSIDE a shard_map region.
+
+    The gather is a ``psum`` of each device's window placed at its own
+    slot of a zero buffer, not ``lax.all_gather``: shard_map's replication
+    check types ``all_gather`` as device-varying, which ``out_specs=P()``
+    rejects, while ``psum`` is typed replicated — and every slot has one
+    writer, so the sum IS the gathered value."""
+    k_rows, k_loc = local_first.shape
+    n_dev = jax.lax.axis_size(AXIS)
+    slot = jax.lax.axis_index(AXIS).astype(jnp.int32) * k_loc
+    cand = jax.lax.psum(
+        jax.lax.dynamic_update_slice(
+            jnp.zeros((k_rows, n_dev * k_loc), local_first.dtype),
+            local_first, (jnp.int32(0), slot),
+        ),
+        AXIS,
+    )
     short = top_r - cand.shape[1]
     if short > 0:  # tiny graphs: fewer candidate slots than top_r
         cand = jnp.concatenate(
-            [cand, jnp.full((cand.shape[0], short), SENTINEL, cand.dtype)],
+            [cand, jnp.full((k_rows, short), SENTINEL, cand.dtype)],
             axis=1,
         )
     # top_k of the negation = the top_r SMALLEST; re-negating restores
@@ -156,10 +169,9 @@ def bfs_serve_batch_sharded(
         local_first = -jax.lax.top_k(-masked, k_loc)[0]
         return counts, _merge_first_r(local_first, top_r)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         compact, mesh=sdev.mesh,
         in_specs=(P(None, AXIS),), out_specs=(P(), P()),
-        **_SHARD_MAP_KW,
     )
     return fn(visited_p)
 
@@ -251,12 +263,11 @@ def pattern_serve_batch_sharded(
         local_first = -jax.lax.top_k(-ranked, k_loc)[0]
         return counts, _merge_first_r(local_first, top_r)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=sdev.mesh,
         in_specs=(P(None, AXIS), P(None, AXIS), P(None, AXIS, None),
                   P(), P()),
         out_specs=(P(), P()),
-        **_SHARD_MAP_KW,
     )
     return fn(rows0, row0_types, tgt_tuples, anchors, type_vec)
 
@@ -402,11 +413,10 @@ def execute_join_sharded(
                                n_lanes=k_loc, sort_cols=sort_cols)
         return counts, trunc, tuples
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lane_prog, mesh=mesh,
         in_specs=(P(AXIS),) + (P(),) * len(rels),
         out_specs=(P(AXIS), P(AXIS), P(AXIS)),
-        **_SHARD_MAP_KW,
     )
     counts, trunc, tuples = fn(consts_dev, *rels)
     return JoinExecution(order=plan.order, counts=counts, trunc=trunc,

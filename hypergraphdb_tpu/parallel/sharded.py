@@ -31,28 +31,11 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from types import MappingProxyType
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # newer jax exports it at top level
-    shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental namespace only
-    from jax.experimental.shard_map import shard_map
-
-# Replication checking needs lax.pcast so bitfrontier._scatter_relation can
-# cast its scan-carry init to axis-varying; on jax builds without pcast the
-# checker would reject that carry, so disable it (the workaround jax itself
-# suggests). Keyed on the SAME probe as the pcast call site — gating it on
-# where shard_map lives would leave a version window with checking on but
-# no cast available.
-_SHARD_MAP_KW = (
-    MappingProxyType({}) if hasattr(jax.lax, "pcast")
-    else MappingProxyType({"check_rep": False})
-)
 
 from hypergraphdb_tpu import verify as hgverify
 from hypergraphdb_tpu.ops.bitfrontier import (
@@ -308,12 +291,11 @@ def bfs_packed_sharded(
         return visited, counts, levels
 
     out_levels_spec = P(None, AXIS) if with_levels else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         stepper,
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P()),
         out_specs=(P(None, AXIS), P(), out_levels_spec),
-        **_SHARD_MAP_KW,
     )
     visited, counts, levels = fn(
         sdev.inc_src, sdev.inc_dst, sdev.tgt_src, sdev.tgt_dst,
@@ -502,12 +484,11 @@ def bfs_packed_sharded_delta(
         return visited, counts, levels
 
     out_levels_spec = P(None, AXIS) if with_levels else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         stepper,
         mesh=mesh,
         in_specs=(P(AXIS),) * 9 + (P(),),
         out_specs=(P(None, AXIS), P(), out_levels_spec),
-        **_SHARD_MAP_KW,
     )
     visited, counts, levels = fn(
         sdev.inc_src, sdev.inc_dst, sdev.tgt_src, sdev.tgt_dst,
@@ -680,9 +661,8 @@ def match_candidates_sharded(
         hits = jax.vmap(lambda row: member_mask(row, cand_slice))(rows)
         return jnp.all(hits, axis=0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=(P(AXIS), P()), out_specs=P(AXIS),
-        **_SHARD_MAP_KW,
     )
     full = fn(cand, anchor_rows)
     return full[:C]

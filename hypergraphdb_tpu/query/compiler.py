@@ -599,8 +599,8 @@ class UnionPlan(Plan):
 
     ``parallel`` mirrors ``OrToParellelQuery``/``UnionResultAsync`` for
     API parity but is OFF by default: index-read children are GIL-bound,
-    and the measured thread-pool 'speedup' is 0.9× — a slight loss
-    (CALIBRATION.md §3)."""
+    and the thread-pool 'speedup' measured on the round-5 host was 0.9×
+    — a slight loss (ROADMAP queue 3 item 7)."""
 
     children: list[Plan]
     parallel: bool = False
@@ -793,7 +793,8 @@ def pipe(graph, producer_condition, key_condition):
 # ============================================================ helpers
 
 
-#: zig-zag/merge crossover, MEASURED (CALIBRATION.md §1): probing wins
+#: zig-zag/merge crossover, measured on the round-5 host (a host-side
+#: constant; tools/calibrate_duality.py is the sweep): probing wins
 #: from 4× size disparity at every tested small size (1K–100K over the
 #: 10M id space); the old 32 made 4×–32× intersections pay the merge
 ZIGZAG_RATIO = 4

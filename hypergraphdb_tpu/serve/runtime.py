@@ -269,6 +269,8 @@ class DeviceExecutor:
         #: to this graph generation (quiet rebuild on mismatch).
         self.aot = self._open_aot_cache()
         self._aot_failed = False
+        #: plan_supported reasons already logged (_note_fused_decline)
+        self._fused_declines: set = set()
         #: (epoch, new_atoms scanned, touched set | "full") —
         #: _join_dirty_info's memo
         self._join_dirty_memo: tuple = (-1, 0, frozenset())
@@ -363,6 +365,7 @@ class DeviceExecutor:
             "top_r": top_r, "widths1": kw["widths1"],
             "widths2": kw["widths2"],
         }
+        self.stats.record_bfs_fused_dispatch()
         if kw["overlay"] is None:
             args = (kw["fused"], seeds_dev, kw["n_atoms"])
             compiled = self._aot_dispatch(
@@ -692,14 +695,36 @@ class DeviceExecutor:
             return None
         from hypergraphdb_tpu.ops import pallas_bfs as _pbfs
 
+        # off-TPU this is False from the platform; on a TPU a kernel the
+        # chip refuses RAISES here and rides the launch's error ladder
+        # (serve.errors, the breaker) — never a quiet unfused answer
         if not _pbfs.pallas_bfs_ok():
             return None
         if view.dead:
             return None
-        try:
-            return _pbfs.serve_fused_kwargs(view.base, view.delta, bucket)
-        except Exception:  # noqa: BLE001 - any plan surprise → fallback
-            return None
+        kw = _pbfs.serve_fused_kwargs(view.base, view.delta, bucket)
+        if kw is None:
+            self._note_fused_decline(view, bucket)
+        return kw
+
+    def _note_fused_decline(self, view, bucket: int) -> None:
+        """The backend serves the fused kernel but this (snapshot,
+        bucket) is outside its plan windows: say so ONCE per reason —
+        the batch is about to be answered by the unfused chain, and
+        ``serve.bfs_fused_dispatches`` staying at zero should never be
+        the only trace of why."""
+        from hypergraphdb_tpu.ops import pallas_bfs as _pbfs
+
+        why = _pbfs.plan_supported(view.base, bucket)
+        if why is None or why in self._fused_declines:
+            return
+        self._fused_declines.add(why)
+        import logging
+
+        logging.getLogger("hypergraphdb_tpu.serve").warning(
+            "fused BFS declined for bucket %d; the unfused chain serves "
+            "it: %s", bucket, why,
+        )
 
     def _dispatch_cm(self, kind: str, bucket: int, statics: int):
         """The per-dispatch profiler annotation, active only when device

@@ -38,6 +38,7 @@ LEGACY_TO_DOTTED = {
     "device_dispatches": "serve.device_dispatches",
     "sharded_dispatches": "serve.sharded_dispatches",
     "range_dispatches": "serve.range_dispatches",
+    "bfs_fused_dispatches": "serve.bfs_fused_dispatches",
     "retries": "serve.retries",
     "breaker_trips": "serve.breaker_trips",
     "breaker_state": "serve.breaker_state",
@@ -117,6 +118,7 @@ DOTTED_NAMES = LANE_NAMES + PLAN_NAMES + (
     "serve.device_dispatches",
     "serve.sharded_dispatches",
     "serve.range_dispatches",
+    "serve.bfs_fused_dispatches",
     "serve.device_seconds",
     "serve.retries",
     "serve.breaker_trips",
@@ -166,6 +168,7 @@ class ServeStats:
         self._device_dispatches = r.counter("serve.device_dispatches")
         self._sharded_dispatches = r.counter("serve.sharded_dispatches")
         self._range_dispatches = r.counter("serve.range_dispatches")
+        self._bfs_fused = r.counter("serve.bfs_fused_dispatches")
         self._retries = r.counter("serve.retries")
         self._perf_errors = r.counter("serve.perf_observe_errors")
         self._join_hub = r.counter("serve.join.hub_dispatches")
@@ -213,6 +216,7 @@ class ServeStats:
             self._gated, self._cancelled, self._errors, self._host_fallbacks,
             self._batches, self._device_dispatches,
             self._sharded_dispatches, self._range_dispatches,
+            self._bfs_fused,
             self._device_seconds,
             self._join_hub, self._join_partial,
             self._retries, self._perf_errors,
@@ -429,6 +433,16 @@ class ServeStats:
         with self._lock:
             self._range_dispatches.inc()
 
+    def record_bfs_fused_dispatch(self) -> None:
+        """One BFS batch served by the fused Pallas entry
+        (``ops.serving.bfs_serve_batch_fused``), counted at the
+        kernel-call site. BFS batches the unfused chain served are the
+        rest of the lane's dispatches — a fused path that declines
+        (budget, tombstones, backend) shows here as a flat zero instead
+        of nowhere."""
+        with self._lock:
+            self._bfs_fused.inc()
+
     def record_lane(self, kind: str, path: str) -> None:
         """One request RESOLVED through lane ``(kind, path)`` — counted
         at completion (beside ``record_complete``), so the family's sum
@@ -537,6 +551,10 @@ class ServeStats:
     def range_dispatches(self) -> int:
         return self._range_dispatches.value
 
+    @property
+    def bfs_fused_dispatches(self) -> int:
+        return self._bfs_fused.value
+
     # -- reading -------------------------------------------------------------
     def occupancy(self) -> Optional[float]:
         """Mean real-lane fraction over every dispatched bucket slot."""
@@ -578,6 +596,7 @@ class ServeStats:
                 "device_dispatches": self._device_dispatches.value,
                 "sharded_dispatches": self._sharded_dispatches.value,
                 "range_dispatches": self._range_dispatches.value,
+                "bfs_fused_dispatches": self._bfs_fused.value,
                 "retries": self._retries.value,
                 "breaker_trips": self._breaker_trips.value,
                 "breaker_state": self._breaker_state.value,

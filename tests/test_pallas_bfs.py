@@ -217,10 +217,11 @@ def test_serve_fused_delta_overlay_path(graph):
         assert np.array_equal(np.asarray(f_ref), np.asarray(f_f)), hops
 
 
-def test_serve_fused_declines_without_breaking(graph):
-    """Gate behavior the runtime relies on: CPU backend preflight is
-    False (fallback exercised by the whole serve suite), and a pinned
-    view with tombstones is refused by the executor gate."""
+def test_serve_fused_declines_without_breaking(graph, monkeypatch):
+    """Gate behavior the runtime relies on: off-TPU the backend gate is
+    False from the platform (fallback exercised by the whole serve
+    suite), and a pinned view with tombstones is refused by the executor
+    gate."""
     from hypergraphdb_tpu.serve import ServeConfig
     from hypergraphdb_tpu.serve.runtime import DeviceExecutor
 
@@ -232,14 +233,56 @@ def test_serve_fused_declines_without_breaking(graph):
     view = ex.mgr.pinned_view()
     assert ex._fused_bfs_kwargs(view, 64) is None  # backend gate
     # force the backend gate open; the tombstone gate must still decline
-    pb._PREFLIGHT["cpu"] = True
-    try:
-        view2 = view._replace(dead={5})
-        assert ex._fused_bfs_kwargs(view2, 64) is None
-        # and with the gates open the kwargs bundle materializes
-        assert ex._fused_bfs_kwargs(view, 64) is not None
-    finally:
-        pb._PREFLIGHT["cpu"] = False
+    monkeypatch.setattr(pb, "pallas_bfs_ok", lambda: True)
+    view2 = view._replace(dead={5})
+    assert ex._fused_bfs_kwargs(view2, 64) is None
+    # and with the gates open the kwargs bundle materializes
+    assert ex._fused_bfs_kwargs(view, 64) is not None
+
+
+@pytest.mark.parametrize("mod,ok", [("pallas_bfs", "pallas_bfs_ok"),
+                                    ("pallas_gather", "pallas_ok")])
+def test_probe_failure_on_tpu_raises(monkeypatch, mod, ok):
+    """On a TPU backend a kernel the chip refuses must RAISE out of the
+    gate (and keep raising: a failed probe is never cached as a quiet
+    False); off-TPU the gate is False from the platform alone."""
+    import importlib
+
+    m = importlib.import_module(f"hypergraphdb_tpu.ops.{mod}")
+    gate = getattr(m, ok)
+    assert gate() is False                      # cpu: platform says no
+    monkeypatch.setattr(m.jax, "default_backend", lambda: "tpu")
+
+    def refused(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    # the real kernel entry cannot run here; make it fail the way a
+    # refusing compiler does
+    monkeypatch.setattr(m, "_hop_call" if mod == "pallas_bfs"
+                        else "gather_or", refused)
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            gate()
+    monkeypatch.setenv("HG_PALLAS_BFS" if mod == "pallas_bfs"
+                       else "HG_PALLAS_GATHER", "0")
+    assert gate() is False                      # the veto still wins
+
+
+def test_wide_rows_decline_with_a_reason(graph):
+    """Rows wider than 128 words are refused by the v5e compiler (kept
+    as a test in tests/test_tpu_compile.py), so BOTH gates must decline
+    them with a reason instead of admitting a MosaicError."""
+    from hypergraphdb_tpu.ops import pallas_gather as pg
+
+    make_random_hypergraph(graph, n_nodes=40, n_links=80, seed=3)
+    snap = graph.snapshot()
+    assert pb.plan_supported(snap, 4096) is None
+    assert "128" in pb.plan_supported(snap, 8192)
+    assert pg.declined(8, 128) is None
+    assert "128" in pg.declined(8, 256)
+    with pytest.raises(ValueError, match="128"):
+        pg.gather_or(jnp.zeros((8, 256), jnp.uint32),
+                     jnp.zeros((16,), jnp.int32), 8, interpret=True)
 
 
 def test_plan_supported_reports_budget_overflow(graph, monkeypatch):

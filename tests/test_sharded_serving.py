@@ -265,7 +265,11 @@ def test_sharded_prewarm_hits_aot_cache(tmp_path):
         make_random_hypergraph(g, n_nodes=80, n_links=160, seed=2)
         return g
 
-    cfg = _cfg(sharded=True, buckets=(16,), prewarm_aot=True,
+    # a bucket no other test dispatches: XLA:CPU cannot serialize an
+    # executable once it has RUN (its sort comparator is resolved in
+    # place — "`LessThan` is not serializable"), and lower().compile()
+    # hands back the process-cached executable of an identical program
+    cfg = _cfg(sharded=True, buckets=(24,), prewarm_aot=True,
                aot_cache_dir=str(tmp_path), prewarm_pattern_arities=(2,))
     g = build()
     rt = ServeRuntime(g, cfg)

@@ -231,7 +231,7 @@ def test_string_range_ties_verified_host_side():
 
 
 def test_value_columns_row_pack_matches_default(valued_db):
-    """The optional (N+1, 4) row-packed rank layout (CALIBRATION.md §4)
+    """The optional (N+1, 4) row-packed rank layout (ROADMAP queue 3 item 7)
     must agree bit-for-bit with the default column gathers."""
     import jax.numpy as jnp
 

@@ -3,8 +3,9 @@ item 10): the zig-zag/merge size ratio in ``query/compiler.intersect_sorted``
 and ``QueryConfig.device_min_batch`` gating host vs device intersections.
 
 Run on the TPU host: ``python tools/calibrate_duality.py``. Prints a
-machine-readable JSON block; the recorded run lives in ``CALIBRATION.md``
-and the pinned constants cite it.
+machine-readable JSON block. No run on the current chip is recorded:
+the pinned constants date from the round-5 set-up (ROADMAP queue 1
+item 3).
 """
 
 from __future__ import annotations

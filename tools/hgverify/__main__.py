@@ -19,8 +19,10 @@ import traceback
 
 
 def _pin_trace_env() -> None:
-    """Must run before the first backend touch (works even when a
-    sitecustomize already imported jax: backend init is lazy)."""
+    """Must run before the first backend touch. Nothing imports jax
+    ahead of this in the current installation (it has no sitecustomize);
+    the config update below covers a caller that already imported jax —
+    backend init is lazy, so both routes hold until the first touch."""
     os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:

@@ -21,6 +21,10 @@ CALLBACK_PRIMS = {
     "io_callback": ("HV102", "an ordered host side effect per dispatch"),
     "debug_callback": ("HV103", "host debug callback baked into the "
                                 "compiled graph"),
+    # jax.debug.print has its own primitive (jax.debug.callback keeps
+    # debug_callback)
+    "debug_print": ("HV103", "host debug print baked into the compiled "
+                             "graph"),
     "outside_call": ("HV104", "legacy host_callback staging"),
     "host_callback_call": ("HV104", "legacy host_callback staging"),
 }
@@ -79,7 +83,7 @@ class JaxprWalk:
         self.callbacks: list = []      # (prim_name, depth)
         self.collectives: list = []    # (prim_name, axes tuple)
         self.conds: list = []          # eqn
-        self.pjits: list = []          # (eqn, containing jaxpr)
+        self.pjits: list = []          # nested jax.jit equations
         self.shard_meshes: list = []   # tuple of axis names per shard_map
         self._walk(closed.jaxpr, 0)
 
@@ -92,8 +96,8 @@ class JaxprWalk:
                 self.collectives.append((name, _axes_of(eqn)))
             if name == "cond":
                 self.conds.append(eqn)
-            if name == "pjit":
-                self.pjits.append((eqn, jaxpr))
+            if name == "jit":  # a nested jax.jit call (once named pjit)
+                self.pjits.append(eqn)
             if name == "shard_map":
                 mesh = eqn.params.get("mesh")
                 axes = tuple(getattr(mesh, "axis_names", ()) or ())
@@ -104,14 +108,15 @@ class JaxprWalk:
 
 
 def _sub_jaxprs(eqn):
-    import jax
+    # jax.extend is not an attribute of a bare ``import jax``
+    from jax.extend import core as jex_core
 
     for v in eqn.params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for w in vs:
-            if isinstance(w, jax.core.ClosedJaxpr):
+            if isinstance(w, jex_core.ClosedJaxpr):
                 yield w.jaxpr
-            elif isinstance(w, jax.core.Jaxpr):
+            elif isinstance(w, jex_core.Jaxpr):
                 yield w
 
 
@@ -209,7 +214,7 @@ def _check_collectives(walk: JaxprWalk, entry, path, line, scope) -> list:
 def _check_donation(walk: JaxprWalk, entry, path, line, scope) -> list:
     findings = []
     donated_any = False
-    for eqn, containing in walk.pjits:
+    for eqn in walk.pjits:
         donated = eqn.params.get("donated_invars", ())
         if not any(donated):
             continue
@@ -217,16 +222,16 @@ def _check_donation(walk: JaxprWalk, entry, path, line, scope) -> list:
         inner = eqn.params.get("jaxpr")
         if inner is None:
             continue
-        # an input returned unchanged is pruned from the pjit body and
-        # passed through in the CONTAINING jaxpr — aliasing shows there
-        passthrough = Counter(id(v) for v in containing.outvars)
+        # an input returned unchanged stays in the nested jit's body: its
+        # own invar shows up among its outvars, once per aliased result
+        out_ids = Counter(id(v) for v in inner.jaxpr.outvars)
         out_avals = [v.aval for v in inner.jaxpr.outvars]
         for pos, (var, is_don) in enumerate(
-                zip(eqn.invars, donated)):
+                zip(inner.jaxpr.invars, donated)):
             if not is_don:
                 continue
             aval = var.aval
-            n_pass = passthrough.get(id(var), 0)
+            n_pass = out_ids.get(id(var), 0)
             if n_pass >= 2:
                 findings.append(Finding(
                     rule="HV302", path=path, line=line, scope=scope,
