@@ -599,7 +599,15 @@ def _bfs_fused(
     s_ins = []
     for _ in range(max_hops):
         if count_edges:
-            s_ins.append(_bitdot(visited, deg_f, rows))
+            # the barrier makes the hop wait for this hop's degree sum:
+            # left free, the TPU scheduler sinks every bit-dot to the end
+            # and keeps each hop's 5 GB bitmap alive for it (26.7 GB of
+            # temps at 10M rows x 3 hops, against 6.3 GB with it —
+            # tests/test_tpu_compile.py)
+            visited, s_in = jax.lax.optimization_barrier(
+                (visited, _bitdot(visited, deg_f, rows))
+            )
+            s_ins.append(s_in)
         if overlay is not None:
             ov = _overlay_reach(visited, overlay, widths1, widths2)
         visited = _hop_fused(visited, plan, geom, interpret)

@@ -67,13 +67,54 @@ def bfs_serve_batch(
         dev, delta, seeds, max_hops, with_levels=False
     )
     counts = visited.sum(axis=1).astype(jnp.int32)
-    n1 = dev.type_of.shape[0]
-    ids = jnp.arange(n1, dtype=jnp.int32)
-    masked = jnp.where(visited, ids[None, :], SENTINEL)
-    # top_k of the negation = the top_r SMALLEST reached ids; re-negating
-    # flips the descending sort back to ascending
-    first_r = -jax.lax.top_k(-masked, top_r)[0]
-    return counts, first_r
+    return counts, first_r_dense(visited, top_r)
+
+
+#: columns per step of :func:`first_r_dense`'s sweep: bounds the int32
+#: transient at K·FIRST_R_BLOCK·4 bytes and keeps each ``top_k`` narrow
+FIRST_R_BLOCK = 8192
+
+
+def first_r_dense(mask: jax.Array, top_r: int, base=0) -> jax.Array:
+    """Per row of a dense ``(K, n)`` bool mask, the ``top_r`` SMALLEST set
+    column ids (``base +`` column), ascending, SENTINEL-padded — the
+    serving compaction, swept in :data:`FIRST_R_BLOCK`-column steps with
+    a per-step ``top_k`` + merge (the ``pallas_bfs.first_r_from_bitmap``
+    discipline).
+
+    One ``top_k`` over the whole row is what this replaces: past ~270K
+    columns the v5e compiler refuses it (``TopKBatchMajorSmallK`` runs
+    out of scoped VMEM — kept as a test in ``tests/test_tpu_compile.py``),
+    and its ``(K, n)`` int32 operand is 4x the mask it ranks."""
+    K, n = mask.shape
+    rb = min(FIRST_R_BLOCK, n)
+    blk_r = min(top_r, rb)
+
+    def block_top(i):
+        # the last block's clamped start overlaps the previous one; the
+        # fresh mask drops the columns already swept
+        start = jnp.minimum(i * rb, n - rb)
+        blk = jax.lax.dynamic_slice(mask, (0, start), (K, rb))
+        col = start + jnp.arange(rb, dtype=jnp.int32)
+        hit = blk & (col >= i * rb)[None, :]
+        ranked = jnp.where(hit, (base + col)[None, :], SENTINEL)
+        # top_k of the negation = the SMALLEST ids; re-negating flips the
+        # descending sort back to ascending
+        return -jax.lax.top_k(-ranked, blk_r)[0]
+
+    # block 0 seeds the carry (so it carries the mask's own sharding type
+    # when this runs inside a shard_map region)
+    first = block_top(0)
+    if blk_r < top_r:
+        first = jnp.concatenate(
+            [first, jnp.full_like(first[:, :1], SENTINEL).repeat(
+                top_r - blk_r, axis=1)], axis=1)
+
+    def body(i, cur):
+        merged = jnp.concatenate([cur, block_top(i)], axis=1)
+        return -jax.lax.top_k(-merged, top_r)[0]
+
+    return jax.lax.fori_loop(1, -(-n // rb), body, first)
 
 
 @hgverify.entry(

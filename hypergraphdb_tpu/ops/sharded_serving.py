@@ -46,6 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from hypergraphdb_tpu import verify as hgverify
 from hypergraphdb_tpu.ops.bitfrontier import unpack_bits
+from hypergraphdb_tpu.ops.serving import first_r_dense
 from hypergraphdb_tpu.ops.setops import ELL_MAX_WIDTH, SENTINEL, _bucket
 from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 from hypergraphdb_tpu.parallel.sharded import (
@@ -164,9 +165,7 @@ def bfs_serve_batch_sharded(
         counts = jax.lax.psum(
             bits.sum(axis=1).astype(jnp.int32), AXIS
         )
-        ids = row_start + jnp.arange(n_loc, dtype=jnp.int32)
-        masked = jnp.where(bits, ids[None, :], SENTINEL)
-        local_first = -jax.lax.top_k(-masked, k_loc)[0]
+        local_first = first_r_dense(bits, k_loc, base=row_start)
         return counts, _merge_first_r(local_first, top_r)
 
     fn = jax.shard_map(

@@ -408,3 +408,27 @@ def test_pattern_launch_skips_device_delta_upload(graph):
     rt.close()
     assert (mgr.full_uploads, mgr.tail_uploads) == up0  # no upload paid
     assert fresh in fut.result(timeout=0).matches.tolist()  # still exact
+
+
+@pytest.mark.parametrize("n,top_r", [(1000, 4), (1000, 17), (257, 100),
+                                     (64, 5), (40, 64)])
+def test_first_r_dense_blocked_sweep_equals_numpy(monkeypatch, n, top_r):
+    """The blocked compaction sweep (many blocks, a ragged last block,
+    top_r wider than a block, rows with fewer hits than top_r) returns
+    exactly the smallest set ids, ascending, SENTINEL-padded."""
+    import jax.numpy as jnp
+
+    from hypergraphdb_tpu.ops import serving
+    from hypergraphdb_tpu.ops.setops import SENTINEL
+
+    monkeypatch.setattr(serving, "FIRST_R_BLOCK", 64)
+    r = np.random.default_rng(n + top_r)
+    mask = r.random((6, n)) < np.asarray([0, 0.001, 0.01, 0.1, 0.5, 1.0]
+                                         )[:, None]
+    got = np.asarray(serving.first_r_dense(jnp.asarray(mask), top_r,
+                                           base=1000))
+    for row, out in zip(mask, got):
+        ids = 1000 + np.nonzero(row)[0][:top_r]
+        want = np.full(top_r, int(SENTINEL), np.int64)
+        want[: len(ids)] = ids
+        assert out.tolist() == want.tolist()

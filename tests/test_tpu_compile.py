@@ -1,0 +1,364 @@
+"""What the TPU v5e's compiler says about the main path, kept as tests.
+
+The TPU compiler is installed here and compiles for a chip that is
+*described*, not attached (``jax.experimental.topologies``). These tests
+lower the kernels and jitted programs the served path and the 10M-atom
+kernels run, at the widths and sizes they run them, and ``compile()`` —
+which raises what the chip's compiler would raise: a Mosaic tiling
+refusal, a scoped-VMEM overflow, a program that does not fit 16 GB of HBM.
+Nothing runs, so nothing here says a word about results or speed.
+
+Rules this file keeps (the ``on-chip-measurement`` guide, section 2): the
+topology is described inside a module-scoped fixture that skips where it
+cannot be — never at import, never in a ``parametrize`` argument, never in
+``conftest.py`` — because only one process may load the TPU library and
+every xdist worker imports every test file; all such tests live in THIS
+file so one worker owns the library; the persistent compile cache is off
+around them (a compile for a described chip cannot be read back).
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from hypergraphdb_tpu import verify as hgverify
+
+#: chip_smoke.py's sizes: the kernels phase's graph
+#: (models.dbpedia_snapshot(2M, 8M)) and the serve phase's (500K entities
+#: + 1M binary links). The serve graph is 1.5M atoms and not 3M because of
+#: what this file found: the dense served BFS holds ~6.5 bytes per
+#: (seed, atom), so the default config's 1024-seed bucket needs 19.6 GB at
+#: 3M atoms and 9.8 GB at 1.5M — one chip has 16.
+ROWS_10M = 10_000_065
+SERVE_ATOMS = 1_500_000
+SERVE_EDGES = 2_000_000          # 1M binary links: 2M incidence = 2M targets
+#: what one v5e chip leaves a program of its 16 GiB
+HBM_USABLE = 15.75e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=False)
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _place(tree, sharding):
+    """The exemplar pytree with every leaf pinned to ``sharding``."""
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+
+
+def _rescaled(entry_name: str, dims: dict):
+    """A registered hgverify entry's own exemplar with its toy dimensions
+    mapped to real ones (``dims``: toy size -> real size)."""
+    import hypergraphdb_tpu.ops.join  # noqa: F401 - registers its entries
+    import hypergraphdb_tpu.ops.value_index  # noqa: F401
+
+    entry = hgverify.REGISTRY.get(entry_name)
+    raw = entry.shapes()
+    args, kwargs = ((raw[0], raw[1])
+                    if (len(raw) == 2 and isinstance(raw[1], dict)
+                        and isinstance(raw[0], (tuple, list)))
+                    else (raw, {}))
+    grow = partial(jax.tree.map, lambda s: _sds(
+        [dims.get(d, d) for d in s.shape], s.dtype))
+    return entry, grow(tuple(args)), grow(kwargs)
+
+
+# ------------------------------------------------------------------ cases
+#
+# name -> builder(place) returning (jitted_fn, args, kwargs). ``place``
+# pins an exemplar pytree to the described chip. Builders run inside the
+# test, after the fixture described the topology.
+
+
+def _case_hop_call(place, kwp=128, nb=512):
+    from hypergraphdb_tpu.ops import pallas_bfs as pb
+
+    cap = 4096
+    fn = jax.jit(partial(pb._hop_call, nb=nb, w=pb.W, interpret=False))
+    return fn, place((
+        _sds((nb + 1,), "int32"), _sds((cap,), "int32"),
+        _sds((cap * pb.W,), "int32"),
+        _sds((4 * nb * pb.B, kwp), "uint32"),
+        _sds((nb * pb.B, kwp), "uint32"),
+    )), {}
+
+
+def _case_gather_or(place, kw=128):
+    from hypergraphdb_tpu.ops import pallas_gather as pg
+
+    fn = jax.jit(partial(pg.gather_or, w=8))
+    return fn, place((_sds((1 << 20, kw), "uint32"),
+                      _sds((1 << 17,), "int32"))), {}
+
+
+def _case_membership(place):
+    from hypergraphdb_tpu.ops.pallas_kernels import _membership_call
+
+    return _membership_call, place((_sds((1024, 128), "int32"),
+                                    _sds((3, 65536), "int32"))), {}
+
+
+def _fused_plan_shapes(n_atoms: int, cap: int):
+    """DeviceFusedPlan avals + FusedGeom of an ADMITTED plan over
+    ``n_atoms`` rows (build_fused_plan's geometry arithmetic)."""
+    from hypergraphdb_tpu.ops import pallas_bfs as pb
+
+    n_blocks = -(-(n_atoms + 2) // pb.B)
+    nb = min(n_blocks, pb.SEG_BLOCKS)
+    n_seg = -(-n_blocks // nb)
+    n_rows = n_seg * nb * pb.B
+    geom = pb.FusedGeom(n_atoms=n_atoms, n_rows=n_rows, n_seg=n_seg, nb=nb,
+                        cap=cap, w=pb.W, zero_row=n_rows - 1,
+                        total_entries=n_seg * cap * pb.W)
+    assert pb._smem_bytes(cap, nb, pb.W) <= pb.SMEM_BUDGET // 2
+    plan = pb.DeviceFusedPlan(
+        blk_off=_sds((n_seg, nb + 1), "int32"),
+        chunk_rows=_sds((n_seg, cap), "int32"),
+        idx=_sds((n_seg, cap * pb.W), "int32"),
+        inc_deg=_sds((n_rows,), "int32"),
+    )
+    return plan, geom
+
+
+def _case_serve_fused(place, top_r=16):
+    from hypergraphdb_tpu.ops.serving import bfs_serve_batch_fused
+
+    plan, geom = _fused_plan_shapes(SERVE_ATOMS, cap=4096)
+    return bfs_serve_batch_fused, place(
+        (plan, _sds((1024,), "int32"), _sds((), "int32"))
+    ), dict(geom=geom, kwp=128, max_hops=2, top_r=top_r)
+
+
+def _case_serve_bfs(place, bucket=256, hops=2):
+    from hypergraphdb_tpu.ops.serving import bfs_serve_batch
+    from hypergraphdb_tpu.serve import ServeConfig
+
+    return bfs_serve_batch, place((
+        hgverify.dev_snapshot_exemplar(SERVE_ATOMS, SERVE_EDGES,
+                                       SERVE_EDGES),
+        hgverify.device_delta_exemplar(SERVE_ATOMS, 1 << 15),
+        _sds((bucket,), "int32"),
+    )), dict(max_hops=hops, top_r=ServeConfig().top_r + 1)
+
+
+def _case_serve_pattern(place, bucket=1024):
+    from hypergraphdb_tpu.ops.serving import pattern_serve_batch
+    from hypergraphdb_tpu.serve import ServeConfig
+
+    cfg = ServeConfig()
+    return pattern_serve_batch, place((
+        hgverify.dev_snapshot_exemplar(SERVE_ATOMS, SERVE_EDGES,
+                                       SERVE_EDGES),
+        _sds((SERVE_ATOMS + 1, 2), "int32"),      # ELL targets, arity 2
+        _sds((bucket, 2), "int32"), _sds((bucket,), "int32"),
+    )), dict(pad_len=cfg.pattern_pad, top_r=cfg.top_r)
+
+
+def _case_range_probe(place):
+    entry, args, kwargs = _rescaled(
+        "ops.value_index.range_probe_batch", {64: 1 << 21, 8: 1024})
+    return entry.fn, place(args), place(kwargs)
+
+
+def _case_join_hub_expand(place):
+    entry, args, kwargs = _rescaled(
+        "ops.join.join_hub_expand",
+        {33: SERVE_ATOMS + 2, 64: SERVE_EDGES, 32: SERVE_ATOMS + 1,
+         8: 4096, 4: 64},
+    )
+    statics = dict(entry.statics, rows_out=8192, n_lanes=64)
+    return entry.fn, place(args), dict(place(kwargs), **statics)
+
+
+CASES = {
+    # the four the served path and the kernels phase cannot do without
+    "hop_call[nb=512,kwp=128]": _case_hop_call,
+    "gather_or[1Mx128,128K]": _case_gather_or,
+    "membership[1024x128,3x65536]": _case_membership,
+    "bfs_serve_batch_fused[K=1024,hops=2,top_r=16]": _case_serve_fused,
+    # the unfused served BFS at the serve phase's graph: past ~270K atoms
+    # a single top_k over the row was refused (scoped VMEM) — first_r_dense
+    "bfs_serve_batch[K=1024,hops=2]": partial(_case_serve_bfs,
+                                              bucket=1024, hops=2),
+    "bfs_serve_batch[K=256,hops=3]": partial(_case_serve_bfs, bucket=256,
+                                             hops=3),
+    "pattern_serve_batch[K=1024]": _case_serve_pattern,
+    "range_probe_batch[2M,K=1024]": _case_range_probe,
+    "join_hub_expand[1.5M,R=4096]": _case_join_hub_expand,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_main_path_compiles_for_v5e(case, one_chip, no_compile_cache):
+    fn, args, kwargs = CASES[case](partial(_place, sharding=one_chip))
+    compiled = fn.lower(*args, **kwargs).compile()
+    mem = compiled.memory_analysis()
+    # one program at a time, not what else the process holds
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < HBM_USABLE, mem
+    if "hop_call" in case or "gather_or" in case or "membership" in case \
+            or "fused" in case:
+        assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not interpret
+
+
+# ------------------------------------------------- widths: gate == compiler
+
+
+@pytest.mark.parametrize("kernel", ["hop_call", "gather_or"])
+def test_wide_rows_gate_agrees_with_compiler(kernel, one_chip,
+                                             no_compile_cache):
+    """No row width exists that the gates admit and the compiler refuses:
+    128 words compiles (above) and is admitted; 256 words is refused by
+    Mosaic ('aligned to tiling (8), but is 1') and declined by the gate,
+    with the reason."""
+    from hypergraphdb_tpu.ops import pallas_bfs as pb
+    from hypergraphdb_tpu.ops import pallas_gather as pg
+
+    place = partial(_place, sharding=one_chip)
+    build = _case_hop_call if kernel == "hop_call" else _case_gather_or
+    fn, args, kwargs = (build(place, kwp=256) if kernel == "hop_call"
+                        else build(place, kw=256))
+    if kernel == "gather_or":
+        # the gate raises at trace time, before the compiler is asked
+        with pytest.raises(ValueError, match="128"):
+            fn.lower(*args, **kwargs)
+        assert pg.declined(8, 128) is None and pg.declined(8, 256)
+        return
+    # _hop_call itself is ungated (plan_supported gates its callers)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        fn.lower(*args, **kwargs).compile()
+    kwp_of = lambda k: max(pb._ceil_to(k // pb.WORD, pb.KWP_MIN),  # noqa: E731
+                           pb.KWP_MIN)
+    assert kwp_of(4096) == 128 and kwp_of(8192) == 256
+
+
+@pytest.mark.slow  # ~25 s to be refused; the fix is guarded above
+def test_whole_row_top_k_is_what_the_compiler_refuses(one_chip,
+                                                      no_compile_cache):
+    """Why ``first_r_dense`` sweeps in blocks: ONE ``top_k`` over a
+    300K-column row — the served BFS compaction as it was — runs
+    ``TopKBatchMajorSmallK`` out of scoped VMEM on this compiler."""
+    x = _place(_sds((8, 300_001), "int32"), one_chip)
+    with pytest.raises(Exception, match="vmem"):
+        jax.jit(lambda m: jax.lax.top_k(m, 17)[0]).lower(x).compile()
+
+
+# --------------------------------------- the fused BFS at the 10M-row scale
+
+
+def test_fused_bfs_fits_one_chip_at_10m_rows(one_chip, no_compile_cache):
+    """The whole jitted ``_bfs_fused`` at the kernels phase's size —
+    10,000,065 rows, a 128-word bitmap (4096 seeds), 3 hops: an input and
+    an output bitmap of 5.1 GB each plus the composed adjacency, on a
+    16 GB chip. (On the zipf benchmark graph itself the plan declines —
+    hub rows overflow the SMEM window — so this is the geometry of an
+    admitted, hub-free plan at that row count.)"""
+    from hypergraphdb_tpu.ops import pallas_bfs as pb
+
+    plan, geom = _fused_plan_shapes(ROWS_10M, cap=4096)
+    args = _place((plan, _sds((4096,), "int32"), _sds((), "int32")),
+                  one_chip)
+    compiled = pb._bfs_fused.lower(
+        *args, geom=geom, kwp=128, max_hops=3, count_edges=True,
+        clear_dummy=True,
+    ).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < HBM_USABLE, (total, mem)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------- four devices: the mesh
+
+
+def test_sharded_bfs_compiles_for_four_chips_with_a_collective(
+        topo, no_compile_cache):
+    """``bfs_serve_batch_sharded`` on a Mesh over the described 2x2: the
+    program partitions, and the compiler put collectives in."""
+    from hypergraphdb_tpu.ops.sharded_serving import bfs_serve_batch_sharded
+    from hypergraphdb_tpu.parallel.sharded import (
+        AXIS,
+        ShardedDelta,
+        ShardedSnapshot,
+    )
+    from hypergraphdb_tpu.serve import ServeConfig
+
+    devices = np.asarray(topo.devices)
+    assert devices.size == 4
+    mesh = Mesh(devices.reshape(-1), (AXIS,))
+    shard = NamedSharding(mesh, P(AXIS))
+    n_dev = 4
+    n_loc = -(-(SERVE_ATOMS + 1) // (n_dev * 128)) * 128
+    chunk = 1 << 16                  # per-device edges: whole scan chunks
+    e_loc, d_loc = -(-SERVE_EDGES // (n_dev * chunk)) * chunk, 1 << 13
+    sh = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.dtype(dt), sharding=shard)
+    sdev = ShardedSnapshot(
+        mesh=mesh, num_atoms=SERVE_ATOMS, n_loc=n_loc, edge_chunk=chunk,
+        inc_src=sh((n_dev * e_loc,), "int32"),
+        inc_dst=sh((n_dev * e_loc,), "int32"),
+        tgt_src=sh((n_dev * e_loc,), "int32"),
+        tgt_dst=sh((n_dev * e_loc,), "int32"),
+        type_of=sh((n_dev * n_loc,), "int32"),
+        is_link=sh((n_dev * n_loc,), "bool"),
+        arity=sh((n_dev * n_loc,), "int32"),
+        value_rank_hi=sh((n_dev * n_loc,), "uint32"),
+        value_rank_lo=sh((n_dev * n_loc,), "uint32"),
+    )
+    sdelta = ShardedDelta(
+        epoch=0, edge_chunk=d_loc,
+        inc_src=sh((n_dev * d_loc,), "int32"),
+        inc_dst=sh((n_dev * d_loc,), "int32"),
+        tgt_src=sh((n_dev * d_loc,), "int32"),
+        tgt_dst=sh((n_dev * d_loc,), "int32"),
+        dead=sh((n_dev * (n_loc // 32),), "uint32"),
+    )
+    seeds = jax.ShapeDtypeStruct(
+        (256,), jnp.int32, sharding=NamedSharding(mesh, P()))
+    compiled = bfs_serve_batch_sharded.lower(
+        sdev, sdelta, seeds, max_hops=2, top_r=ServeConfig().top_r + 1,
+    ).compile()
+    text = compiled.as_text()
+    assert "all-gather" in text or "all-reduce" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_USABLE
