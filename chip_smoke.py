@@ -1,0 +1,1359 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once on ONE TPU chip, through the entry points a user
+calls, and checks every answer against a plain host reference:
+
+- ``device``   JAX finds a TPU (else: exit 1, no result); the XLA compile
+               cache is placed by the rule ``bench.py`` shares.
+- ``kernels``  the 10,000,065-atom / 48M-arity benchmark snapshot
+               (``models.dbpedia_snapshot``): 1024 conjunctive patterns
+               through ``plan_pattern → execute_pattern → collect_pattern``;
+               a 3-hop pull BFS from 4096 seeds through ``ops.bfs_pull``
+               (the fused plan declines this graph — hub rows overflow its
+               SMEM window — so the staged chain runs, on the Pallas
+               gather); on a hub-free graph of the same row count the
+               fused Pallas hop against the unfused chain with the Pallas
+               and with the XLA gather; ``gather_or`` and
+               ``intersect_sorted_pallas`` at one real-width shape each.
+- ``serve``    a ``HyperGraph`` loaded through ``bulk_import`` (1.5M atoms
+               through the real store and type system), ``enable_incremental``,
+               a ``ServeRuntime`` with the default ``ServeConfig``: BFS,
+               pattern, range, join and planned requests on a quiet graph,
+               under concurrent ingest, and — after a forced compaction —
+               over the new atoms; a second runtime must warm-hit the AOT
+               cache and serve from the loaded executables; the runtime's
+               own counters must show the DEVICE answered.
+- ``--four-chips``  ONLY the mesh-sharded serving phase and what it is
+               compared with (needs four devices; the driver runs one chip).
+
+One JSON object per phase on stdout; the last line is exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+One process; never sets ``JAX_PLATFORMS``; exits non-zero the moment a
+phase fails. ``--scale tiny`` is the CPU rehearsal and is refused unless
+the caller set ``JAX_PLATFORMS=cpu``. All data comes from ``--seed``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import logging
+import os
+import sys
+import threading
+import time
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+#: sizes per scale. ``full`` is what the driver runs on the chip; ``tiny``
+#: is the CPU rehearsal (same control flow, Pallas kernels interpreted).
+SCALES = {
+    "full": dict(
+        # kernels: the r05 benchmark graph, and a hub-free graph of the
+        # same row count (T + entities + links = 10,000,065 both)
+        kern_entities=2_000_000, kern_links=8_000_000,
+        sparse_entities=5_000_000, sparse_links=5_000_000,
+        pairs=1024, seeds=4096, ref_seeds=64, hops=3,
+        gather_rows=1 << 20, gather_idx=1 << 17,
+        isect_base=60_000, isect_other=65_536,
+        # serve: 1.5M atoms (every valued atom takes a second handle, so
+        # the id space is ~3M x headroom). headroom/pad are deployment
+        # sizing: this deployment ingests 1.3% more, not 100%
+        serve_entities=500_000, serve_links=1_000_000,
+        headroom=1.2, pad_multiple=1 << 17,
+        stage1=256, stage2=128, stage3=64, new_entities=5_000,
+        shard_bfs=128, shard_pattern=128,
+    ),
+    "tiny": dict(
+        kern_entities=300, kern_links=900,
+        sparse_entities=150, sparse_links=150,
+        pairs=48, seeds=64, ref_seeds=64, hops=3,
+        gather_rows=512, gather_idx=2048,
+        isect_base=900, isect_other=1024,
+        serve_entities=420, serve_links=520,
+        headroom=1.2, pad_multiple=128,
+        stage1=48, stage2=24, stage3=24, new_entities=40,
+        shard_bfs=16, shard_pattern=16,
+    ),
+}
+
+#: link values live above every entity value, new links above those —
+#: so range windows address exactly one population
+LINK_VALUE0 = 1_000_000_000
+NEW_LINK_VALUE0 = 2_000_000_000
+
+
+class PhaseFailed(Exception):
+    """A phase's check did not hold; the message says which."""
+
+
+class NoAccelerator(Exception):
+    """JAX found no TPU and nobody asked for the CPU rehearsal."""
+
+
+def emit(obj: dict) -> None:
+    print(json.dumps(obj, sort_keys=True), flush=True)
+
+
+def require(cond, msg: str) -> None:
+    if not cond:
+        raise PhaseFailed(msg)
+
+
+#: what ``ops.serving.bfs_serve_batch`` holds per seed: its (K, N) bool
+#: frontier / visited / next / link-live / neighbour arrays (bytes per
+#: id-space slot) and the (K, E) gathers that feed its scatters (bytes per
+#: incidence entry) — fitted to the TPU compiler's memory analysis at two
+#: (N, E) points and held to a third in tests/test_tpu_compile.py
+DENSE_BFS_BYTES_PER_SLOT = 4.72
+DENSE_BFS_BYTES_PER_EDGE = 1.26
+
+
+def dense_bfs_bytes(bucket: int, id_space: int, edges: int) -> float:
+    return bucket * (DENSE_BFS_BYTES_PER_SLOT * (id_space + 1)
+                     + DENSE_BFS_BYTES_PER_EDGE * edges)
+
+
+def serve_id_space(scale: dict) -> int:
+    """The padded id space the serve phase's base snapshot packs to
+    (``SnapshotManager._assemble_and_swap``'s arithmetic): every valued
+    atom mints a value handle beside its own, the manager multiplies the
+    handle high-water by ``headroom`` and rounds up to ``pad_multiple``."""
+    atoms = scale["serve_entities"] + scale["serve_links"]
+    cap = int((2 * atoms + 4096) * scale["headroom"])
+    pm = scale["pad_multiple"]
+    return -(-cap // pm) * pm
+
+
+# ------------------------------------------------------- host references
+#
+# Plain numpy, independent of the code under test: they read the
+# generator's own arrays (or a snapshot's host target table), never a
+# device result and never the system's planners.
+
+
+def host_bfs_bits(n_ids: int, flat: np.ndarray, link_of: np.ndarray,
+                  n_links: int, seeds: np.ndarray, hops: int) -> np.ndarray:
+    """Bit-parallel BFS for up to 64 seeds: bit k of ``out[v]`` says seed
+    k reaches atom v within ``hops`` (seed included). ``flat[e]`` is the
+    target atom of incidence entry e and ``link_of[e]`` (non-decreasing)
+    its link: a hop is 'a link is live when any of its targets is
+    visited; every target of a live link is reached'."""
+    require(len(seeds) <= 64, "host_bfs_bits takes at most 64 seeds")
+    order = np.argsort(flat, kind="stable")
+    flat_s, link_s = flat[order], link_of[order]
+    grp = np.flatnonzero(np.r_[True, flat_s[1:] != flat_s[:-1]])
+    grp_ids = flat_s[grp]
+    lst = np.flatnonzero(np.r_[True, link_of[1:] != link_of[:-1]])
+    lst_ids = link_of[lst]
+    vis = np.zeros(n_ids, dtype=np.uint64)
+    np.bitwise_or.at(vis, seeds,
+                     np.uint64(1) << np.arange(len(seeds), dtype=np.uint64))
+    for _ in range(hops):
+        live = np.zeros(n_links, dtype=np.uint64)
+        live[lst_ids] = np.bitwise_or.reduceat(vis[flat], lst)
+        vis[grp_ids] |= np.bitwise_or.reduceat(live[link_s], grp)
+    return vis
+
+
+def bits_column(vis: np.ndarray, k: int) -> np.ndarray:
+    """Sorted atom ids whose bit ``k`` is set."""
+    return np.flatnonzero((vis >> np.uint64(k)) & np.uint64(1))
+
+
+def snapshot_incidence(snap):
+    """(flat, link_of, n_links) of a CSRSnapshot's HOST target table."""
+    n1 = snap.num_atoms + 1
+    off = np.asarray(snap.tgt_offsets[: n1 + 1], dtype=np.int64)
+    arity = np.diff(off)
+    flat = np.asarray(snap.tgt_flat[: int(off[-1])], dtype=np.int64)
+    link_of = np.repeat(np.arange(n1, dtype=np.int64), arity)
+    return flat, link_of, n1
+
+
+def transposed_bits(visited_t: np.ndarray, n_rows: int,
+                    n_seeds: int) -> np.ndarray:
+    """The first ``n_seeds`` (<= 64) seed columns of a transposed
+    ``(rows, Kw)`` uint32 bitmap as one uint64 word per row."""
+    lo = visited_t[:n_rows, 0].astype(np.uint64)
+    if n_seeds > 32:
+        lo |= visited_t[:n_rows, 1].astype(np.uint64) << np.uint64(32)
+    if n_seeds < 64:
+        lo &= (np.uint64(1) << np.uint64(n_seeds)) - np.uint64(1)
+    return lo
+
+
+# ------------------------------------------------------------- the phases
+
+
+class Smoke:
+    def __init__(self, scale_name: str, seed: int, rehearsal: bool):
+        self.scale_name = scale_name
+        self.s = SCALES[scale_name]
+        self.seed = seed
+        self.rehearsal = rehearsal
+        self.dev = None
+
+    # -- device ---------------------------------------------------------------
+    def phase_device(self) -> dict:
+        import jax
+
+        from hypergraphdb_tpu.utils.compile_cache import (
+            cache_entries,
+            place_compile_cache,
+        )
+
+        t0 = time.perf_counter()
+        devices = jax.devices()
+        self.dev = devices[0]
+        if self.dev.platform != "tpu" and not self.rehearsal:
+            raise NoAccelerator(self.dev.platform)
+        cache_dir = place_compile_cache(HERE)
+        self.compile_events = {"hits": 0, "misses": 0}
+
+        def on_event(name: str, **_kw) -> None:
+            if name == "/jax/compilation_cache/cache_hits":
+                self.compile_events["hits"] += 1
+            elif name == "/jax/compilation_cache/cache_misses":
+                self.compile_events["misses"] += 1
+
+        jax.monitoring.register_event_listener(on_event)
+        self.device_json = {
+            "platform": self.dev.platform,
+            "kind": self.dev.device_kind,
+            "count": len(devices),
+        }
+        return {
+            **self.device_json,
+            "rehearsal": self.rehearsal,
+            "scale": self.scale_name,
+            "seed": self.seed,
+            "compile_cache_dir": cache_dir,
+            "compile_cache_from_env": bool(
+                os.environ.get("JAX_COMPILATION_CACHE_DIR")),
+            "compile_cache_was_empty": cache_entries(cache_dir) == 0,
+            "memory_bytes_limit": (self.dev.memory_stats() or {}).get(
+                "bytes_limit"),
+            "seconds": round(time.perf_counter() - t0, 3),
+        }
+
+    def peak_bytes(self):
+        stats = self.dev.memory_stats() or {}
+        return stats.get("peak_bytes_in_use")
+
+    # -- kernels --------------------------------------------------------------
+    def phase_kernels(self) -> dict:
+        t_phase = time.perf_counter()
+        out: dict = {}
+        from hypergraphdb_tpu import models
+        from hypergraphdb_tpu.ops import pallas_bfs, pallas_gather
+
+        on_tpu = self.dev.platform == "tpu"
+        if on_tpu:
+            # a refused kernel raises out of these; False would mean the
+            # program decided to serve without its kernels
+            require(pallas_bfs.pallas_bfs_ok(), "pallas_bfs_ok() is False "
+                    "on a TPU (HG_PALLAS_BFS veto set?)")
+            require(pallas_gather.pallas_ok(), "pallas_ok() is False on a "
+                    "TPU (HG_PALLAS_GATHER veto set?)")
+        t0 = time.perf_counter()
+        snap, info = models.dbpedia_snapshot(
+            n_entities=self.s["kern_entities"], n_links=self.s["kern_links"],
+            seed=self.seed + 13,
+        )
+        out["graph"] = {"atoms": info["n_atoms"],
+                        "total_arity": info["total_arity"],
+                        "build_s": round(time.perf_counter() - t0, 1)}
+        out["pattern"] = self._leg_pattern(snap, info)
+        self._drop_device_state(snap)
+        out["bfs_zipf"] = self._leg_bfs_zipf(snap, info)
+        del snap
+        out["bfs_fused"] = self._leg_bfs_fused()
+        out["gather_or"] = self._leg_gather_or()
+        out["intersect"] = self._leg_intersect()
+        out["seconds"] = round(time.perf_counter() - t_phase, 1)
+        return out
+
+    @staticmethod
+    def _drop_device_state(snap) -> None:
+        """Free a snapshot's cached device arrays (bench.py's discipline
+        between configs): the next leg needs most of the chip."""
+        snap.__dict__.pop("device", None)  # cached_property storage
+        for attr in ("_tgt_ell", "_value_cols", "_pull_device",
+                     "_fused_device"):
+            if hasattr(snap, attr):
+                object.__delattr__(snap, attr)
+
+    def _leg_pattern(self, snap, info) -> dict:
+        import jax
+
+        from hypergraphdb_tpu.ops.setops import (
+            collect_pattern,
+            execute_pattern,
+            plan_pattern,
+        )
+
+        r = np.random.default_rng(self.seed + 1)
+        K = self.s["pairs"]
+        th = int(max(info["property_types"],
+                     key=lambda t: len(snap.type_set(t))))
+        cands = snap.type_set(th)
+        n_hit = (3 * K) // 4
+        links = cands[r.integers(0, len(cands), size=n_hit)].astype(np.int64)
+        starts = snap.tgt_offsets[links].astype(np.int64)
+        pairs = np.stack([snap.tgt_flat[starts], snap.tgt_flat[starts + 1]],
+                         axis=1).astype(np.int64)
+        e0, e1 = info["entities"]
+        pairs = np.concatenate(
+            [pairs, r.integers(e0, e1, size=(K - n_hit, 2))])
+        # cold = plan + compile + one run; warm = one run
+        t0 = time.perf_counter()
+        plan = plan_pattern(snap, pairs.astype(np.int32), th)
+        pending = execute_pattern(plan)
+        jax.block_until_ready([x for _, c, f in pending for x in (c, f)])
+        cold = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        pending = execute_pattern(plan)
+        jax.block_until_ready([x for _, c, f in pending for x in (c, f)])
+        warm = time.perf_counter() - t0
+        got = collect_pattern(plan, pending)
+        # host engine: the smaller anchor's incidence row, each candidate
+        # link kept when its own target tuple holds the other anchor and
+        # its type is the asked one
+        inc_off, inc_links = snap.inc_offsets, snap.inc_links
+        tgt_off, tgt_flat, type_of = (snap.tgt_offsets, snap.tgt_flat,
+                                      snap.type_of)
+        nonempty = 0
+        for qi, (a, b) in enumerate(pairs.tolist()):
+            if inc_off[a + 1] - inc_off[a] > inc_off[b + 1] - inc_off[b]:
+                a, b = b, a
+            want = []
+            for lk in inc_links[inc_off[a]: inc_off[a + 1]].tolist():
+                ts = tgt_flat[tgt_off[lk]: tgt_off[lk + 1]]
+                if type_of[lk] == th and (ts == b).any() and (ts == a).any():
+                    want.append(lk)
+            want = sorted(set(want))
+            nonempty += bool(want)
+            require(got[qi].tolist() == want,
+                    f"pattern {qi} {(a, b)}: device {got[qi].tolist()[:8]} "
+                    f"!= host {want[:8]}")
+        require(nonempty >= n_hit, "pattern leg: too few non-empty results")
+        return {"queries": K, "nonempty": nonempty, "equal_host": True,
+                "buckets": [int(p) for _, _, p in plan.buckets],
+                "compile_s": round(cold - warm, 3), "run_s": round(warm, 4),
+                "peak_bytes": self.peak_bytes()}
+
+    def _bfs_twice(self, fn):
+        """``fn()`` cold then warm; returns (host bitmap of the warm run,
+        reach counts, edges touched, compile_s, run_s). Each result is
+        dropped before the next run — one 4096-seed bitmap at 10M rows is
+        5.1 GB and the staged chain needs the rest of the chip."""
+        import jax
+
+        t0 = time.perf_counter()
+        res = fn()
+        jax.block_until_ready(res.visited_t)
+        cold = time.perf_counter() - t0
+        del res
+        t0 = time.perf_counter()
+        res = fn()
+        jax.block_until_ready(res.visited_t)
+        warm = time.perf_counter() - t0
+        host = np.asarray(res.visited_t)
+        reach = np.asarray(res.reach_counts)
+        edges = np.asarray(res.edges_touched)
+        del res
+        return host, reach, edges, max(cold - warm, 0.0), warm
+
+    def _check_vs_host_bfs(self, snap, seeds, host_t, reach, what) -> None:
+        n_ref = min(self.s["ref_seeds"], len(seeds))
+        flat, link_of, n1 = snapshot_incidence(snap)
+        ref = host_bfs_bits(n1, flat, link_of, n1, seeds[:n_ref],
+                            self.s["hops"])
+        got = transposed_bits(host_t, snap.num_atoms, n_ref)
+        bad = np.flatnonzero(got != ref[: snap.num_atoms])
+        require(len(bad) == 0,
+                f"{what}: visited sets differ from the host BFS at "
+                f"{len(bad)} atoms (first {bad[:4].tolist()})")
+        for k in range(n_ref):
+            require(int(reach[k]) == len(bits_column(ref, k)),
+                    f"{what}: reach count of seed {k} differs from host")
+
+    #: the gather chunk bench.py c4 runs a 4096-seed block at: the XLA
+    #: gather's transient is chunk x 8 rows of 512 bytes, and at 10M atoms
+    #: the hop's widest step has ~1 GB to spare (tests/test_tpu_compile.py)
+    PULL_CHUNK = 1 << 16
+
+    def _pull(self, snap, seeds):
+        from hypergraphdb_tpu.ops import bfs_pull
+
+        return bfs_pull(snap, seeds, self.s["hops"], chunk=self.PULL_CHUNK,
+                        k_block=self.s["seeds"])
+
+    def _counting_gather(self):
+        """Swap ``pallas_gather.gather_or`` for a counting twin: whether
+        a leg traced the Pallas gather is read from what ran."""
+        from hypergraphdb_tpu.ops import pallas_gather
+
+        calls = {"n": 0}
+        real = pallas_gather.gather_or
+
+        def counted(values, idx, w, interpret=False):
+            calls["n"] += 1
+            require(not interpret, "gather_or ran in interpret mode")
+            return real(values, idx, w, interpret)
+
+        pallas_gather.gather_or = counted
+        return calls, lambda: setattr(pallas_gather, "gather_or", real)
+
+    def _leg_bfs_zipf(self, snap, info) -> dict:
+        """3-hop pull BFS from 4096 seeds on the benchmark graph through
+        ``ops.bfs_pull``, the entry the traversal API calls, with what the
+        code selects there: the fused plan DECLINES this graph (its hub
+        rows overflow the SMEM window), so the staged chain runs, with
+        the Pallas gather under its 128-word rows. Held, for 64 seeds, to
+        the host BFS. (Fused against unfused, Pallas gather against XLA
+        gather: the next leg, on a graph the fused plan admits.)"""
+        from hypergraphdb_tpu.ops import pallas_bfs
+        from hypergraphdb_tpu.ops.ellbfs import plans_for
+
+        r = np.random.default_rng(self.seed + 2)
+        e0, e1 = info["entities"]
+        seeds = r.integers(e0, e1, size=self.s["seeds"]).astype(np.int32)
+        t0 = time.perf_counter()
+        plans_for(snap)
+        plan_s = time.perf_counter() - t0
+        declined = pallas_bfs.plan_supported(snap, self.s["seeds"])
+        calls, restore = self._counting_gather()
+        try:
+            host, reach, edges, compile_s, run_s = self._bfs_twice(
+                lambda: self._pull(snap, seeds))
+        finally:
+            restore()
+        peak = self.peak_bytes()
+        if self.dev.platform == "tpu":
+            require(calls["n"] > 0, "bfs_pull at a 4096-seed block on a "
+                    "TPU did not trace the Pallas gather")
+        self._drop_device_state(snap)
+        self._check_vs_host_bfs(snap, seeds, host, reach, "bfs_pull")
+        return {
+            "seeds": len(seeds), "hops": self.s["hops"],
+            "plan_build_s": round(plan_s, 1),
+            "fused_plan": ("admitted" if declined is None
+                           else f"declined: {declined}"),
+            "pallas_gather_traced": calls["n"] > 0,
+            "edges_touched": int(edges.sum()),
+            "compile_s": round(compile_s, 2), "run_s": round(run_s, 3),
+            "peak_bytes": peak,
+            "equal_host_seeds": min(self.s["ref_seeds"], len(seeds)),
+        }
+
+    def _sparse_snapshot(self):
+        """A hub-free graph the fused plan admits: uniform binary links,
+        ids laid out like ``dbpedia_snapshot`` (65 type atoms, entities,
+        links) so the row count matches the benchmark graph's."""
+        from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
+
+        r = np.random.default_rng(self.seed + 3)
+        T, ne, nl = 65, self.s["sparse_entities"], self.s["sparse_links"]
+        N = T + ne + nl
+        l0 = T + ne
+        type_of = np.zeros(N, dtype=np.int32)
+        type_of[l0:] = 1
+        is_link = np.zeros(N, dtype=bool)
+        is_link[l0:] = True
+        tgt_offsets = np.zeros(N + 1, dtype=np.int64)
+        tgt_offsets[l0 + 1:] = 2 * np.arange(1, nl + 1)
+        tgt_flat = (T + r.integers(0, ne, size=2 * nl)).astype(np.int32)
+        snap = CSRSnapshot.from_tables(
+            type_of, is_link, tgt_offsets, tgt_flat,
+            value_rank=np.zeros(N, dtype=np.uint64),
+        )
+        return snap, (T, l0)
+
+    def _leg_bfs_fused(self) -> dict:
+        """The fused Pallas hop at full row width (128 words = 4096 seeds)
+        on a hub-free graph of the benchmark's row count, through
+        ``ops.bfs_pull`` — against the unfused chain with the Pallas
+        gather, the unfused chain with the XLA gather, and the host BFS."""
+        from hypergraphdb_tpu.ops import pallas_bfs
+        from hypergraphdb_tpu.ops.ellbfs import PullBFSResult
+
+        t0 = time.perf_counter()
+        snap, (e0, e1) = self._sparse_snapshot()
+        build_s = time.perf_counter() - t0
+        r = np.random.default_rng(self.seed + 4)
+        seeds = r.integers(e0, e1, size=self.s["seeds"]).astype(np.int32)
+        hops, on_tpu = self.s["hops"], self.dev.platform == "tpu"
+        why = pallas_bfs.plan_supported(snap, self.s["seeds"])
+        require(why is None, f"fused plan declined the hub-free graph: {why}")
+        geom = pallas_bfs.fused_plans_for(snap).geom
+        calls = {"hop": 0, "interpret": 0}
+        real_hop = pallas_bfs._hop_call
+
+        def counted(*a, **kw):
+            calls["hop"] += 1
+            calls["interpret"] += bool(kw.get("interpret"))
+            return real_hop(*a, **kw)
+
+        def fused():
+            if on_tpu:
+                return self._pull(snap, seeds)
+            # rehearsal: the same kernel through the Pallas interpreter
+            # (bfs_pull keeps it off anywhere but a TPU)
+            vis, s_ins, reach = pallas_bfs.bfs_pull_fused(
+                snap, seeds, hops, interpret=True)
+            return PullBFSResult(vis, np.asarray(s_ins[-1]).astype(np.int64),
+                                 reach)
+
+        legs: dict = {}
+        pallas_bfs._hop_call = counted
+        try:
+            ref = self._bfs_twice(fused)
+        finally:
+            pallas_bfs._hop_call = real_hop
+        legs["fused"] = {"compile_s": round(ref[3], 2),
+                         "run_s": round(ref[4], 3),
+                         "peak_bytes": self.peak_bytes()}
+        require(calls["hop"] > 0, "the 'fused' leg never traced the "
+                "pallas_call: bfs_pull took another path")
+        if on_tpu:
+            require(calls["interpret"] == 0, "fused hop ran interpreted")
+        self._drop_device_state(snap)
+        # the unfused chain, by the program's own switches: fused hop off;
+        # then the Pallas gather off too
+        for leg, off in (("unfused_pallas_gather", ("HG_PALLAS_BFS",)),
+                         ("unfused_xla_gather", ("HG_PALLAS_BFS",
+                                                 "HG_PALLAS_GATHER"))):
+            gathers, restore = self._counting_gather()
+            for name in off:
+                os.environ[name] = "0"
+            try:
+                got = self._bfs_twice(lambda: self._pull(snap, seeds))
+            finally:
+                restore()
+                for name in off:
+                    del os.environ[name]
+            self._drop_device_state(snap)
+            require(np.array_equal(ref[0], got[0]),
+                    f"fused and {leg} visited sets differ")
+            require(np.array_equal(ref[1], got[1])
+                    and np.array_equal(ref[2], got[2]),
+                    f"fused and {leg} reach/edge counts differ")
+            legs[leg] = {"compile_s": round(got[3], 2),
+                         "run_s": round(got[4], 3),
+                         "peak_bytes": self.peak_bytes(),
+                         "pallas_gather_traced": gathers["n"] > 0}
+            del got
+        if on_tpu:
+            require(legs["unfused_pallas_gather"]["pallas_gather_traced"]
+                    and not legs["unfused_xla_gather"]["pallas_gather_traced"],
+                    f"gather legs did not take their paths: {legs}")
+        self._check_vs_host_bfs(snap, seeds, ref[0], ref[1], "fused BFS")
+        return {
+            "atoms": snap.num_atoms, "build_s": round(build_s, 1),
+            "seeds": len(seeds), "hops": hops,
+            "geom": {"n_rows": geom.n_rows, "n_seg": geom.n_seg,
+                     "nb": geom.nb, "cap": geom.cap,
+                     "entries": geom.total_entries},
+            "pallas_call_traced": calls["hop"],
+            "interpreted": bool(calls["interpret"]),
+            **legs, "legs_equal": True,
+            "equal_host_seeds": min(self.s["ref_seeds"], len(seeds)),
+        }
+
+    def _cold_warm(self, fn):
+        """``fn()`` cold (compile + run) then warm (run); the interpreted
+        rehearsal runs it once — there is no compile to separate."""
+        t0 = time.perf_counter()
+        out = fn()
+        cold = warm = time.perf_counter() - t0
+        if self.dev.platform == "tpu":
+            t0 = time.perf_counter()
+            out = fn()
+            warm = time.perf_counter() - t0
+        return out, cold, warm
+
+    def _leg_gather_or(self) -> dict:
+        import jax
+        import jax.numpy as jnp
+
+        from hypergraphdb_tpu.ops import pallas_gather as pg
+
+        r = np.random.default_rng(self.seed + 5)
+        rows, n_idx, w = self.s["gather_rows"], self.s["gather_idx"], 8
+        values = r.integers(0, 1 << 32, size=(rows, pg.ROW_WORDS),
+                            dtype=np.uint64).astype(np.uint32)
+        idx = r.integers(0, rows, size=n_idx).astype(np.int32)
+        interpret = self.dev.platform != "tpu"
+        vd, idd = jnp.asarray(values), jnp.asarray(idx)
+        out, cold, warm = self._cold_warm(lambda: jax.block_until_ready(
+            pg.gather_or(vd, idd, w, interpret)))
+        want = np.bitwise_or.reduce(
+            values[idx].reshape(-1, w, pg.ROW_WORDS), axis=1)
+        require(np.array_equal(np.asarray(out), want),
+                "gather_or differs from numpy")
+        return {"values": [rows, pg.ROW_WORDS], "indices": n_idx, "w": w,
+                "interpreted": interpret, "equal_host": True,
+                "compile_s": round(max(cold - warm, 0), 3),
+                "run_s": round(warm, 4), "peak_bytes": self.peak_bytes()}
+
+    def _leg_intersect(self) -> dict:
+        from functools import reduce
+
+        from hypergraphdb_tpu.ops.pallas_kernels import intersect_sorted_pallas
+
+        r = np.random.default_rng(self.seed + 6)
+        nb, no = self.s["isect_base"], self.s["isect_other"]
+        universe = 4 * no
+        arrays = [np.sort(r.choice(universe, size=n, replace=False))
+                  .astype(np.int64) for n in (nb, no, no, no)]
+        interpret = self.dev.platform != "tpu"
+        got, cold, warm = self._cold_warm(
+            lambda: intersect_sorted_pallas(arrays, interpret=interpret))
+        want = reduce(np.intersect1d, arrays)
+        require(np.array_equal(got, want),
+                "intersect_sorted_pallas differs from numpy")
+        return {"base": nb, "others": [3, no], "matches": int(len(want)),
+                "interpreted": interpret, "equal_host": True,
+                "compile_s": round(max(cold - warm, 0), 3),
+                "run_s": round(warm, 4)}
+
+    # -- the serve-phase graph ------------------------------------------------
+    def build_serve_graph(self) -> "ServeGraph":
+        return ServeGraph(self.s, self.seed)
+
+    # -- serve ----------------------------------------------------------------
+    def phase_serve(self) -> dict:
+        from hypergraphdb_tpu.plan import QueryPlanner
+        from hypergraphdb_tpu.serve import ServeConfig, ServeRuntime
+
+        t_phase = time.perf_counter()
+        out: dict = {}
+        sg = self.build_serve_graph()
+        out["graph"] = sg.describe()
+        g = sg.g
+        mgr = g.enable_incremental(headroom=self.s["headroom"],
+                                   pack_pad_multiple=self.s["pad_multiple"])
+        out["graph"]["id_space"] = int(mgr.base.num_atoms)
+        aot_dir = os.path.join(HERE, ".aot_cache")
+        cfg = ServeConfig(aot_cache_dir=aot_dir)
+        out["config"] = {"buckets": list(cfg.buckets), "top_r": cfg.top_r,
+                         "use_pallas_bfs": cfg.use_pallas_bfs,
+                         "aot_cache_dir": aot_dir}
+        warnings = _WarningTap().install()
+        try:
+            t0 = time.perf_counter()
+            rt = ServeRuntime(g, cfg)
+            out["runtime_start_s"] = round(time.perf_counter() - t0, 1)
+            entries = _EntryTap(rt.executor)
+            rt.attach_planner(QueryPlanner(g))
+            top_r = cfg.top_r
+            try:
+                # the second runtime: same graph, same AOT directory — it
+                # must load what the first one stored AND serve from it
+                out["aot_warm"] = self._second_runtime(sg, cfg, top_r)
+                # stage 1: a quiet graph
+                reqs = sg.requests(self.s["stage1"], self.seed + 21, "old")
+                s1 = self._drive(rt, sg, reqs, top_r)
+                require(s1["served_by_host"] == 0,
+                        f"stage 1 (quiet graph): {s1['served_by_host']} "
+                        f"requests fell back to the host: {s1['host_kinds']}")
+                out["stage1"] = s1
+                # stage 2: the same kinds of request in flight while a
+                # writer ingests a component no old seed can reach
+                reqs = sg.requests(self.s["stage2"], self.seed + 22, "old")
+                writer = threading.Thread(target=sg.ingest, name="ingest")
+                writer.start()
+                s2 = self._drive(rt, sg, reqs, top_r)
+                writer.join(timeout=600)
+                require(not writer.is_alive(), "ingest did not finish")
+                require(sg.ingest_error is None,
+                        f"ingest failed: {sg.ingest_error!r}")
+                by_contract = sum(1 for q in reqs if q["kind"] == "join")
+                require(s2["served_by_host"] <= by_contract,
+                        f"stage 2: {s2['served_by_host']} host answers, only "
+                        f"{by_contract} joins may go to the host under a "
+                        f"dirty memtable: {s2['host_kinds']}")
+                s2["host_by_contract_max"] = by_contract
+                s2["ingested_atoms"] = sg.n_new_atoms
+                out["stage2"] = s2
+                # one forced compaction bakes the new atoms into the base
+                before = mgr.compactions
+                t0 = time.perf_counter()
+                mgr._request_compact()
+                require(mgr.wait_compacted(timeout=600),
+                        "compaction did not finish")
+                require(mgr.compactions > before, "no compaction happened")
+                out["compaction"] = {
+                    "passes": mgr.compactions - before,
+                    "seconds": round(time.perf_counter() - t0, 2),
+                    "id_space": int(mgr.base.num_atoms),
+                    "edges": int(mgr.base.n_edges_inc),
+                }
+                # stage 3: answers that exist only because of the new atoms
+                reqs = sg.requests(self.s["stage3"], self.seed + 23, "new")
+                s3 = self._drive(rt, sg, reqs, top_r)
+                newer = sum(1 for q in reqs
+                            if max(q["atoms"]) >= mgr.base.num_atoms)
+                require(s3["served_by_host"] <= newer,
+                        f"stage 3 (after compaction): {s3['served_by_host']}"
+                        f" host answers, {newer} seeds are newer than the "
+                        f"base: {s3['host_kinds']}")
+                s3["seeds_newer_than_base"] = newer
+                out["stage3"] = s3
+                snap = rt.stats_snapshot()
+            finally:
+                entries.remove()
+                rt.close()
+        finally:
+            warnings.remove()
+        g.close()
+        out["bfs_entry_by_bucket"] = entries.summary()
+        out["stats"] = {k: snap.get(k) for k in (
+            "submitted", "completed", "device_dispatches", "host_fallbacks",
+            "bfs_fused_dispatches", "range_dispatches", "errors", "retries",
+            "breaker_trips", "shed_deadline", "batches", "batch_occupancy")}
+        out["aot"] = snap.get("aot")
+        out["warnings"] = warnings.messages[:8]
+        st = out["stats"]
+        require(st["device_dispatches"] > 0, "no device dispatch at all")
+        require(st["errors"] == 0, f"serve.errors = {st['errors']}")
+        require(st["breaker_trips"] == 0,
+                f"breaker tripped {st['breaker_trips']} times")
+        allowed = (out["stage2"]["host_by_contract_max"]
+                   + out["stage3"]["seeds_newer_than_base"])
+        require(st["host_fallbacks"] <= allowed,
+                f"host_fallbacks {st['host_fallbacks']} > {allowed} that go "
+                f"to the host by contract")
+        aot = out["aot"] or {}
+        require(aot.get("corrupt", 0) == 0 and aot.get("stale", 0) == 0,
+                f"AOT cache reports failures: {aot}")
+        # the dense served BFS holds (K, id space) and (K, edges) arrays:
+        # a bucket past the chip's memory cannot be prewarmed (or served)
+        # and the runtime says so in a warning — expected exactly for
+        # those buckets, a failure for any other
+        hbm = ((self.dev.memory_stats() or {}).get("bytes_limit")
+               or float("inf"))
+        need = {b: dense_bfs_bytes(b, out["compaction"]["id_space"],
+                                   out["compaction"]["edges"])
+                for b in cfg.buckets}
+        too_wide = [b for b in cfg.buckets if need[b] > hbm]
+        out["bfs_buckets_past_hbm"] = {
+            str(b): f"{need[b] / 1e9:.1f} GB of {hbm / 1e9:.1f}"
+            for b in too_wide}
+        bad = [m for m in warnings.messages if "aot" in m.lower()
+               and not any(f"bucket={b}," in m for b in too_wide)]
+        require(not bad, f"AOT warnings: {bad[:3]}")
+        driven = {int(k.split(",")[0].split("=")[1])
+                  for k in out["bfs_entry_by_bucket"]}
+        require(not driven & set(too_wide),
+                f"a BFS batch ran at a bucket past HBM: {driven}")
+        out["seconds"] = round(time.perf_counter() - t_phase, 1)
+        return out
+
+    def _second_runtime(self, sg, cfg, top_r) -> dict:
+        from hypergraphdb_tpu.serve import ServeRuntime
+
+        t0 = time.perf_counter()
+        rt2 = ServeRuntime(sg.g, cfg)
+        start_s = time.perf_counter() - t0
+        try:
+            aot = rt2.stats_snapshot().get("aot") or {}
+            require(aot.get("disk_hits", 0) > 0 and aot.get("misses", 1) == 0,
+                    f"second runtime did not warm-hit the AOT cache: {aot}")
+            # and the LOADED executables answer (on the right devices)
+            reqs = [q for q in sg.requests(32, self.seed + 20, "old")
+                    if q["kind"] in ("bfs", "pattern")]
+            res = self._drive(rt2, sg, reqs, top_r)
+            after = rt2.stats_snapshot()
+            require(after["errors"] == 0 and after["breaker_trips"] == 0,
+                    "loaded executables failed at execute time")
+            require(res["served_by_host"] == 0,
+                    "second runtime answered from the host")
+        finally:
+            rt2.close()
+        return {"start_s": round(start_s, 2), "aot": aot,
+                "requests_served": res["requests"]}
+
+    def _drive(self, rt, sg, reqs, top_r) -> dict:
+        """Submit every request, wait for every answer, then — outside
+        any timing — hold each answer to the host reference."""
+        t0 = time.perf_counter()
+        futs = [sg.submit(rt, q) for q in reqs]
+        results = [f.result(timeout=900) for f in futs]
+        wall = time.perf_counter() - t0
+        kinds: dict = {}
+        host_kinds: dict = {}
+        crossed = 0
+        for q, res in zip(reqs, results):
+            crossed += sg.check(q, res, top_r)
+            kinds[q["kind"]] = kinds.get(q["kind"], 0) + 1
+            planned_host = (q["kind"] == "planned"
+                            and res.plan.get("shape") == "host")
+            if res.served_by == "host" and not planned_host:
+                host_kinds[q["kind"]] = host_kinds.get(q["kind"], 0) + 1
+        return {"requests": len(reqs), "kinds": kinds, "all_equal_host": True,
+                "also_equal_find_all": crossed,
+                "served_by_host": sum(host_kinds.values()),
+                "host_kinds": host_kinds, "wall_s": round(wall, 2)}
+
+    # -- four chips -----------------------------------------------------------
+    def phase_sharded(self) -> dict:
+        import jax
+
+        from hypergraphdb_tpu.serve import (
+            DeviceExecutor,
+            ServeConfig,
+            ServeRuntime,
+            ShardedExecutor,
+        )
+
+        t_phase = time.perf_counter()
+        require(len(jax.devices()) == 4,
+                f"--four-chips needs 4 devices, JAX reports "
+                f"{len(jax.devices())}")
+        out: dict = {}
+        sg = self.build_serve_graph()
+        out["graph"] = sg.describe()
+        g = sg.g
+        mgr = g.enable_incremental(headroom=self.s["headroom"],
+                                   pack_pad_multiple=self.s["pad_multiple"])
+        cfg = dict(prewarm_aot=False)
+        rt_sh = ServeRuntime(g, ServeConfig(sharded=True, **cfg))
+        rt_one = ServeRuntime(g, ServeConfig(sharded=False, **cfg))
+        try:
+            require(isinstance(rt_sh.executor, ShardedExecutor)
+                    and type(rt_one.executor) is DeviceExecutor,
+                    "executors are not (sharded, single-chip)")
+            top_r = ServeConfig().top_r
+            reqs = sg.requests_of(
+                {"bfs": self.s["shard_bfs"],
+                 "pattern": self.s["shard_pattern"]}, self.seed + 31)
+            t0 = time.perf_counter()
+            futs = [sg.submit(rt_sh, q) for q in reqs]
+            got_sh = [f.result(timeout=900) for f in futs]
+            sh_wall = time.perf_counter() - t0
+            futs = [sg.submit(rt_one, q) for q in reqs]
+            got_one = [f.result(timeout=900) for f in futs]
+            for q, a, b in zip(reqs, got_sh, got_one):
+                sg.check(q, a, top_r)     # == host
+                require(a.count == b.count and a.truncated == b.truncated
+                        and np.array_equal(np.asarray(a.matches),
+                                           np.asarray(b.matches)),
+                        f"sharded != single-chip for {q}")
+                require(a.served_by == "device" and b.served_by == "device",
+                        f"served_by {a.served_by}/{b.served_by} for {q}")
+            # every device of the mesh holds a shard of the snapshot
+            view = mgr.pinned_view(sharded=True)
+            sb = view.sharded_base
+            # (real entries, not padding: pad edges carry the dummy row)
+            held = {}
+            for name in ("inc_src", "tgt_src"):
+                shards = getattr(sb, name).addressable_shards
+                held[name] = sorted(
+                    (int(s.device.id),
+                     int((np.asarray(s.data) != sb.num_atoms).sum()))
+                    for s in shards)
+                # rows are owned by gid range, so a device whose range is
+                # all entities (or all headroom) holds none of a relation:
+                # the counts are printed, the check is that the arrays sit
+                # on four devices and no relation sits on one alone
+                require(len({int(s.device.id) for s in shards}) == 4
+                        and all(np.prod(s.data.shape) > 0 for s in shards),
+                        f"{name} is not laid out over the mesh")
+            real = [sum(n for name in held for d, n in held[name] if d == i)
+                    for i in sorted({d for d, _ in held["inc_src"]})]
+            require(sum(1 for n in real if n > 0) >= 2,
+                    f"every real edge sits on one device: {held}")
+            st = rt_sh.stats_snapshot()
+            require(st["sharded_dispatches"] > 0, "no sharded dispatch")
+            # every answer of the sharded runtime resolved through the
+            # mesh lane, every answer of the other through one chip
+            lanes = {f"{k}.{p}": n for (k, p), n in
+                     rt_sh.stats.lane_counts().items() if n}
+            require(lanes == {"bfs.sharded": self.s["shard_bfs"],
+                              "pattern.sharded": self.s["shard_pattern"]},
+                    f"sharded runtime lanes: {lanes}")
+            one = {f"{k}.{p}": n for (k, p), n in
+                   rt_one.stats.lane_counts().items() if n}
+            require(set(one) == {"bfs.device", "pattern.device"},
+                    f"single-chip runtime lanes: {one}")
+            require(st["errors"] == 0 and st["breaker_trips"] == 0,
+                    f"sharded runtime errors: {st}")
+            out.update({
+                "requests": len(reqs), "all_equal_host": True,
+                "equal_single_chip": True, "wall_s": round(sh_wall, 2),
+                "mesh": [int(d.id) for d in rt_sh.executor.mesh.devices.flat],
+                "shards": held,
+                "sharded_dispatches": st["sharded_dispatches"],
+                "lanes": lanes,
+                "host_fallbacks": st["host_fallbacks"],
+                "peak_bytes_per_device": [
+                    (d.memory_stats() or {}).get("peak_bytes_in_use")
+                    for d in jax.devices()],
+            })
+        finally:
+            rt_sh.close()
+            rt_one.close()
+        g.close()
+        out["seconds"] = round(time.perf_counter() - t_phase, 1)
+        return out
+
+
+# --------------------------------------------- the serve graph + references
+
+
+class ServeGraph:
+    """A store-loaded graph the shape of ``models.dbpedia_like``: entities
+    with integer values, zipf-skewed binary links carrying integer values
+    — plus everything the host references need, kept as plain arrays as
+    the data is generated (never read back from the system under test)."""
+
+    def __init__(self, s: dict, seed: int):
+        from hypergraphdb_tpu import HyperGraph
+
+        self.s = s
+        r = np.random.default_rng(seed + 11)
+        t0 = time.perf_counter()
+        self.g = g = HyperGraph()
+        ne, nl = s["serve_entities"], s["serve_links"]
+        ents = g.bulk_import(values=np.arange(ne).tolist())
+        self.e0 = int(ents[0])
+        require(len(ents) == ne and int(ents[-1]) == self.e0 + ne - 1,
+                "entity handles are not one contiguous range")
+        link_h, link_a, link_b = [], [], []
+        for s0 in range(0, nl, 100_000):
+            m = min(100_000, nl - s0)
+            subj = self.e0 + (r.zipf(1.1, size=m) % ne)
+            obj = self.e0 + r.integers(0, ne, size=m)
+            hs = g.bulk_import(
+                values=[LINK_VALUE0 + s0 + i for i in range(m)],
+                target_lists=np.stack([subj, obj], axis=1).tolist(),
+            )
+            link_h.append(np.arange(int(hs[0]), int(hs[0]) + m))
+            link_a.append(subj)
+            link_b.append(obj)
+        self.load_s = time.perf_counter() - t0
+        self.link_h = np.concatenate(link_h).astype(np.int64)
+        self.link_a = np.concatenate(link_a).astype(np.int64)
+        self.link_b = np.concatenate(link_b).astype(np.int64)
+        self.link_val = LINK_VALUE0 + np.arange(nl, dtype=np.int64)
+        self.n_old_links = nl
+        self.link_type = int(g.get_type_handle_of(int(self.link_h[0])))
+        deg = np.bincount(np.concatenate([self.link_a, self.link_b]),
+                          minlength=self.e0 + ne)
+        self.deg = deg
+        # the widest row among each entity's neighbours: a join that
+        # expands through a hub is cut by the lane's pad cap and re-served
+        # on the host BY CONTRACT — the smoke draws anchors that never do
+        self.nbr_deg = np.zeros_like(deg)
+        np.maximum.at(self.nbr_deg, self.link_a, deg[self.link_b])
+        np.maximum.at(self.nbr_deg, self.link_b, deg[self.link_a])
+        ent_ids = np.arange(self.e0, self.e0 + ne)
+        self.isolated = ent_ids[deg[ent_ids] == 0]
+        # the new component (stage 2 ingests it): new entities in a ring
+        # with chords (triangles), each also tied to an old ISOLATED
+        # entity — so nothing any old seed reaches ever changes
+        m = min(s["new_entities"], len(self.isolated))
+        require(m >= 8, "too few isolated entities to attach the ingest to")
+        self.n_new_entities = m
+        self.n_new_atoms = 0
+        self.new_e0 = None
+        self.ingest_error = None
+
+    # -- ingest ---------------------------------------------------------------
+    def ingest(self) -> None:
+        """The writer thread: 4 batches of new entities, then their links."""
+        try:
+            g, m = self.g, self.n_new_entities
+            ne = self.s["serve_entities"]
+            new_h = []
+            for part in np.array_split(np.arange(m), 4):
+                hs = g.bulk_import(values=(ne + part).tolist())
+                new_h.append(np.arange(int(hs[0]), int(hs[0]) + len(part)))
+            new_h = np.concatenate(new_h).astype(np.int64)
+            i = np.arange(m)
+            a = np.concatenate([new_h, new_h, new_h])
+            b = np.concatenate([new_h[(i + 1) % m], new_h[(i + 2) % m],
+                                self.isolated[:m]])
+            vals = NEW_LINK_VALUE0 + np.arange(3 * m, dtype=np.int64)
+            link_h = []
+            for part in np.array_split(np.arange(3 * m), 4):
+                hs = g.bulk_import(
+                    values=vals[part].tolist(),
+                    target_lists=np.stack([a[part], b[part]], axis=1)
+                    .tolist(),
+                )
+                link_h.append(np.arange(int(hs[0]), int(hs[0]) + len(part)))
+            self.new_entities = new_h
+            self.link_h = np.concatenate([self.link_h] + link_h)
+            self.link_a = np.concatenate([self.link_a, a])
+            self.link_b = np.concatenate([self.link_b, b])
+            self.link_val = np.concatenate([self.link_val, vals])
+            self.n_new_atoms = 4 * m
+        except Exception as e:  # noqa: BLE001 - reported by the main thread
+            self.ingest_error = e
+
+    def describe(self) -> dict:
+        return {"entities": self.s["serve_entities"],
+                "links": self.s["serve_links"],
+                "atoms": self.s["serve_entities"] + self.s["serve_links"],
+                "load_s": round(self.load_s, 1),
+                "atoms_per_s": round(
+                    (self.s["serve_entities"] + self.s["serve_links"])
+                    / self.load_s),
+                "max_degree": int(self.deg.max()),
+                "isolated_entities": int(len(self.isolated))}
+
+    # -- requests -------------------------------------------------------------
+    def requests(self, n: int, seed: int, where: str) -> list:
+        """``n`` requests over every lane; ``where`` picks the population
+        their anchors come from: 'old' (the loaded graph) or 'new' (the
+        ingested component — answers that depend on the new atoms)."""
+        mix = {"bfs": 0.45, "pattern": 0.22, "range": 0.11, "join": 0.11,
+               "planned": 0.11}
+        counts = {k: max(int(n * f), 1) for k, f in mix.items()}
+        counts["bfs"] += n - sum(counts.values())
+        return self.requests_of(counts, seed, where)
+
+    def requests_of(self, counts: dict, seed: int,
+                    where: str = "old") -> list:
+        r = np.random.default_rng(seed)
+        old = where == "old"
+        n_links = self.n_old_links
+        if old:
+            # calm anchors: both ends of a link whose neighbourhoods hold
+            # no wide row, so no lane is pushed to the host by a cap
+            lk = r.permutation(n_links)[: 64 * sum(counts.values())]
+            calm = lk[(self.nbr_deg[self.link_a[lk]] <= 16)
+                      & (self.nbr_deg[self.link_b[lk]] <= 16)]
+            require(len(calm) >= sum(counts.values()),
+                    "too few calm links to draw anchors from")
+            pick = iter(calm.tolist())
+            val0 = LINK_VALUE0
+            n_vals = n_links
+        else:
+            m = self.n_new_entities
+            pick = iter((n_links + r.permutation(3 * m)).tolist())
+            val0 = NEW_LINK_VALUE0
+            n_vals = 3 * m
+        out = []
+        for i in range(counts.get("bfs", 0)):
+            li = next(pick)
+            seed_atom = int(self.link_b[li] if old else self.link_a[li])
+            out.append({"kind": "bfs", "hops": 2 + (i % 2),
+                        "atoms": [seed_atom]})
+        for i in range(counts.get("pattern", 0)):
+            li = next(pick)
+            a, b = int(self.link_a[li]), int(self.link_b[li])
+            typed = i % 3 == 0
+            if i % 8 == 7:            # a pair no link joins
+                b = int(self.link_b[next(pick)])
+            out.append({"kind": "pattern", "atoms": [a, b],
+                        "type": self.link_type if typed else None})
+        for i in range(counts.get("range", 0)):
+            lo = val0 + int(r.integers(0, max(n_vals - 100, 1)))
+            width = int(r.integers(0, 60))
+            out.append({"kind": "range", "lo": lo, "hi": lo + width,
+                        "desc": i % 4 == 3, "atoms": [0]})
+        for i in range(counts.get("join", 0)):
+            li = next(pick)
+            a = int(self.link_b[li] if old else self.link_a[li])
+            out.append({"kind": "join", "atoms": [a]})
+        for i in range(counts.get("planned", 0)):
+            li = next(pick)
+            a, b = int(self.link_a[li]), int(self.link_b[li])
+            v = int(self.link_val[li])
+            out.append({"kind": "planned", "atoms": [a, b],
+                        "window": None if i % 2 else (v - 20, v + 20)})
+        order = r.permutation(len(out))
+        return [out[i] for i in order]
+
+    @staticmethod
+    def _path2(a: int) -> dict:
+        """The join spec: two-step paths a — y — z."""
+        from hypergraphdb_tpu.query import conditions as c
+        from hypergraphdb_tpu.query.variables import var
+
+        return {"y": c.CoIncident(a), "z": c.CoIncident(var("y"))}
+
+    @staticmethod
+    def _condition(q: dict):
+        """A planned request's condition: links on both anchors, or links
+        on the second anchor whose value lies in a window."""
+        from hypergraphdb_tpu.query import conditions as c
+
+        a, b = q["atoms"]
+        if q["window"] is None:
+            return c.And(c.Incident(a), c.Incident(b))
+        lo, hi = q["window"]
+        return c.And(c.AtomValue(lo, "gte"), c.AtomValue(hi, "lte"),
+                     c.Incident(b))
+
+    def submit(self, rt, q: dict):
+        k = q["kind"]
+        if k == "bfs":
+            return rt.submit_bfs(q["atoms"][0], max_hops=q["hops"])
+        if k == "pattern":
+            return rt.submit_pattern(q["atoms"], type_handle=q["type"])
+        if k == "range":
+            return rt.submit_range(q["lo"], q["hi"], desc=q["desc"])
+        if k == "join":
+            return rt.submit_join(self._path2(q["atoms"][0]))
+        return rt.submit_planned(self._condition(q))
+
+    # -- references -----------------------------------------------------------
+    def _bfs_ref(self, seed_atom: int, hops: int) -> np.ndarray:
+        key = (seed_atom, hops, len(self.link_h))
+        memo = self.__dict__.setdefault("_bfs_memo", {})
+        if key not in memo:
+            n_ids = int(max(self.link_h.max(), self.link_a.max(),
+                            self.link_b.max())) + 1
+            L = len(self.link_h)
+            flat = np.stack([self.link_a, self.link_b], axis=1).reshape(-1)
+            link_of = np.repeat(np.arange(L, dtype=np.int64), 2)
+            vis = host_bfs_bits(n_ids, flat, link_of, L,
+                                np.asarray([seed_atom]), hops)
+            memo[key] = bits_column(vis, 0)
+        return memo[key]
+
+    def prime_bfs_refs(self, reqs: list) -> None:
+        """Bit-parallel: the references of up to 64 BFS requests per pass."""
+        memo = self.__dict__.setdefault("_bfs_memo", {})
+        L = len(self.link_h)
+        todo: dict = {}
+        for q in reqs:
+            if q["kind"] == "bfs":
+                key = (q["atoms"][0], q["hops"], L)
+                if key not in memo:
+                    todo.setdefault(q["hops"], set()).add(q["atoms"][0])
+        if not todo:
+            return
+        n_ids = int(max(self.link_h.max(), self.link_a.max(),
+                        self.link_b.max())) + 1
+        flat = np.stack([self.link_a, self.link_b], axis=1).reshape(-1)
+        link_of = np.repeat(np.arange(L, dtype=np.int64), 2)
+        for hops, seeds in todo.items():
+            seeds = sorted(seeds)
+            for s0 in range(0, len(seeds), 64):
+                part = np.asarray(seeds[s0: s0 + 64])
+                vis = host_bfs_bits(n_ids, flat, link_of, L, part, hops)
+                for k, sd in enumerate(part.tolist()):
+                    memo[(sd, hops, L)] = bits_column(vis, k)
+
+    def _on(self, atom: int) -> np.ndarray:
+        return (self.link_a == atom) | (self.link_b == atom)
+
+    def reference(self, q: dict):
+        """The host answer of one request, from the generator's arrays:
+        a sorted id array (value order for a range), or — for the join —
+        sorted (y, z) tuples."""
+        k = q["kind"]
+        if k == "bfs":
+            if (q["atoms"][0], q["hops"], len(self.link_h)) \
+                    not in self.__dict__.get("_bfs_memo", {}):
+                self.prime_bfs_refs([q])
+            return self._bfs_ref(q["atoms"][0], q["hops"])
+        if k == "pattern":
+            hit = self._on(q["atoms"][0]) & self._on(q["atoms"][1])
+            if q["type"] is not None and q["type"] != self.link_type:
+                hit[:] = False
+            return np.sort(self.link_h[hit])
+        if k == "range":
+            sel = np.flatnonzero((self.link_val >= q["lo"])
+                                 & (self.link_val <= q["hi"]))
+            sel = sel[np.argsort(self.link_val[sel], kind="stable")]
+            return self.link_h[sel[::-1] if q["desc"] else sel]
+        if k == "planned":
+            hit = self._on(q["atoms"][1])
+            if q["window"] is None:
+                hit &= self._on(q["atoms"][0])
+            else:
+                lo, hi = q["window"]
+                hit &= (self.link_val >= lo) & (self.link_val <= hi)
+            return np.sort(self.link_h[hit])
+        # join: a - y - z over co-incidence, y != a, z != y, z != a when
+        # distinct (the lane's default); both ends of a link are neighbours
+        a = q["atoms"][0]
+
+        def nbrs(v):
+            on = self._on(v)
+            both = np.concatenate([self.link_a[on], self.link_b[on]])
+            return np.unique(both[both != v])
+
+        return sorted((int(y), int(z)) for y in nbrs(a)
+                      for z in nbrs(y) if z != a)
+
+    def check(self, q: dict, res, top_r: int) -> bool:
+        """Hold one answer to its host reference — exact count, the exact
+        prefix, an honest truncation flag — and, where the repo's own
+        host engine answers the same question in milliseconds, hold the
+        reference to ``find_all`` / ``host_join`` too. Returns whether
+        that second comparison was made."""
+        k = q["kind"]
+        want = self.reference(q)
+        if k == "join":
+            got = [tuple(int(v) for v in row) for row in res.tuples]
+            require(res.count == len(want), f"join count {res.count} != "
+                    f"host {len(want)} for {q['atoms']}")
+            require(got == want[: len(got)] and
+                    len(got) == min(len(want), top_r),
+                    f"join tuples differ from host for {q['atoms']}: "
+                    f"{got[:4]} vs {want[:4]}")
+            require(res.truncated == (res.count > len(got)),
+                    "join truncation flag is not honest")
+        else:
+            got = np.asarray(res.matches, dtype=np.int64)
+            require(res.count == len(want),
+                    f"{k} count {res.count} != host {len(want)} for {q}")
+            # a planned answer is re-served whole; a lane answers a window
+            full = len(want) if k == "planned" else min(len(want), top_r)
+            require(len(got) == full
+                    and np.array_equal(got, want[: len(got)]),
+                    f"{k} matches differ from host for {q}: "
+                    f"{got[:6].tolist()} vs {want[:6].tolist()}")
+            require(res.truncated == (res.count > len(got)),
+                    f"{k} truncation flag is not honest for {q}")
+        return self._cross_check(q, want)
+
+    def _cross_check(self, q: dict, want) -> bool:
+        """reference == the repo's host engine, for the questions it
+        answers fast (a value window is a seconds-long host scan at this
+        scale, and a hub's neighbourhood a long traversal: skipped)."""
+        from hypergraphdb_tpu import join
+        from hypergraphdb_tpu.query import dsl
+
+        k = q["kind"]
+        if k == "bfs" and len(want) <= 2000:
+            host = set(int(h) for h in self.g.find_all(
+                dsl.bfs(q["atoms"][0], max_distance=q["hops"])))
+            host.add(q["atoms"][0])     # find_all leaves the seed out
+            require(sorted(host) == want.tolist(),
+                    f"reference BFS != find_all for {q}")
+        elif k == "pattern" and q["type"] in (None, self.link_type):
+            host = sorted(int(h) for h in self.g.find_all(dsl.and_(
+                dsl.incident(q["atoms"][0]), dsl.incident(q["atoms"][1]))))
+            require(host == want.tolist(),
+                    f"reference pattern != find_all for {q}")
+        elif k == "planned" and q["window"] is None:
+            host = sorted(int(h) for h in self.g.find_all(
+                self._condition(q)))
+            require(host == want.tolist(),
+                    f"reference condition != find_all for {q}")
+        elif k == "join":
+            host = join.host_join(self.g, join.extract_pattern(
+                self.g, self._path2(q["atoms"][0])))
+            require([tuple(t) for t in host] == want,
+                    f"reference join != host_join for {q}")
+        else:
+            return False
+        return True
+
+
+class _WarningTap(logging.Handler):
+    """Collects WARNING+ records of the package's loggers for a phase: a
+    swallowed failure that was at least logged shows on the phase line."""
+
+    def __init__(self):
+        super().__init__(level=logging.WARNING)
+        self.messages: list = []
+
+    def emit(self, record) -> None:
+        self.messages.append(f"{record.name}: {record.getMessage()}"[:300])
+
+    def install(self):
+        logging.getLogger("hypergraphdb_tpu").addHandler(self)
+        return self
+
+    def remove(self) -> None:
+        logging.getLogger("hypergraphdb_tpu").removeHandler(self)
+
+
+class _EntryTap:
+    """Which BFS entry served each bucket — from what RAN: wraps the
+    executor's two BFS dispatch methods on this instance and counts."""
+
+    def __init__(self, executor):
+        self.ex = executor
+        self.counts: dict = {}
+        self._real = (executor._serve_bfs, executor._serve_bfs_fused)
+
+        def unfused(view, seeds_dev, max_hops, top_r):
+            self._note("unfused", seeds_dev, max_hops)
+            return self._real[0](view, seeds_dev, max_hops, top_r)
+
+        def fused(kw, seeds_dev, max_hops, top_r):
+            self._note("fused", seeds_dev, max_hops)
+            return self._real[1](kw, seeds_dev, max_hops, top_r)
+
+        executor._serve_bfs, executor._serve_bfs_fused = unfused, fused
+
+    def _note(self, entry, seeds_dev, hops) -> None:
+        key = f"bucket={int(seeds_dev.shape[0])},hops={hops}"
+        slot = self.counts.setdefault(key, {"fused": 0, "unfused": 0})
+        slot[entry] += 1
+
+    def remove(self) -> None:
+        del self.ex._serve_bfs, self.ex._serve_bfs_fused
+
+    def summary(self) -> dict:
+        return dict(sorted(self.counts.items()))
+
+
+# -------------------------------------------------------------------- main
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--seed", type=int, default=0,
+                    help="every graph, request and probe is generated "
+                         "from it")
+    ap.add_argument("--scale", choices=sorted(SCALES), default="full",
+                    help="'tiny' is the CPU rehearsal: refused unless the "
+                         "caller set JAX_PLATFORMS=cpu")
+    ap.add_argument("--four-chips", action="store_true",
+                    help="run ONLY the mesh-sharded serving phase and what "
+                         "it is compared with (needs four devices)")
+    args = ap.parse_args(argv)
+    rehearsal = args.scale == "tiny"
+    if rehearsal and os.environ.get("JAX_PLATFORMS") != "cpu":
+        print("chip_smoke: --scale tiny is the CPU rehearsal; set "
+              "JAX_PLATFORMS=cpu to ask for it", file=sys.stderr)
+        return 2
+    # the program itself, imported before anything is printed: a directory
+    # that holds this script and nothing else fails here, with no result
+    import hypergraphdb_tpu  # noqa: F401
+
+    smoke = Smoke(args.scale, args.seed, rehearsal)
+    phases = [("device", smoke.phase_device)]
+    phases += ([("sharded", smoke.phase_sharded)] if args.four_chips else
+               [("kernels", smoke.phase_kernels),
+                ("serve", smoke.phase_serve)])
+    t_all = time.perf_counter()
+    for name, run in phases:
+        t0 = time.perf_counter()
+        try:
+            line = run()
+        except NoAccelerator as e:
+            # no accelerator: nothing on stdout, the reason on stderr
+            print(f"chip_smoke: device phase failed: JAX found platform "
+                  f"{e}, not a TPU", file=sys.stderr)
+            return 1
+        except PhaseFailed as e:
+            emit({"phase": name, "ok": False, "error": str(e),
+                  "seconds": round(time.perf_counter() - t0, 1)})
+            return 1
+        emit({"phase": name, "ok": True, **line})
+    emit({"phase": "summary", "ok": True,
+          "seconds": round(time.perf_counter() - t_all, 1),
+          "compile_cache": smoke.compile_events})
+    print(json.dumps({"ok": True, "device": smoke.device_json}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -294,8 +294,12 @@ def _upper_levels(
         idx_g = jnp.where(
             idx == n_prev, total - 1, idx + prev_off
         ).astype(idx.dtype)
-        out = _reduce_level(buf, idx_g, w, chunk, False)
-        buf = jax.lax.dynamic_update_slice(buf, out, (off, 0))
+        # gather FROM and write INTO the one buffer (sections are
+        # disjoint: a level reads its predecessor's and writes its own) —
+        # a separate level output is a padded scan result plus its trimmed
+        # copy, ~2 GB of temps at 10M atoms x 4096 seeds that the hop's
+        # widest step has no room for
+        buf = _reduce_into(buf, off, None, idx_g, w, chunk, False)
         off += sizes[i + 1]
     return buf
 
@@ -312,7 +316,8 @@ def _reduce_into(
     """OR-reduce ``values`` rows over ``idx`` groups of ``w``, writing the
     ``len(idx)//w`` output rows into ``buf[off:]`` in place: full blocks of
     ``chunk`` outputs stream through a scan (carry = buf, aliased by XLA),
-    the ragged tail lands with one final update."""
+    the ragged tail lands with one final update. ``values=None`` gathers
+    from ``buf`` itself (the rows read must lie outside ``buf[off:]``)."""
     E = idx.shape[0]
     n_out = E // w
     n_full = n_out // chunk
@@ -321,7 +326,8 @@ def _reduce_into(
 
         def body(b, ib_i):
             ib, i = ib_i
-            out = _reduce_level(values, ib, w, chunk, use_pallas)
+            out = _reduce_level(b if values is None else values, ib, w,
+                                chunk, use_pallas)
             return jax.lax.dynamic_update_slice(
                 b, out, (off + i * chunk, 0)
             ), None
@@ -332,7 +338,8 @@ def _reduce_into(
     tail = n_out - n_full * chunk
     if tail:
         out = _reduce_level(
-            values, idx[n_full * chunk * w :], w, chunk, use_pallas
+            buf if values is None else values,
+            idx[n_full * chunk * w :], w, chunk, use_pallas
         )
         buf = jax.lax.dynamic_update_slice(
             buf, out, (off + n_full * chunk, 0)

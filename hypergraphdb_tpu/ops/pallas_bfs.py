@@ -789,6 +789,12 @@ def plan_supported(snap: CSRSnapshot, k_block: int) -> Optional[str]:
                 f"{VMEM_BUDGET} B budget at kwp={kwp}")
     plan = fused_plans_for(snap)
     g = plan.geom
+    limit = device_memory_bytes()
+    if limit is not None and fused_bytes(g, kwp) > HBM_PLAN_FRACTION * limit:
+        return (f"fused working set {fused_bytes(g, kwp) / 1e9:.1f} GB "
+                f"(two {g.n_rows}-row bitmaps + the composed adjacency) "
+                f"exceeds {HBM_PLAN_FRACTION:.0%} of the device's "
+                f"{limit / 1e9:.1f} GB")
     if _vmem_bytes(kwp, g.w) > VMEM_BUDGET:
         return (f"VMEM working set {_vmem_bytes(kwp, g.w)} B exceeds the "
                 f"{VMEM_BUDGET} B budget at kwp={kwp}, w={g.w}")
@@ -798,6 +804,29 @@ def plan_supported(snap: CSRSnapshot, k_block: int) -> Optional[str]:
                 f"exceeds half the {SMEM_BUDGET} B SMEM budget "
                 f"(cap={g.cap}) — hub rows too wide to prefetch")
     return None
+
+
+#: share of the device's memory the fused program may plan to use: the
+#: rest is the allocator's own (fragmentation, the runtime's reservations)
+HBM_PLAN_FRACTION = 0.9
+
+
+def device_memory_bytes() -> Optional[int]:
+    """The default device's memory limit as its allocator reports it, or
+    None where the backend reports none (CPU: nothing to fit into)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def fused_bytes(geom: FusedGeom, kwp: int) -> int:
+    """Device bytes the whole jitted ``_bfs_fused`` holds: the input and
+    the output bitmap, the bit-dot transient, and the scalar plan (idx,
+    chunk->row map, block bounds, degrees) — 11.06 GB by the TPU
+    compiler's memory analysis at 10,000,065 rows, 3 hops
+    (``tests/test_tpu_compile.py``)."""
+    plan = 4 * (geom.n_seg * (geom.cap * (geom.w + 1) + geom.nb + 1)
+                + geom.n_rows)
+    return 2 * geom.n_rows * kwp * 4 + (1 << 29) + plan
 
 
 def fused_bytes_per_hop(geom: FusedGeom, K: int) -> int:

@@ -665,9 +665,9 @@ class DeviceExecutor:
                     import logging
 
                     logging.getLogger("hypergraphdb_tpu.serve").warning(
-                        "aot warm failed (bfs_serve_batch, hops=%d); "
-                        "first dispatch compiles cold", hops,
-                        exc_info=True,
+                        "aot warm failed (bfs_serve_batch, bucket=%d, "
+                        "hops=%d); first dispatch compiles cold",
+                        int(b), hops, exc_info=True,
                     )
                 if fkw is None or fkw["overlay"] is not None:
                     continue

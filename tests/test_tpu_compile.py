@@ -28,19 +28,18 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+import chip_smoke
 from hypergraphdb_tpu import verify as hgverify
 
-#: chip_smoke.py's sizes: the kernels phase's graph
-#: (models.dbpedia_snapshot(2M, 8M)) and the serve phase's (500K entities
-#: + 1M binary links). The serve graph is 1.5M atoms and not 3M because of
-#: what this file found: the dense served BFS holds ~6.5 bytes per
-#: (seed, atom), so the default config's 1024-seed bucket needs 19.6 GB at
-#: 3M atoms and 9.8 GB at 1.5M — one chip has 16.
+#: chip_smoke.py's sizes, read from it: the kernels phase's row count
+#: (models.dbpedia_snapshot(2M, 8M)) and the serve phase's padded id space
+#: and edge capacity (500K entities + 1M binary links through the store:
+#: every valued atom takes a second handle, times the headroom).
 ROWS_10M = 10_000_065
-SERVE_ATOMS = 1_500_000
-SERVE_EDGES = 2_000_000          # 1M binary links: 2M incidence = 2M targets
-#: what one v5e chip leaves a program of its 16 GiB
-HBM_USABLE = 15.75e9
+SERVE_ATOMS = chip_smoke.serve_id_space(chip_smoke.SCALES["full"])
+SERVE_EDGES = 1 << 21            # 2M incidence = 2M targets, padded
+#: what one v5e chip leaves a program of its 16 GiB (15.75 GiB, in bytes)
+HBM_USABLE = 15.75 * 2**30
 
 
 @pytest.fixture(scope="module")
@@ -217,13 +216,11 @@ CASES = {
     "bfs_serve_batch_fused[K=1024,hops=2,top_r=16]": _case_serve_fused,
     # the unfused served BFS at the serve phase's graph: past ~270K atoms
     # a single top_k over the row was refused (scoped VMEM) — first_r_dense
-    "bfs_serve_batch[K=1024,hops=2]": partial(_case_serve_bfs,
-                                              bucket=1024, hops=2),
     "bfs_serve_batch[K=256,hops=3]": partial(_case_serve_bfs, bucket=256,
                                              hops=3),
     "pattern_serve_batch[K=1024]": _case_serve_pattern,
     "range_probe_batch[2M,K=1024]": _case_range_probe,
-    "join_hub_expand[1.5M,R=4096]": _case_join_hub_expand,
+    "join_hub_expand[R=4096]": _case_join_hub_expand,
 }
 
 
@@ -238,6 +235,15 @@ def test_main_path_compiles_for_v5e(case, one_chip, no_compile_cache):
     if "hop_call" in case or "gather_or" in case or "membership" in case \
             or "fused" in case:
         assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not interpret
+    if case.startswith("bfs_serve_batch[K=256"):
+        # the smoke's rule for which buckets it may drive rests on this
+        # constant: held to the compiler at the widest bucket that fits;
+        # by the same arithmetic the default config's 1024-seed bucket
+        # plans ~20 GB here and is never driven (PERF.md, PR 22)
+        model = chip_smoke.dense_bfs_bytes(256, SERVE_ATOMS, SERVE_EDGES)
+        assert 0.9 * model <= mem.temp_size_in_bytes <= 1.1 * model
+        assert chip_smoke.dense_bfs_bytes(
+            1024, SERVE_ATOMS, SERVE_EDGES) > HBM_USABLE
 
 
 # ------------------------------------------------- widths: gate == compiler
@@ -362,3 +368,57 @@ def test_sharded_bfs_compiles_for_four_chips_with_a_collective(
     assert "all-gather" in text or "all-reduce" in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_USABLE
+
+
+# --------------------- the staged hop at the benchmark graph's real shapes
+
+
+#: level lengths of ``plans_for(models.dbpedia_snapshot(2M, 8M))`` — the
+#: index pyramids of the 10,000,065-atom benchmark graph
+_L1 = (78_239_528, 14_239_528)
+_L2 = (55_021_736, 16_594_768, 120_696, 12_328, 1_432, 160, 16)
+_N_PAD, _KW, _CHUNK = 10_000_072, 128, 1 << 16
+
+
+def _staged_step(step: str):
+    """(jitted step, exemplar args, statics, bytes resident beside it)."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    rows = lambda ls: sum(n // 8 for n in ls) + 1  # noqa: E731
+    bitmap = _N_PAD * _KW * 4
+    visited = _sds((_N_PAD, _KW), "uint32")
+    if step == "_stage":
+        return (eb._stage,
+                (visited, tuple(_sds((n,), "int32") for n in _L1)),
+                dict(widths=(8, 8), chunk=_CHUNK, use_pallas=True),
+                4 * sum(_L2))
+    if step == "_stage_lvl0_consume":
+        return (eb._stage_lvl0_consume,
+                (_sds((rows(_L1), _KW), "uint32"), _sds((_L2[0],), "int32")),
+                dict(w=8, chunk=_CHUNK, use_pallas=True),
+                bitmap + 4 * (sum(_L1) + sum(_L2[1:])))
+    return (eb._stage_upper,
+            (_sds((_L2[0] // 8, _KW), "uint32"),
+             tuple(_sds((n,), "int32") for n in _L2[1:])),
+            dict(widths=(8,) * 7, chunk=_CHUNK),
+            bitmap + 4 * (sum(_L1) + _L2[0]))
+
+
+@pytest.mark.parametrize("step", ["_stage", "_stage_lvl0_consume",
+                                  "_stage_upper"])
+def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
+                                                          no_compile_cache):
+    """Each host-sequenced step of ``ellbfs._bfs_pull_device`` at the
+    benchmark graph, a 4096-seed block (128-word rows — narrower rows are
+    lane-padded to 128 on the chip and save nothing) and the chunk
+    ``bench.py`` c4 runs: the step's program PLUS what the hop keeps
+    resident beside it (the visited bitmap, the other index plans) fits
+    the chip. The widest is ``_stage_lvl0_consume`` at ~15.5 of 16.9 GB;
+    before the upper levels wrote in place ``_stage_upper`` planned
+    17.2."""
+    fn, args, statics, resident = _staged_step(step)
+    mem = fn.lower(*_place(args, one_chip), **statics).compile() \
+        .memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes + resident)
+    assert total < HBM_USABLE, (step, total)
