@@ -408,8 +408,9 @@ def bench_c3(snap, info):
     # first, then the serving windows (which pay the host link), then
     # result collection and host baselines. The order was load-bearing on
     # the round-5 set-up, where one bulk device_get slowed every later
-    # launch of the process ~100×; that effect is not measured on the
-    # current chip (see the isolation re-check in CHANGES.md, PR 22).
+    # launch of the process ~100×. On the current chip it is not: the
+    # exec and the serving window both read 127K q/s (8.06 ms per
+    # 1024-query batch; my chip run, PR 22) — the batch is device-bound.
     plan = plan_pattern(snap, pairs, th)
     reps = int(os.environ.get("BENCH_C3_REPS", 64))
     compile_info = _timed_warmup(lambda: jax.block_until_ready([
@@ -2704,14 +2705,25 @@ def _config_c11() -> dict:
 def _run_isolated(name: str) -> dict:
     """Run one config in a FRESH python subprocess.
 
-    Why process isolation: measured head-to-head, the identical exec
-    window runs the c3 pattern kernel at ~11.2M q/s in a fresh process and
-    ~95K q/s after EITHER c2's or c4's scan-heavy executables have been on
-    the chip — small-kernel launch latency degrades ~100× for the rest of
-    the process even with all buffers freed, and in-process ordering can
-    only protect ONE config. Each config now gets pristine launch state;
-    the duplicated 10M build is absorbed by the persistent XLA-compile and
-    plan caches."""
+    THE RULE (one process per chip): a chip belongs to one process at a
+    time, so this parent must never initialize a JAX backend while a
+    child needs the chip. Importing jax and updating its config
+    (``_bench_entry_env``) does not initialize one — a child started
+    after it got the chip (my chip run, PR 22); ``jax.devices()`` (hence
+    ``_backend_name()``), any array op or ``memory_stats()`` does — a
+    child started after it failed in 3 s with "Unable to initialize
+    backend 'tpu' ... libtpu multi-process lockfile" (same run). Configs
+    call those; ``main()`` does not.
+
+    Why process isolation: on the round-5 set-up a config that ran after
+    another's scan-heavy executables lost ~100x of its small-kernel launch
+    rate for the rest of the process. That does NOT reproduce on the
+    current chip (TPU v5 lite, my chip run, PR 22, one reading per side):
+    c3 alone in a fresh process 127,188 exec q/s and again 127,165; c3
+    in a process that ran c2 first 117,883 (-7%). What isolation still
+    buys is a clean HBM per config (three configs hold a 10M-atom graph)
+    at the price of rebuilding that graph per config; the next benchmark
+    is free to run its cells in one process."""
     import subprocess
     import sys
 

@@ -98,6 +98,12 @@ def emit(obj: dict) -> None:
     print(json.dumps(obj, sort_keys=True), flush=True)
 
 
+def note(msg: str) -> None:
+    """Progress, on stderr: stdout carries the phase lines only."""
+    print(f"chip_smoke [{time.strftime('%H:%M:%S')}] {msg}", file=sys.stderr,
+          flush=True)
+
+
 def require(cond, msg: str) -> None:
     if not cond:
         raise PhaseFailed(msg)
@@ -267,13 +273,15 @@ class Smoke:
         out["graph"] = {"atoms": info["n_atoms"],
                         "total_arity": info["total_arity"],
                         "build_s": round(time.perf_counter() - t0, 1)}
-        out["pattern"] = self._leg_pattern(snap, info)
-        self._drop_device_state(snap)
-        out["bfs_zipf"] = self._leg_bfs_zipf(snap, info)
-        del snap
-        out["bfs_fused"] = self._leg_bfs_fused()
-        out["gather_or"] = self._leg_gather_or()
-        out["intersect"] = self._leg_intersect()
+        for leg, run in (
+                ("pattern", lambda: self._leg_pattern(snap, info)),
+                ("bfs_zipf", lambda: self._leg_bfs_zipf(snap, info)),
+                ("bfs_fused", self._leg_bfs_fused),
+                ("gather_or", self._leg_gather_or),
+                ("intersect", self._leg_intersect)):
+            out[leg] = run()
+            self._drop_device_state(snap)
+            note(f"kernels: {leg} {out[leg]}")
         out["seconds"] = round(time.perf_counter() - t_phase, 1)
         return out
 
@@ -635,10 +643,13 @@ class Smoke:
         out: dict = {}
         sg = self.build_serve_graph()
         out["graph"] = sg.describe()
+        note(f"serve: graph loaded {out['graph']}")
         g = sg.g
+        t0 = time.perf_counter()
         mgr = g.enable_incremental(headroom=self.s["headroom"],
                                    pack_pad_multiple=self.s["pad_multiple"])
         out["graph"]["id_space"] = int(mgr.base.num_atoms)
+        out["graph"]["first_pack_s"] = round(time.perf_counter() - t0, 1)
         aot_dir = os.path.join(HERE, ".aot_cache")
         cfg = ServeConfig(aot_cache_dir=aot_dir)
         out["config"] = {"buckets": list(cfg.buckets), "top_r": cfg.top_r,
@@ -649,6 +660,7 @@ class Smoke:
             t0 = time.perf_counter()
             rt = ServeRuntime(g, cfg)
             out["runtime_start_s"] = round(time.perf_counter() - t0, 1)
+            note(f"serve: runtime up in {out['runtime_start_s']} s")
             entries = _EntryTap(rt.executor)
             rt.attach_planner(QueryPlanner(g))
             top_r = cfg.top_r
@@ -656,6 +668,7 @@ class Smoke:
                 # the second runtime: same graph, same AOT directory — it
                 # must load what the first one stored AND serve from it
                 out["aot_warm"] = self._second_runtime(sg, cfg, top_r)
+                note(f"serve: second runtime {out['aot_warm']}")
                 # stage 1: a quiet graph
                 reqs = sg.requests(self.s["stage1"], self.seed + 21, "old")
                 s1 = self._drive(rt, sg, reqs, top_r)
@@ -663,6 +676,7 @@ class Smoke:
                         f"stage 1 (quiet graph): {s1['served_by_host']} "
                         f"requests fell back to the host: {s1['host_kinds']}")
                 out["stage1"] = s1
+                note(f"serve: stage 1 {s1}")
                 # stage 2: the same kinds of request in flight while a
                 # writer ingests a component no old seed can reach
                 reqs = sg.requests(self.s["stage2"], self.seed + 22, "old")
@@ -681,6 +695,7 @@ class Smoke:
                 s2["host_by_contract_max"] = by_contract
                 s2["ingested_atoms"] = sg.n_new_atoms
                 out["stage2"] = s2
+                note(f"serve: stage 2 {s2}")
                 # one forced compaction bakes the new atoms into the base
                 before = mgr.compactions
                 t0 = time.perf_counter()
@@ -705,6 +720,7 @@ class Smoke:
                         f"base: {s3['host_kinds']}")
                 s3["seeds_newer_than_base"] = newer
                 out["stage3"] = s3
+                note(f"serve: stage 3 {s3}")
                 snap = rt.stats_snapshot()
             finally:
                 entries.remove()

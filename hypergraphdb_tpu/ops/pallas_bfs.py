@@ -789,12 +789,6 @@ def plan_supported(snap: CSRSnapshot, k_block: int) -> Optional[str]:
                 f"{VMEM_BUDGET} B budget at kwp={kwp}")
     plan = fused_plans_for(snap)
     g = plan.geom
-    limit = device_memory_bytes()
-    if limit is not None and fused_bytes(g, kwp) > HBM_PLAN_FRACTION * limit:
-        return (f"fused working set {fused_bytes(g, kwp) / 1e9:.1f} GB "
-                f"(two {g.n_rows}-row bitmaps + the composed adjacency) "
-                f"exceeds {HBM_PLAN_FRACTION:.0%} of the device's "
-                f"{limit / 1e9:.1f} GB")
     if _vmem_bytes(kwp, g.w) > VMEM_BUDGET:
         return (f"VMEM working set {_vmem_bytes(kwp, g.w)} B exceeds the "
                 f"{VMEM_BUDGET} B budget at kwp={kwp}, w={g.w}")
@@ -803,6 +797,12 @@ def plan_supported(snap: CSRSnapshot, k_block: int) -> Optional[str]:
                 f"{_smem_bytes(g.cap, g.nb, g.w)} B "
                 f"exceeds half the {SMEM_BUDGET} B SMEM budget "
                 f"(cap={g.cap}) — hub rows too wide to prefetch")
+    limit = device_memory_bytes()
+    if limit is not None and fused_bytes(g, kwp) > HBM_PLAN_FRACTION * limit:
+        return (f"fused working set {fused_bytes(g, kwp) / 1e9:.1f} GB "
+                f"(two {g.n_rows}-row bitmaps + the composed adjacency) "
+                f"exceeds {HBM_PLAN_FRACTION:.0%} of the device's "
+                f"{limit / 1e9:.1f} GB")
     return None
 
 

@@ -1826,7 +1826,9 @@ class ServeRuntime:
     def attach_planner(self, planner) -> None:
         """Wire an hgplan ``QueryPlanner`` into this runtime: the
         planner's telemetry binds to THIS runtime's ``ServeStats``
-        (``plan.*`` metrics ride the serving registry) and — unless the
+        (``plan.*`` metrics ride the serving registry), its cardinality
+        estimator — unless it already follows a manager — reads this
+        runtime's snapshot manager, and — unless the
         planner already carries one — its sentinel guard binds to this
         runtime's perf sentinel (a learned correction may never steer
         the argmin onto a lane currently listed in the sentinel's
@@ -1834,6 +1836,15 @@ class ServeRuntime:
         called."""
         with self._close_lock:
             planner.stats = self.stats
+            est = getattr(planner, "estimator", None)
+            mgr = getattr(self.executor, "mgr", None)
+            if est is not None and est.mgr is None and mgr is not None:
+                # price plans from the base the lanes serve, per
+                # compaction epoch. A standalone estimator re-packs the
+                # whole store per mutation, outside the commit lock: under
+                # concurrent ingest that is seconds per planned request
+                # and a torn read of the store's link table
+                est.mgr = mgr
             if planner.lane_degraded is None and self.perf is not None:
                 perf = self.perf
 
