@@ -47,8 +47,7 @@ import numpy as np
 #: an error, never a default: a roofline share against the wrong peak is
 #: a wrong number with a right-looking name.
 HBM_PEAK_BYTES_PER_S = {
-    "TPU v5 lite": 819e9,
-    "TPU v5e": 819e9,
+    "TPU v5 lite": 819e9,   # how JAX names a v5e chip
 }
 
 

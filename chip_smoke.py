@@ -43,6 +43,7 @@ import os
 import sys
 import threading
 import time
+from functools import partial
 
 import numpy as np
 
@@ -207,6 +208,7 @@ class Smoke:
     def phase_device(self) -> dict:
         import jax
 
+        from hypergraphdb_tpu.ops.pallas_bfs import device_memory_bytes
         from hypergraphdb_tpu.utils.compile_cache import (
             cache_entries,
             place_compile_cache,
@@ -241,8 +243,7 @@ class Smoke:
             "compile_cache_from_env": bool(
                 os.environ.get("JAX_COMPILATION_CACHE_DIR")),
             "compile_cache_was_empty": cache_entries(cache_dir) == 0,
-            "memory_bytes_limit": (self.dev.memory_stats() or {}).get(
-                "bytes_limit"),
+            "memory_bytes_limit": device_memory_bytes(),
             "seconds": round(time.perf_counter() - t0, 3),
         }
 
@@ -598,8 +599,11 @@ class Smoke:
         idx = r.integers(0, rows, size=n_idx).astype(np.int32)
         interpret = self.dev.platform != "tpu"
         vd, idd = jnp.asarray(values), jnp.asarray(idx)
-        out, cold, warm = self._cold_warm(lambda: jax.block_until_ready(
-            pg.gather_or(vd, idd, w, interpret)))
+        # jitted, as every caller in the tree runs it (un-jitted, the
+        # pallas_call is lowered again on every call)
+        fn = jax.jit(partial(pg.gather_or, w=w, interpret=interpret))
+        out, cold, warm = self._cold_warm(
+            lambda: jax.block_until_ready(fn(vd, idd)))
         want = np.bitwise_or.reduce(
             values[idx].reshape(-1, w, pg.ROW_WORDS), axis=1)
         require(np.array_equal(np.asarray(out), want),
@@ -630,10 +634,6 @@ class Smoke:
                 "compile_s": round(max(cold - warm, 0), 3),
                 "run_s": round(warm, 4)}
 
-    # -- the serve-phase graph ------------------------------------------------
-    def build_serve_graph(self) -> "ServeGraph":
-        return ServeGraph(self.s, self.seed)
-
     # -- serve ----------------------------------------------------------------
     def phase_serve(self) -> dict:
         from hypergraphdb_tpu.plan import QueryPlanner
@@ -641,7 +641,7 @@ class Smoke:
 
         t_phase = time.perf_counter()
         out: dict = {}
-        sg = self.build_serve_graph()
+        sg = ServeGraph(self.s, self.seed)
         out["graph"] = sg.describe()
         note(f"serve: graph loaded {out['graph']}")
         g = sg.g
@@ -748,25 +748,24 @@ class Smoke:
         aot = out["aot"] or {}
         require(aot.get("corrupt", 0) == 0 and aot.get("stale", 0) == 0,
                 f"AOT cache reports failures: {aot}")
-        # the dense served BFS holds (K, id space) and (K, edges) arrays:
-        # a bucket past the chip's memory cannot be prewarmed (or served)
-        # and the runtime says so in a warning — expected exactly for
-        # those buckets, a failure for any other
-        hbm = ((self.dev.memory_stats() or {}).get("bytes_limit")
-               or float("inf"))
+        require(not any("aot" in m.lower() for m in warnings.messages),
+                f"AOT warnings: {warnings.messages[:3]}")
+        # the dense served BFS holds (K, id space) and (K, edges) arrays: a
+        # bucket whose program plans past the chip's memory compiles all
+        # the same and would fail when it ran — the smoke names such
+        # buckets and must not have driven one
+        from hypergraphdb_tpu.ops.pallas_bfs import device_memory_bytes
+
+        hbm = device_memory_bytes() or float("inf")
         need = {b: dense_bfs_bytes(b, out["compaction"]["id_space"],
                                    out["compaction"]["edges"])
                 for b in cfg.buckets}
-        too_wide = [b for b in cfg.buckets if need[b] > hbm]
         out["bfs_buckets_past_hbm"] = {
             str(b): f"{need[b] / 1e9:.1f} GB of {hbm / 1e9:.1f}"
-            for b in too_wide}
-        bad = [m for m in warnings.messages if "aot" in m.lower()
-               and not any(f"bucket={b}," in m for b in too_wide)]
-        require(not bad, f"AOT warnings: {bad[:3]}")
-        driven = {int(k.split(",")[0].split("=")[1])
+            for b in cfg.buckets if need[b] > hbm}
+        driven = {k.split(",")[0].split("=")[1]
                   for k in out["bfs_entry_by_bucket"]}
-        require(not driven & set(too_wide),
+        require(not driven & set(out["bfs_buckets_past_hbm"]),
                 f"a BFS batch ran at a bucket past HBM: {driven}")
         out["seconds"] = round(time.perf_counter() - t_phase, 1)
         return out
@@ -802,6 +801,7 @@ class Smoke:
         futs = [sg.submit(rt, q) for q in reqs]
         results = [f.result(timeout=900) for f in futs]
         wall = time.perf_counter() - t0
+        sg.prime_bfs_refs(reqs)     # 64 BFS references per host pass
         kinds: dict = {}
         host_kinds: dict = {}
         crossed = 0
@@ -833,7 +833,7 @@ class Smoke:
                 f"--four-chips needs 4 devices, JAX reports "
                 f"{len(jax.devices())}")
         out: dict = {}
-        sg = self.build_serve_graph()
+        sg = ServeGraph(self.s, self.seed)
         out["graph"] = sg.describe()
         g = sg.g
         mgr = g.enable_incremental(headroom=self.s["headroom"],
@@ -855,6 +855,7 @@ class Smoke:
             sh_wall = time.perf_counter() - t0
             futs = [sg.submit(rt_one, q) for q in reqs]
             got_one = [f.result(timeout=900) for f in futs]
+            sg.prime_bfs_refs(reqs)
             for q, a, b in zip(reqs, got_sh, got_one):
                 sg.check(q, a, top_r)     # == host
                 require(a.count == b.count and a.truncated == b.truncated
@@ -1123,30 +1124,16 @@ class ServeGraph:
         return rt.submit_planned(self._condition(q))
 
     # -- references -----------------------------------------------------------
-    def _bfs_ref(self, seed_atom: int, hops: int) -> np.ndarray:
-        key = (seed_atom, hops, len(self.link_h))
-        memo = self.__dict__.setdefault("_bfs_memo", {})
-        if key not in memo:
-            n_ids = int(max(self.link_h.max(), self.link_a.max(),
-                            self.link_b.max())) + 1
-            L = len(self.link_h)
-            flat = np.stack([self.link_a, self.link_b], axis=1).reshape(-1)
-            link_of = np.repeat(np.arange(L, dtype=np.int64), 2)
-            vis = host_bfs_bits(n_ids, flat, link_of, L,
-                                np.asarray([seed_atom]), hops)
-            memo[key] = bits_column(vis, 0)
-        return memo[key]
-
     def prime_bfs_refs(self, reqs: list) -> None:
-        """Bit-parallel: the references of up to 64 BFS requests per pass."""
+        """Bit-parallel: the references of up to 64 BFS requests per pass,
+        memoized per (seed, hops, links so far)."""
         memo = self.__dict__.setdefault("_bfs_memo", {})
         L = len(self.link_h)
         todo: dict = {}
         for q in reqs:
-            if q["kind"] == "bfs":
-                key = (q["atoms"][0], q["hops"], L)
-                if key not in memo:
-                    todo.setdefault(q["hops"], set()).add(q["atoms"][0])
+            if q["kind"] == "bfs" and (q["atoms"][0], q["hops"], L) \
+                    not in memo:
+                todo.setdefault(q["hops"], set()).add(q["atoms"][0])
         if not todo:
             return
         n_ids = int(max(self.link_h.max(), self.link_a.max(),
@@ -1170,10 +1157,9 @@ class ServeGraph:
         sorted (y, z) tuples."""
         k = q["kind"]
         if k == "bfs":
-            if (q["atoms"][0], q["hops"], len(self.link_h)) \
-                    not in self.__dict__.get("_bfs_memo", {}):
-                self.prime_bfs_refs([q])
-            return self._bfs_ref(q["atoms"][0], q["hops"])
+            self.prime_bfs_refs([q])
+            return self._bfs_memo[(q["atoms"][0], q["hops"],
+                                   len(self.link_h))]
         if k == "pattern":
             hit = self._on(q["atoms"][0]) & self._on(q["atoms"][1])
             if q["type"] is not None and q["type"] != self.link_type:

@@ -811,11 +811,16 @@ def plan_supported(snap: CSRSnapshot, k_block: int) -> Optional[str]:
 HBM_PLAN_FRACTION = 0.9
 
 
+@functools.lru_cache(maxsize=None)
+def _bytes_limit(device) -> Optional[int]:
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
 def device_memory_bytes() -> Optional[int]:
-    """The default device's memory limit as its allocator reports it, or
-    None where the backend reports none (CPU: nothing to fit into)."""
-    stats = jax.devices()[0].memory_stats() or {}
-    return stats.get("bytes_limit")
+    """The default device's memory limit as its allocator reports it (a
+    constant of the device, read once), or None where the backend reports
+    none (CPU: nothing to fit into)."""
+    return _bytes_limit(jax.devices()[0])
 
 
 def fused_bytes(geom: FusedGeom, kwp: int) -> int:
