@@ -539,7 +539,12 @@ class SubscriptionManager:
             done = [s for s in self.subs.all()
                     if s.inflight is not None and s.inflight[0].done()]
         for sub in done:
-            fut, _s1 = sub.inflight
+            inflight = sub.inflight
+            if inflight is None:
+                # a concurrent pump (the runtime's thread and a caller's
+                # both pump) resolved it between the scan and here
+                continue
+            fut, _s1 = inflight
             new: Optional[set] = None
             failed = False
             try:
