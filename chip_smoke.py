@@ -9,21 +9,26 @@ calls, and checks every answer against a plain host reference:
 - ``kernels``  the 10,000,065-atom / 48M-arity benchmark snapshot
                (``models.dbpedia_snapshot``): 1024 conjunctive patterns
                through ``plan_pattern → execute_pattern → collect_pattern``;
-               a 3-hop pull BFS from 4096 seeds through ``ops.bfs_pull``
-               (the fused plan declines this graph — hub rows overflow its
-               SMEM window — so the staged chain runs, on the Pallas
-               gather); on a hub-free graph of the same row count the
-               fused Pallas hop against the unfused chain with the Pallas
-               and with the XLA gather; ``gather_or`` and
+               a 3-hop pull BFS from 4096 seeds through ``ops.bfs_pull``,
+               once as the code selects it (the fused Pallas plan DECLINES
+               this graph — hub rows overflow its SMEM window — so the
+               staged chain runs, on the Pallas gather) and once with the
+               Pallas gather switched off (the XLA gather), the two equal
+               in all 4096 columns and, in 64 columns spread over the
+               bitmap's words, equal to a numpy BFS; ``gather_or`` and
                ``intersect_sorted_pallas`` at one real-width shape each.
-- ``serve``    a ``HyperGraph`` loaded through ``bulk_import`` (1.5M atoms
+- ``serve``    a ``HyperGraph`` loaded through ``bulk_import`` (3M atoms
                through the real store and type system), ``enable_incremental``,
                a ``ServeRuntime`` with the default ``ServeConfig``: BFS,
-               pattern, range, join and planned requests on a quiet graph,
-               under concurrent ingest, and — after a forced compaction —
-               over the new atoms; a second runtime must warm-hit the AOT
-               cache and serve from the loaded executables; the runtime's
-               own counters must show the DEVICE answered.
+               pattern, range, join and planned requests on a quiet graph;
+               a same-key BFS burst wide enough to fill the largest bucket
+               (the executor caps BFS batches at the widest bucket whose
+               dense program fits the chip — the burst must run at that
+               width, full, and form nothing wider); the mix again under
+               concurrent ingest, and — after a forced compaction — over
+               the new atoms; a second runtime must warm-hit the AOT cache
+               and serve from the loaded executables; the runtime's own
+               counters must show the DEVICE answered.
 - ``--four-chips``  ONLY the mesh-sharded serving phase and what it is
                compared with (needs four devices; the driver runs one chip).
 
@@ -53,30 +58,30 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 #: is the CPU rehearsal (same control flow, Pallas kernels interpreted).
 SCALES = {
     "full": dict(
-        # kernels: the r05 benchmark graph, and a hub-free graph of the
-        # same row count (T + entities + links = 10,000,065 both)
+        # kernels: the r05 benchmark graph (T + entities + links =
+        # 10,000,065 rows)
         kern_entities=2_000_000, kern_links=8_000_000,
-        sparse_entities=5_000_000, sparse_links=5_000_000,
         pairs=1024, seeds=4096, ref_seeds=64, hops=3,
         gather_rows=1 << 20, gather_idx=1 << 17,
         isect_base=60_000, isect_other=65_536,
-        # serve: 1.5M atoms (every valued atom takes a second handle, so
-        # the id space is ~3M x headroom). headroom/pad are deployment
-        # sizing: this deployment ingests 1.3% more, not 100%
-        serve_entities=500_000, serve_links=1_000_000,
-        headroom=1.2, pad_multiple=1 << 17,
-        stage1=256, stage2=128, stage3=64, new_entities=5_000,
+        # serve: 3M atoms. Every valued atom takes a second handle and
+        # enable_incremental()'s default headroom doubles the lot: an id
+        # space of 12,582,912 after the pad — a coarse one, the streaming
+        # lever (SnapshotManager): the base keeps its shapes, and so its
+        # executables, across the compaction
+        serve_entities=1_000_000, serve_links=2_000_000,
+        pad_multiple=1 << 21,
+        stage1=256, burst=2048, stage2=128, stage3=64, new_entities=5_000,
         shard_bfs=128, shard_pattern=128,
     ),
     "tiny": dict(
         kern_entities=300, kern_links=900,
-        sparse_entities=150, sparse_links=150,
         pairs=48, seeds=64, ref_seeds=64, hops=3,
         gather_rows=512, gather_idx=2048,
         isect_base=900, isect_other=1024,
         serve_entities=420, serve_links=520,
-        headroom=1.2, pad_multiple=128,
-        stage1=48, stage2=24, stage3=24, new_entities=40,
+        pad_multiple=128,
+        stage1=48, burst=160, stage2=24, stage3=24, new_entities=40,
         shard_bfs=16, shard_pattern=16,
     ),
 }
@@ -108,31 +113,6 @@ def note(msg: str) -> None:
 def require(cond, msg: str) -> None:
     if not cond:
         raise PhaseFailed(msg)
-
-
-#: what ``ops.serving.bfs_serve_batch`` holds per seed: its (K, N) bool
-#: frontier / visited / next / link-live / neighbour arrays (bytes per
-#: id-space slot) and the (K, E) gathers that feed its scatters (bytes per
-#: incidence entry) — fitted to the TPU compiler's memory analysis at two
-#: (N, E) points and held to a third in tests/test_tpu_compile.py
-DENSE_BFS_BYTES_PER_SLOT = 4.72
-DENSE_BFS_BYTES_PER_EDGE = 1.26
-
-
-def dense_bfs_bytes(bucket: int, id_space: int, edges: int) -> float:
-    return bucket * (DENSE_BFS_BYTES_PER_SLOT * (id_space + 1)
-                     + DENSE_BFS_BYTES_PER_EDGE * edges)
-
-
-def serve_id_space(scale: dict) -> int:
-    """The padded id space the serve phase's base snapshot packs to
-    (``SnapshotManager._assemble_and_swap``'s arithmetic): every valued
-    atom mints a value handle beside its own, the manager multiplies the
-    handle high-water by ``headroom`` and rounds up to ``pad_multiple``."""
-    atoms = scale["serve_entities"] + scale["serve_links"]
-    cap = int((2 * atoms + 4096) * scale["headroom"])
-    pm = scale["pad_multiple"]
-    return -(-cap // pm) * pm
 
 
 # ------------------------------------------------------- host references
@@ -181,16 +161,15 @@ def snapshot_incidence(snap):
     return flat, link_of, n1
 
 
-def transposed_bits(visited_t: np.ndarray, n_rows: int,
-                    n_seeds: int) -> np.ndarray:
-    """The first ``n_seeds`` (<= 64) seed columns of a transposed
-    ``(rows, Kw)`` uint32 bitmap as one uint64 word per row."""
-    lo = visited_t[:n_rows, 0].astype(np.uint64)
-    if n_seeds > 32:
-        lo |= visited_t[:n_rows, 1].astype(np.uint64) << np.uint64(32)
-    if n_seeds < 64:
-        lo &= (np.uint64(1) << np.uint64(n_seeds)) - np.uint64(1)
-    return lo
+def bitmap_columns(visited_t: np.ndarray, n_rows: int,
+                   cols: np.ndarray) -> np.ndarray:
+    """Seed columns ``cols`` (at most 64) of a transposed ``(rows, Kw)``
+    uint32 bitmap as one uint64 word per row: bit j is column ``cols[j]``."""
+    out = np.zeros(n_rows, dtype=np.uint64)
+    for j, k in enumerate(cols.tolist()):
+        bit = (visited_t[:n_rows, k // 32] >> np.uint32(k % 32)) & np.uint32(1)
+        out |= bit.astype(np.uint64) << np.uint64(j)
+    return out
 
 
 # ------------------------------------------------------------- the phases
@@ -208,7 +187,6 @@ class Smoke:
     def phase_device(self) -> dict:
         import jax
 
-        from hypergraphdb_tpu.ops.pallas_bfs import device_memory_bytes
         from hypergraphdb_tpu.utils.compile_cache import (
             cache_entries,
             place_compile_cache,
@@ -243,7 +221,8 @@ class Smoke:
             "compile_cache_from_env": bool(
                 os.environ.get("JAX_COMPILATION_CACHE_DIR")),
             "compile_cache_was_empty": cache_entries(cache_dir) == 0,
-            "memory_bytes_limit": device_memory_bytes(),
+            "memory_bytes_limit": (self.dev.memory_stats() or {}).get(
+                "bytes_limit"),
             "seconds": round(time.perf_counter() - t0, 3),
         }
 
@@ -276,8 +255,7 @@ class Smoke:
                         "build_s": round(time.perf_counter() - t0, 1)}
         for leg, run in (
                 ("pattern", lambda: self._leg_pattern(snap, info)),
-                ("bfs_zipf", lambda: self._leg_bfs_zipf(snap, info)),
-                ("bfs_fused", self._leg_bfs_fused),
+                ("bfs", lambda: self._leg_bfs(snap, info)),
                 ("gather_or", self._leg_gather_or),
                 ("intersect", self._leg_intersect)):
             out[leg] = run()
@@ -377,29 +355,36 @@ class Smoke:
         del res
         return host, reach, edges, max(cold - warm, 0.0), warm
 
-    def _check_vs_host_bfs(self, snap, seeds, host_t, reach, what) -> None:
+    def _check_vs_host_bfs(self, snap, seeds, host_t, reach, what) -> int:
+        """Hold ``ref_seeds`` columns — spread over the whole width of the
+        bitmap, so every second word of a row is looked at — to a numpy
+        BFS on the host CSR. Returns how many columns were held."""
         n_ref = min(self.s["ref_seeds"], len(seeds))
+        cols = (np.arange(n_ref) * (len(seeds) - 1)) // max(n_ref - 1, 1)
         flat, link_of, n1 = snapshot_incidence(snap)
-        ref = host_bfs_bits(n1, flat, link_of, n1, seeds[:n_ref],
+        ref = host_bfs_bits(n1, flat, link_of, n1, seeds[cols],
                             self.s["hops"])
-        got = transposed_bits(host_t, snap.num_atoms, n_ref)
+        got = bitmap_columns(host_t, snap.num_atoms, cols)
         bad = np.flatnonzero(got != ref[: snap.num_atoms])
         require(len(bad) == 0,
                 f"{what}: visited sets differ from the host BFS at "
                 f"{len(bad)} atoms (first {bad[:4].tolist()})")
-        for k in range(n_ref):
-            require(int(reach[k]) == len(bits_column(ref, k)),
+        for j, k in enumerate(cols.tolist()):
+            require(int(reach[k]) == len(bits_column(ref, j)),
                     f"{what}: reach count of seed {k} differs from host")
+        return n_ref
 
     #: the gather chunk bench.py c4 runs a 4096-seed block at: the XLA
     #: gather's transient is chunk x 8 rows of 512 bytes, and at 10M atoms
-    #: the hop's widest step has ~1 GB to spare (tests/test_tpu_compile.py)
+    #: the hop's widest step has ~1.5 GB to spare on the Pallas gather and
+    #: half of that on the XLA gather (tests/test_tpu_compile.py) — which
+    #: therefore runs at half the chunk
     PULL_CHUNK = 1 << 16
 
-    def _pull(self, snap, seeds):
+    def _pull(self, snap, seeds, chunk=PULL_CHUNK):
         from hypergraphdb_tpu.ops import bfs_pull
 
-        return bfs_pull(snap, seeds, self.s["hops"], chunk=self.PULL_CHUNK,
+        return bfs_pull(snap, seeds, self.s["hops"], chunk=chunk,
                         k_block=self.s["seeds"])
 
     def _counting_gather(self):
@@ -418,14 +403,15 @@ class Smoke:
         pallas_gather.gather_or = counted
         return calls, lambda: setattr(pallas_gather, "gather_or", real)
 
-    def _leg_bfs_zipf(self, snap, info) -> dict:
+    def _leg_bfs(self, snap, info) -> dict:
         """3-hop pull BFS from 4096 seeds on the benchmark graph through
-        ``ops.bfs_pull``, the entry the traversal API calls, with what the
-        code selects there: the fused plan DECLINES this graph (its hub
-        rows overflow the SMEM window), so the staged chain runs, with
-        the Pallas gather under its 128-word rows. Held, for 64 seeds, to
-        the host BFS. (Fused against unfused, Pallas gather against XLA
-        gather: the next leg, on a graph the fused plan admits.)"""
+        ``ops.bfs_pull``, the entry the traversal API calls. First with
+        what the code selects there: the fused Pallas plan DECLINES this
+        graph (its hub rows overflow the SMEM window; the reason is on the
+        line), so the staged chain runs, with the Pallas gather under its
+        128-word rows. Then with the program's own switch for that gather
+        off (``HG_PALLAS_GATHER=0``: the XLA gather). The two must agree
+        in every column, and the first is held to the host BFS."""
         from hypergraphdb_tpu.ops import pallas_bfs
         from hypergraphdb_tpu.ops.ellbfs import plans_for
 
@@ -436,142 +422,50 @@ class Smoke:
         plans_for(snap)
         plan_s = time.perf_counter() - t0
         declined = pallas_bfs.plan_supported(snap, self.s["seeds"])
-        calls, restore = self._counting_gather()
-        try:
-            host, reach, edges, compile_s, run_s = self._bfs_twice(
-                lambda: self._pull(snap, seeds))
-        finally:
-            restore()
-        peak = self.peak_bytes()
-        if self.dev.platform == "tpu":
-            require(calls["n"] > 0, "bfs_pull at a 4096-seed block on a "
-                    "TPU did not trace the Pallas gather")
-        self._drop_device_state(snap)
-        self._check_vs_host_bfs(snap, seeds, host, reach, "bfs_pull")
+        on_tpu = self.dev.platform == "tpu"
+        legs: dict = {}
+        ref = None
+        for leg, env, chunk in (
+                ("pallas_gather", {}, self.PULL_CHUNK),
+                ("xla_gather", {"HG_PALLAS_GATHER": "0"},
+                 self.PULL_CHUNK // 2)):
+            calls, restore = self._counting_gather()
+            os.environ.update(env)
+            try:
+                got = self._bfs_twice(lambda: self._pull(snap, seeds, chunk))
+            finally:
+                restore()
+                for name in env:
+                    del os.environ[name]
+            legs[leg] = {"compile_s": round(got[3], 2),
+                         "run_s": round(got[4], 3), "chunk": chunk,
+                         "peak_bytes": self.peak_bytes(),
+                         "pallas_gather_traced": calls["n"] > 0}
+            self._drop_device_state(snap)
+            if ref is None:
+                ref = got
+            else:
+                require(np.array_equal(ref[0], got[0]),
+                        "visited sets differ between the Pallas and the "
+                        "XLA gather")
+                require(np.array_equal(ref[1], got[1])
+                        and np.array_equal(ref[2], got[2]),
+                        "reach/edge counts differ between the Pallas and "
+                        "the XLA gather")
+            del got
+        if on_tpu:
+            require(legs["pallas_gather"]["pallas_gather_traced"]
+                    and not legs["xla_gather"]["pallas_gather_traced"],
+                    f"gather legs did not take their paths: {legs}")
+        n_ref = self._check_vs_host_bfs(snap, seeds, ref[0], ref[1],
+                                        "bfs_pull")
         return {
             "seeds": len(seeds), "hops": self.s["hops"],
             "plan_build_s": round(plan_s, 1),
             "fused_plan": ("admitted" if declined is None
                            else f"declined: {declined}"),
-            "pallas_gather_traced": calls["n"] > 0,
-            "edges_touched": int(edges.sum()),
-            "compile_s": round(compile_s, 2), "run_s": round(run_s, 3),
-            "peak_bytes": peak,
-            "equal_host_seeds": min(self.s["ref_seeds"], len(seeds)),
-        }
-
-    def _sparse_snapshot(self):
-        """A hub-free graph the fused plan admits: uniform binary links,
-        ids laid out like ``dbpedia_snapshot`` (65 type atoms, entities,
-        links) so the row count matches the benchmark graph's."""
-        from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
-
-        r = np.random.default_rng(self.seed + 3)
-        T, ne, nl = 65, self.s["sparse_entities"], self.s["sparse_links"]
-        N = T + ne + nl
-        l0 = T + ne
-        type_of = np.zeros(N, dtype=np.int32)
-        type_of[l0:] = 1
-        is_link = np.zeros(N, dtype=bool)
-        is_link[l0:] = True
-        tgt_offsets = np.zeros(N + 1, dtype=np.int64)
-        tgt_offsets[l0 + 1:] = 2 * np.arange(1, nl + 1)
-        tgt_flat = (T + r.integers(0, ne, size=2 * nl)).astype(np.int32)
-        snap = CSRSnapshot.from_tables(
-            type_of, is_link, tgt_offsets, tgt_flat,
-            value_rank=np.zeros(N, dtype=np.uint64),
-        )
-        return snap, (T, l0)
-
-    def _leg_bfs_fused(self) -> dict:
-        """The fused Pallas hop at full row width (128 words = 4096 seeds)
-        on a hub-free graph of the benchmark's row count, through
-        ``ops.bfs_pull`` — against the unfused chain with the Pallas
-        gather, the unfused chain with the XLA gather, and the host BFS."""
-        from hypergraphdb_tpu.ops import pallas_bfs
-        from hypergraphdb_tpu.ops.ellbfs import PullBFSResult
-
-        t0 = time.perf_counter()
-        snap, (e0, e1) = self._sparse_snapshot()
-        build_s = time.perf_counter() - t0
-        r = np.random.default_rng(self.seed + 4)
-        seeds = r.integers(e0, e1, size=self.s["seeds"]).astype(np.int32)
-        hops, on_tpu = self.s["hops"], self.dev.platform == "tpu"
-        why = pallas_bfs.plan_supported(snap, self.s["seeds"])
-        require(why is None, f"fused plan declined the hub-free graph: {why}")
-        geom = pallas_bfs.fused_plans_for(snap).geom
-        calls = {"hop": 0, "interpret": 0}
-        real_hop = pallas_bfs._hop_call
-
-        def counted(*a, **kw):
-            calls["hop"] += 1
-            calls["interpret"] += bool(kw.get("interpret"))
-            return real_hop(*a, **kw)
-
-        def fused():
-            if on_tpu:
-                return self._pull(snap, seeds)
-            # rehearsal: the same kernel through the Pallas interpreter
-            # (bfs_pull keeps it off anywhere but a TPU)
-            vis, s_ins, reach = pallas_bfs.bfs_pull_fused(
-                snap, seeds, hops, interpret=True)
-            return PullBFSResult(vis, np.asarray(s_ins[-1]).astype(np.int64),
-                                 reach)
-
-        legs: dict = {}
-        pallas_bfs._hop_call = counted
-        try:
-            ref = self._bfs_twice(fused)
-        finally:
-            pallas_bfs._hop_call = real_hop
-        legs["fused"] = {"compile_s": round(ref[3], 2),
-                         "run_s": round(ref[4], 3),
-                         "peak_bytes": self.peak_bytes()}
-        require(calls["hop"] > 0, "the 'fused' leg never traced the "
-                "pallas_call: bfs_pull took another path")
-        if on_tpu:
-            require(calls["interpret"] == 0, "fused hop ran interpreted")
-        self._drop_device_state(snap)
-        # the unfused chain, by the program's own switches: fused hop off;
-        # then the Pallas gather off too
-        for leg, off in (("unfused_pallas_gather", ("HG_PALLAS_BFS",)),
-                         ("unfused_xla_gather", ("HG_PALLAS_BFS",
-                                                 "HG_PALLAS_GATHER"))):
-            gathers, restore = self._counting_gather()
-            for name in off:
-                os.environ[name] = "0"
-            try:
-                got = self._bfs_twice(lambda: self._pull(snap, seeds))
-            finally:
-                restore()
-                for name in off:
-                    del os.environ[name]
-            self._drop_device_state(snap)
-            require(np.array_equal(ref[0], got[0]),
-                    f"fused and {leg} visited sets differ")
-            require(np.array_equal(ref[1], got[1])
-                    and np.array_equal(ref[2], got[2]),
-                    f"fused and {leg} reach/edge counts differ")
-            legs[leg] = {"compile_s": round(got[3], 2),
-                         "run_s": round(got[4], 3),
-                         "peak_bytes": self.peak_bytes(),
-                         "pallas_gather_traced": gathers["n"] > 0}
-            del got
-        if on_tpu:
-            require(legs["unfused_pallas_gather"]["pallas_gather_traced"]
-                    and not legs["unfused_xla_gather"]["pallas_gather_traced"],
-                    f"gather legs did not take their paths: {legs}")
-        self._check_vs_host_bfs(snap, seeds, ref[0], ref[1], "fused BFS")
-        return {
-            "atoms": snap.num_atoms, "build_s": round(build_s, 1),
-            "seeds": len(seeds), "hops": hops,
-            "geom": {"n_rows": geom.n_rows, "n_seg": geom.n_seg,
-                     "nb": geom.nb, "cap": geom.cap,
-                     "entries": geom.total_entries},
-            "pallas_call_traced": calls["hop"],
-            "interpreted": bool(calls["interpret"]),
-            **legs, "legs_equal": True,
-            "equal_host_seeds": min(self.s["ref_seeds"], len(seeds)),
+            "edges_touched": int(ref[2].sum()),
+            **legs, "legs_equal": True, "equal_host_seeds": n_ref,
         }
 
     def _cold_warm(self, fn):
@@ -636,6 +530,7 @@ class Smoke:
 
     # -- serve ----------------------------------------------------------------
     def phase_serve(self) -> dict:
+        from hypergraphdb_tpu.obs.trace import Tracer
         from hypergraphdb_tpu.plan import QueryPlanner
         from hypergraphdb_tpu.serve import ServeConfig, ServeRuntime
 
@@ -646,12 +541,15 @@ class Smoke:
         note(f"serve: graph loaded {out['graph']}")
         g = sg.g
         t0 = time.perf_counter()
-        mgr = g.enable_incremental(headroom=self.s["headroom"],
-                                   pack_pad_multiple=self.s["pad_multiple"])
+        mgr = g.enable_incremental(
+            pack_pad_multiple=self.s["pad_multiple"])
         out["graph"]["id_space"] = int(mgr.base.num_atoms)
         out["graph"]["first_pack_s"] = round(time.perf_counter() - t0, 1)
+        # the default ServeConfig, told where its AOT cache lives and
+        # given a tracer of its own: a BFS request sent with explain=True
+        # comes back with the bucket it rode, from the request's own trace
         aot_dir = os.path.join(HERE, ".aot_cache")
-        cfg = ServeConfig(aot_cache_dir=aot_dir)
+        cfg = ServeConfig(aot_cache_dir=aot_dir, tracer=Tracer().enable())
         out["config"] = {"buckets": list(cfg.buckets), "top_r": cfg.top_r,
                          "use_pallas_bfs": cfg.use_pallas_bfs,
                          "aot_cache_dir": aot_dir}
@@ -661,9 +559,12 @@ class Smoke:
             rt = ServeRuntime(g, cfg)
             out["runtime_start_s"] = round(time.perf_counter() - t0, 1)
             note(f"serve: runtime up in {out['runtime_start_s']} s")
-            entries = _EntryTap(rt.executor)
             rt.attach_planner(QueryPlanner(g))
             top_r = cfg.top_r
+            # the widest BFS batch the executor will form on this chip
+            cap = rt.executor.bfs_bucket_cap()
+            out["config"]["bfs_bucket_cap"] = cap
+            widest = cap or cfg.buckets[-1]
             try:
                 # the second runtime: same graph, same AOT directory — it
                 # must load what the first one stored AND serve from it
@@ -677,6 +578,24 @@ class Smoke:
                         f"requests fell back to the host: {s1['host_kinds']}")
                 out["stage1"] = s1
                 note(f"serve: stage 1 {s1}")
+                # burst: one key, more requests than the largest bucket
+                # holds — the widest bucket the executor admits must run
+                # FULL on the chip, and nothing wider may form
+                reqs = sg.requests_of({"bfs": self.s["burst"]},
+                                      self.seed + 24)
+                for q in reqs:
+                    q["hops"] = cfg.default_max_hops
+                sb = self._drive(rt, sg, reqs, top_r, cross=False)
+                require(sb["served_by_host"] == 0,
+                        f"burst: {sb['served_by_host']} host answers")
+                if len(reqs) >= 2 * widest:
+                    require(sb["bfs_buckets"].get(str(widest), {})
+                            .get("widest_batch") == widest,
+                            f"burst of {len(reqs)}: no full batch at the "
+                            f"widest admitted bucket {widest}: "
+                            f"{sb['bfs_buckets']}")
+                out["burst"] = sb
+                note(f"serve: burst {sb}")
                 # stage 2: the same kinds of request in flight while a
                 # writer ingests a component no old seed can reach
                 reqs = sg.requests(self.s["stage2"], self.seed + 22, "old")
@@ -723,12 +642,10 @@ class Smoke:
                 note(f"serve: stage 3 {s3}")
                 snap = rt.stats_snapshot()
             finally:
-                entries.remove()
                 rt.close()
         finally:
             warnings.remove()
         g.close()
-        out["bfs_entry_by_bucket"] = entries.summary()
         out["stats"] = {k: snap.get(k) for k in (
             "submitted", "completed", "device_dispatches", "host_fallbacks",
             "bfs_fused_dispatches", "range_dispatches", "errors", "retries",
@@ -748,25 +665,21 @@ class Smoke:
         aot = out["aot"] or {}
         require(aot.get("corrupt", 0) == 0 and aot.get("stale", 0) == 0,
                 f"AOT cache reports failures: {aot}")
-        require(not any("aot" in m.lower() for m in warnings.messages),
+        # (every message of the AOT paths begins "aot ...")
+        require(not any(": aot" in m.lower() for m in warnings.messages),
                 f"AOT warnings: {warnings.messages[:3]}")
-        # the dense served BFS holds (K, id space) and (K, edges) arrays: a
-        # bucket whose program plans past the chip's memory compiles all
-        # the same and would fail when it ran — the smoke names such
-        # buckets and must not have driven one
-        from hypergraphdb_tpu.ops.pallas_bfs import device_memory_bytes
-
-        hbm = device_memory_bytes() or float("inf")
-        need = {b: dense_bfs_bytes(b, out["compaction"]["id_space"],
-                                   out["compaction"]["edges"])
-                for b in cfg.buckets}
-        out["bfs_buckets_past_hbm"] = {
-            str(b): f"{need[b] / 1e9:.1f} GB of {hbm / 1e9:.1f}"
-            for b in cfg.buckets if need[b] > hbm}
-        driven = {k.split(",")[0].split("=")[1]
-                  for k in out["bfs_entry_by_bucket"]}
-        require(not driven & set(out["bfs_buckets_past_hbm"]),
-                f"a BFS batch ran at a bucket past HBM: {driven}")
+        # which entry served each bucket that ran — both from what ran:
+        # the buckets from the requests' traces, the entry from the
+        # counter at the fused kernel's call site
+        ran = sorted({int(b) for k in ("stage1", "burst", "stage2", "stage3")
+                      for b in out[k]["bfs_buckets"]})
+        require(all(b <= widest for b in ran),
+                f"a BFS batch formed past the executor's cap {cap}: {ran}")
+        fused = st["bfs_fused_dispatches"]
+        out["bfs_entry_by_bucket"] = {
+            str(b): ("unfused" if fused == 0 else
+                     f"{fused} fused dispatches among all buckets")
+            for b in ran}
         out["seconds"] = round(time.perf_counter() - t_phase, 1)
         return out
 
@@ -778,7 +691,11 @@ class Smoke:
         start_s = time.perf_counter() - t0
         try:
             aot = rt2.stats_snapshot().get("aot") or {}
-            require(aot.get("disk_hits", 0) > 0 and aot.get("misses", 1) == 0,
+            # (a refusal leaves nothing to cache: where the executor caps
+            # BFS batches, the first bucket past the cap is asked again)
+            asked_again = rt2.executor.bfs_bucket_cap() is not None
+            require(aot.get("disk_hits", 0) > 0
+                    and aot.get("misses", 1) == asked_again,
                     f"second runtime did not warm-hit the AOT cache: {aot}")
             # and the LOADED executables answer (on the right devices)
             reqs = [q for q in sg.requests(32, self.seed + 20, "old")
@@ -794,9 +711,10 @@ class Smoke:
         return {"start_s": round(start_s, 2), "aot": aot,
                 "requests_served": res["requests"]}
 
-    def _drive(self, rt, sg, reqs, top_r) -> dict:
+    def _drive(self, rt, sg, reqs, top_r, cross=True) -> dict:
         """Submit every request, wait for every answer, then — outside
-        any timing — hold each answer to the host reference."""
+        any timing — hold each answer to the host reference (and, with
+        ``cross``, the reference to the repo's host engine)."""
         t0 = time.perf_counter()
         futs = [sg.submit(rt, q) for q in reqs]
         results = [f.result(timeout=900) for f in futs]
@@ -804,16 +722,24 @@ class Smoke:
         sg.prime_bfs_refs(reqs)     # 64 BFS references per host pass
         kinds: dict = {}
         host_kinds: dict = {}
+        buckets: dict = {}
         crossed = 0
-        for q, res in zip(reqs, results):
-            crossed += sg.check(q, res, top_r)
+        for q, f, res in zip(reqs, futs, results):
+            crossed += sg.check(q, res, top_r, cross)
             kinds[q["kind"]] = kinds.get(q["kind"], 0) + 1
             planned_host = (q["kind"] == "planned"
                             and res.plan.get("shape") == "host")
             if res.served_by == "host" and not planned_host:
                 host_kinds[q["kind"]] = host_kinds.get(q["kind"], 0) + 1
+            elif q["kind"] == "bfs":
+                ex = f.explain
+                slot = buckets.setdefault(
+                    str(ex["bucket"]), {"requests": 0, "widest_batch": 0})
+                slot["requests"] += 1
+                slot["widest_batch"] = max(slot["widest_batch"],
+                                           ex["lanes_real"])
         return {"requests": len(reqs), "kinds": kinds, "all_equal_host": True,
-                "also_equal_find_all": crossed,
+                "also_equal_find_all": crossed, "bfs_buckets": buckets,
                 "served_by_host": sum(host_kinds.values()),
                 "host_kinds": host_kinds, "wall_s": round(wall, 2)}
 
@@ -836,8 +762,8 @@ class Smoke:
         sg = ServeGraph(self.s, self.seed)
         out["graph"] = sg.describe()
         g = sg.g
-        mgr = g.enable_incremental(headroom=self.s["headroom"],
-                                   pack_pad_multiple=self.s["pad_multiple"])
+        mgr = g.enable_incremental(
+            pack_pad_multiple=self.s["pad_multiple"])
         cfg = dict(prewarm_aot=False)
         rt_sh = ServeRuntime(g, ServeConfig(sharded=True, **cfg))
         rt_one = ServeRuntime(g, ServeConfig(sharded=False, **cfg))
@@ -1114,7 +1040,10 @@ class ServeGraph:
     def submit(self, rt, q: dict):
         k = q["kind"]
         if k == "bfs":
-            return rt.submit_bfs(q["atoms"][0], max_hops=q["hops"])
+            # explain: the answer carries the bucket it rode (needs the
+            # runtime's tracer on; the sharded phase runs without one)
+            return rt.submit_bfs(q["atoms"][0], max_hops=q["hops"],
+                                 explain=rt.tracer.enabled)
         if k == "pattern":
             return rt.submit_pattern(q["atoms"], type_handle=q["type"])
         if k == "range":
@@ -1190,7 +1119,7 @@ class ServeGraph:
         return sorted((int(y), int(z)) for y in nbrs(a)
                       for z in nbrs(y) if z != a)
 
-    def check(self, q: dict, res, top_r: int) -> bool:
+    def check(self, q: dict, res, top_r: int, cross: bool = True) -> bool:
         """Hold one answer to its host reference — exact count, the exact
         prefix, an honest truncation flag — and, where the repo's own
         host engine answers the same question in milliseconds, hold the
@@ -1220,7 +1149,7 @@ class ServeGraph:
                     f"{got[:6].tolist()} vs {want[:6].tolist()}")
             require(res.truncated == (res.count > len(got)),
                     f"{k} truncation flag is not honest for {q}")
-        return self._cross_check(q, want)
+        return cross and self._cross_check(q, want)
 
     def _cross_check(self, q: dict, want) -> bool:
         """reference == the repo's host engine, for the questions it
@@ -1273,37 +1202,6 @@ class _WarningTap(logging.Handler):
 
     def remove(self) -> None:
         logging.getLogger("hypergraphdb_tpu").removeHandler(self)
-
-
-class _EntryTap:
-    """Which BFS entry served each bucket — from what RAN: wraps the
-    executor's two BFS dispatch methods on this instance and counts."""
-
-    def __init__(self, executor):
-        self.ex = executor
-        self.counts: dict = {}
-        self._real = (executor._serve_bfs, executor._serve_bfs_fused)
-
-        def unfused(view, seeds_dev, max_hops, top_r):
-            self._note("unfused", seeds_dev, max_hops)
-            return self._real[0](view, seeds_dev, max_hops, top_r)
-
-        def fused(kw, seeds_dev, max_hops, top_r):
-            self._note("fused", seeds_dev, max_hops)
-            return self._real[1](kw, seeds_dev, max_hops, top_r)
-
-        executor._serve_bfs, executor._serve_bfs_fused = unfused, fused
-
-    def _note(self, entry, seeds_dev, hops) -> None:
-        key = f"bucket={int(seeds_dev.shape[0])},hops={hops}"
-        slot = self.counts.setdefault(key, {"fused": 0, "unfused": 0})
-        slot[entry] += 1
-
-    def remove(self) -> None:
-        del self.ex._serve_bfs, self.ex._serve_bfs_fused
-
-    def summary(self) -> dict:
-        return dict(sorted(self.counts.items()))
 
 
 # -------------------------------------------------------------------- main

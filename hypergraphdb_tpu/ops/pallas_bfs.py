@@ -797,41 +797,7 @@ def plan_supported(snap: CSRSnapshot, k_block: int) -> Optional[str]:
                 f"{_smem_bytes(g.cap, g.nb, g.w)} B "
                 f"exceeds half the {SMEM_BUDGET} B SMEM budget "
                 f"(cap={g.cap}) — hub rows too wide to prefetch")
-    limit = device_memory_bytes()
-    if limit is not None and fused_bytes(g, kwp) > HBM_PLAN_FRACTION * limit:
-        return (f"fused working set {fused_bytes(g, kwp) / 1e9:.1f} GB "
-                f"(two {g.n_rows}-row bitmaps + the composed adjacency) "
-                f"exceeds {HBM_PLAN_FRACTION:.0%} of the device's "
-                f"{limit / 1e9:.1f} GB")
     return None
-
-
-#: share of the device's memory the fused program may plan to use: the
-#: rest is the allocator's own (fragmentation, the runtime's reservations)
-HBM_PLAN_FRACTION = 0.9
-
-
-@functools.lru_cache(maxsize=None)
-def _bytes_limit(device) -> Optional[int]:
-    return (device.memory_stats() or {}).get("bytes_limit")
-
-
-def device_memory_bytes() -> Optional[int]:
-    """The default device's memory limit as its allocator reports it (a
-    constant of the device, read once), or None where the backend reports
-    none (CPU: nothing to fit into)."""
-    return _bytes_limit(jax.devices()[0])
-
-
-def fused_bytes(geom: FusedGeom, kwp: int) -> int:
-    """Device bytes the whole jitted ``_bfs_fused`` holds: the input and
-    the output bitmap, the bit-dot transient, and the scalar plan (idx,
-    chunk->row map, block bounds, degrees) — 11.06 GB by the TPU
-    compiler's memory analysis at 10,000,065 rows, 3 hops
-    (``tests/test_tpu_compile.py``)."""
-    plan = 4 * (geom.n_seg * (geom.cap * (geom.w + 1) + geom.nb + 1)
-                + geom.n_rows)
-    return 2 * geom.n_rows * kwp * 4 + (1 << 29) + plan
 
 
 def fused_bytes_per_hop(geom: FusedGeom, K: int) -> int:

@@ -3,14 +3,19 @@
 Pure decision logic — no threads, no device code — so tier-1 tests drive
 it deterministically with a fake clock. The batcher owns two decisions:
 
-- WHEN to flush: a compatible group reaching the LARGEST bucket flushes
-  immediately (batch-full); otherwise the oldest queued ticket's linger
-  reaching ``max_linger_s`` flushes whatever is pending (latency bound).
-  ``drain=True`` (shutdown) flushes unconditionally.
+- WHEN to flush: a compatible group reaching the LARGEST bucket (or its
+  key's cap, below) flushes immediately (batch-full); otherwise the
+  oldest queued ticket's linger reaching ``max_linger_s`` flushes
+  whatever is pending (latency bound). ``drain=True`` (shutdown) flushes
+  unconditionally.
 - WHAT shape to pay for: the flushed group pads up to the smallest
   configured bucket that fits (K ∈ {64, 256, 1024} by default) —
   power-of-two-style buckets bound the number of distinct compiled
-  programs while keeping padding waste ≤ the bucket ratio.
+  programs while keeping padding waste ≤ the bucket ratio. ``key_cap``
+  (the executor's say: a configured bucket whose device program does not
+  fit the chip's memory for this key) bounds how many tickets one flush
+  takes, so a bucket past the cap is never formed — the rest of a burst
+  rides the next flushes.
 
 Groups are keyed by ``Ticket.batch_key`` (kernel statics + shape dims:
 ``("bfs", max_hops)`` / ``("pattern", P)``) — requests with different
@@ -22,7 +27,7 @@ defines the next batch.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Sequence
+from typing import Callable, Optional, Sequence
 
 from hypergraphdb_tpu.serve.admission import AdmissionQueue
 
@@ -63,13 +68,16 @@ class Batcher:
 
     def __init__(self, queue: AdmissionQueue,
                  buckets: Sequence[int] = BUCKETS,
-                 max_linger_s: float = 0.002):
+                 max_linger_s: float = 0.002,
+                 key_cap: Optional[Callable[[tuple], Optional[int]]] = None):
         if not buckets or list(buckets) != sorted(set(buckets)):
             raise ValueError("buckets must be sorted, unique, non-empty")
         self.queue = queue
         self.buckets = tuple(int(b) for b in buckets)
         self.max_batch = self.buckets[-1]
         self.max_linger_s = max_linger_s
+        #: batch key -> the widest batch to form for it, or None (no cap)
+        self.key_cap = key_cap
 
     def next_batch(self, now: float, drain: bool = False
                    ) -> Optional[MicroBatch]:
@@ -86,7 +94,10 @@ class Batcher:
             return None
         key = head.batch_key
         pending = self.queue.count_key(key)
-        full = pending >= self.max_batch
+        cap = self.max_batch
+        if self.key_cap is not None:
+            cap = min(cap, self.key_cap(key) or cap)
+        full = pending >= cap
         oldest = self.queue.oldest()
         lingered = (
             oldest is not None
@@ -94,7 +105,7 @@ class Batcher:
         )
         if not (full or lingered or drain):
             return None
-        tickets = self.queue.take(key, self.max_batch)
+        tickets = self.queue.take(key, cap)
         if not tickets:  # raced with another consumer (single-thread: no-op)
             return None
         return MicroBatch(key=key, tickets=tickets,

@@ -271,6 +271,8 @@ class DeviceExecutor:
         self._aot_failed = False
         #: plan_supported reasons already logged (_note_fused_decline)
         self._fused_declines: set = set()
+        #: ((id space, edges), cap) — bfs_bucket_cap's memo per base shape
+        self._bfs_cap: tuple = (None, None)
         #: (epoch, new_atoms scanned, touched set | "full") —
         #: _join_dirty_info's memo
         self._join_dirty_memo: tuple = (-1, 0, frozenset())
@@ -320,8 +322,9 @@ class DeviceExecutor:
         EXECUTION errors of the returned executable propagate to the
         retry/breaker ladder like any device failure. Dispatch-time
         compiles do not persist (``persist=False``): only the prewarm
-        writes disk entries, so shape churn (resized delta buckets)
-        cannot mint superseded multi-MB files on a serving thread."""
+        (and ``bfs_bucket_cap``, its part that may run again per base
+        shape) writes disk entries, so shape churn (resized delta buckets) cannot mint
+        superseded multi-MB files on a serving thread."""
         if self.aot is None or self._aot_failed:
             return None
         try:
@@ -590,10 +593,13 @@ class DeviceExecutor:
             # the executable depends on shapes, not contents
             empty_delta = build_delta_column(self.graph, [], 0, epoch=-1)
         warm = 0
+        bfs_cap = self.bfs_bucket_cap()
         for b in buckets:
             seeds = jnp.full((int(b),), n, dtype=jnp.int32)
+            # a bucket no BFS batch will ever form at warms no BFS program
+            bfs_fits = bfs_cap is None or int(b) <= bfs_cap
             # plan build + backend probe happen HERE regardless of cache
-            fkw = self._fused_bfs_kwargs(view, int(b))
+            fkw = self._fused_bfs_kwargs(view, int(b)) if bfs_fits else None
             if self.aot is None:
                 continue
             if ell is not None:
@@ -650,7 +656,7 @@ class DeviceExecutor:
                     )
                 except Exception:  # noqa: BLE001 - never block startup
                     continue
-            for hops in hops_list:
+            for hops in (hops_list if bfs_fits else ()):
                 # independent try blocks: a bucket whose unfused lowering
                 # fails must not forfeit the fused warm (or vice versa) —
                 # whichever entry the first dispatch routes to should be
@@ -725,6 +731,96 @@ class DeviceExecutor:
             "fused BFS declined for bucket %d; the unfused chain serves "
             "it: %s", bucket, why,
         )
+
+    # -- which BFS buckets fit the chip ---------------------------------------
+    def _device_memory_is_bounded(self) -> bool:
+        """Does the device that holds the snapshot — where
+        ``DeviceSnapshot.from_host`` uploads: jax's default device — report
+        an allocator limit? (CPU reports none: every program fits.)"""
+        from hypergraphdb_tpu.ops.aot_cache import execution_devices
+
+        stats = execution_devices(())[0].memory_stats() or {}
+        return stats.get("bytes_limit") is not None
+
+    def _bfs_program(self, view, bucket: int):
+        """The compiled dense BFS program of one bucket, at the default
+        hop count (hops are a loop: they change no buffer) — through the
+        AOT cache where one is configured, persisted (this is the
+        bucket's prewarm); jit keeps the executable for the dispatch
+        either way. Raises what the compiler raises."""
+        import jax.numpy as jnp
+
+        from hypergraphdb_tpu.ops.serving import bfs_serve_batch
+
+        n = view.base.num_atoms
+        args = (view.device, view.delta,
+                jnp.full((int(bucket),), n, dtype=jnp.int32))
+        statics = {"max_hops": self.config.default_max_hops,
+                   "top_r": min(self.config.top_r + 1, n + 1)}
+        if self.aot is not None and not self._aot_failed:
+            return self.aot.get_or_compile("ops.serving.bfs_serve_batch",
+                                           bfs_serve_batch, args, statics)
+        return bfs_serve_batch.lower(*args, **statics).compile()
+
+    def bfs_bucket_cap(self) -> Optional[int]:
+        """The widest configured bucket a BFS batch may take, or None (no
+        limit). The dense served BFS holds (K, id space) and (K, edges)
+        arrays, so past some width its program does not fit the chip, and
+        the chip's compiler refuses it (RESOURCE_EXHAUSTED, "Ran out of
+        memory in memory space hbm"). Left to the first wide batch, that
+        refusal fails every caller in it and trips the key's breaker. So
+        on a device with bounded memory the executor compiles the buckets'
+        programs narrowest first — where the dispatch would compile them
+        anyway — and stops at the first the compiler refuses: a bucket
+        past the cap is neither prewarmed nor formed (``Batcher.key_cap``)
+        — a burst rides more batches of the widest bucket that fits — and
+        the refusal is logged, once per base shape. The cap binds the
+        fused entry too: a fused batch falls to the dense entry whenever
+        a tombstone is pending."""
+        base = self.mgr.base
+        shape = (int(base.num_atoms), int(len(base.inc_links)))
+        if self._bfs_cap[0] == shape:
+            return self._bfs_cap[1]
+        cap = None
+        if self._device_memory_is_bounded():
+            import logging
+
+            log = logging.getLogger("hypergraphdb_tpu.serve")
+            view = self._pin_view("bfs")
+            buckets = sorted(int(b) for b in self.config.buckets)
+            for b in buckets:
+                try:
+                    self._bfs_program(view, b)
+                except Exception as e:  # noqa: BLE001 - sorted just below
+                    if "RESOURCE_EXHAUSTED" not in str(e):
+                        # not a verdict on memory: leave every bucket open
+                        # and let the dispatch meet the fault, loudly
+                        log.warning("could not compile the BFS program of "
+                                    "bucket %d to size BFS batches", b,
+                                    exc_info=True)
+                        cap = None
+                        break
+                    log.log(
+                        logging.WARNING if cap else logging.ERROR,
+                        "BFS bucket %d declined: the compiler refuses its "
+                        "program at id space %d and %d incidence entries "
+                        "(%s); BFS batches form at most %d wide%s",
+                        b, *shape, " ".join(str(e).split())[:240],
+                        cap or b, "" if cap else " — and will fail on the "
+                        "device and degrade to the host",
+                    )
+                    cap = cap or b
+                    break
+                cap = b
+            if cap == buckets[-1]:
+                cap = None
+        self._bfs_cap = (shape, cap)
+        return cap
+
+    def max_batch(self, key: tuple) -> Optional[int]:
+        """``Batcher.key_cap``: how many tickets one flush of ``key`` may
+        take (None = the largest bucket)."""
+        return self.bfs_bucket_cap() if key[0] == "bfs" else None
 
     def _dispatch_cm(self, kind: str, bucket: int, statics: int):
         """The per-dispatch profiler annotation, active only when device
@@ -1624,6 +1720,9 @@ class ServeRuntime:
             else _make_executor(graph, self.config, self.stats)
         )
         self.graph = graph
+        # the executor's say on how wide a batch of each key may form
+        # (injected executors without one leave every bucket open)
+        self.batcher.key_cap = getattr(self.executor, "max_batch", None)
         # deploy-time compile: load-or-build the serving executables for
         # every bucket BEFORE the dispatch thread takes traffic, so a
         # warm AOT cache reaches first dispatch without recompiling.

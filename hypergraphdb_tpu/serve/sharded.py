@@ -84,6 +84,11 @@ class ShardedExecutor(DeviceExecutor):
         )
 
     # -- BFS -------------------------------------------------------------------
+    def bfs_bucket_cap(self) -> Optional[int]:
+        # not modelled: the sharded program holds (K, n_loc) per device,
+        # a 1/n_dev share of the single-chip program's rows
+        return None
+
     def _fused_bfs_kwargs(self, view, bucket: int):
         return None  # the fused Pallas chain is single-chip only
 

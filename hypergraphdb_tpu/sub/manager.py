@@ -535,16 +535,19 @@ class SubscriptionManager:
         )
 
     def _resolve_inflight(self) -> None:
+        # claimed under the lock: the runtime's dispatch thread and a
+        # caller's pump may both be here, and exactly one of them owns
+        # each finished evaluation. The claimed subscription keeps its
+        # ``inflight`` until the answer is applied, so _submit_dirty can
+        # start no second evaluation beside the one being resolved
         with self._lock:
             done = [s for s in self.subs.all()
-                    if s.inflight is not None and s.inflight[0].done()]
+                    if s.inflight is not None and not s.resolving
+                    and s.inflight[0].done()]
+            for s in done:
+                s.resolving = True
         for sub in done:
-            inflight = sub.inflight
-            if inflight is None:
-                # a concurrent pump (the runtime's thread and a caller's
-                # both pump) resolved it between the scan and here
-                continue
-            fut, _s1 = inflight
+            fut, _s1 = sub.inflight
             new: Optional[set] = None
             failed = False
             try:
@@ -564,6 +567,7 @@ class SubscriptionManager:
             latency = None
             with self._lock:
                 sub.inflight = None
+                sub.resolving = False
                 if failed:
                     sub.dirty = True
                     sub.retry_at = self._clock() + \

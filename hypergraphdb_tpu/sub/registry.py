@@ -13,7 +13,8 @@ the state the incremental evaluator and the delivery plane share:
   audit that its replayed set matches the server's);
 - ``queue`` — the bounded per-subscription notification queue
   (``window`` deep) with its condition variable (long-poll parking);
-- ``dirty`` / ``inflight`` — the evaluator's re-fire state.
+- ``dirty`` / ``inflight`` / ``resolving`` — the evaluator's re-fire
+  state (``resolving``: one pump has claimed the finished evaluation).
 
 The :class:`SubscriptionRegistry` is a locked id → subscription map;
 evaluation policy lives in :class:`~hypergraphdb_tpu.sub.manager
@@ -64,6 +65,7 @@ class Subscription:
     dirty: bool = False
     dirty_since: Optional[float] = None
     inflight: Optional[tuple] = None     # (future, eval_seq)
+    resolving: bool = False              # a pump owns the finished eval
     retry_at: float = 0.0                # failed-eval backoff gate
     #: prebuilt serve request (PatternRequest / RangeRequest; None for
     #: bfs, whose request is rebuilt from params per submit)

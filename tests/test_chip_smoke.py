@@ -48,7 +48,24 @@ def smoke_sandbox(tmp_path, monkeypatch):
         cc.reset_cache()
 
 
-def test_tiny_rehearsal_reaches_the_last_line(smoke_sandbox, capsys):
+def test_tiny_rehearsal_reaches_the_last_line(smoke_sandbox, capsys,
+                                              monkeypatch):
+    # a device whose compiler refuses every BFS program wider than 64
+    # seeds: the rehearsal walks the path the chip does — the executor
+    # caps BFS batches and the burst must fill that bucket
+    from hypergraphdb_tpu.serve.runtime import DeviceExecutor
+
+    real = DeviceExecutor._bfs_program
+
+    def compiler(self, view, bucket):
+        if bucket > 64:
+            self.aot.stats.misses += 1      # asked, and nothing to cache
+            raise RuntimeError("RESOURCE_EXHAUSTED: ran out of hbm")
+        return real(self, view, bucket)
+
+    monkeypatch.setattr(DeviceExecutor, "_bfs_program", compiler)
+    monkeypatch.setattr(DeviceExecutor, "_device_memory_is_bounded",
+                        lambda self: True)
     assert chip_smoke.main(["--scale", "tiny", "--seed", "1"]) == 0
     lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
     phases = {x["phase"]: x for x in lines[:-1]}
@@ -58,18 +75,24 @@ def test_tiny_rehearsal_reaches_the_last_line(smoke_sandbox, capsys):
     k = phases["kernels"]
     assert k["pattern"]["equal_host"] and k["gather_or"]["equal_host"]
     assert k["intersect"]["equal_host"]
-    assert k["bfs_zipf"]["equal_host_seeds"] == 64
-    assert k["bfs_fused"]["equal_host_seeds"] == 64
-    assert k["bfs_fused"]["legs_equal"]         # fused == both unfused legs
-    assert k["bfs_fused"]["pallas_call_traced"] > 0
+    assert k["bfs"]["equal_host_seeds"] == 64
+    assert k["bfs"]["legs_equal"]       # Pallas-gather leg == XLA-gather leg
     s = phases["serve"]
     for stage in ("stage1", "stage2", "stage3"):
         assert s[stage]["all_equal_host"]
         assert s[stage]["also_equal_find_all"] > 0
     assert s["stage1"]["served_by_host"] == 0
+    # the burst: capped at 64, ran full at 64, nothing wider anywhere
+    assert s["config"]["bfs_bucket_cap"] == 64
+    assert s["burst"]["all_equal_host"]
+    assert s["burst"]["bfs_buckets"] == {
+        "64": {"requests": s["burst"]["requests"], "widest_batch": 64}}
+    assert set(s["bfs_entry_by_bucket"]) == {"64"}
+    assert any("BFS bucket 256 declined" in w for w in s["warnings"])
     assert s["stats"]["device_dispatches"] > 0
     assert s["stats"]["errors"] == 0 and s["stats"]["breaker_trips"] == 0
-    assert s["aot_warm"]["aot"]["misses"] == 0
+    # the second runtime loaded everything but the refusal, asked again
+    assert s["aot_warm"]["aot"]["misses"] == 1
     assert s["aot_warm"]["aot"]["disk_hits"] > 0
     assert s["compaction"]["passes"] >= 1
     # the caches went under the (sandboxed) checkout and nowhere else

@@ -410,6 +410,57 @@ def test_pattern_launch_skips_device_delta_upload(graph):
     assert fresh in fut.result(timeout=0).matches.tolist()  # still exact
 
 
+def test_bfs_bucket_the_compiler_refuses_is_not_formed(graph, monkeypatch,
+                                                       caplog, tmp_path):
+    """On a device with bounded memory the executor compiles the BFS
+    buckets' programs narrowest first and stops at the first the compiler
+    refuses for memory (here: a stand-in that refuses 128 seeds, as the
+    v5e's compiler refuses 1024 at 3M atoms): that bucket is logged once,
+    not prewarmed, never formed; a burst wider than the cap rides more
+    batches of the widest bucket that fits, every answer unchanged."""
+    import logging
+
+    from hypergraphdb_tpu.serve import runtime as srt
+
+    nodes, links, iso = _build(graph)
+    real = srt.DeviceExecutor._bfs_program
+
+    def compiler(self, view, bucket):
+        if bucket >= 128:
+            raise RuntimeError(
+                "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran "
+                "out of memory in memory space hbm.")
+        return real(self, view, bucket)
+
+    monkeypatch.setattr(srt.DeviceExecutor, "_bfs_program", compiler)
+    cfg = ServeConfig(buckets=(8, 32, 128), manual=True, max_linger_s=0.0,
+                      top_r=512, aot_cache_dir=str(tmp_path))
+    rt = ServeRuntime(graph, cfg)
+    assert rt.executor.bfs_bucket_cap() is None     # CPU: nothing is asked
+    rt.close()
+    monkeypatch.setattr(srt.DeviceExecutor, "_device_memory_is_bounded",
+                        lambda self: True)
+    with caplog.at_level(logging.WARNING, logger="hypergraphdb_tpu.serve"):
+        rt = ServeRuntime(graph, cfg)
+        assert rt.executor.bfs_bucket_cap() == 32
+        assert rt.executor.max_batch(("pattern", 2)) is None
+        seeds = [nodes[i % len(nodes)] for i in range(100)]
+        futs = [rt.submit_bfs(s, max_hops=2) for s in seeds]
+        _drain(rt)
+    declines = [r.getMessage() for r in caplog.records
+                if "BFS bucket" in r.getMessage()]
+    assert len(declines) == 1 and "bucket 128 declined" in declines[0]
+    snap = rt.stats_snapshot()
+    # 100 requests: 32 + 32 + 32 + 4 (an 8-lane bucket), never 128
+    assert snap["batches"] == 4 and snap["errors"] == 0
+    assert snap["batch_occupancy"] == pytest.approx(100 / (3 * 32 + 8))
+    rt.close()
+    for s, f in zip(seeds, futs):
+        got = f.result(timeout=0)
+        assert got.matches.tolist() == sorted(
+            set(_bfs_truth(graph, s, 2)) | {s})
+
+
 @pytest.mark.parametrize("n,top_r", [(1000, 4), (1000, 17), (257, 100),
                                      (64, 5), (40, 64)])
 def test_first_r_dense_blocked_sweep_equals_numpy(monkeypatch, n, top_r):

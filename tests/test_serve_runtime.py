@@ -176,6 +176,26 @@ def test_flush_on_batch_full_ignores_linger():
     assert rt.stats.batches == 1
 
 
+def test_key_cap_bounds_what_one_flush_takes():
+    """An executor's per-key cap (a bucket whose device program does not
+    fit the chip): that key's group is FULL at the cap, one flush takes
+    at most the cap, and a bucket past it is never formed; other keys
+    keep the largest bucket."""
+    rt, ex, clock = make_runtime(linger=1e9)  # linger can never expire
+    rt.batcher.key_cap = lambda key: 4 if key[0] == "bfs" else None
+    bfs = [rt.submit_bfs(i) for i in range(10)]
+    pat = [rt.submit_pattern([1, 2]) for _ in range(10)]
+    assert rt.step() is True and rt.step() is True   # 4 + 4: full at cap
+    assert rt.step() is False           # 2 left: neither full nor lingered
+    assert [(b.key[0], b.bucket, len(b.tickets)) for b in ex.batches] == [
+        ("bfs", 4, 4), ("bfs", 4, 4)]
+    while rt.step(drain=True):
+        pass
+    assert [(b.key[0], b.bucket, len(b.tickets)) for b in ex.batches[2:]] \
+        == [("bfs", 4, 2), ("pattern", 16, 10)]
+    assert all(f.result(timeout=0) is not None for f in bfs + pat)
+
+
 def test_no_flush_before_linger_then_flush_after():
     rt, ex, clock = make_runtime(linger=0.010)
     fut = rt.submit_bfs(7)

@@ -256,35 +256,55 @@ def test_executor_pick_forced_and_auto():
         g.close()
 
 
+_PREWARM_TWICE = """
+import json, sys
+from hypergraphdb_tpu import HyperGraph
+from hypergraphdb_tpu.serve import ServeConfig, ServeRuntime
+from tests.conftest import make_random_hypergraph
+
+out = []
+for _ in range(2):          # a pod, then a fresh pod over the same cache
+    g = HyperGraph()
+    make_random_hypergraph(g, n_nodes=80, n_links=160, seed=2)
+    rt = ServeRuntime(g, ServeConfig(
+        sharded=True, buckets=(16,), max_linger_s=0.001, top_r=16,
+        use_pallas_bfs=False, prewarm_aot=True, aot_cache_dir=sys.argv[1],
+        prewarm_pattern_arities=(2,)))
+    out.append(rt.stats_snapshot()["aot"])
+    rt.close()
+    g.close()
+print(json.dumps(out))
+"""
+
+
 def test_sharded_prewarm_hits_aot_cache(tmp_path):
     """Satellite: a fresh pod over a populated cache reaches first
     sharded dispatch with ZERO compiles — every prewarmed sharded bucket
-    program loads from disk."""
-    def build():
-        g = HyperGraph()
-        make_random_hypergraph(g, n_nodes=80, n_links=160, seed=2)
-        return g
+    program loads from disk.
 
-    # a bucket no other test dispatches: XLA:CPU cannot serialize an
-    # executable once it has RUN (its sort comparator is resolved in
-    # place — "`LessThan` is not serializable"), and lower().compile()
-    # hands back the process-cached executable of an identical program
-    cfg = _cfg(sharded=True, buckets=(24,), prewarm_aot=True,
-               aot_cache_dir=str(tmp_path), prewarm_pattern_arities=(2,))
-    g = build()
-    rt = ServeRuntime(g, cfg)
-    first = rt.stats_snapshot()["aot"]
+    In a process of its own: XLA:CPU cannot serialize an executable once
+    it has RUN (its sort comparator is resolved in place — "`LessThan`
+    is not serializable"), and jit hands ``lower().compile()`` the
+    process-cached executable of an identical program, so in-process the
+    first pod's puts would depend on which tests this worker ran before
+    (``AOTCache._store`` logs such a put and goes on: the cache
+    accelerates, it never gates)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    # tests/conftest.py already put the 8-device CPU mesh in os.environ
+    proc = subprocess.run(
+        [sys.executable, "-c", _PREWARM_TWICE, str(tmp_path)],
+        capture_output=True, text=True, timeout=300,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    first, warm = json.loads(proc.stdout.strip().splitlines()[-1])
     assert first["puts"] >= 2          # bfs + pattern sharded programs
-    rt.close()
-    g.close()
-
-    g = build()
-    rt = ServeRuntime(g, cfg)
-    warm = rt.stats_snapshot()["aot"]
     assert warm["misses"] == 0, warm
     assert warm["disk_hits"] >= 2, warm
-    rt.close()
-    g.close()
 
 
 def test_healthz_advertises_mesh_and_partition_map():

@@ -160,6 +160,49 @@ def test_pattern_delta_chains_adds_and_removals(rig):
     assert folded == {int(h) for h in g.find_all(c.Incident(nodes[0]))}
 
 
+def test_two_pumps_one_finished_evaluation(rig):
+    """The runtime's dispatch thread and a caller both pump. A finished
+    evaluation belongs to exactly one of them: the pump that finds it
+    claims it under the lock, and a second pump arriving while the first
+    is still resolving leaves it alone — it neither resolves the answer
+    again nor clears ``inflight`` under a later evaluation."""
+    g, rt, mgr, nodes, links = rig
+    rt.subscriptions = None          # this test does all the pumping
+    resp = mgr.subscribe("pattern", {"anchors": [nodes[0]]})
+    sub = mgr.subs.get(resp["id"])
+    entered, release = threading.Event(), threading.Event()
+    calls = []
+
+    class Finished:
+        truncated = False
+        matches = list(resp["matches"]) + [nodes[5]]
+
+        def done(self):
+            return True
+
+        def result(self):
+            calls.append(threading.current_thread().name)
+            entered.set()
+            assert release.wait(30)
+            return self
+
+    evals0 = mgr.stats.evals
+    with mgr._lock:
+        sub.inflight = (Finished(), mgr.current_seq())
+    first = threading.Thread(target=mgr._resolve_inflight, name="first")
+    first.start()
+    assert entered.wait(30)          # the first pump is mid-resolve
+    mgr._resolve_inflight()          # the second finds nothing to own
+    assert calls == ["first"] and mgr.stats.evals == evals0
+    assert sub.inflight is not None  # still the first pump's to clear
+    release.set()
+    first.join(30)
+    assert calls == ["first"] and mgr.stats.evals == evals0 + 1
+    assert sub.inflight is None and not sub.resolving
+    (note,) = mgr.poll(resp["id"], timeout_s=0.0)["notes"]
+    assert note["added"] == [nodes[5]]
+
+
 def test_irrelevant_ingest_never_fires(rig):
     g, rt, mgr, nodes, links = rig
     sid = mgr.subscribe("pattern", {"anchors": [nodes[0]]})["id"]

@@ -33,11 +33,16 @@ from hypergraphdb_tpu import verify as hgverify
 
 #: chip_smoke.py's sizes, read from it: the kernels phase's row count
 #: (models.dbpedia_snapshot(2M, 8M)) and the serve phase's padded id space
-#: and edge capacity (500K entities + 1M binary links through the store:
-#: every valued atom takes a second handle, times the headroom).
+#: and edge count (1M entities + 2M binary links through the store: every
+#: valued atom takes a second handle, the type system a few thousand, times
+#: the default headroom of 2, rounded up to the pad — SnapshotManager's
+#: arithmetic).
 ROWS_10M = 10_000_065
-SERVE_ATOMS = chip_smoke.serve_id_space(chip_smoke.SCALES["full"])
-SERVE_EDGES = 1 << 21            # 2M incidence = 2M targets, padded
+_FULL = chip_smoke.SCALES["full"]
+_PAD = _FULL["pad_multiple"]
+SERVE_ATOMS = -(-2 * (2 * (_FULL["serve_entities"] + _FULL["serve_links"])
+                      + 4096) // _PAD) * _PAD
+SERVE_EDGES = -(-2 * _FULL["serve_links"] // _PAD) * _PAD
 #: what one v5e chip leaves a program of its 16 GiB (15.75 GiB, in bytes)
 HBM_USABLE = 15.75 * 2**30
 
@@ -167,14 +172,14 @@ def _case_serve_fused(place, top_r=16):
     ), dict(geom=geom, kwp=128, max_hops=2, top_r=top_r)
 
 
-def _case_serve_bfs(place, bucket=256, hops=2):
+def _case_serve_bfs(place, bucket, hops, atoms=SERVE_ATOMS,
+                    edges=SERVE_EDGES):
     from hypergraphdb_tpu.ops.serving import bfs_serve_batch
     from hypergraphdb_tpu.serve import ServeConfig
 
     return bfs_serve_batch, place((
-        hgverify.dev_snapshot_exemplar(SERVE_ATOMS, SERVE_EDGES,
-                                       SERVE_EDGES),
-        hgverify.device_delta_exemplar(SERVE_ATOMS, 1 << 15),
+        hgverify.dev_snapshot_exemplar(atoms, edges, edges),
+        hgverify.device_delta_exemplar(atoms, 1 << 15),
         _sds((bucket,), "int32"),
     )), dict(max_hops=hops, top_r=ServeConfig().top_r + 1)
 
@@ -214,10 +219,11 @@ CASES = {
     "gather_or[1Mx128,128K]": _case_gather_or,
     "membership[1024x128,3x65536]": _case_membership,
     "bfs_serve_batch_fused[K=1024,hops=2,top_r=16]": _case_serve_fused,
-    # the unfused served BFS at the serve phase's graph: past ~270K atoms
-    # a single top_k over the row was refused (scoped VMEM) — first_r_dense
-    "bfs_serve_batch[K=256,hops=3]": partial(_case_serve_bfs, bucket=256,
-                                             hops=3),
+    # the unfused served BFS at the serve phase's graph (past ~270K atoms
+    # a single top_k over the row was refused — scoped VMEM — hence
+    # first_r_dense), at the bucket the executor admits there
+    "bfs_serve_batch[K=64,hops=3]": partial(_case_serve_bfs, bucket=64,
+                                            hops=3),
     "pattern_serve_batch[K=1024]": _case_serve_pattern,
     "range_probe_batch[2M,K=1024]": _case_range_probe,
     "join_hub_expand[R=4096]": _case_join_hub_expand,
@@ -227,23 +233,31 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_main_path_compiles_for_v5e(case, one_chip, no_compile_cache):
     fn, args, kwargs = CASES[case](partial(_place, sharding=one_chip))
+    # compile() is the verdict: a program that does not fit the chip is
+    # refused here (RESOURCE_EXHAUSTED) — one program at a time, not what
+    # else the process holds. (memory_analysis() is not held to the chip's
+    # size: it overstates what the temporaries take — PERF.md, PR 22.)
     compiled = fn.lower(*args, **kwargs).compile()
-    mem = compiled.memory_analysis()
-    # one program at a time, not what else the process holds
-    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
-            + mem.output_size_in_bytes) < HBM_USABLE, mem
     if "hop_call" in case or "gather_or" in case or "membership" in case \
             or "fused" in case:
         assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not interpret
-    if case.startswith("bfs_serve_batch[K=256"):
-        # the smoke's rule for which buckets it may drive rests on this
-        # constant: held to the compiler at the widest bucket that fits;
-        # by the same arithmetic the default config's 1024-seed bucket
-        # plans ~20 GB here and is never driven (PERF.md, PR 22)
-        model = chip_smoke.dense_bfs_bytes(256, SERVE_ATOMS, SERVE_EDGES)
-        assert 0.9 * model <= mem.temp_size_in_bytes <= 1.1 * model
-        assert chip_smoke.dense_bfs_bytes(
-            1024, SERVE_ATOMS, SERVE_EDGES) > HBM_USABLE
+
+
+def test_the_compiler_refuses_the_bfs_bucket_that_does_not_fit(
+        one_chip, no_compile_cache):
+    """``DeviceExecutor.bfs_bucket_cap`` rests on this: a dense BFS program
+    too wide for the chip is REFUSED by its compiler, not accepted and
+    left to fail when it runs. At the serve phase's graph the v5e compiler
+    accepts 256 seeds and refuses 1024 ("Ran out of memory in memory space
+    hbm") — so the executor caps BFS batches at 256 there.
+    (Its ``memory_analysis()`` is no such oracle: it reports 20.4 GB of
+    temporaries for a program that runs on the chip — PERF.md, PR 22.)"""
+    place = partial(_place, sharding=one_chip)
+    fn, args, kw = _case_serve_bfs(place, 256, 2)
+    fn.lower(*args, **kw).compile()
+    fn, args, kw = _case_serve_bfs(place, 1024, 2)
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+        fn.lower(*args, **kw).compile()
 
 
 # ------------------------------------------------- widths: gate == compiler
