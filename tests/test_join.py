@@ -805,7 +805,9 @@ def test_serve_join_bushy_signature_batch(graph):
         futs = [(x, y, rt.submit_join(spec_of(x, y)))
                 for x, y in pairs]
         for x, y, f in futs:
-            res = f.result(timeout=60)
+            # (a hang guard, not a bound: the bushy plan's compiles take
+            # ~10 s alone and have passed 60 s beside five busy workers)
+            res = f.result(timeout=300)
             truth = join.host_join(
                 graph, join.extract_pattern(graph, spec_of(x, y))
             )

@@ -219,18 +219,22 @@ CASES = {
     "gather_or[1Mx128,128K]": _case_gather_or,
     "membership[1024x128,3x65536]": _case_membership,
     "bfs_serve_batch_fused[K=1024,hops=2,top_r=16]": _case_serve_fused,
-    # the unfused served BFS at the serve phase's graph (past ~270K atoms
-    # a single top_k over the row was refused — scoped VMEM — hence
-    # first_r_dense), at the bucket the executor admits there
-    "bfs_serve_batch[K=64,hops=3]": partial(_case_serve_bfs, bucket=64,
-                                            hops=3),
+    # the unfused served BFS at the serve phase's graph and the widest
+    # bucket the executor admits there (past ~270K atoms a single top_k
+    # over the row was refused — scoped VMEM — hence first_r_dense). slow:
+    # each of these dense programs keeps every core busy for ~25 s, and
+    # the smoke compiles and runs this one on the chip
+    "bfs_serve_batch[K=256,hops=3]": partial(_case_serve_bfs, bucket=256,
+                                             hops=3),
     "pattern_serve_batch[K=1024]": _case_serve_pattern,
     "range_probe_batch[2M,K=1024]": _case_range_probe,
     "join_hub_expand[R=4096]": _case_join_hub_expand,
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("case", [
+    pytest.param(c, marks=pytest.mark.slow) if c.startswith("bfs_serve_batch[")
+    else c for c in sorted(CASES)])
 def test_main_path_compiles_for_v5e(case, one_chip, no_compile_cache):
     fn, args, kwargs = CASES[case](partial(_place, sharding=one_chip))
     # compile() is the verdict: a program that does not fit the chip is
@@ -248,14 +252,13 @@ def test_the_compiler_refuses_the_bfs_bucket_that_does_not_fit(
     """``DeviceExecutor.bfs_bucket_cap`` rests on this: a dense BFS program
     too wide for the chip is REFUSED by its compiler, not accepted and
     left to fail when it runs. At the serve phase's graph the v5e compiler
-    accepts 256 seeds and refuses 1024 ("Ran out of memory in memory space
-    hbm") — so the executor caps BFS batches at 256 there.
-    (Its ``memory_analysis()`` is no such oracle: it reports 20.4 GB of
-    temporaries for a program that runs on the chip — PERF.md, PR 22.)"""
-    place = partial(_place, sharding=one_chip)
-    fn, args, kw = _case_serve_bfs(place, 256, 2)
-    fn.lower(*args, **kw).compile()
-    fn, args, kw = _case_serve_bfs(place, 1024, 2)
+    refuses 1024 seeds ("Ran out of memory in memory space hbm") — and
+    accepts 256 (the slow case above; the smoke runs it on the chip), so
+    the executor caps BFS batches at 256 there. (``memory_analysis()`` is
+    no such oracle: it reports 20.4 GB of temporaries for a program that
+    runs on the chip — PERF.md, PR 22.)"""
+    fn, args, kw = _case_serve_bfs(partial(_place, sharding=one_chip),
+                                   1024, 2)
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
         fn.lower(*args, **kw).compile()
 
