@@ -49,10 +49,10 @@ class Driver:
         runs = []
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < seconds:
-            self.last = None            # free the bitmap before the next
+            # free the bitmap before the next: no other name may hold it
+            self.last = None
             seeds = self._seeds()
-            res, counts = self._traverse(seeds)
-            self.last = res
+            self.last, counts = self._traverse(seeds)
             runs.append({"seeds": seeds, "counts": counts,
                          "t_done": time.perf_counter() - t0})
         window_s = time.perf_counter() - t0
@@ -139,3 +139,12 @@ class Driver:
         return {"counts_differ": (counts_differ, 0),
                 "bitmap_rows_differ": (rows_differ, 0),
                 "seeds_compared": (len(got["picks"]), None)}
+
+    def control(self, got: dict) -> dict:
+        """The comparison of the CONTROL's answers: the reference in the
+        program's place with one stated guarantee broken — an approximate
+        traversal that looks at no more than ``control_row_cap`` incident
+        links of an atom. It has to come out as not correct."""
+        return self.check(self.reference(
+            got["picks"], got["n_last"],
+            row_cap=self.traffic["control_row_cap"]))

@@ -336,3 +336,18 @@ class Driver:
             for a, w in zip(answers, want))
         return {"answers_wrong": (wrong, 0), "answers_missing": (missing, 0),
                 "answers_compared": (len(answers), None)}
+
+    def control(self, got: dict) -> dict:
+        """The comparison of the CONTROL's answers: the reference in the
+        program's place with one stated guarantee broken — a stale reader,
+        to which the newest ``control_stale_links`` acknowledged links (the
+        last ``bulk_import``) are invisible. It has to come out as not
+        correct."""
+        qs = [a["q"] for a in got["answers"]]
+        answers = []
+        for q, w in zip(qs, self.reference(
+                qs, stale_links=self.traffic["control_stale_links"])):
+            rows = w if q["kind"] == "planned" else w[: self.top_r]
+            answers.append({"q": q, "missing": False, "count": len(w),
+                            "rows": rows, "truncated": len(w) > len(rows)})
+        return self.check({"answers": answers})

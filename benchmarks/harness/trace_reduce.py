@@ -52,6 +52,12 @@ def module_key(name: str) -> str:
     return re.sub(r"\(\d+\)$", "", name)
 
 
+def op_key(name: str) -> str:
+    """``%fusion.3 = u32[...] fusion(...)`` -> ``fusion.3``: an operation
+    goes by its HLO name, without its shapes."""
+    return name.split(" = ", 1)[0].lstrip("%")
+
+
 def _top(table: dict, n: int = 10) -> list:
     return [[k, v] for k, v in sorted(table.items(),
                                       key=lambda kv: -kv[1])[:n]]
@@ -73,7 +79,8 @@ def reduce_planes(planes, window_s: float | None = None) -> dict:
             for ev in line.events:
                 iv.append((ev.start_ns, ev.start_ns + ev.duration_ns))
                 if line.name == OPS_LINE:
-                    ops[ev.name] = ops.get(ev.name, 0.0) + ev.duration_ns / 1e9
+                    op = op_key(ev.name)
+                    ops[op] = ops.get(op, 0.0) + ev.duration_ns / 1e9
             merged = union(iv)
             merged_all.append(merged)
             per_device_busy.append(sum(e - s for s, e in merged) / 1e9)

@@ -81,9 +81,10 @@ def place_caches() -> dict:
         jax.config.update("jax_compilation_cache_dir", jax_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    os.environ["HG_PLAN_CACHE"] = os.path.join(ROOT, ".plan_cache")
-    return {"jax": jax_dir, "plans": os.environ["HG_PLAN_CACHE"],
-            "aot": os.path.join(ROOT, ".aot_cache")}
+    # no plan cache: it is keyed by the graph's content, every seed makes
+    # another graph, and a 10M-atom plan is 0.74 GB on disk for a run
+    os.environ.pop("HG_PLAN_CACHE", None)
+    return {"jax": jax_dir, "aot": os.path.join(ROOT, ".aot_cache")}
 
 
 class CompileCount:
@@ -106,7 +107,10 @@ class CompileCount:
         return {"compiled": self.n[self.MISS], "loaded": self.n[self.HIT]}
 
 
-def main(argv=None) -> int:
+def main(argv=None, also=None) -> int:
+    """One run. ``also(driver, got)`` is for the tests beside the benchmark
+    (the control): it is given the answers the comparison was given, and
+    what it returns is printed under ``also`` in the result."""
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload")
     ap.add_argument("--seed", type=int, default=0)
@@ -191,6 +195,7 @@ def main(argv=None) -> int:
     got = driver.collect()
     compared = driver.check(got)
     check_s = time.perf_counter() - t0
+    extra = also(driver, got) if also is not None else None
     # (a number without a limit says how much was compared)
     checked = {k: v for k, (v, lim) in compared.items() if lim is None}
     compared = {k: vl for k, vl in compared.items() if vl[1] is not None}
@@ -211,7 +216,6 @@ def main(argv=None) -> int:
             device["busy_s"], device["window_s"] = trace["busy_s"], traced_s
             out["breakdown"] = trace["breakdown"]
             window["modules"] = trace["modules"]
-        shutil.rmtree(trace_dir, ignore_errors=True)
         ctx = {"cell": cell, "config": cfg, "traffic": traffic,
                "window": window, "setup": setup, "trace": trace,
                "device": device}
@@ -238,6 +242,7 @@ def main(argv=None) -> int:
         "setup": {k: v for k, v in setup.items() if k != "checkout"},
         "generator": window.get("generator"),
         "counters": window.get("counters"), "checked": checked,
+        "also": extra,
         "compared": {k: {"value": v, "limit": lim}
                      for k, (v, lim) in compared.items()},
     }
