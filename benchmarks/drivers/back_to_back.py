@@ -127,8 +127,8 @@ class Driver:
                                  row_cap=row_cap)
         keep = np.uint64((1 << n_last) - 1)
         return {"picks": picks, "n_last": n_last, "bitmap": vis & keep,
-                "counts": [len(refs.bits_column(vis, j))
-                           for j in range(len(picks))]}
+                "counts": [len(c) for c in
+                           refs.bits_columns(vis, len(picks))]}
 
     def check(self, got: dict) -> dict:
         """Every number compared, beside its limit: all exact."""

@@ -40,6 +40,7 @@ class Requests:
         np.maximum.at(nbr, sut.link_a, deg[sut.link_b])
         np.maximum.at(nbr, sut.link_b, deg[sut.link_a])
         cap = traffic["join"]["max_neighbourhood_degree"]
+        self.deg = deg
         self.join_ok = (deg <= cap) & (nbr <= cap)
 
     def _endpoint(self, li: int) -> int:
@@ -75,10 +76,14 @@ class Requests:
                 a = self._endpoint(int(r.integers(0, n_links)))
                 if self.join_ok[a]:
                     return {"kind": kind, "atoms": [a]}
+        windowed = i % t["planned"]["window_every"] == 0
+        while windowed and self.deg[s.link_b[li]] > \
+                t["planned"]["window_anchor_max_degree"]:
+            li = int(r.integers(0, n_links))
         a, b, v = int(s.link_a[li]), int(s.link_b[li]), int(s.link_val[li])
         w = t["planned"]["window_half_width"]
-        window = (None if i % t["planned"]["window_every"] else (v - w, v + w))
-        return {"kind": kind, "atoms": [a, b], "window": window}
+        return {"kind": kind, "atoms": [a, b],
+                "window": (v - w, v + w) if windowed else None}
 
     def _block(self) -> list:
         kinds = [k for k in KINDS for _ in range(self.t["mix_per_100"][k])]
@@ -92,6 +97,12 @@ class Requests:
 
     def of_kind(self, kind: str, n: int) -> list:
         return [self._one(kind) for _ in range(n)]
+
+    def joins_of_degree(self, degree: int, n: int) -> list:
+        """Warm-up only: up to ``n`` joins anchored at that exact degree."""
+        fit = np.flatnonzero(self.join_ok & (self.deg == degree))
+        return [{"kind": "join", "atoms": [int(a)]}
+                for a in self.r.permutation(fit)[:n]]
 
 
 def submit(rt, q: dict):
@@ -126,6 +137,7 @@ COUNTERS = ("submitted", "completed", "shed_deadline", "rejected_queue_full",
 class Driver:
     def __init__(self, sut, cfg: dict, traffic: dict, seed: int, setup: dict):
         self.sut, self.traffic, self.seed = sut, traffic, seed
+        self.setup = setup
         self.top_r = sut.serve_config.top_r
 
     # -- the loop ---------------------------------------------------------
@@ -201,21 +213,57 @@ class Driver:
         for rec in list(recs):
             while rec["t_done"] is None and time.perf_counter() < deadline:
                 time.sleep(0.01)
+        late = [r for r in recs if r["t_done"] is None]
+        if late:
+            self._say_where_it_hangs(late, t_close)
         return recs, t0, t_close
 
+    def _say_where_it_hangs(self, late: list, t_close: float) -> None:
+        """Requests that no answer came for: what they are, what the
+        runtime counts, and where every thread of the process stands."""
+        import sys
+        import traceback
+
+        kinds: dict = {}
+        for r in late:
+            k = r["q"]["kind"]
+            kinds[k] = kinds.get(k, 0) + 1
+        sent = sorted(round(r["t_send"] - t_close, 2) for r in late)
+        print(f"bench: {len(late)} requests unanswered "
+              f"{self.traffic['straggler_timeout_s']} s past the close: "
+              f"{kinds}; sent at {sent[0]}..{sent[-1]} s of the close; "
+              f"runtime: {self.sut.rt.stats_snapshot()}", file=sys.stderr)
+        names = {th.ident: th.name for th in threading.enumerate()}
+        for ident, frame in sys._current_frames().items():
+            print(f"bench: thread {names.get(ident, ident)}:\n"
+                  + "".join(traceback.format_stack(frame)[-8:]),
+                  file=sys.stderr)
+
     def warm(self) -> None:
-        """Every lane at the widths the window can form: per kind, one
-        burst wide enough to fill the widest bucket the executor admits
-        and then the narrow ones; then the mix itself for a while."""
+        """Every lane at the shapes the window can form: per kind, one
+        burst (wide enough to fill the widest bucket the executor admits,
+        where the traffic file says so), then a narrow batch; a join's pads
+        follow the widest row among a batch's anchors, so one narrow batch
+        per anchor degree up to the lane's cap; then the mix itself for a
+        while."""
         rt, t = self.sut.rt, self.traffic
         warm = Requests(self.sut, t, self.seed, stream=0)
-        for kind in KINDS:
-            futs = [submit(rt, q) for q in warm.of_kind(kind, t["warm_burst"])]
-            for f in futs:
+
+        def batch(qs):
+            for f in [submit(rt, q) for q in qs]:
                 f.result(timeout=900)
-            for q in warm.of_kind(kind, 3):
-                submit(rt, q).result(timeout=900)
+
+        for kind in KINDS:
+            t0 = time.perf_counter()
+            batch(warm.of_kind(kind, t["warm_burst"][kind]))
+            batch(warm.of_kind(kind, 3))
+            if kind == "join":
+                for d in range(1, t["join"]["max_neighbourhood_degree"] + 1):
+                    batch(warm.joins_of_degree(d, 3))
+            self.setup[f"warm_{kind}_s"] = time.perf_counter() - t0
+        t0 = time.perf_counter()
         self._loop(warm, None, t["warm_mixed"])
+        self.setup["warm_mixed_s"] = time.perf_counter() - t0
 
     def run(self, seconds: float) -> dict:
         before = self.counters()
@@ -227,6 +275,7 @@ class Driver:
         window_s = t_close - t0
         timeout_ms = 1e3 * (seconds + self.traffic["straggler_timeout_s"])
         lat, by_lane, failed, in_window = [], {"bfs": [], "other": []}, 0, 0
+        by_kind: dict = {}
         bfs_by_hops: dict = {}
         for rec in recs:
             ok = rec["error"] is None and rec["t_done"] is not None
@@ -241,6 +290,8 @@ class Driver:
             failed += not ok
             lat.append(ms)
             by_lane["bfs" if rec["q"]["kind"] == "bfs" else "other"].append(ms)
+            by_kind.setdefault(rec["q"]["kind"] + str(rec["q"].get("hops", "")),
+                               []).append(ms)
             in_window += ok and rec["t_done"] <= t_close
             if ok and rec["q"]["kind"] == "bfs":
                 # (stragglers too: a traced window holds their device time)
@@ -258,6 +309,9 @@ class Driver:
                            "served_p95_ms": pct(lat, 95)},
             "lane_p95_ms": {k: pct(v, 95) for k, v in by_lane.items()},
             "completed_in_window": in_window,
+            "latency_ms_by_kind": {
+                k: {"n": len(v), "p50": pct(v, 50), "p95": pct(v, 95),
+                    "max": max(v)} for k, v in sorted(by_kind.items())},
             "counters": {k: after[k] - before[k] for k in after},
             "bfs_bytes": bytes_model.served_bfs_bytes(
                 requests_by_hops=bfs_by_hops, **self.sut.shapes),
@@ -305,7 +359,9 @@ class Driver:
         self.sut_arrays = (self.sut.link_h, self.sut.link_a, self.sut.link_b,
                            self.sut.link_val, self.sut.link_type)
         self.recs = None
+        t0 = time.perf_counter()
         self.sut.close()
+        self.setup["close_s"] = time.perf_counter() - t0
         return {"answers": got}
 
     def reference(self, qs: list, stale_links: int = 0) -> list:

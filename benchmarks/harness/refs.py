@@ -62,6 +62,15 @@ def bits_column(vis: np.ndarray, k: int) -> np.ndarray:
     return np.flatnonzero((vis >> np.uint64(k)) & np.uint64(1))
 
 
+def bits_columns(vis: np.ndarray, n: int) -> list:
+    """``bits_column`` for the first ``n`` bits, looking only at the atoms
+    that any seed reached."""
+    reached = np.flatnonzero(vis)
+    words = vis[reached]
+    return [reached[(words >> np.uint64(k)) & np.uint64(1) != 0]
+            for k in range(n)]
+
+
 class ServeReference:
     """Host answers for the served lanes over binary valued links.
 
@@ -111,7 +120,7 @@ class ServeReference:
             part = np.asarray(seeds[s0: s0 + 64], dtype=np.int64)
             vis = host_bfs_bits(self.n_ids, flat, link_of, n, part, hops,
                                 prepared=self._bfs_prepared)
-            out.extend(bits_column(vis, k) for k in range(len(part)))
+            out.extend(bits_columns(vis, len(part)))
         return out
 
     def answer(self, q: dict):

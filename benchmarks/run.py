@@ -97,14 +97,51 @@ class CompileCount:
         import jax
 
         self.n = {self.MISS: 0, self.HIT: 0}
+        self.programs: list = []       # (when, name, seconds)
         jax.monitoring.register_event_listener(self._on)
+        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
 
     def _on(self, name: str, **_kw) -> None:
         if name in self.n:
             self.n[name] += 1
 
+    def _on_secs(self, name: str, secs: float, **kw) -> None:
+        if name == "/jax/core/compile/backend_compile_duration":
+            self.programs.append((time.perf_counter(), kw.get("fun_name"),
+                                  secs))
+
+    def since(self, t0: float) -> list:
+        """Programs compiled, or loaded from the cache, since ``t0``."""
+        return [[name, secs] for t, name, secs in self.programs if t >= t0]
+
     def read(self) -> dict:
         return {"compiled": self.n[self.MISS], "loaded": self.n[self.HIT]}
+
+
+class GcWatch:
+    """The interpreter's garbage collections, timed: a full collection over
+    a store's millions of objects stops every thread of the process."""
+
+    def __init__(self):
+        import gc
+
+        self.pauses: list = []          # (when, generation, seconds)
+        self._t0 = 0.0
+        gc.callbacks.append(self._on)
+
+    def _on(self, phase: str, info: dict) -> None:
+        now = time.perf_counter()
+        if phase == "start":
+            self._t0 = now
+        else:
+            self.pauses.append((now, info["generation"], now - self._t0))
+
+    def since(self, t0: float) -> dict:
+        mine = [(g, s) for t, g, s in self.pauses if t >= t0]
+        full = [s for g, s in mine if g == 2]
+        return {"collections": len(mine), "full": len(full),
+                "full_s": sum(full), "seconds": sum(s for _, s in mine),
+                "longest_s": max([s for _, s in mine], default=0.0)}
 
 
 def main(argv=None, also=None) -> int:
@@ -153,7 +190,7 @@ def main(argv=None, also=None) -> int:
               "count": len(devices)}
     note(f"platform: {dev.platform}, device_kind: {dev.device_kind}, "
          f"count: {len(devices)}; caches {caches}")
-    compiles = CompileCount()
+    compiles, gcs = CompileCount(), GcWatch()
 
     setup: dict = {"checkout": ROOT}
     sut = load_module("builders", cfg["builder"]).build(cfg, args.seed, setup)
@@ -169,7 +206,7 @@ def main(argv=None, also=None) -> int:
 
     # ---- the measured window
     trace_dir = os.path.join(ROOT, ".bench_trace")
-    before = compiles.read()
+    before, t_window = compiles.read(), time.perf_counter()
     if args.trace:
         from hypergraphdb_tpu.obs.device import profile
 
@@ -184,6 +221,8 @@ def main(argv=None, also=None) -> int:
         window = driver.run(args.seconds)
     after = compiles.read()
     window["compiles_in_window"] = after["compiled"] - before["compiled"]
+    window["programs_in_window"] = compiles.since(t_window)
+    window["gc_in_window"] = gcs.since(t_window)
     note(f"window closed: {window['window_s']:.2f} s, "
          f"{window['attempted']} attempted, {window['failed']} failed")
     stats = dev.memory_stats() or {}
@@ -193,6 +232,7 @@ def main(argv=None, also=None) -> int:
     # ---- the comparison, once the program's state is freed
     t0 = time.perf_counter()
     got = driver.collect()
+    collect_s = time.perf_counter() - t0
     compared = driver.check(got)
     check_s = time.perf_counter() - t0
     extra = also(driver, got) if also is not None else None
@@ -215,7 +255,9 @@ def main(argv=None, also=None) -> int:
             note(f"trace reduced in {time.perf_counter() - t0:.1f} s")
             device["busy_s"], device["window_s"] = trace["busy_s"], traced_s
             out["breakdown"] = trace["breakdown"]
-            window["modules"] = trace["modules"]
+            out["device_modules"] = [
+                [k, v, trace["module_runs"][k]] for k, v in sorted(
+                    trace["modules"].items(), key=lambda kv: -kv[1])[:8]]
         ctx = {"cell": cell, "config": cfg, "traffic": traffic,
                "window": window, "setup": setup, "trace": trace,
                "device": device}
@@ -238,9 +280,13 @@ def main(argv=None, also=None) -> int:
         **out,
         "workload": args.workload, "seed": args.seed, "rehearsal": args.rehearse,
         "window_s": window["window_s"], "check_s": check_s,
+        "collect_s": collect_s,
         "compiles_in_window": window["compiles_in_window"],
+        "programs_in_window": window["programs_in_window"],
+        "gc_in_window": window["gc_in_window"],
         "setup": {k: v for k, v in setup.items() if k != "checkout"},
         "generator": window.get("generator"),
+        "latency_ms_by_kind": window.get("latency_ms_by_kind"),
         "counters": window.get("counters"), "checked": checked,
         "also": extra,
         "compared": {k: {"value": v, "limit": lim}
