@@ -66,9 +66,6 @@ class Driver:
                 **self.sut.shapes),
         }
 
-    def counters(self) -> dict:
-        return {}
-
     def sample(self) -> list:
         """(traversal, column) pairs to hold to the reference: half from
         the last traversal (its bitmap is still on the device), the rest

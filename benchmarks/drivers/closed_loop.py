@@ -28,20 +28,16 @@ KINDS = ("bfs", "pattern", "range", "join", "planned")
 class Requests:
     """The request stream, generated block by block from the seed."""
 
-    def __init__(self, sut, traffic: dict, seed: int, stream: int):
+    def __init__(self, sut, traffic: dict, seed: int, stream: int,
+                 degrees: tuple):
         self.sut, self.t = sut, traffic
         self.r = np.random.default_rng([seed, 21, stream])
         self.made = {k: 0 for k in KINDS}
         self.lock = threading.Lock()
         self.buf: list = []
-        deg = np.bincount(np.concatenate([sut.link_a, sut.link_b]),
-                          minlength=sut.e0 + sut.n_entities)
-        nbr = np.zeros_like(deg)
-        np.maximum.at(nbr, sut.link_a, deg[sut.link_b])
-        np.maximum.at(nbr, sut.link_b, deg[sut.link_a])
         cap = traffic["join"]["max_neighbourhood_degree"]
-        self.deg = deg
-        self.join_ok = (deg <= cap) & (nbr <= cap)
+        self.deg, nbr = degrees
+        self.join_ok = (self.deg <= cap) & (nbr <= cap)
 
     def _endpoint(self, li: int) -> int:
         s = self.sut
@@ -105,6 +101,16 @@ class Requests:
                 for a in self.r.permutation(fit)[:n]]
 
 
+def degrees_of(sut) -> tuple:
+    """Per atom: its degree, and the widest degree among its neighbours."""
+    deg = np.bincount(np.concatenate([sut.link_a, sut.link_b]),
+                      minlength=sut.e0 + sut.n_entities)
+    nbr = np.zeros_like(deg)
+    np.maximum.at(nbr, sut.link_a, deg[sut.link_b])
+    np.maximum.at(nbr, sut.link_b, deg[sut.link_a])
+    return deg, nbr
+
+
 def submit(rt, q: dict):
     from hypergraphdb_tpu.query import conditions as c
     from hypergraphdb_tpu.query.variables import var
@@ -139,12 +145,14 @@ class Driver:
         self.sut, self.traffic, self.seed = sut, traffic, seed
         self.setup = setup
         self.top_r = sut.serve_config.top_r
+        self.degrees = degrees_of(sut)
 
     # -- the loop ---------------------------------------------------------
     def _loop(self, requests: Requests, seconds: float | None,
               total: int | None) -> tuple:
         """Run the closed loop for ``seconds`` (or until ``total`` requests
-        were sent); returns (records, window length, stragglers' wait)."""
+        were sent), then wait for the stragglers; returns (one record per
+        request, the loop's start, its close)."""
         rt, t = self.sut.rt, self.traffic
         ready: queue.SimpleQueue = queue.SimpleQueue()
         recs: list = []
@@ -247,7 +255,7 @@ class Driver:
         per anchor degree up to the lane's cap; then the mix itself for a
         while."""
         rt, t = self.sut.rt, self.traffic
-        warm = Requests(self.sut, t, self.seed, stream=0)
+        warm = Requests(self.sut, t, self.seed, 0, self.degrees)
 
         def batch(qs):
             for f in [submit(rt, q) for q in qs]:
@@ -268,7 +276,7 @@ class Driver:
     def run(self, seconds: float) -> dict:
         before = self.counters()
         recs, t0, t_close = self._loop(
-            Requests(self.sut, self.traffic, self.seed, stream=1),
+            Requests(self.sut, self.traffic, self.seed, 1, self.degrees),
             seconds, None)
         after = self.counters()
         self.recs = recs

@@ -57,14 +57,9 @@ def host_bfs_bits(n_ids: int, flat: np.ndarray, link_of: np.ndarray,
     return vis
 
 
-def bits_column(vis: np.ndarray, k: int) -> np.ndarray:
-    """Sorted atom ids whose bit ``k`` is set."""
-    return np.flatnonzero((vis >> np.uint64(k)) & np.uint64(1))
-
-
 def bits_columns(vis: np.ndarray, n: int) -> list:
-    """``bits_column`` for the first ``n`` bits, looking only at the atoms
-    that any seed reached."""
+    """For each of the first ``n`` bits, the sorted atom ids that have it
+    set; looks only at the atoms that any seed reached."""
     reached = np.flatnonzero(vis)
     words = vis[reached]
     return [reached[(words >> np.uint64(k)) & np.uint64(1) != 0]

@@ -48,6 +48,13 @@ def load_module(kind: str, name: str):
 def load_cell(workload: str, rehearse: bool) -> dict:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    # cells that run but are not admitted yet are found the same way
+    with open(os.path.join(HERE, "candidates.json")) as f:
+        waiting = json.load(f)
+    for part in ("configs", "workloads", "end_to_end", "per_layer"):
+        known = {e["name"] for e in bench[part]}
+        bench[part] = bench[part] + [e for e in waiting[part]
+                                     if e["name"] not in known]
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise SystemExit(f"no workload {workload!r} in BENCHMARK.json; "

@@ -32,7 +32,9 @@ def main(argv=None) -> int:
                for k, (v, lim) in out["also"].items() if lim is not None}
     control_correct = all(c["value"] <= c["limit"] for c in control.values())
     print(json.dumps({"workload": out["workload"], "seed": out["seed"],
-                      "device": out["device"], "correct": out["correct"],
+                      "device": out["device"], "metrics": out["metrics"],
+                      "breakdown": out.get("breakdown"),
+                      "check_s": out["check_s"], "correct": out["correct"],
                       "compared": out["compared"], "checked": out["checked"],
                       "control_correct": control_correct,
                       "control_compared": control}), flush=True)
