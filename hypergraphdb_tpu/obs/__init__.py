@@ -15,8 +15,9 @@ this repro had faithfully reproduced as ``utils.metrics.Metrics`` vs
   counters/gauges/log-bucketed histograms under dotted namespaces
   (``serve.*``, ``graph.*``, ``compact.*``, ``query.*``, ``tx.*``);
 - **device timing** (:mod:`~hypergraphdb_tpu.obs.device`): opt-in
-  launch→ready wall deltas, per-dispatch profiler annotations, and a
-  gated ``jax.profiler`` session;
+  launch→ready wall deltas, per-dispatch profiler annotations, a gated
+  ``jax.profiler`` session, and ``phase`` for coarse synced host steps
+  (seconds in the registry, a host span on the device trace's clock);
 - **export** (:mod:`~hypergraphdb_tpu.obs.export`): Prometheus text and
   schema-versioned JSONL traces;
 - **flight recorder** (:mod:`~hypergraphdb_tpu.obs.flight`): an
@@ -65,7 +66,13 @@ Usage::
 """
 
 from hypergraphdb_tpu.obs import device, export, fleet, flight, http, perf, slo
-from hypergraphdb_tpu.obs.device import annotate, block_timed, profile
+from hypergraphdb_tpu.obs.device import (
+    annotate,
+    block_timed,
+    phase,
+    profile,
+    profiling,
+)
 from hypergraphdb_tpu.obs.export import (
     TRACE_SCHEMA_VERSION,
     merge_expositions,
@@ -167,7 +174,9 @@ __all__ = [
     "parse_flight_jsonl",
     "parse_traces_jsonl",
     "perf",
+    "phase",
     "profile",
+    "profiling",
     "prometheus_text",
     "relabel_exposition",
     "runtime_health",

@@ -27,14 +27,24 @@ device actually ran. Each ``device`` span likewise carries its ``slot``
 Both hooks are clean no-ops when jax/profiling is unavailable — call
 sites carry the knob unconditionally.
 
+**Coarse host steps**: :func:`phase` is the one primitive for a host step
+that is synced at its end (a plan build, an upload, one stage of a hop):
+seconds into the default registry always, a host span on the device
+trace's clock while a session is open, a child span under the thread's
+current trace. ``PERF.md`` section 3 lists every name and its reader.
+
 No module-level jax import: the deterministic tier-1 tests import obs
 with zero device work.
 """
 
 from __future__ import annotations
 
+import time
 from contextlib import contextmanager
 from typing import Callable, Optional
+
+from hypergraphdb_tpu.obs.registry import default_registry
+from hypergraphdb_tpu.obs.trace import global_tracer
 
 #: True while a profile() session is open — the serving executor gates
 #: its per-dispatch TraceAnnotations on (device_timing or this), so a
@@ -63,6 +73,33 @@ def annotate(name: str):
         yield True
 
 
+@contextmanager
+def phase(name: str):
+    """A coarse host step, named ``hg.<layer>.<step>``, around a dispatch
+    AND the sync that ends it. Always: the elapsed ``time.perf_counter()``
+    seconds go into the default registry's histogram ``phase.<name>`` (its
+    ``total`` and ``count`` are what readers use), whether the body raises
+    or not. While an ``obs.profile`` session is open: also a
+    ``TraceAnnotation``, so the step is a host span on the device trace's
+    clock and the trace reducer charges the device's idle gaps to it.
+    Under the thread's current trace of the process tracer: also a child
+    span, through ``Tracer.span``.
+
+    Rule: a phase is per call, per hop or per dispatch — NEVER per
+    request, row or chunk (a 3-hop traversal records about 20)."""
+    with global_tracer().span(name):
+        t0 = time.perf_counter()
+        try:
+            if _PROFILING:
+                with annotate(name):
+                    yield
+            else:
+                yield
+        finally:
+            default_registry().histogram(f"phase.{name}").observe(
+                time.perf_counter() - t0)
+
+
 def block_timed(handles, clock: Callable[[], float]) -> tuple:
     """Block until ``handles`` (any pytree of jax arrays) are ready;
     returns ``(handles, t_ready)``. Against a launch timestamp taken on
@@ -80,7 +117,11 @@ def profile(logdir: Optional[str]):
     context when ``logdir`` is falsy or the profiler is unavailable (CPU
     CI images without profiling support must not error). Sets the
     :func:`profiling` flag so dispatch sites turn their per-batch
-    :func:`annotate` markers on for the session's duration."""
+    :func:`annotate` markers on for the session's duration. The session
+    traces the host's ``TraceAnnotation``s and the device, NOT the Python
+    interpreter: the Python tracer slows every host thread (under it a
+    served window left requests unanswered, ``PERF.md`` section 6) and
+    makes the trace large."""
     global _PROFILING
 
     if not logdir:
@@ -89,7 +130,10 @@ def profile(logdir: Optional[str]):
     try:
         import jax
 
-        jax.profiler.start_trace(logdir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1  # TraceAnnotations only
+        jax.profiler.start_trace(logdir, profiler_options=options)
     except Exception:
         yield False
         return

@@ -42,6 +42,7 @@ total index count per hop drops from ``K × E`` to ``~1.3 × E × (1 + 1/w)``
 from __future__ import annotations
 
 import os
+from contextlib import nullcontext
 from dataclasses import dataclass
 from functools import partial
 from typing import NamedTuple, Optional, Sequence
@@ -51,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from hypergraphdb_tpu import verify as hgverify
+from hypergraphdb_tpu.obs.device import phase
 from hypergraphdb_tpu.ops import pallas_gather as _pg
 from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 
@@ -246,6 +248,7 @@ def _apply_plan(
     widths: Sequence[int],
     chunk: int,
     use_pallas: bool = False,
+    scopes: Optional[tuple[str, str]] = None,
 ) -> jax.Array:
     """Run the reduction pyramid; returns the CONCATENATION of every
     level's chunk array plus one global zero row at the end — the address
@@ -260,15 +263,24 @@ def _apply_plan(
     (512-byte rows) was the difference between ~13 GB peak and
     ResourceExhausted. Upper levels gather FROM the buffer itself with
     host-local indices rebased on device (pad marker ``n_prev`` → the
-    global zero row); their outputs are small enough to materialize."""
+    global zero row); their outputs are small enough to materialize.
+
+    ``scopes`` names the device operations of (level 0, the upper levels)
+    for a profile, as ``jax.named_scope`` components of their ``op_name``;
+    a caller that passes none leaves them unnamed."""
     Kw = values.shape[1]
     sizes = [lvl.shape[0] // w for lvl, w in zip(levels, widths)]
     total = sum(sizes) + 1  # + global zero row at index `sum(sizes)`
-    buf = jnp.zeros((total, Kw), dtype=values.dtype)
-    buf = _reduce_into(buf, 0, values, levels[0], widths[0], chunk,
-                       use_pallas)
-    return _upper_levels(buf, levels[1:], widths[1:], sizes, sizes[0],
-                         chunk)
+    lvl0_scope, upper_scope = (
+        map(jax.named_scope, scopes) if scopes
+        else (nullcontext(), nullcontext()))
+    with lvl0_scope:
+        buf = jnp.zeros((total, Kw), dtype=values.dtype)
+        buf = _reduce_into(buf, 0, values, levels[0], widths[0], chunk,
+                           use_pallas)
+    with upper_scope:
+        return _upper_levels(buf, levels[1:], widths[1:], sizes, sizes[0],
+                             chunk)
 
 
 def _upper_levels(
@@ -530,27 +542,34 @@ def plans_for(snap: CSRSnapshot) -> PullBFSPlans:
     10M-scale rebuild entirely."""
     plans = getattr(snap, "_pull_plans", None)
     if plans is None:
-        cache_dir = os.environ.get("HG_PLAN_CACHE")
-        cache_path = None
-        fp = None
-        if cache_dir:
-            os.makedirs(cache_dir, exist_ok=True)
-            fp = snapshot_fingerprint(snap)
-            cache_path = os.path.join(cache_dir, f"pullplans_{fp}.npz")
-            if os.path.exists(cache_path):
-                try:
-                    plans = load_plans(cache_path, expect_fingerprint=fp)
-                except Exception:
-                    plans = None  # stale/corrupt cache entry → rebuild
-        if plans is None:
-            plans = build_pull_plans(snap)
-            if cache_path is not None:
-                # .npz suffix keeps np.savez from appending another one;
-                # write-then-rename = no torn cache entries
-                tmp = cache_path[:-4] + ".tmp.npz"
-                save_plans(plans, tmp, fingerprint=fp)
-                os.replace(tmp, cache_path)
+        with phase("hg.bfs.plan"):
+            plans = _build_or_load_plans(snap)
         object.__setattr__(snap, "_pull_plans", plans)
+    return plans
+
+
+def _build_or_load_plans(snap: CSRSnapshot) -> PullBFSPlans:
+    cache_dir = os.environ.get("HG_PLAN_CACHE")
+    cache_path = None
+    fp = None
+    plans = None
+    if cache_dir:
+        os.makedirs(cache_dir, exist_ok=True)
+        fp = snapshot_fingerprint(snap)
+        cache_path = os.path.join(cache_dir, f"pullplans_{fp}.npz")
+        if os.path.exists(cache_path):
+            try:
+                plans = load_plans(cache_path, expect_fingerprint=fp)
+            except Exception:
+                plans = None  # stale/corrupt cache entry → rebuild
+    if plans is None:
+        plans = build_pull_plans(snap)
+        if cache_path is not None:
+            # .npz suffix keeps np.savez from appending another one;
+            # write-then-rename = no torn cache entries
+            tmp = cache_path[:-4] + ".tmp.npz"
+            save_plans(plans, tmp, fingerprint=fp)
+            os.replace(tmp, cache_path)
     return plans
 
 
@@ -601,6 +620,26 @@ def _bitdot(packed_t: jax.Array, vec: jax.Array, block_rows: int) -> jax.Array:
     return jax.lax.fori_loop(0, n_blocks, body, jnp.zeros((K,), jnp.int32))
 
 
+def _program(module: str, scope: Optional[str] = None):
+    """Names that survive a refactor, for one stage program (the innermost
+    decorator, under ``jax.jit``). ``module``: the XLA module is named
+    ``jit_<module>`` — JAX takes it from ``__name__``; ``__qualname__``
+    stays the Python attribute's, which the hgverify registry keys by — so
+    a profile's ``XLA Modules`` line and the compile-cache key read by
+    stage. ``scope``: a ``jax.named_scope`` over the whole body, a
+    component of every operation's ``op_name``, which is how a profile's
+    device operations are told apart by stage whatever the compiler calls
+    them. ``PERF.md`` section 3 lists the names and their readers."""
+
+    def deco(fn):
+        if scope is not None:
+            fn = jax.named_scope(scope)(fn)
+        fn.__name__ = module
+        return fn
+
+    return deco
+
+
 # The hop runs as FOUR host-sequenced jits instead of one scan. At 10M
 # atoms × 4096 seeds the hop's working set (visited 5.1 GB + stage-1
 # buffer 5.9 GB + stage-2 buffer 4.6 GB) only fits the 16 GiB HBM when
@@ -624,6 +663,7 @@ def _bitdot(packed_t: jax.Array, vec: jax.Array, block_rows: int) -> jax.Array:
     statics={"n_pad": 64},
 )
 @partial(jax.jit, static_argnames=("n_pad",))
+@_program("hg_bfs_seed_bitmap", "hg.bfs.seed_bitmap")
 def _seed_bitmap(seeds: jax.Array, n_atoms: jax.Array, n_pad: int):
     K = seeds.shape[0]
     Kw = K // WORD
@@ -648,6 +688,7 @@ def _bitdot_rows(K: int, n_pad: int) -> int:
                     hgverify.sds((64,), "float32")),
 )
 @jax.jit
+@_program("hg_bfs_deg_sum", "hg.bfs.deg_sum")
 def _deg_sum(visited: jax.Array, deg_f: jax.Array) -> jax.Array:
     """S = Σ_v visited[v]·deg(v) per seed. Bounded by E_inc < 2^31 so
     int32 cannot wrap (bit-exactness subject to _bitdot's f32
@@ -662,11 +703,14 @@ def _deg_sum(visited: jax.Array, deg_f: jax.Array) -> jax.Array:
     statics={"widths": (8,), "chunk": 1 << 19, "use_pallas": False},
 )
 @partial(jax.jit, static_argnames=("widths", "chunk", "use_pallas"))
+@_program("hg_bfs_stage1")
 def _stage(values, levels, widths, chunk, use_pallas):
-    return _apply_plan(values, levels, widths, chunk, use_pallas)
+    return _apply_plan(values, levels, widths, chunk, use_pallas,
+                       scopes=("hg.bfs.stage1.lvl0", "hg.bfs.stage1.upper"))
 
 
 @partial(jax.jit, static_argnames=("w", "chunk", "use_pallas"))
+@_program("hg_bfs_stage2_lvl0", "hg.bfs.stage2.lvl0")
 def _stage_lvl0_consume(values, idx, w, chunk, use_pallas):
     """Level-0 chunks only, into an exact-size buffer. ``values`` (the
     previous stage's buffer, ~5.9 GB at benchmark scale) is genuinely dead
@@ -680,6 +724,7 @@ def _stage_lvl0_consume(values, idx, w, chunk, use_pallas):
 
 
 @partial(jax.jit, static_argnames=("widths", "chunk"))
+@_program("hg_bfs_stage2_upper", "hg.bfs.stage2.upper")
 def _stage_upper(lvl0, levels, widths, chunk):
     """Assemble the stage's concat buffer from the level-0 chunks, then
     run the (small) upper levels on the XLA gather path. ``widths``
@@ -702,6 +747,7 @@ def _stage_upper(lvl0, levels, widths, chunk):
     donate=True,
 )
 @partial(jax.jit, donate_argnums=(0,))  # visited aliases the output
+@_program("hg_bfs_visited_update", "hg.bfs.visited_update")
 def _visited_update(visited, reach_chunks, out_map, n_atoms):
     """visited | reach_chunks[out_map], folded in row blocks so no second
     (n_pad, Kw) array materializes while the stage buffer is alive;
@@ -731,6 +777,7 @@ def _visited_update(visited, reach_chunks, out_map, n_atoms):
 
 @hgverify.entry(shapes=lambda: (hgverify.sds((64, 1), "uint32"),))
 @jax.jit
+@_program("hg_bfs_reach_counts", "hg.bfs.reach_counts")
 def _reach_counts(visited: jax.Array) -> jax.Array:
     n_pad = visited.shape[0]
     return _bitdot(visited, jnp.ones((n_pad,), jnp.float32),
@@ -755,28 +802,37 @@ def _bfs_pull_device(
     visited = _seed_bitmap(seeds, n_atoms, n_pad)
     deg_f = inc_deg.astype(jnp.float32)
     s_ins: list[jax.Array] = []
+    # one obs.phase per synced step, four a hop: a traversal's seconds by
+    # stage in the default registry, and under a profiler the host span
+    # that a device idle gap is charged to
     for _ in range(max_hops):
         if count_edges:
-            s_ins.append(_deg_sum(visited, deg_f))
-            jax.block_until_ready(s_ins[-1])
-        live = _stage(visited, levels1, widths1, chunk, use_pallas)
-        jax.block_until_ready(live)
-        lvl0b = _stage_lvl0_consume(live, levels2[0], widths2[0], chunk,
-                                    use_pallas)
-        # the donations can't alias (shapes differ), so the host ref is
-        # what keeps each dead buffer resident — drop it AND sync before
-        # the next dispatch: async dispatch would let the allocator grab
-        # stage-upper's buffers while the consume step (and therefore
-        # `live`'s 5.9 GB) is still in flight. The sync costs one RTT per
-        # hop against multi-second hops.
-        del live
-        jax.block_until_ready(lvl0b)
-        reach_chunks = _stage_upper(lvl0b, levels2[1:], widths2, chunk)
-        del lvl0b
-        visited = _visited_update(visited, reach_chunks, out_map, n_atoms)
-        del reach_chunks
-        jax.block_until_ready(visited)
-    reach = _reach_counts(visited)
+            with phase("hg.bfs.hop.deg_sum"):
+                s_ins.append(_deg_sum(visited, deg_f))
+                jax.block_until_ready(s_ins[-1])
+        with phase("hg.bfs.hop.stage1"):
+            live = _stage(visited, levels1, widths1, chunk, use_pallas)
+            jax.block_until_ready(live)
+        with phase("hg.bfs.hop.stage2_lvl0"):
+            lvl0b = _stage_lvl0_consume(live, levels2[0], widths2[0], chunk,
+                                        use_pallas)
+            # the donations can't alias (shapes differ), so the host ref
+            # is what keeps each dead buffer resident — drop it AND sync
+            # before the next dispatch: async dispatch would let the
+            # allocator grab stage-upper's buffers while the consume step
+            # (and therefore `live`'s 5.9 GB) is still in flight. The sync
+            # costs one RTT per hop against multi-second hops.
+            del live
+            jax.block_until_ready(lvl0b)
+        with phase("hg.bfs.hop.stage2_upper_update"):
+            reach_chunks = _stage_upper(lvl0b, levels2[1:], widths2, chunk)
+            del lvl0b
+            visited = _visited_update(visited, reach_chunks, out_map,
+                                      n_atoms)
+            del reach_chunks
+            jax.block_until_ready(visited)
+    with phase("hg.bfs.reach_counts"):  # the dispatch: nothing syncs here
+        reach = _reach_counts(visited)
     return visited, s_ins, reach
 
 
@@ -850,12 +906,14 @@ def bfs_pull(
         # keeps the XLA gather (no width limits)
         use_pallas = (len(block) == _pg.ROW_WORDS * WORD
                       and _pg.pallas_ok())
+        with phase("hg.bfs.seeds_upload"):
+            block_dev = jnp.asarray(block)
         blocks.append(
             _bfs_pull_device(
                 dev["levels1"], plans.stage1.widths,
                 dev["levels2"], plans.stage2_widths,
                 dev["out_map"], dev["inc_deg"],
-                jnp.asarray(block), n_atoms, max_hops,
+                block_dev, n_atoms, max_hops,
                 chunk=chunk, count_edges=count_edges,
                 use_pallas=use_pallas,
             )
@@ -867,7 +925,8 @@ def bfs_pull(
         s_ins = b[1]
         if not len(s_ins):  # zero hops / counting off
             return np.zeros(b[2].shape[0], np.int64)
-        return np.asarray(s_ins[-1]).astype(np.int64)
+        with phase("hg.bfs.edges_to_host"):
+            return np.asarray(s_ins[-1]).astype(np.int64)
 
     if len(blocks) == 1:
         visited_t, _, reach = blocks[0]
@@ -888,12 +947,18 @@ def bfs_pull(
 def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:
     cache = getattr(snap, "_pull_device", None)
     if cache is None:
-        cache = {
-            "levels1": tuple(jnp.asarray(l) for l in plans.stage1.levels),
-            "levels2": tuple(jnp.asarray(l) for l in plans.stage2_levels),
-            "out_map": jnp.asarray(plans.out_map),
-            "inc_deg": jnp.asarray(plans.inc_deg),
-        }
+        with phase("hg.bfs.plan.upload"):
+            cache = {
+                "levels1": tuple(jnp.asarray(l)
+                                 for l in plans.stage1.levels),
+                "levels2": tuple(jnp.asarray(l)
+                                 for l in plans.stage2_levels),
+                "out_map": jnp.asarray(plans.out_map),
+                "inc_deg": jnp.asarray(plans.inc_deg),
+            }
+            # the first stage needs them all: waiting here moves no work,
+            # it puts the upload's seconds under the upload's name
+            jax.block_until_ready(cache)
         object.__setattr__(snap, "_pull_device", cache)
     return cache
 

@@ -164,6 +164,7 @@ def _call(seg_idx: jax.Array, values: jax.Array, w: int,
         ),
         out_shape=jax.ShapeDtypeStruct((n_out, Kw), jnp.uint32),
         interpret=interpret,
+        name="hg_gather_or",  # the kernel's name in a profile
     )(seg_idx, values)
 
 

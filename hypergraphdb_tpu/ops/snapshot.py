@@ -42,6 +42,7 @@ from typing import Optional
 
 import numpy as np
 
+from hypergraphdb_tpu.obs.device import phase
 from hypergraphdb_tpu.utils.ordered_bytes import rank64, rank_ambiguous
 
 #: sentinel for padded entries in id arrays
@@ -150,6 +151,7 @@ class CSRSnapshot:
     n_edges_tgt: int = 0    # real (unpadded) target entries
 
     @staticmethod
+    @phase("hg.snapshot.from_tables")
     def from_tables(
         type_of: np.ndarray,      # (N,) int32 type handle per atom, -1 dead
         is_link: np.ndarray,      # (N,) bool

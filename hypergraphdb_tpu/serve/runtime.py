@@ -77,6 +77,17 @@ from hypergraphdb_tpu.serve.types import (
 )
 
 
+def _thread_cm(config: "ServeConfig", name: str):
+    """What the one dispatch thread is doing (``hg.serve.launch`` /
+    ``.collect`` / ``.host_reserve`` / ``.park``), as a host span on the
+    profiler's clock so a trace's idle gaps are charged to it. Gated
+    exactly as ``DeviceExecutor._dispatch_cm``: the un-profiled path
+    re-enters the shared null context and allocates nothing."""
+    if config.device_timing or profiling():
+        return annotate(name)
+    return _NULL_CM
+
+
 @dataclass
 class ServeConfig:
     """Knobs of one runtime; defaults suit the streaming-bench scale."""
@@ -1429,24 +1440,25 @@ class DeviceExecutor:
         exception capture — one failing request surfaces, never kills
         its batch."""
         out = []
-        for ticket in tickets:
-            self.stats.record_host_fallback()
-            try:
-                kind = ticket.request.kind
-                if kind == "bfs":
-                    out.append((ticket, self._host_bfs(ticket.request,
-                                                       epoch)))
-                elif kind == "join":
-                    out.append((ticket, self._host_join(ticket.request,
-                                                        epoch)))
-                elif kind == "range":
-                    out.append((ticket, self._host_range(ticket.request,
-                                                         epoch)))
-                else:
-                    out.append((ticket, self._host_pattern(ticket.request,
+        with _thread_cm(self.config, "hg.serve.host_reserve"):
+            for ticket in tickets:
+                self.stats.record_host_fallback()
+                try:
+                    kind = ticket.request.kind
+                    if kind == "bfs":
+                        out.append((ticket, self._host_bfs(ticket.request,
                                                            epoch)))
-            except Exception as e:  # surface, don't kill the batch
-                out.append((ticket, e))
+                    elif kind == "join":
+                        out.append((ticket, self._host_join(ticket.request,
+                                                            epoch)))
+                    elif kind == "range":
+                        out.append((ticket, self._host_range(ticket.request,
+                                                             epoch)))
+                    else:
+                        out.append((ticket, self._host_pattern(
+                            ticket.request, epoch)))
+                except Exception as e:  # surface, don't kill the batch
+                    out.append((ticket, e))
         return out
 
     # -- per-request result assembly -----------------------------------------
@@ -2040,8 +2052,9 @@ class ServeRuntime:
         plan_rec["actual_rows"] = actual
         truncated = bool(res.truncated)
         if truncated:
-            matches = tuple(sorted(
-                int(h) for h in self.graph.find_all(choice.condition)))
+            with _thread_cm(self.config, "hg.serve.host_reserve"):
+                matches = tuple(sorted(
+                    int(h) for h in self.graph.find_all(choice.condition)))
             served_by = "host"
         else:
             if getattr(res, "kind", None) == "join":
@@ -2177,7 +2190,8 @@ class ServeRuntime:
             device = not batch.force_host and self.breaker.allow(key)
             batch.force_host = not device
             try:
-                launched = self.executor.launch(batch)
+                with _thread_cm(cfg, "hg.serve.launch"):
+                    launched = self.executor.launch(batch)
             except Exception as e:
                 if not device:
                     # the DEGRADED path itself failed: no ladder left
@@ -2274,7 +2288,8 @@ class ServeRuntime:
         traced = tracer.enabled
         t_c0 = tracer.clock() if traced else 0.0
         try:
-            results = self.executor.collect(token)
+            with _thread_cm(self.config, "hg.serve.collect"):
+                results = self.executor.collect(token)
         except Exception as e:
             results = self._recover_collect(tickets, token, key, device, e)
             if results is None:
@@ -2481,17 +2496,18 @@ class ServeRuntime:
                         and self._pending_empty()):
                     return
                 ttf = self.batcher.time_to_flush(self.clock())
-                if ttf is None:
-                    # empty queue: wait_for_work's non-empty pre-check
-                    # makes the submit-before-wait race safe for an
-                    # unbounded park
-                    self.queue.wait_for_work(None)
-                else:
-                    # items queued but linger remaining: sleep the
-                    # remainder (a submit filling the bucket notifies and
-                    # wakes us early; a missed wakeup costs at most
-                    # max_linger_s)
-                    self.queue.park(ttf)
+                with _thread_cm(self.config, "hg.serve.park"):
+                    if ttf is None:
+                        # empty queue: wait_for_work's non-empty pre-check
+                        # makes the submit-before-wait race safe for an
+                        # unbounded park
+                        self.queue.wait_for_work(None)
+                    else:
+                        # items queued but linger remaining: sleep the
+                        # remainder (a submit filling the bucket notifies
+                        # and wakes us early; a missed wakeup costs at
+                        # most max_linger_s)
+                        self.queue.park(ttf)
             except Exception:
                 # the per-batch paths already route errors onto tickets;
                 # anything landing here is a runtime bug — log it and

@@ -1,0 +1,287 @@
+"""``obs.phase`` and the names it and the traversal path give their work.
+
+One primitive for a coarse, synced host step (``obs/device.py``): seconds in
+the default registry always, a ``TraceAnnotation`` while a profile session
+is open, a child span under the thread's current trace. The staged BFS
+(``ops/ellbfs.py``) wires it per hop, names its device operations with
+``jax.named_scope`` and its seven programs' XLA modules ``jit_hg_bfs_*``;
+``benchmarks/harness/scope_reduce.py`` reads those scopes back out of a
+profiler trace. ``PERF.md`` section 3 lists every name and its reader.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import os
+from contextlib import nullcontext
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hypergraphdb_tpu import obs
+from hypergraphdb_tpu.obs import device as obs_device
+from hypergraphdb_tpu.ops import ellbfs as eb
+from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _hist(name: str) -> dict:
+    h = obs.default_registry().get(f"phase.{name}")
+    return h.summary() if h is not None else {"count": 0, "total": 0.0}
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the names."""
+
+    def __init__(self, monkeypatch):
+        self.names: list = []
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", self._make)
+
+    def _make(self, name):
+        self.names.append(name)
+        return nullcontext()
+
+
+@pytest.fixture
+def global_tracing():
+    tracer = obs.tracer()
+    tracer.enable()
+    tracer.drain()
+    try:
+        yield tracer
+    finally:
+        tracer.disable()
+        tracer.drain()
+
+
+# ------------------------------------------------------------ the primitive
+
+
+def test_phase_records_total_and_count():
+    ticks = iter([10.0, 10.5, 20.0, 20.25])
+    before = _hist("hg.test.total")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(obs_device.time, "perf_counter", lambda: next(ticks))
+        for _ in range(2):
+            with obs.phase("hg.test.total"):
+                pass
+    after = _hist("hg.test.total")
+    assert after["count"] - before["count"] == 2
+    assert after["total"] - before["total"] == pytest.approx(0.75)
+
+
+def test_phase_nests():
+    b_out, b_in = _hist("hg.test.outer"), _hist("hg.test.inner")
+    with obs.phase("hg.test.outer"):
+        for _ in range(3):
+            with obs.phase("hg.test.inner"):
+                pass
+    a_out, a_in = _hist("hg.test.outer"), _hist("hg.test.inner")
+    assert a_out["count"] - b_out["count"] == 1
+    assert a_in["count"] - b_in["count"] == 3
+    assert (a_out["total"] - b_out["total"]
+            >= a_in["total"] - b_in["total"] >= 0.0)
+
+
+def test_phase_is_a_span_under_the_current_trace(global_tracing):
+    with global_tracing.trace_ctx("embedded.call"):
+        with obs.phase("hg.test.outer"):
+            with obs.phase("hg.test.inner"):
+                pass
+    (tr,) = [t for t in global_tracing.drain() if t.name == "embedded.call"]
+    assert [s.name for s in tr.spans()] == [
+        "embedded.call", "hg.test.outer", "hg.test.inner"]
+    outer, inner = tr.find("hg.test.outer"), tr.find("hg.test.inner")
+    assert outer.parent_id == tr.find("embedded.call").span_id
+    assert inner.parent_id == outer.span_id
+    assert inner.t1 is not None and outer.t1 >= inner.t1
+
+
+def test_phase_opens_no_trace_of_its_own(global_tracing):
+    before = _hist("hg.test.lonely")["count"]
+    with obs.phase("hg.test.lonely"):
+        pass
+    assert global_tracing.drain() == []
+    assert global_tracing.current_trace() is None
+    assert _hist("hg.test.lonely")["count"] == before + 1
+
+
+def test_phase_annotates_only_inside_a_profile_session(monkeypatch,
+                                                       tmp_path):
+    seen = _Annotations(monkeypatch)
+    started: list = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda logdir, **kw: started.append((logdir, kw)))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    with obs.phase("hg.test.quiet"):
+        pass
+    assert seen.names == [] and not obs.profiling()
+    with obs.profile(str(tmp_path)) as on:
+        assert on and obs.profiling()
+        with obs.phase("hg.test.loud"):
+            pass
+    assert seen.names == ["hg.test.loud"] and not obs.profiling()
+    with obs.phase("hg.test.quiet"):
+        pass
+    assert seen.names == ["hg.test.loud"]
+    # the session traces annotations and the device, not the interpreter
+    (logdir, kw), = started
+    assert logdir == str(tmp_path)
+    assert kw["profiler_options"].python_tracer_level == 0
+    assert kw["profiler_options"].host_tracer_level >= 1
+
+
+def test_phase_records_when_the_body_raises(global_tracing):
+    before = _hist("hg.test.raises")["count"]
+    with global_tracing.trace_ctx("embedded.call"):
+        with pytest.raises(KeyError):
+            with obs.phase("hg.test.raises"):
+                raise KeyError("boom")
+    assert _hist("hg.test.raises")["count"] == before + 1
+    (tr,) = [t for t in global_tracing.drain() if t.name == "embedded.call"]
+    assert tr.find("hg.test.raises").t1 is not None
+
+
+# ------------------------------------------------------ the traversal path
+
+HOP_PHASES = ("hg.bfs.hop.deg_sum", "hg.bfs.hop.stage1",
+              "hg.bfs.hop.stage2_lvl0", "hg.bfs.hop.stage2_upper_update")
+CALL_PHASES = ("hg.bfs.seeds_upload", "hg.bfs.reach_counts",
+               "hg.bfs.edges_to_host")
+ONCE_PHASES = ("hg.bfs.plan", "hg.bfs.plan.upload")
+
+
+def _small_snapshot(seed: int = 7):
+    r = np.random.default_rng(seed)
+    n_nodes, n_links = 300, 200
+    n = n_nodes + n_links
+    is_link = np.zeros(n, dtype=bool)
+    is_link[n_nodes:] = True
+    arities = r.integers(2, 5, size=n_links)
+    offsets = np.zeros(n + 1, dtype=np.int64)
+    offsets[n_nodes + 1:] = np.cumsum(arities)
+    flat = r.integers(0, n_nodes, size=int(arities.sum()))
+    return CSRSnapshot.from_tables(np.zeros(n, np.int32), is_link, offsets,
+                                   flat)
+
+
+def test_bfs_pull_leaves_every_phase_with_the_right_counts():
+    names = HOP_PHASES + CALL_PHASES + ONCE_PHASES + (
+        "hg.snapshot.from_tables",)
+    before = {n: _hist(n)["count"] for n in names}
+    snap = _small_snapshot()
+    seeds = np.arange(40, dtype=np.int32)
+    hops = 3
+    eb.bfs_pull(snap, seeds, hops)
+    grew = {n: _hist(n)["count"] - before[n] for n in names}
+    assert grew == {**{n: hops for n in HOP_PHASES},
+                    **{n: 1 for n in CALL_PHASES + ONCE_PHASES},
+                    "hg.snapshot.from_tables": 1}
+    # a second call on the snapshot: the memoised plan and its upload
+    # record nothing, the per-call and per-hop phases again
+    eb.bfs_pull(snap, seeds, 2)
+    grew = {n: _hist(n)["count"] - before[n] for n in names}
+    assert grew == {**{n: hops + 2 for n in HOP_PHASES},
+                    **{n: 2 for n in CALL_PHASES},
+                    **{n: 1 for n in ONCE_PHASES},
+                    "hg.snapshot.from_tables": 1}
+    assert all(_hist(n)["total"] > 0.0 for n in names)
+
+
+def _u32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.uint32)
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+#: attribute -> (module name, scopes in its operations' paths, args, statics)
+STAGE_PROGRAMS = {
+    "_seed_bitmap": ("hg_bfs_seed_bitmap", ("hg.bfs.seed_bitmap",),
+                     (_i32(32), _i32()), {"n_pad": 64}),
+    "_deg_sum": ("hg_bfs_deg_sum", ("hg.bfs.deg_sum",),
+                 (_u32(64, 1), jax.ShapeDtypeStruct((64,), jnp.float32)),
+                 {}),
+    "_stage": ("hg_bfs_stage1",
+               ("hg.bfs.stage1.lvl0", "hg.bfs.stage1.upper"),
+               (_u32(64, 1), (_i32(128), _i32(16))),
+               {"widths": (8, 8), "chunk": 4, "use_pallas": False}),
+    "_stage_lvl0_consume": ("hg_bfs_stage2_lvl0", ("hg.bfs.stage2.lvl0",),
+                            (_u32(64, 1), _i32(128)),
+                            {"w": 8, "chunk": 4, "use_pallas": False}),
+    "_stage_upper": ("hg_bfs_stage2_upper", ("hg.bfs.stage2.upper",),
+                     (_u32(16, 1), (_i32(16),)),
+                     {"widths": (8, 8), "chunk": 4}),
+    "_visited_update": ("hg_bfs_visited_update", ("hg.bfs.visited_update",),
+                        (_u32(64, 1), _u32(9, 1), _i32(64), _i32()), {}),
+    "_reach_counts": ("hg_bfs_reach_counts", ("hg.bfs.reach_counts",),
+                      (_u32(64, 1),), {}),
+}
+
+
+@pytest.mark.parametrize("attr", sorted(STAGE_PROGRAMS))
+def test_stage_program_carries_its_names(attr):
+    module, scopes, args, statics = STAGE_PROGRAMS[attr]
+    fn = getattr(eb, attr)
+    # the Python attribute and the hgverify key stay; the XLA module is new
+    assert fn.__wrapped__.__qualname__ == attr
+    text = fn.lower(*args, **statics).compile().as_text()
+    assert text.startswith(f"HloModule jit_{module},"), text[:80]
+    for scope in scopes:
+        assert f'op_name="jit({module})/{scope}/' in text, scope
+    # every operation that has a path has one of the program's scopes in it
+    paths = [ln.split('op_name="', 1)[1].split('"', 1)[0]
+             for ln in text.splitlines() if 'op_name="jit(' in ln]
+    assert paths and all(
+        any(f"/{s}/" in p for s in scopes) for p in paths), paths[:5]
+
+
+def test_pallas_gather_kernel_is_named():
+    from hypergraphdb_tpu.ops import pallas_gather as pg
+
+    jaxpr = jax.make_jaxpr(
+        lambda v, i: pg.gather_or(v, i, 8, interpret=True))(
+        _u32(8, pg.ROW_WORDS), _i32(8 * pg.G))
+    assert "hg_gather_or" in str(jaxpr)
+
+
+def test_dispatch_thread_annotations_are_off_the_unprofiled_path(
+        monkeypatch):
+    from hypergraphdb_tpu.serve import runtime as rt
+
+    seen = _Annotations(monkeypatch)
+    cfg = rt.ServeConfig()
+    assert rt._thread_cm(cfg, "hg.serve.park") is rt._NULL_CM
+    assert seen.names == []
+    monkeypatch.setattr(obs_device, "_PROFILING", True)
+    with rt._thread_cm(cfg, "hg.serve.park"):
+        pass
+    monkeypatch.setattr(obs_device, "_PROFILING", False)
+    with rt._thread_cm(rt.ServeConfig(device_timing=True),
+                       "hg.serve.launch"):
+        pass
+    assert seen.names == ["hg.serve.park", "hg.serve.launch"]
+
+
+# ------------------------------------------------- the reader of the scopes
+
+
+def _check_scope_reduce():
+    path = os.path.join(ROOT, "benchmarks", "tests", "check_scope_reduce.py")
+    spec = importlib.util.spec_from_file_location("check_scope_reduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("check", ["check_by_hand", "check_readers",
+                                   "check_on_recorded_trace"])
+def test_scope_reduce(check):
+    """The benchmark's own checks of its reducer (hand-made bytes: a nest
+    counts once, an unscoped child inherits, coverage; the recorded TPU
+    fixture), run here so that tier-1 holds them."""
+    getattr(_check_scope_reduce(), check)()
