@@ -17,8 +17,16 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   atom carries ALL seeds of the block at once (128 bytes at K=1024; 512
   bytes at K=4096 — the wide mode that feeds the Pallas gather, see
   ``ops/pallas_gather.py``).
-- a hop is two *pull* reductions with NO scatters, pulling from VISITED
-  (monotone closure — no separate frontier array, half the state):
+- the FIRST hop follows the frontier: its K single-atom frontiers are
+  known exactly, so the seeds' own neighbourhood (incident links → their
+  targets) is expanded on the host from the snapshot's CSR arrays and its
+  bits are placed into the seed bitmap — work ∝ Σ deg(seeds), not ∝ E
+  (``_sparse_first_hop``; the sparse step of a direction-optimising BFS).
+  Only seeds whose neighbourhood is a sizeable share of the plan (hubs on
+  a small graph) send hop 1 down the pull chain too; the rule reads the
+  input alone (``SPARSE_SHARE``).
+- every later hop is two *pull* reductions with NO scatters, pulling from
+  VISITED (monotone closure — no separate frontier array, half the state):
   stage 1: ``link_live[l] = OR_{t ∈ targets(l)} V[t]``
   stage 2: ``reach[v]    = OR_{l ∈ incident(v)} link_live[l]``
   Each is a gather of edge-many rows followed by a fixed-width tree
@@ -775,6 +783,41 @@ def _visited_update(visited, reach_chunks, out_map, n_atoms):
     return nxt.at[n_atoms].set(jnp.uint32(0))
 
 
+# Pairs a placement dispatch carries: the one shape `_sparse_hop` compiles at
+# for a bitmap, whatever the seeds hold — a hub among them runs more blocks.
+SPARSE_BLOCK = 1 << 20
+
+
+@hgverify.entry(
+    shapes=lambda: (hgverify.sds((64, 1), "uint32"),
+                    hgverify.sds((2, 16), "int32"),
+                    hgverify.sds((), "int32")),
+    donate=True,
+)
+@partial(jax.jit, donate_argnums=(0,))  # visited aliases the output
+@_program("hg_bfs_sparse_hop", "hg.bfs.sparse_hop")
+def _sparse_hop(visited, pairs, n_atoms):
+    """OR bit ``k`` into row ``r`` for every column ``(r, k)`` of ``pairs``.
+    The pairs are distinct and none is a seed's own bit, so no bit is added
+    twice and the scatter-add IS the OR (the trick of ``_seed_bitmap``).
+    Pad columns are ``(n_atoms, 0)``: the dummy row, zeroed last.
+
+    On the TPU the whole block in ONE scatter is the fast form: XLA sorts
+    the indices and streams the bitmap once (25 ms at 10M atoms x 4096
+    seeds whatever the block holds; sixteen narrower scatters measured
+    97 ms). It runs inside a loop of one trip that the compiler cannot
+    count, for the profile's sake: the TPU's scatter expansion leaves its
+    sort and its fusion without an ``op_name``, and a profile charges
+    nameless operations to the scope of the loop they run in — bare, they
+    stand outside ``hg.bfs.sparse_hop`` (PERF.md section 6, PR 26)."""
+    rows, ks = pairs[0], pairs[1]
+    bit = jnp.left_shift(jnp.uint32(1), (ks & 31).astype(jnp.uint32))
+    visited = jax.lax.fori_loop(
+        0, jnp.minimum(n_atoms, 1),
+        lambda _, vis: vis.at[rows, ks >> 5].add(bit), visited)
+    return visited.at[n_atoms].set(jnp.uint32(0))
+
+
 @hgverify.entry(shapes=lambda: (hgverify.sds((64, 1), "uint32"),))
 @jax.jit
 @_program("hg_bfs_reach_counts", "hg.bfs.reach_counts")
@@ -784,28 +827,103 @@ def _reach_counts(visited: jax.Array) -> jax.Array:
                    _bitdot_rows(visited.shape[1] * WORD, n_pad))
 
 
+# The rule that sends a block's first hop the sparse way (module docstring),
+# from the input only: the seeds' (target, seed) pairs, counted before any
+# is made, are fewer than one in SPARSE_SHARE of the plan's indices. On the
+# chip the two sides met at one in 7.3 to 8.4, by the host's speed (PERF.md
+# section 6, PR 26); ten keeps the sparse side ahead at the threshold.
+SPARSE_SHARE = 10
+
+
+class _SeedLinks(NamedTuple):
+    """The incident links of a seed block, seed by seed."""
+
+    links: np.ndarray   # (L,) link ids, the seeds' incidence rows in order
+    cols: np.ndarray    # (L,) the seed column each link belongs to
+    arity: np.ndarray   # (L,) targets of each link (>= 1: it has a seed)
+    deg: np.ndarray     # (K,) incidence degree of every seed (S_0)
+
+
+def _seed_links(snap: CSRSnapshot, seeds: np.ndarray,
+                limit: int) -> Optional[_SeedLinks]:
+    """The rule, then the first expansion: None where the seeds' first hop
+    is not sparse (``limit`` pairs or more), and nothing larger than the
+    seeds' link list is built to find that out."""
+    s = seeds.astype(np.int64)
+    deg = snap.inc_offsets[s + 1].astype(np.int64) - snap.inc_offsets[s]
+    if int(deg.sum()) >= limit:  # a link has a target: pairs >= links
+        return None
+    nz = np.flatnonzero(deg)
+    links = snap.inc_links[
+        _segmented_ranges(snap.inc_offsets[s[nz]], deg[nz])
+    ].astype(np.int64)
+    arity = snap.tgt_offsets[links + 1].astype(np.int64) \
+        - snap.tgt_offsets[links]
+    if int(arity.sum()) >= limit:
+        return None
+    return _SeedLinks(links, np.repeat(nz, deg[nz]), arity, deg)
+
+
+def _sparse_first_hop(visited: jax.Array, snap: CSRSnapshot,
+                      seeds: np.ndarray, sl: _SeedLinks,
+                      n_atoms: jax.Array) -> jax.Array:
+    """``visited_0`` → ``visited_1``: every target of every link incident to
+    seed k gets bit k. The pairs are made unique and sorted by row, the
+    seeds' own bits (always among them, and already set) are left out, and
+    they go up in SPARSE_BLOCK-wide blocks to one program."""
+    rows = snap.tgt_flat[
+        _segmented_ranges(snap.tgt_offsets[sl.links], sl.arity)
+    ].astype(np.int64)
+    ks = np.repeat(sl.cols, sl.arity)
+    fresh = rows != seeds[ks]
+    K = len(seeds)
+    keys = np.unique(rows[fresh] * K + ks[fresh])
+    n_blocks = -(-len(keys) // SPARSE_BLOCK)
+    pairs = np.zeros((2, n_blocks * SPARSE_BLOCK), dtype=np.int32)
+    pairs[0] = snap.num_atoms  # pad pairs: (dummy row, column 0)
+    pairs[:, : len(keys)] = np.divmod(keys, K)
+    pairs = pairs.reshape(2, n_blocks, SPARSE_BLOCK)
+    for b in range(n_blocks):
+        visited = _sparse_hop(visited, jnp.asarray(pairs[:, b]), n_atoms)
+    return visited
+
+
 def _bfs_pull_device(
-    levels1: tuple[jax.Array, ...],
-    widths1: tuple[int, ...],
-    levels2: tuple[jax.Array, ...],
-    widths2: tuple[int, ...],
-    out_map: jax.Array,      # (N_pad,) int32
-    inc_deg: jax.Array,      # (N_pad,) int32
-    seeds: jax.Array,        # (K,) int32 — K % 32 == 0
-    n_atoms: jax.Array,      # scalar int32 — dummy row id
+    snap: CSRSnapshot,
+    dev: dict,               # the plan's device arrays (_device_plans)
+    plans: PullBFSPlans,
+    seeds: np.ndarray,       # (K,) int32 — K % 32 == 0
     max_hops: int,
     chunk: int = 1 << 19,
     count_edges: bool = True,
     use_pallas: bool = False,
-) -> tuple[jax.Array, list[jax.Array], jax.Array]:
-    n_pad = out_map.shape[0]
-    visited = _seed_bitmap(seeds, n_atoms, n_pad)
-    deg_f = inc_deg.astype(jnp.float32)
-    s_ins: list[jax.Array] = []
+) -> tuple[jax.Array, list, jax.Array]:
+    levels1, widths1 = dev["levels1"], plans.stage1.widths
+    levels2, widths2 = dev["levels2"], plans.stage2_widths
+    n_atoms = jnp.int32(plans.n_atoms)
+    with phase("hg.bfs.seeds_upload"):
+        seeds_dev = jnp.asarray(seeds)
+    visited = _seed_bitmap(seeds_dev, n_atoms, plans.n_pad)
+    s_ins: list = []  # S_h entering each counted hop; the last one is read
+    dense_hops = max_hops
+    # the rule's look at the seeds runs beside the bitmap's zero fill
+    sl = (_seed_links(snap, seeds, plans.total_indices // SPARSE_SHARE)
+          if max_hops >= 1 else None)
+    if sl is not None:
+        # once a sparse hop, around expansion, upload, dispatch and sync:
+        # its count beside hg.bfs.hop.stage1's says how often the rule
+        # took this side
+        with phase("hg.bfs.hop.sparse"):
+            visited = _sparse_first_hop(visited, snap, seeds, sl, n_atoms)
+            jax.block_until_ready(visited)
+        dense_hops -= 1
+        if count_edges and not dense_hops:
+            s_ins.append(sl.deg)  # S_0 = deg(seed), which the host holds
+    deg_f = dev["inc_deg"].astype(jnp.float32)
     # one obs.phase per synced step, four a hop: a traversal's seconds by
     # stage in the default registry, and under a profiler the host span
     # that a device idle gap is charged to
-    for _ in range(max_hops):
+    for _ in range(dense_hops):
         if count_edges:
             with phase("hg.bfs.hop.deg_sum"):
                 s_ins.append(_deg_sum(visited, deg_f))
@@ -827,7 +945,7 @@ def _bfs_pull_device(
         with phase("hg.bfs.hop.stage2_upper_update"):
             reach_chunks = _stage_upper(lvl0b, levels2[1:], widths2, chunk)
             del lvl0b
-            visited = _visited_update(visited, reach_chunks, out_map,
+            visited = _visited_update(visited, reach_chunks, dev["out_map"],
                                       n_atoms)
             del reach_chunks
             jax.block_until_ready(visited)
@@ -871,6 +989,13 @@ def bfs_pull(
     Blocks run sequentially: each hop synchronizes internally so stage
     buffers free before the next allocates (HBM headroom, see
     ``_bfs_pull_device``).
+
+    Per block, the first hop is the seeds' own neighbourhood, expanded on
+    the host and placed into the seed bitmap, when its (target, seed)
+    pairs are few beside the plan (fewer than ``total_indices //
+    SPARSE_SHARE``); hops 2..H, and hop 1 otherwise, run on the dense pull
+    chain, whose cost does not depend on what the bitmap holds. The
+    answers are the same bit for bit on either side.
     """
     if k_block <= 0 or k_block % WORD:
         raise ValueError(
@@ -886,7 +1011,6 @@ def bfs_pull(
             [seeds, np.full(K_pad - K, snap.num_atoms, dtype=np.int32)]
         )
     dev = _device_plans(snap, plans)
-    n_atoms = jnp.int32(plans.n_atoms)
     blocks = []
     for s in range(0, K_pad, k_block):
         block = seeds[s : s + k_block]
@@ -906,16 +1030,10 @@ def bfs_pull(
         # keeps the XLA gather (no width limits)
         use_pallas = (len(block) == _pg.ROW_WORDS * WORD
                       and _pg.pallas_ok())
-        with phase("hg.bfs.seeds_upload"):
-            block_dev = jnp.asarray(block)
         blocks.append(
             _bfs_pull_device(
-                dev["levels1"], plans.stage1.widths,
-                dev["levels2"], plans.stage2_widths,
-                dev["out_map"], dev["inc_deg"],
-                block_dev, n_atoms, max_hops,
-                chunk=chunk, count_edges=count_edges,
-                use_pallas=use_pallas,
+                snap, dev, plans, block, max_hops,
+                chunk=chunk, count_edges=count_edges, use_pallas=use_pallas,
             )
         )
     # The device emits S_h (Σ deg over visited entering each hop);

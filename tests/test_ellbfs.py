@@ -5,6 +5,8 @@ from SURVEY §7 M4)."""
 import numpy as np
 import pytest
 
+from hypergraphdb_tpu import obs
+from hypergraphdb_tpu.ops import ellbfs as eb
 from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 from hypergraphdb_tpu.ops.ellbfs import (
     bfs_pull,
@@ -132,3 +134,126 @@ def test_reduce_plan_shapes():
 def test_plans_cached():
     snap = random_snapshot(50, 40, 3, seed=1)
     assert plans_for(snap) is plans_for(snap)
+
+
+# ------------------------------------------------------ the sparse first hop
+#
+# Which side of the rule ran is read off the phase counts, as an operator
+# would: ``hg.bfs.hop.sparse`` once per block that took the sparse side,
+# ``hg.bfs.hop.stage1`` once per dense hop. The dense chain is reached
+# through the input (many seeds on a small graph) or, where the SAME input
+# has to take both sides, by setting the rule's constant in the test.
+
+
+def _phase_count(name):
+    h = obs.default_registry().get(f"phase.{name}")
+    return h.count if h is not None else 0
+
+
+class _Sides:
+    """(sparse first hops, dense hops) run inside the ``with`` block."""
+
+    NAMES = ("hg.bfs.hop.sparse", "hg.bfs.hop.stage1")
+
+    def __enter__(self):
+        self._t0 = [_phase_count(n) for n in self.NAMES]
+        return self
+
+    def __exit__(self, *exc):
+        self.sparse, self.dense = (
+            _phase_count(n) - t for n, t in zip(self.NAMES, self._t0))
+
+
+def _first_hop_pairs(snap, seeds):
+    """(target, seed) pairs of the seeds' first hop, duplicates included:
+    what the rule holds against the plan's size."""
+    return sum(len(snap.targets_row(int(l)))
+               for s in seeds for l in snap.incidence_row(int(s)).tolist())
+
+
+def _assert_matches_host(snap, seeds, hops, res):
+    rows = visited_rows(res, snap.num_atoms)
+    for k, s in enumerate(seeds.tolist()):
+        want, edges = host_bfs(snap, s, hops)
+        if s == snap.num_atoms:  # a pad seed reaches nothing, not itself
+            want, edges = set(), 0
+        assert set(rows[k].tolist()) == want, f"seed {s} (column {k})"
+        assert res.edges_touched[k] == edges, f"seed {s} (column {k})"
+        assert int(res.reach_counts[k]) == len(want)
+
+
+@pytest.mark.parametrize("zipf", [False, True])
+@pytest.mark.parametrize("hops", [1, 2, 3])
+def test_sparse_first_hop_matches_host_and_dense_chain(zipf, hops,
+                                                       monkeypatch):
+    """Few seeds on a graph large enough for the rule — the hub among them,
+    a duplicate, pad seeds (explicit and from K % 32), a seed nothing
+    points at — over two seed blocks: the answers are the host BFS's and,
+    bit for bit, the dense chain's."""
+    snap = random_snapshot(30000, 3000, 4, seed=31 + hops, zipf=zipf)
+    deg = np.diff(snap.inc_offsets[: 30001].astype(np.int64))
+    hub, lonely = int(np.argmax(deg)), int(np.argmin(deg))
+    assert deg[lonely] == 0 and deg[hub] > (200 if zipf else 3)
+    r = np.random.default_rng(hops)
+    seeds = np.concatenate([
+        [hub, lonely, 7, 7, snap.num_atoms],
+        r.integers(0, 30000, size=35),
+    ]).astype(np.int32)  # 40 seeds: blocks of 32 and 8 (+ 24 pad columns)
+    with _Sides() as ran:
+        res = bfs_pull(snap, seeds, hops, k_block=32)
+    assert (ran.sparse, ran.dense) == (2, 2 * (hops - 1))
+    _assert_matches_host(snap, seeds, hops, res)
+
+    monkeypatch.setattr(eb, "SPARSE_SHARE", 1 << 62)  # no input is sparse
+    with _Sides() as ran:
+        dense = bfs_pull(snap, seeds, hops, k_block=32)
+    assert (ran.sparse, ran.dense) == (0, 2 * hops)
+    assert np.array_equal(np.asarray(res.visited_t),
+                          np.asarray(dense.visited_t))
+    assert np.array_equal(res.edges_touched, dense.edges_touched)
+    assert np.array_equal(np.asarray(res.reach_counts),
+                          np.asarray(dense.reach_counts))
+
+
+@pytest.mark.parametrize("side", ["below", "at"])
+def test_rule_takes_the_side_the_pair_count_says(side):
+    """The threshold is ``total_indices // SPARSE_SHARE`` pairs: the longest
+    run of seeds that stays below it takes the sparse side, one seed more
+    the dense one."""
+    snap = random_snapshot(400, 300, 4, seed=12)
+    limit = plans_for(snap).total_indices // eb.SPARSE_SHARE
+    order = np.random.default_rng(3).permutation(400).astype(np.int32)
+    cum = np.cumsum([_first_hop_pairs(snap, [s]) for s in order])
+    m = int(np.searchsorted(cum, limit))  # cum[m-1] < limit <= cum[m]
+    assert 8 < m < len(order) and cum[m] > cum[m - 1]
+    seeds = order[: m if side == "below" else m + 1]
+    with _Sides() as ran:
+        res = bfs_pull(snap, seeds, 2)
+    assert (ran.sparse, ran.dense) == ((1, 1) if side == "below" else (0, 2))
+    _assert_matches_host(snap, seeds, 2, res)
+
+
+@pytest.mark.parametrize("count_edges", [True, False])
+def test_sparse_first_hop_in_several_placement_blocks(count_edges,
+                                                      monkeypatch):
+    """More pairs than one placement dispatch carries: more dispatches of
+    the one program, the last one padded with the dummy row."""
+    monkeypatch.setattr(eb, "SPARSE_BLOCK", 64)
+    calls = []
+    placed = eb._sparse_hop
+    monkeypatch.setattr(
+        eb, "_sparse_hop",
+        lambda v, pairs, n: calls.append(pairs.shape) or placed(v, pairs, n))
+    snap = random_snapshot(30000, 3000, 4, seed=8, zipf=True)
+    seeds = np.asarray([1, 40, 41, 42, 43, 44], np.int32)  # 1: the hub
+    pairs = _first_hop_pairs(snap, seeds)
+    with _Sides() as ran:
+        res = bfs_pull(snap, seeds, 1, count_edges=count_edges)
+    assert (ran.sparse, ran.dense) == (1, 0)
+    assert len(calls) > 3 and set(calls) == {(2, 64)}
+    assert len(calls) <= -(-pairs // 64)  # unique pairs, seeds' own left out
+    rows = visited_rows(res, snap.num_atoms)
+    for k, s in enumerate(seeds.tolist()):
+        want, edges = host_bfs(snap, s, 1)
+        assert set(rows[k].tolist()) == want
+        assert res.edges_touched[k] == (edges if count_edges else 0)

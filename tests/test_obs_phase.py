@@ -4,7 +4,7 @@ One primitive for a coarse, synced host step (``obs/device.py``): seconds in
 the default registry always, a ``TraceAnnotation`` while a profile session
 is open, a child span under the thread's current trace. The staged BFS
 (``ops/ellbfs.py``) wires it per hop, names its device operations with
-``jax.named_scope`` and its seven programs' XLA modules ``jit_hg_bfs_*``;
+``jax.named_scope`` and its eight programs' XLA modules ``jit_hg_bfs_*``;
 ``benchmarks/harness/scope_reduce.py`` reads those scopes back out of a
 profiler trace. ``PERF.md`` section 3 lists every name and its reader.
 """
@@ -149,6 +149,7 @@ def test_phase_records_when_the_body_raises(global_tracing):
 
 HOP_PHASES = ("hg.bfs.hop.deg_sum", "hg.bfs.hop.stage1",
               "hg.bfs.hop.stage2_lvl0", "hg.bfs.hop.stage2_upper_update")
+SPARSE_PHASE = "hg.bfs.hop.sparse"   # once a block whose first hop is sparse
 CALL_PHASES = ("hg.bfs.seeds_upload", "hg.bfs.reach_counts",
                "hg.bfs.edges_to_host")
 ONCE_PHASES = ("hg.bfs.plan", "hg.bfs.plan.upload")
@@ -169,23 +170,33 @@ def _small_snapshot(seed: int = 7):
 
 
 def test_bfs_pull_leaves_every_phase_with_the_right_counts():
-    names = HOP_PHASES + CALL_PHASES + ONCE_PHASES + (
+    names = HOP_PHASES + (SPARSE_PHASE,) + CALL_PHASES + ONCE_PHASES + (
         "hg.snapshot.from_tables",)
     before = {n: _hist(n)["count"] for n in names}
     snap = _small_snapshot()
+    # few seeds: the rule takes the sparse side, the first hop is the
+    # seeds' own neighbourhood and the pull chain runs hops 2..H
     seeds = np.arange(40, dtype=np.int32)
     hops = 3
     eb.bfs_pull(snap, seeds, hops)
     grew = {n: _hist(n)["count"] - before[n] for n in names}
-    assert grew == {**{n: hops for n in HOP_PHASES},
+    assert grew == {**{n: hops - 1 for n in HOP_PHASES}, SPARSE_PHASE: 1,
                     **{n: 1 for n in CALL_PHASES + ONCE_PHASES},
                     "hg.snapshot.from_tables": 1}
     # a second call on the snapshot: the memoised plan and its upload
     # record nothing, the per-call and per-hop phases again
     eb.bfs_pull(snap, seeds, 2)
     grew = {n: _hist(n)["count"] - before[n] for n in names}
-    assert grew == {**{n: hops + 2 for n in HOP_PHASES},
+    assert grew == {**{n: hops - 1 + 1 for n in HOP_PHASES}, SPARSE_PHASE: 2,
                     **{n: 2 for n in CALL_PHASES},
+                    **{n: 1 for n in ONCE_PHASES},
+                    "hg.snapshot.from_tables": 1}
+    # every node a seed: too many pairs for the rule, so all H hops run on
+    # the pull chain and the sparse phase records nothing
+    eb.bfs_pull(snap, np.arange(300, dtype=np.int32), hops)
+    grew = {n: _hist(n)["count"] - before[n] for n in names}
+    assert grew == {**{n: hops + hops for n in HOP_PHASES}, SPARSE_PHASE: 2,
+                    **{n: 3 for n in CALL_PHASES},
                     **{n: 1 for n in ONCE_PHASES},
                     "hg.snapshot.from_tables": 1}
     assert all(_hist(n)["total"] > 0.0 for n in names)
@@ -206,6 +217,8 @@ STAGE_PROGRAMS = {
     "_deg_sum": ("hg_bfs_deg_sum", ("hg.bfs.deg_sum",),
                  (_u32(64, 1), jax.ShapeDtypeStruct((64,), jnp.float32)),
                  {}),
+    "_sparse_hop": ("hg_bfs_sparse_hop", ("hg.bfs.sparse_hop",),
+                    (_u32(64, 1), _i32(2, 16), _i32()), {}),
     "_stage": ("hg_bfs_stage1",
                ("hg.bfs.stage1.lvl0", "hg.bfs.stage1.upper"),
                (_u32(64, 1), (_i32(128), _i32(16))),

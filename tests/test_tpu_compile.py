@@ -401,6 +401,11 @@ def _staged_step(step: str):
     rows = lambda ls: sum(n // 8 for n in ls) + 1  # noqa: E731
     bitmap = _N_PAD * _KW * 4
     visited = _sds((_N_PAD, _KW), "uint32")
+    if step == "_sparse_hop":
+        return (eb._sparse_hop,
+                (visited, _sds((2, eb.SPARSE_BLOCK), "int32"),
+                 _sds((), "int32")),
+                {}, 4 * (sum(_L1) + sum(_L2)))
     if step == "_stage":
         return (eb._stage,
                 (visited, tuple(_sds((n,), "int32") for n in _L1)),
@@ -418,8 +423,8 @@ def _staged_step(step: str):
             bitmap + 4 * (sum(_L1) + _L2[0]))
 
 
-@pytest.mark.parametrize("step", ["_stage", "_stage_lvl0_consume",
-                                  "_stage_upper"])
+@pytest.mark.parametrize("step", ["_sparse_hop", "_stage",
+                                  "_stage_lvl0_consume", "_stage_upper"])
 def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
                                                           no_compile_cache):
     """Each host-sequenced step of ``ellbfs._bfs_pull_device`` at the
@@ -429,10 +434,14 @@ def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
     resident beside it (the visited bitmap, the other index plans) fits
     the chip. The widest is ``_stage_lvl0_consume`` at ~15.5 of 16.9 GB;
     before the upper levels wrote in place ``_stage_upper`` planned
-    17.2."""
+    17.2. The sparse first hop's placement (``_sparse_hop``, one block of
+    pairs) updates the donated bitmap where it lies."""
     fn, args, statics, resident = _staged_step(step)
     mem = fn.lower(*_place(args, one_chip), **statics).compile() \
         .memory_analysis()
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes + resident)
     assert total < HBM_USABLE, (step, total)
+    if step == "_sparse_hop":
+        assert mem.alias_size_in_bytes >= _N_PAD * _KW * 4
+        assert mem.temp_size_in_bytes < 2**30
