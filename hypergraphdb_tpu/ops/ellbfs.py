@@ -40,6 +40,17 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   without materializing a per-link destination array.
 - per-seed edge counts (the benchmark numerator) are a bit-unpack +
   degree matmul per hop — MXU work, not gathers.
+- a LINK PREDICATE (``bfs_pull(..., link_types=F)``: follow a link only if
+  its type atom is in ``F`` — ``DefaultALGenerator``'s ``linkPredicate``,
+  ``DefaultALGenerator.java:73``, for a family of link types) is not a
+  second traversal: it is this one over the sub-hypergraph the family
+  selects (``CSRSnapshot.restrict_links``, both relations filtered by
+  ``type_of[link]``, id space unchanged), with a plan of its own per
+  (snapshot, family) (:func:`restricted_for`), so the gathers move the
+  admitted entries only. ``DefaultALGenerator``'s other options stay
+  host-only (``algorithms/traversals.py``): the sibling predicate, the
+  ordered-link directions (preceding / succeeding targets, reverse order)
+  and a predicate that differs by hop.
 
 Geometry note: each gather row is ``Kw = K/32`` uint32 words (32 lanes for
 K=1024). Gathers remain the dominant cost and are latency-bound, but the
@@ -61,6 +72,7 @@ import numpy as np
 
 from hypergraphdb_tpu import verify as hgverify
 from hypergraphdb_tpu.obs.device import phase
+from hypergraphdb_tpu.obs.registry import default_registry
 from hypergraphdb_tpu.ops import pallas_gather as _pg
 from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 
@@ -553,7 +565,29 @@ def plans_for(snap: CSRSnapshot) -> PullBFSPlans:
         with phase("hg.bfs.plan"):
             plans = _build_or_load_plans(snap)
         object.__setattr__(snap, "_pull_plans", plans)
+        # the newest plan's size beside the entries it covers: their ratio
+        # is the padding a hop's gathers pay for every entry
+        reg = default_registry()
+        reg.gauge("bfs.plan.total_indices").set(plans.total_indices)
+        reg.gauge("bfs.plan.entries").set(snap.n_edges_inc
+                                          + snap.n_edges_tgt)
     return plans
+
+
+def restricted_for(snap: CSRSnapshot, link_types) -> CSRSnapshot:
+    """The snapshot a traversal under a link predicate runs over
+    (``CSRSnapshot.restrict_links``), with its plan: built once per
+    (snapshot, family) under phase ``hg.bfs.restrict`` and kept on the
+    parent, as :func:`plans_for` keeps a plan — a family's host and device
+    arrays live as long as the parent does. A hit records nothing."""
+    family = frozenset(int(t) for t in link_types)
+    memo = vars(snap).setdefault("_pull_restricted", {})
+    sub = memo.get(family)
+    if sub is None:
+        with phase("hg.bfs.restrict"):
+            sub = memo[family] = snap.restrict_links(family)
+            plans_for(sub)
+    return sub
 
 
 def _build_or_load_plans(snap: CSRSnapshot) -> PullBFSPlans:
@@ -973,6 +1007,7 @@ def bfs_pull(
     chunk: int = 1 << 19,
     k_block: int = 1024,
     count_edges: bool = True,
+    link_types=None,
 ) -> PullBFSResult:
     """Pull-mode multi-hop BFS over all seeds at once (blocked past
     ``k_block``; at 10M atoms a 4096-wide block's working set fills most
@@ -996,12 +1031,32 @@ def bfs_pull(
     SPARSE_SHARE``); hops 2..H, and hop 1 otherwise, run on the dense pull
     chain, whose cost does not depend on what the bitmap holds. The
     answers are the same bit for bit on either side.
+
+    ``link_types`` is the link predicate: a collection of link type atoms,
+    and a hop follows a link only if ``snap.type_of[link]`` is among them —
+    the reference's ``HGBreadthFirstTraversal(start, DefaultALGenerator(
+    graph, linkPredicate = type in family))`` with every other option at
+    its default; ``edges_touched`` then counts admitted links only. It IS
+    this traversal over the sub-hypergraph the family selects
+    (:func:`restricted_for`: built and planned once per family, so a hop
+    gathers no entry of an excluded link, and the first hop's rule reads
+    the restricted plan's size). ``None`` follows every link (the
+    reference's ``SimpleALGenerator``); the family of all link types
+    answers the same bit for bit, an empty one returns the seeds, a type
+    atom no link has is ignored. Still host-only
+    (``algorithms/traversals.DefaultALGenerator``): the sibling predicate,
+    the ordered-link directions (``return_preceeding`` /
+    ``return_succeeding``, ``reverse_order``), a predicate per hop.
     """
     if k_block <= 0 or k_block % WORD:
         raise ValueError(
             f"k_block must be a positive multiple of {WORD} (device words "
             f"pack {WORD} seeds); got {k_block}"
         )
+    if link_types is not None:
+        snap = restricted_for(snap, link_types)
+    if not snap.n_edges_tgt:  # no link to follow: the seeds are the answer
+        max_hops = 0
     plans = plans_for(snap)
     seeds = np.asarray(seeds, dtype=np.int32)
     K = len(seeds)

@@ -36,7 +36,7 @@ dump for padding):
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from functools import cached_property
 from typing import Optional
 
@@ -403,6 +403,46 @@ class CSRSnapshot:
 
     def type_set(self, type_handle: int) -> np.ndarray:
         return self.by_type.get(int(type_handle), np.empty(0, dtype=np.int32))
+
+    def restrict_links(self, link_types) -> "CSRSnapshot":
+        """The sub-hypergraph whose links have a type atom among
+        ``link_types``: both relations keep only the entries of admitted
+        links (``type_of[link]`` in the family), rows keep their order, the
+        id space and every per-atom column stay the parent's (shared, not
+        copied — an excluded link is still an atom that an admitted link
+        may target). A traversal over the result follows exactly the links
+        a ``DefaultALGenerator(link_predicate = type in family)`` follows
+        over the parent. A type atom no link has admits nothing; where the
+        family admits every link entry the parent itself comes back. Not
+        memoised here: ``ops/ellbfs.restricted_for`` keeps one per family
+        on the parent, beside its plan."""
+        N, e_tgt, e_inc = self.num_atoms, self.n_edges_tgt, self.n_edges_inc
+        admit = np.isin(self.type_of,
+                        np.fromiter(link_types, dtype=np.int64)
+                        ) & self.is_link
+        keep_tgt = admit[self.tgt_src[:e_tgt]]
+        keep_inc = admit[self.inc_links[:e_inc]]
+        if keep_tgt.all() and keep_inc.all():
+            return self
+
+        def kept(keep, rows, entries):
+            """One relation filtered: its offsets, row ids and entries,
+            padded as ``from_tables`` pads them."""
+            rows = rows[: len(keep)][keep]
+            offsets = np.zeros(N + 2, dtype=np.int32)
+            np.cumsum(np.bincount(rows, minlength=N + 1), out=offsets[1:])
+            return (offsets, _pad_to(rows, 128, N),
+                    _pad_to(entries[: len(keep)][keep], 128, N))
+
+        tgt_offsets, tgt_src, tgt_flat = kept(
+            keep_tgt, self.tgt_src, self.tgt_flat)
+        inc_offsets, inc_src, inc_links = kept(
+            keep_inc, self.inc_src, self.inc_links)
+        return replace(
+            self, inc_offsets=inc_offsets, inc_links=inc_links,
+            inc_src=inc_src, tgt_offsets=tgt_offsets, tgt_flat=tgt_flat,
+            tgt_src=tgt_src, n_edges_inc=int(inc_offsets[-1]),
+            n_edges_tgt=int(tgt_offsets[-1]))
 
     # ------------------------------------------------------------------ device
     @cached_property

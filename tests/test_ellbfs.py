@@ -257,3 +257,153 @@ def test_sparse_first_hop_in_several_placement_blocks(count_edges,
         want, edges = host_bfs(snap, s, 1)
         assert set(rows[k].tolist()) == want
         assert res.edges_touched[k] == (edges if count_edges else 0)
+
+
+# ------------------------------------------------------ the link predicate
+#
+# ``bfs_pull(..., link_types=F)`` against the repo's host oracle,
+# ``HGBreadthFirstTraversal`` over ``DefaultALGenerator(link_predicate =
+# type in F)``, on a real ``HyperGraph``: links of four types, a link that
+# targets a link, a hub, an atom no link of some families touches. Both
+# sides of the first-hop rule, chosen through the rule's constant as above.
+
+LINK_TYPES = ("knows", "likes", "cites", "tags")
+FAMILIES = {"empty": (), "one": ("likes",), "several": ("knows", "cites"),
+            "all": LINK_TYPES}
+
+
+@pytest.fixture(scope="module")
+def typed_graph():
+    from hypergraphdb_tpu import HyperGraph
+    from hypergraphdb_tpu.types.primitive import StringType
+
+    g = HyperGraph()
+    handle = {}
+    for name in LINK_TYPES:
+        t = type(name, (StringType,), {})()
+        t.name = name
+        handle[name] = int(g.typesystem.register(t))
+    r = np.random.default_rng(27)
+    nodes = [int(g.add(f"n{i}")) for i in range(48)]
+    hub, lonely = nodes[0], nodes[47]  # lonely: only a "tags" link
+    links = []
+    for i in range(90):
+        name = LINK_TYPES[int(r.integers(0, 4))]
+        ends = r.choice(nodes[1:47], int(r.integers(2, 5)), replace=False)
+        ends = [hub, *ends] if i % 3 == 0 else list(ends)
+        links.append(int(g.add_link(ends, value=f"l{i}", type=name)))
+    g.add_link((lonely, nodes[5]), value="t", type="tags")
+    # links that target a link, of a type that differs from its target's
+    for i, name in enumerate(LINK_TYPES):
+        g.add_link((nodes[10 + i], links[i], links[20 + i]),
+                   value=f"m{i}", type=name)
+    seeds = np.asarray([hub, lonely, links[0], links[21], *nodes[10:14],
+                        nodes[30]], dtype=np.int32)
+    yield g, g.snapshot(), handle, seeds
+    g.close()
+
+
+def _oracle(g, family, seed, hops):
+    """(visited set, admitted links looked at) by the host traversal."""
+    from hypergraphdb_tpu.algorithms.traversals import (
+        DefaultALGenerator,
+        HGBreadthFirstTraversal,
+    )
+
+    def admitted(graph, link):
+        return int(graph.get_type_handle_of(link)) in family
+
+    def within(h):
+        gen = DefaultALGenerator(g, link_predicate=admitted)
+        return {seed} | {int(a) for _, a in HGBreadthFirstTraversal(
+            g, seed, gen, max_distance=h)}
+
+    edges = sum(admitted(g, int(l)) for a in within(hops - 1)
+                for l in g.get_incidence_set(a))
+    return within(hops), edges
+
+
+@pytest.mark.parametrize("first_hop", ["sparse", "dense"])
+@pytest.mark.parametrize("hops", [1, 2, 3])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_link_predicate_matches_host_traversal(typed_graph, family, hops,
+                                               first_hop, monkeypatch):
+    g, snap, handle, seeds = typed_graph
+    fam = {handle[n] for n in FAMILIES[family]}
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_hop == "sparse" else 1 << 62)
+    with _Sides() as ran:
+        res = bfs_pull(snap, seeds, hops, link_types=fam)
+    if not fam:  # nothing to follow: no hop runs
+        assert (ran.sparse, ran.dense) == (0, 0)
+    elif first_hop == "sparse":
+        assert (ran.sparse, ran.dense) == (1, hops - 1)
+    else:
+        assert (ran.sparse, ran.dense) == (0, hops)
+    rows = visited_rows(res, snap.num_atoms)
+    for k, s in enumerate(seeds.tolist()):
+        want, edges = _oracle(g, fam, s, hops)
+        assert set(rows[k].tolist()) == want, f"seed {s} (column {k})"
+        assert int(res.reach_counts[k]) == len(want)
+        assert res.edges_touched[k] == edges
+    if family == "empty":
+        assert all(set(rows[k].tolist()) == {int(s)}
+                   for k, s in enumerate(seeds))
+    if family == "one":  # the lonely atom has no admitted link
+        assert set(rows[1].tolist()) == {int(seeds[1])}
+
+
+@pytest.mark.parametrize("hops", [1, 3])
+def test_no_predicate_is_the_family_of_all_types_is_todays_answer(
+        typed_graph, hops):
+    """``None``, every link type, and every link type beside a type atom no
+    link has: one answer bit for bit, over one snapshot and one plan."""
+    g, snap, handle, seeds = typed_graph
+    plain = bfs_pull(snap, seeds, hops)
+    _assert_matches_host(snap, seeds, hops, plain)
+    every = set(handle.values())
+    entity_type = int(snap.type_of[int(seeds[0])])
+    for fam in (every, every | {entity_type, 10 ** 6}):
+        assert eb.restricted_for(snap, fam) is snap
+        typed = bfs_pull(snap, seeds, hops, link_types=fam)
+        assert np.array_equal(np.asarray(plain.visited_t),
+                              np.asarray(typed.visited_t))
+        assert np.array_equal(plain.edges_touched, typed.edges_touched)
+        assert np.array_equal(np.asarray(plain.reach_counts),
+                              np.asarray(typed.reach_counts))
+
+
+def test_one_restriction_and_plan_per_family(typed_graph, monkeypatch):
+    """Phase ``hg.bfs.restrict`` fires once per (snapshot, family), its
+    plan's size lands in the two gauges, the restricted plan gathers
+    admitted entries only, and the fused path is asked about the restricted
+    snapshot, never the parent."""
+    from hypergraphdb_tpu.ops import pallas_bfs
+
+    g, snap, handle, seeds = typed_graph
+    fam = [handle["knows"], handle["tags"]]
+    asked = []
+    monkeypatch.setattr(pallas_bfs, "fused_ready",
+                        lambda s, k: asked.append(s) and False)
+    t0 = _phase_count("hg.bfs.restrict")
+    sub = eb.restricted_for(snap, fam)
+    assert sub is not snap and sub.num_atoms == snap.num_atoms
+    assert _phase_count("hg.bfs.restrict") == t0 + 1
+    reg = obs.default_registry()
+    assert reg.get("bfs.plan.total_indices").value == \
+        plans_for(sub).total_indices
+    assert reg.get("bfs.plan.entries").value == \
+        sub.n_edges_inc + sub.n_edges_tgt
+    for _ in range(2):
+        bfs_pull(snap, seeds, 2, link_types=reversed(fam))
+    assert _phase_count("hg.bfs.restrict") == t0 + 1
+    assert asked and all(s is sub for s in asked)
+    # every entry the restricted relations hold belongs to an admitted link
+    admitted = np.isin(snap.type_of, fam)
+    assert admitted[sub.tgt_src[: sub.n_edges_tgt]].all()
+    assert admitted[sub.inc_links[: sub.n_edges_inc]].all()
+    assert sub.n_edges_tgt == int(
+        admitted[snap.tgt_src[: snap.n_edges_tgt]].sum())
+    assert 0 < sub.n_edges_tgt < snap.n_edges_tgt
+    assert plans_for(sub).total_indices < plans_for(snap).total_indices
+    assert plans_for(sub) is not plans_for(snap)
