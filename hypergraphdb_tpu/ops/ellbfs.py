@@ -38,8 +38,9 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
 - levels compose: stage 2's level-0 indices are pre-composed with stage
   1's output map on host, so link-space results are consumed directly
   without materializing a per-link destination array.
-- per-seed edge counts (the benchmark numerator) are a bit-unpack +
-  degree matmul per hop — MXU work, not gathers.
+- per-seed edge counts (the benchmark numerator) are one exact pass over
+  the bitmap a seed block (``_deg_sum``: bit-unpack, weight by degree and
+  sum in ``int32``, fused on the vector unit) — no gathers.
 - a LINK PREDICATE (``bfs_pull(..., link_types=F)``: follow a link only if
   its type atom is in ``F`` — ``DefaultALGenerator``'s ``linkPredicate``,
   ``DefaultALGenerator.java:73``, for a family of link types) is not a
@@ -618,28 +619,32 @@ def _build_or_load_plans(snap: CSRSnapshot) -> PullBFSPlans:
 # ------------------------------------------------------------------ kernel
 
 
-def _bitdot(packed_t: jax.Array, vec: jax.Array, block_rows: int) -> jax.Array:
-    """Σ_v vec[v] · bit(v, k) for every seed column k.
+# Rows of the bitmap a step of `_bitdot`'s loop counts. The chip keeps
+# nothing of a block's size (tests/test_tpu_compile.py) and hardly cares: a
+# pass over the 10M-atom x 4096-seed bitmap read 47.8 / 44.9 / 44.5 ms at
+# 2^12 / 2^15 / 2^17 rows (PERF.md section 6, PR 28). The CPU backend does
+# write a block's unpacked bits out, block x K x 4 bytes: at 4096 seeds the
+# 0.5 GB it held before, less at every narrower block.
+BITDOT_ROWS = 1 << 15
 
-    ``packed_t (R, Kw) uint32``, ``vec (R,) float32`` → ``(K,) int32``.
-    Bit-unpack + matvec in row blocks so the unpack transient stays
-    ~``block_rows × K`` floats (MXU work, not gathers). Values are exact
-    while each block's partial sum stays below 2^24 (always true in the
-    test-scale graphs; at benchmark scale the relative error is ≤1e-7 of a
-    throughput counter).
+
+def _bitdot(packed_t: jax.Array, vec: jax.Array,
+            block_rows: int = BITDOT_ROWS) -> jax.Array:
+    """Σ_v vec[v] · bit(v, k) for every seed column k, exactly.
+
+    ``packed_t (R, Kw) uint32``, ``vec (R,) int32`` → ``(K,) int32``; the
+    caller bounds the sums below 2^31. The bits are unpacked shift-major,
+    ``(block_rows, 32, Kw)`` with the words still in the lanes, so that XLA
+    fuses unpack, weight and sum into one loop over the slice and the
+    unpacked bits never reach HBM (unpacked word-major and reshaped to
+    ``(block_rows, K)`` they did: 0.5 GB a block, PERF.md section 6,
+    PR 28); one ``(32, Kw) → K`` transpose after the loop restores the
+    column order ``word * 32 + bit``.
     """
     R, Kw = packed_t.shape
-    K = Kw * WORD
-    if R < block_rows:  # tiny inputs: pad up to one whole block
-        pad = _ceil_to(R, 8) - R
-        if pad:
-            packed_t = jnp.concatenate(
-                [packed_t, jnp.zeros((pad, Kw), jnp.uint32)]
-            )
-            vec = jnp.concatenate([vec, jnp.zeros((pad,), vec.dtype)])
-        block_rows = R + pad
+    block_rows = min(block_rows, R)
     n_blocks = -(-R // block_rows)
-    shifts = jnp.arange(WORD, dtype=jnp.uint32)
+    shifts = jnp.arange(WORD, dtype=jnp.uint32)[None, :, None]
 
     # fori + clamped dynamic slices instead of pad-and-reshape: the pad
     # path CONCATENATED (= copied) the whole packed array, a second
@@ -647,19 +652,17 @@ def _bitdot(packed_t: jax.Array, vec: jax.Array, block_rows: int) -> jax.Array:
     # block's clamped start overlaps the previous block; the row mask
     # zeroes the already-counted rows.
     def body(i, acc):
-        start = jnp.minimum(i * block_rows, packed_t.shape[0] - block_rows)
+        start = jnp.minimum(i * block_rows, R - block_rows)
         sl = jax.lax.dynamic_slice(packed_t, (start, 0), (block_rows, Kw))
-        dg = jax.lax.dynamic_slice(vec, (start,), (block_rows,))
+        w = jax.lax.dynamic_slice(vec, (start,), (block_rows,))
         fresh = (start + jnp.arange(block_rows)) >= i * block_rows
-        dg = jnp.where(fresh, dg, 0.0)
-        bits = ((sl[:, :, None] >> shifts) & 1).astype(jnp.float32)
-        part = jnp.einsum(
-            "rk,r->k", bits.reshape(block_rows, K), dg,
-            preferred_element_type=jnp.float32,
-        )
-        return acc + part.astype(jnp.int32)
+        w = jnp.where(fresh, w, 0)
+        bits = ((sl[:, None, :] >> shifts) & 1).astype(jnp.int32)
+        return acc + jnp.sum(bits * w[:, None, None], axis=0)
 
-    return jax.lax.fori_loop(0, n_blocks, body, jnp.zeros((K,), jnp.int32))
+    acc = jax.lax.fori_loop(0, n_blocks, body,
+                            jnp.zeros((WORD, Kw), jnp.int32))
+    return acc.T.reshape(Kw * WORD)
 
 
 def _program(module: str, scope: Optional[str] = None):
@@ -718,25 +721,16 @@ def _seed_bitmap(seeds: jax.Array, n_atoms: jax.Array, n_pad: int):
     return visited.at[n_atoms].set(jnp.uint32(0))  # dummy row stays zero
 
 
-def _bitdot_rows(K: int, n_pad: int) -> int:
-    # bitdot unpacks a (block_rows, K) f32 transient — cap it at ~0.5 GB
-    # so wide seed blocks leave HBM for the state
-    return max(1024, min((1 << 27) // max(K, 1), 131072,
-                         _ceil_to(n_pad, 8) // 8))
-
-
 @hgverify.entry(
     shapes=lambda: (hgverify.sds((64, 1), "uint32"),
-                    hgverify.sds((64,), "float32")),
+                    hgverify.sds((64,), "int32")),
 )
 @jax.jit
 @_program("hg_bfs_deg_sum", "hg.bfs.deg_sum")
-def _deg_sum(visited: jax.Array, deg_f: jax.Array) -> jax.Array:
-    """S = Σ_v visited[v]·deg(v) per seed. Bounded by E_inc < 2^31 so
-    int32 cannot wrap (bit-exactness subject to _bitdot's f32
-    accumulation, see its docstring)."""
-    return _bitdot(visited, deg_f,
-                   _bitdot_rows(visited.shape[1] * WORD, visited.shape[0]))
+def _deg_sum(visited: jax.Array, inc_deg: jax.Array) -> jax.Array:
+    """S = Σ_v visited[v]·deg(v) per seed, exact: bounded by E_inc < 2^31,
+    so int32 cannot wrap."""
+    return _bitdot(visited, inc_deg)
 
 
 @hgverify.entry(
@@ -856,9 +850,7 @@ def _sparse_hop(visited, pairs, n_atoms):
 @jax.jit
 @_program("hg_bfs_reach_counts", "hg.bfs.reach_counts")
 def _reach_counts(visited: jax.Array) -> jax.Array:
-    n_pad = visited.shape[0]
-    return _bitdot(visited, jnp.ones((n_pad,), jnp.float32),
-                   _bitdot_rows(visited.shape[1] * WORD, n_pad))
+    return _bitdot(visited, jnp.ones((visited.shape[0],), jnp.int32))
 
 
 # The rule that sends a block's first hop the sparse way (module docstring),
@@ -938,7 +930,10 @@ def _bfs_pull_device(
     with phase("hg.bfs.seeds_upload"):
         seeds_dev = jnp.asarray(seeds)
     visited = _seed_bitmap(seeds_dev, n_atoms, plans.n_pad)
-    s_ins: list = []  # S_h entering each counted hop; the last one is read
+    # S entering the block's last hop, the one Σ deg that `total_edges`
+    # reads (it telescopes over the hops before): one entry, or none where
+    # nothing counts edges or no hop runs
+    s_ins: list = []
     dense_hops = max_hops
     # the rule's look at the seeds runs beside the bitmap's zero fill
     sl = (_seed_links(snap, seeds, plans.total_indices // SPARSE_SHARE)
@@ -953,14 +948,13 @@ def _bfs_pull_device(
         dense_hops -= 1
         if count_edges and not dense_hops:
             s_ins.append(sl.deg)  # S_0 = deg(seed), which the host holds
-    deg_f = dev["inc_deg"].astype(jnp.float32)
-    # one obs.phase per synced step, four a hop: a traversal's seconds by
-    # stage in the default registry, and under a profiler the host span
-    # that a device idle gap is charged to
-    for _ in range(dense_hops):
-        if count_edges:
+    # one obs.phase per synced step, three a hop and the degree sum once a
+    # block: a traversal's seconds by stage in the default registry, and
+    # under a profiler the host span that a device idle gap is charged to
+    for hop in range(dense_hops):
+        if count_edges and hop == dense_hops - 1:
             with phase("hg.bfs.hop.deg_sum"):
-                s_ins.append(_deg_sum(visited, deg_f))
+                s_ins.append(_deg_sum(visited, dev["inc_deg"]))
                 jax.block_until_ready(s_ins[-1])
         with phase("hg.bfs.hop.stage1"):
             live = _stage(visited, levels1, widths1, chunk, use_pallas)

@@ -68,7 +68,6 @@ from hypergraphdb_tpu.ops.ellbfs import (
     ReducePlan,
     _apply_plan,
     _bitdot,
-    _bitdot_rows,
     _ceil_to,
     _segmented_ranges,
     build_reduce_plan,
@@ -594,8 +593,6 @@ def _bfs_fused(
     visited = _seed_rows(seeds, geom.n_rows, kwp)
     if clear_dummy:
         visited = visited.at[n_atoms].set(jnp.uint32(0))
-    deg_f = plan.inc_deg.astype(jnp.float32)
-    rows = _bitdot_rows(kwp * WORD, geom.n_rows)
     s_ins = []
     for _ in range(max_hops):
         if count_edges:
@@ -605,7 +602,7 @@ def _bfs_fused(
             # temps at 10M rows x 3 hops, against 6.3 GB with it —
             # tests/test_tpu_compile.py)
             visited, s_in = jax.lax.optimization_barrier(
-                (visited, _bitdot(visited, deg_f, rows))
+                (visited, _bitdot(visited, plan.inc_deg))
             )
             s_ins.append(s_in)
         if overlay is not None:
@@ -615,7 +612,7 @@ def _bfs_fused(
             visited = visited.at[overlay.rows].set(
                 visited[overlay.rows] | ov
             )
-    reach = _bitdot(visited, jnp.ones((geom.n_rows,), jnp.float32), rows)
+    reach = _bitdot(visited, jnp.ones((geom.n_rows,), jnp.int32))
     return visited, tuple(s_ins), reach
 
 

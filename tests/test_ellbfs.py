@@ -407,3 +407,73 @@ def test_one_restriction_and_plan_per_family(typed_graph, monkeypatch):
     assert 0 < sub.n_edges_tgt < snap.n_edges_tgt
     assert plans_for(sub).total_indices < plans_for(snap).total_indices
     assert plans_for(sub) is not plans_for(snap)
+
+
+# ------------------------------------------------------ the counting passes
+#
+# ``_bitdot`` is every per-seed number the traversal returns (``_deg_sum``,
+# ``_reach_counts``, the fused path's): exact ``int32`` sums against the
+# definition in ``int64`` numpy, at every row width the seed blocks take.
+
+
+def _bitdot_definition(packed, vec):
+    """Σ_r vec[r] · bit(r, k), column k = word * 32 + bit, in int64."""
+    bits = (packed[:, :, None] >> np.arange(32, dtype=np.uint32)) & 1
+    return (bits.reshape(len(packed), -1).astype(np.int64)
+            * vec[:, None].astype(np.int64)).sum(axis=0)
+
+
+@pytest.mark.parametrize("weights", ["ones", "degrees"])
+@pytest.mark.parametrize("rows", ["short", "multiple", "ragged"])
+@pytest.mark.parametrize("kw", [1, 4, 32, 128])
+def test_bitdot_is_exact(kw, rows, weights):
+    """Rows fewer than a block (one narrower block), an exact multiple,
+    and a ragged last block (its clamped start overlaps the block before;
+    the ``fresh`` mask counts each row once). Degrees run to 800,000 and
+    the column sums past 2^24, where a ``float32`` sum rounds."""
+    block = 64
+    R = {"short": 37, "multiple": 4 * block, "ragged": 3 * block + 29}[rows]
+    r = np.random.default_rng(kw * 1000 + R)
+    packed = r.integers(0, 1 << 32, size=(R, kw), dtype=np.uint32)
+    packed[:, 0] |= np.uint32(1)  # a column every row counts in
+    vec = (np.ones(R, np.int32) if weights == "ones"
+           else r.integers(600_000, 800_001, size=R).astype(np.int32))
+    want = _bitdot_definition(packed, vec)
+    if weights == "degrees":
+        assert want[0] > 1 << 24 and want.max() < 1 << 31
+    got = np.asarray(eb._bitdot(packed, vec, block))
+    assert got.dtype == np.int32 and got.shape == (kw * 32,)
+    assert np.array_equal(got, want)
+    # the block size is no part of the answer
+    assert np.array_equal(np.asarray(eb._bitdot(packed, vec)), want)
+
+
+@pytest.mark.parametrize("count_edges", [True, False])
+@pytest.mark.parametrize("typed", [False, True])
+@pytest.mark.parametrize("first_hop", ["sparse", "dense"])
+@pytest.mark.parametrize("hops", [1, 2, 3])
+def test_counts_are_exact_under_the_counting_schedule(
+        typed_graph, hops, first_hop, typed, count_edges, monkeypatch):
+    """``edges_touched`` is Σ deg over everything visited before the last
+    hop (admitted links only under a predicate) whichever program or host
+    array it is read from — the one ``_deg_sum`` of a block, or the seeds'
+    own degrees where the only hop is sparse — and zeros where nothing
+    counts edges; ``reach_counts`` is the visited set's size either way."""
+    g, snap, handle, seeds = typed_graph
+    fam = {handle[n] for n in FAMILIES["several"]} if typed else None
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_hop == "sparse" else 1 << 62)
+    dense_hops = hops - (first_hop == "sparse")
+    t0 = _phase_count("hg.bfs.hop.deg_sum")
+    with _Sides() as ran:
+        res = bfs_pull(snap, seeds, hops, link_types=fam,
+                       count_edges=count_edges)
+    assert ran.dense == dense_hops
+    assert _phase_count("hg.bfs.hop.deg_sum") - t0 == \
+        int(count_edges and dense_hops > 0)
+    assert res.edges_touched.dtype == np.int64
+    for k, s in enumerate(seeds.tolist()):
+        want, edges = (_oracle(g, fam, s, hops) if typed
+                       else host_bfs(snap, s, hops))
+        assert int(res.reach_counts[k]) == len(want)
+        assert res.edges_touched[k] == (edges if count_edges else 0)

@@ -147,8 +147,11 @@ def test_phase_records_when_the_body_raises(global_tracing):
 
 # ------------------------------------------------------ the traversal path
 
-HOP_PHASES = ("hg.bfs.hop.deg_sum", "hg.bfs.hop.stage1",
-              "hg.bfs.hop.stage2_lvl0", "hg.bfs.hop.stage2_upper_update")
+HOP_PHASES = ("hg.bfs.hop.stage1", "hg.bfs.hop.stage2_lvl0",
+              "hg.bfs.hop.stage2_upper_update")
+# once a seed block that counts edges and has a dense hop: the one Σ deg
+# `edges_touched` reads, on the bitmap entering the block's last hop
+DEG_SUM_PHASE = "hg.bfs.hop.deg_sum"
 SPARSE_PHASE = "hg.bfs.hop.sparse"   # once a block whose first hop is sparse
 CALL_PHASES = ("hg.bfs.seeds_upload", "hg.bfs.reach_counts",
                "hg.bfs.edges_to_host")
@@ -170,8 +173,8 @@ def _small_snapshot(seed: int = 7):
 
 
 def test_bfs_pull_leaves_every_phase_with_the_right_counts():
-    names = HOP_PHASES + (SPARSE_PHASE,) + CALL_PHASES + ONCE_PHASES + (
-        "hg.snapshot.from_tables",)
+    names = HOP_PHASES + (DEG_SUM_PHASE, SPARSE_PHASE) + CALL_PHASES \
+        + ONCE_PHASES + ("hg.snapshot.from_tables",)
     before = {n: _hist(n)["count"] for n in names}
     snap = _small_snapshot()
     # few seeds: the rule takes the sparse side, the first hop is the
@@ -180,14 +183,16 @@ def test_bfs_pull_leaves_every_phase_with_the_right_counts():
     hops = 3
     eb.bfs_pull(snap, seeds, hops)
     grew = {n: _hist(n)["count"] - before[n] for n in names}
-    assert grew == {**{n: hops - 1 for n in HOP_PHASES}, SPARSE_PHASE: 1,
+    assert grew == {**{n: hops - 1 for n in HOP_PHASES},
+                    DEG_SUM_PHASE: 1, SPARSE_PHASE: 1,
                     **{n: 1 for n in CALL_PHASES + ONCE_PHASES},
                     "hg.snapshot.from_tables": 1}
     # a second call on the snapshot: the memoised plan and its upload
     # record nothing, the per-call and per-hop phases again
     eb.bfs_pull(snap, seeds, 2)
     grew = {n: _hist(n)["count"] - before[n] for n in names}
-    assert grew == {**{n: hops - 1 + 1 for n in HOP_PHASES}, SPARSE_PHASE: 2,
+    assert grew == {**{n: hops - 1 + 1 for n in HOP_PHASES},
+                    DEG_SUM_PHASE: 2, SPARSE_PHASE: 2,
                     **{n: 2 for n in CALL_PHASES},
                     **{n: 1 for n in ONCE_PHASES},
                     "hg.snapshot.from_tables": 1}
@@ -195,11 +200,24 @@ def test_bfs_pull_leaves_every_phase_with_the_right_counts():
     # the pull chain and the sparse phase records nothing
     eb.bfs_pull(snap, np.arange(300, dtype=np.int32), hops)
     grew = {n: _hist(n)["count"] - before[n] for n in names}
-    assert grew == {**{n: hops + hops for n in HOP_PHASES}, SPARSE_PHASE: 2,
+    assert grew == {**{n: hops + hops for n in HOP_PHASES},
+                    DEG_SUM_PHASE: 3, SPARSE_PHASE: 2,
                     **{n: 3 for n in CALL_PHASES},
                     **{n: 1 for n in ONCE_PHASES},
                     "hg.snapshot.from_tables": 1}
     assert all(_hist(n)["total"] > 0.0 for n in names)
+    # nothing counts edges: no degree sum and no download, the hops as ever
+    res = eb.bfs_pull(snap, seeds, hops, count_edges=False)
+    assert not res.edges_touched.any()
+    grew = {n: _hist(n)["count"] - before[n] for n in names}
+    assert grew[DEG_SUM_PHASE] == 3 and grew["hg.bfs.edges_to_host"] == 3
+    assert grew["hg.bfs.hop.stage1"] == hops + hops + hops - 1
+    assert grew["hg.bfs.reach_counts"] == 4 and grew[SPARSE_PHASE] == 3
+    # one sparse hop and no other: S is the seeds' own degrees, which the
+    # host holds — no pass over the bitmap
+    eb.bfs_pull(snap, seeds, 1)
+    grew = {n: _hist(n)["count"] - before[n] for n in names}
+    assert grew[DEG_SUM_PHASE] == 3 and grew["hg.bfs.edges_to_host"] == 4
 
 
 def _u32(*shape):
@@ -215,8 +233,7 @@ STAGE_PROGRAMS = {
     "_seed_bitmap": ("hg_bfs_seed_bitmap", ("hg.bfs.seed_bitmap",),
                      (_i32(32), _i32()), {"n_pad": 64}),
     "_deg_sum": ("hg_bfs_deg_sum", ("hg.bfs.deg_sum",),
-                 (_u32(64, 1), jax.ShapeDtypeStruct((64,), jnp.float32)),
-                 {}),
+                 (_u32(64, 1), _i32(64)), {}),
     "_sparse_hop": ("hg_bfs_sparse_hop", ("hg.bfs.sparse_hop",),
                     (_u32(64, 1), _i32(2, 16), _i32()), {}),
     "_stage": ("hg_bfs_stage1",

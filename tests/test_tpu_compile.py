@@ -445,3 +445,24 @@ def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
     if step == "_sparse_hop":
         assert mem.alias_size_in_bytes >= _N_PAD * _KW * 4
         assert mem.temp_size_in_bytes < 2**30
+
+
+@pytest.mark.parametrize("program", ["_deg_sum", "_reach_counts"])
+def test_counting_pass_keeps_its_unpacked_bits_on_the_chip(
+        program, one_chip, no_compile_cache):
+    """The two counting programs at the cells' bitmap (``n_pad`` 10,000,072
+    rows x 128 words): whatever ``_bitdot`` holds besides its arguments
+    stays far below a block of unpacked bits. Unpacked word-major and
+    reshaped to ``(block_rows, K)`` — across the lane tiling, where XLA
+    cannot fuse the unpack into the sum — each program kept
+    ``u32[32768,128,32]``, 537,000,448 bytes, and wrote and read it once a
+    block: 0.98 TB a traversal to count a bitmap of 5.12 GB (PERF.md
+    section 6, PR 28). A profile shows that only on the chip; this is the
+    guard that runs without one."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    visited = _sds((_N_PAD, _KW), "uint32")
+    args = ((visited, _sds((_N_PAD,), "int32")) if program == "_deg_sum"
+            else (visited,))
+    compiled = getattr(eb, program).lower(*_place(args, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 64 * 2**20
