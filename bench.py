@@ -593,25 +593,11 @@ def bench_c4(snap, info, budget_s=240.0):
     dt = min(rep_times)
     device_eps = edges / dt
 
-    # charge each block its REAL width (the kernel's own layout rule) and
-    # its REAL path: a block the fused megakernel served moves only the
-    # gathered rows + one visited read/write per hop (ops/pallas_bfs
-    # traffic model — no stage buffers, no out_map re-gather), so fused
-    # and staged runs stay comparable on the same honest basis
-    from hypergraphdb_tpu.ops import pallas_bfs as _pbfs
+    # charge each block its REAL width (the kernel's own layout rule)
     from hypergraphdb_tpu.ops.ellbfs import block_layout
 
-    widths = block_layout(K, k_block)
-    fused_w = {w: _pbfs.fused_ready(snap, w) for w in set(widths)}
-
-    def bytes_for(w: int) -> int:
-        if fused_w[w]:
-            return _pbfs.fused_bytes_per_hop(
-                _pbfs.fused_plans_for(snap).geom, w
-            ) * HOPS
-        return pull_bytes_per_run(plans, w, HOPS)
-
-    gbps = sum(bytes_for(w) for w in widths) / dt / 1e9
+    gbps = sum(pull_bytes_per_run(plans, w, HOPS)
+               for w in block_layout(K, k_block)) / dt / 1e9
 
     host_n = min(8, K)
     host_eps, _ = best_of(
@@ -626,7 +612,6 @@ def bench_c4(snap, info, budget_s=240.0):
         "edges_per_run": edges,
         "device_s": round(dt, 3),
         "plan_build_s": round(plan_s, 1),
-        "fused_path": bool(any(fused_w.values())),
         "reps": reps,
         "n_devices": n_dev,
         **compile_info,

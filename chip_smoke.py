@@ -10,10 +10,9 @@ calls, and checks every answer against a plain host reference:
                (``models.dbpedia_snapshot``): 1024 conjunctive patterns
                through ``plan_pattern → execute_pattern → collect_pattern``;
                a 3-hop pull BFS from 4096 seeds through ``ops.bfs_pull``,
-               once as the code selects it (the fused Pallas plan DECLINES
-               this graph — hub rows overflow its SMEM window — so the
-               staged chain runs, on the Pallas gather) and once with the
-               Pallas gather switched off (the XLA gather), the two equal
+               once as the code selects it (the staged chain on the
+               Pallas gather) and once with the Pallas gather switched
+               off (the XLA gather), the two equal
                in all 4096 columns and, in 64 columns spread over the
                bitmap's words, equal to a numpy BFS; ``gather_or`` and
                ``intersect_sorted_pallas`` at one real-width shape each.
@@ -235,14 +234,12 @@ class Smoke:
         t_phase = time.perf_counter()
         out: dict = {}
         from hypergraphdb_tpu import models
-        from hypergraphdb_tpu.ops import pallas_bfs, pallas_gather
+        from hypergraphdb_tpu.ops import pallas_gather
 
         on_tpu = self.dev.platform == "tpu"
         if on_tpu:
-            # a refused kernel raises out of these; False would mean the
-            # program decided to serve without its kernels
-            require(pallas_bfs.pallas_bfs_ok(), "pallas_bfs_ok() is False "
-                    "on a TPU (HG_PALLAS_BFS veto set?)")
+            # a refused kernel raises out of this; False would mean the
+            # program decided to serve without its kernel
             require(pallas_gather.pallas_ok(), "pallas_ok() is False on a "
                     "TPU (HG_PALLAS_GATHER veto set?)")
         t0 = time.perf_counter()
@@ -269,8 +266,7 @@ class Smoke:
         """Free a snapshot's cached device arrays (bench.py's discipline
         between configs): the next leg needs most of the chip."""
         snap.__dict__.pop("device", None)  # cached_property storage
-        for attr in ("_tgt_ell", "_value_cols", "_pull_device",
-                     "_fused_device"):
+        for attr in ("_tgt_ell", "_value_cols", "_pull_device"):
             if hasattr(snap, attr):
                 object.__delattr__(snap, attr)
 
@@ -406,13 +402,11 @@ class Smoke:
     def _leg_bfs(self, snap, info) -> dict:
         """3-hop pull BFS from 4096 seeds on the benchmark graph through
         ``ops.bfs_pull``, the entry the traversal API calls. First with
-        what the code selects there: the fused Pallas plan DECLINES this
-        graph (its hub rows overflow the SMEM window; the reason is on the
-        line), so the staged chain runs, with the Pallas gather under its
-        128-word rows. Then with the program's own switch for that gather
-        off (``HG_PALLAS_GATHER=0``: the XLA gather). The two must agree
-        in every column, and the first is held to the host BFS."""
-        from hypergraphdb_tpu.ops import pallas_bfs
+        what the code selects there: the staged chain with the Pallas
+        gather under its 128-word rows. Then with the program's own switch
+        for that gather off (``HG_PALLAS_GATHER=0``: the XLA gather). The
+        two must agree in every column, and the first is held to the host
+        BFS."""
         from hypergraphdb_tpu.ops.ellbfs import plans_for
 
         r = np.random.default_rng(self.seed + 2)
@@ -421,7 +415,6 @@ class Smoke:
         t0 = time.perf_counter()
         plans_for(snap)
         plan_s = time.perf_counter() - t0
-        declined = pallas_bfs.plan_supported(snap, self.s["seeds"])
         on_tpu = self.dev.platform == "tpu"
         legs: dict = {}
         ref = None
@@ -462,8 +455,6 @@ class Smoke:
         return {
             "seeds": len(seeds), "hops": self.s["hops"],
             "plan_build_s": round(plan_s, 1),
-            "fused_plan": ("admitted" if declined is None
-                           else f"declined: {declined}"),
             "edges_touched": int(ref[2].sum()),
             **legs, "legs_equal": True, "equal_host_seeds": n_ref,
         }
@@ -551,7 +542,6 @@ class Smoke:
         aot_dir = os.path.join(HERE, ".aot_cache")
         cfg = ServeConfig(aot_cache_dir=aot_dir, tracer=Tracer().enable())
         out["config"] = {"buckets": list(cfg.buckets), "top_r": cfg.top_r,
-                         "use_pallas_bfs": cfg.use_pallas_bfs,
                          "aot_cache_dir": aot_dir}
         warnings = _WarningTap().install()
         try:
@@ -648,7 +638,7 @@ class Smoke:
         g.close()
         out["stats"] = {k: snap.get(k) for k in (
             "submitted", "completed", "device_dispatches", "host_fallbacks",
-            "bfs_fused_dispatches", "range_dispatches", "errors", "retries",
+            "range_dispatches", "errors", "retries",
             "breaker_trips", "shed_deadline", "batches", "batch_occupancy")}
         out["aot"] = snap.get("aot")
         out["warnings"] = warnings.messages[:8]
@@ -668,18 +658,12 @@ class Smoke:
         # (every message of the AOT paths begins "aot ...")
         require(not any(": aot" in m.lower() for m in warnings.messages),
                 f"AOT warnings: {warnings.messages[:3]}")
-        # which entry served each bucket that ran — both from what ran:
-        # the buckets from the requests' traces, the entry from the
-        # counter at the fused kernel's call site
+        # the buckets that ran, from the requests' traces
         ran = sorted({int(b) for k in ("stage1", "burst", "stage2", "stage3")
                       for b in out[k]["bfs_buckets"]})
         require(all(b <= widest for b in ran),
                 f"a BFS batch formed past the executor's cap {cap}: {ran}")
-        fused = st["bfs_fused_dispatches"]
-        out["bfs_entry_by_bucket"] = {
-            str(b): ("unfused" if fused == 0 else
-                     f"{fused} fused dispatches among all buckets")
-            for b in ran}
+        out["bfs_entry_by_bucket"] = {str(b): "unfused" for b in ran}
         out["seconds"] = round(time.perf_counter() - t_phase, 1)
         return out
 

@@ -15,12 +15,7 @@ from hypergraphdb_tpu.ops.incremental import (
     bfs_levels_delta,
 )
 from hypergraphdb_tpu.ops.aot_cache import AOTCache
-from hypergraphdb_tpu.ops.pallas_bfs import bfs_pull_fused, pallas_bfs_ok
-from hypergraphdb_tpu.ops.serving import (
-    bfs_serve_batch,
-    bfs_serve_batch_fused,
-    pattern_serve_batch,
-)
+from hypergraphdb_tpu.ops.serving import bfs_serve_batch, pattern_serve_batch
 from hypergraphdb_tpu.ops.setops import (
     and_incident_pattern,
     collect_pattern,
@@ -42,10 +37,7 @@ __all__ = [
     "PinnedView",
     "PullBFSResult",
     "SnapshotManager",
-    "bfs_pull_fused",
     "bfs_serve_batch",
-    "bfs_serve_batch_fused",
-    "pallas_bfs_ok",
     "pattern_serve_batch",
     "and_incident_pattern",
     "bfs_levels",

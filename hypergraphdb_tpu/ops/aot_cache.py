@@ -10,7 +10,7 @@ cache lookup: ``jax.jit(...).lower().compile()`` products are serialized
 (``jax.experimental.serialize_executable``) into a **fingerprinted
 on-disk directory** and loaded back in milliseconds.
 
-Key anatomy (see README "Fused BFS kernel & AOT cache"):
+Key anatomy (see README "AOT cache"):
 
 - the cache **directory** is fingerprinted by environment —
   ``<root>/<jax-version>_<backend>/`` — so upgrading jax or moving

@@ -62,7 +62,6 @@ total index count per hop drops from ``K × E`` to ``~1.3 × E × (1 + 1/w)``
 from __future__ import annotations
 
 import os
-from contextlib import nullcontext
 from dataclasses import dataclass
 from functools import partial
 from typing import NamedTuple, Optional, Sequence
@@ -268,8 +267,8 @@ def _apply_plan(
     levels: Sequence[jax.Array],
     widths: Sequence[int],
     chunk: int,
-    use_pallas: bool = False,
-    scopes: Optional[tuple[str, str]] = None,
+    use_pallas: bool,
+    scopes: tuple[str, str],
 ) -> jax.Array:
     """Run the reduction pyramid; returns the CONCATENATION of every
     level's chunk array plus one global zero row at the end — the address
@@ -287,14 +286,11 @@ def _apply_plan(
     global zero row); their outputs are small enough to materialize.
 
     ``scopes`` names the device operations of (level 0, the upper levels)
-    for a profile, as ``jax.named_scope`` components of their ``op_name``;
-    a caller that passes none leaves them unnamed."""
+    for a profile, as ``jax.named_scope`` components of their ``op_name``."""
     Kw = values.shape[1]
     sizes = [lvl.shape[0] // w for lvl, w in zip(levels, widths)]
     total = sum(sizes) + 1  # + global zero row at index `sum(sizes)`
-    lvl0_scope, upper_scope = (
-        map(jax.named_scope, scopes) if scopes
-        else (nullcontext(), nullcontext()))
+    lvl0_scope, upper_scope = map(jax.named_scope, scopes)
     with lvl0_scope:
         buf = jnp.zeros((total, Kw), dtype=values.dtype)
         buf = _reduce_into(buf, 0, values, levels[0], widths[0], chunk,
@@ -317,9 +313,10 @@ def _upper_levels(
     (level 0 first); ``off`` is the first upper section's offset; the
     global zero row sits at ``buf.shape[0] - 1``. Prev-level-local indices
     are rebased into buffer space on device (pad marker ``len(prev)`` →
-    the global zero row). Upper levels stay on the XLA gather: they are
-    small, and the Pallas wrapper's pad/slice copies would cost more than
-    they save."""
+    the global zero row). Upper levels stay on the XLA gather, where they
+    are 29% of the untyped 10M-atom traversal (``traverse_dev_s.upper``
+    2.18 s, PERF.md section 5); whether ``hg_gather_or`` would serve them
+    better is not measured."""
     total = buf.shape[0]
     for i, (idx, w) in enumerate(zip(levels, widths)):
         n_prev = sizes[i]
@@ -1063,17 +1060,6 @@ def bfs_pull(
     blocks = []
     for s in range(0, K_pad, k_block):
         block = seeds[s : s + k_block]
-        # fused megakernel first: ONE dispatch runs every hop with no
-        # stage buffers and no host sequencing (ops/pallas_bfs); declines
-        # (CPU backend, window budgets) fall through to the staged chain
-        from hypergraphdb_tpu.ops import pallas_bfs as _pbfs
-
-        if _pbfs.fused_ready(snap, len(block)):
-            blocks.append(
-                _pbfs.bfs_pull_fused(snap, block, max_hops,
-                                     count_edges=count_edges)
-            )
-            continue
         # 4096-seed blocks (128-lane rows, the one width the kernel
         # compiles at) run the Pallas gather on a TPU; everything else
         # keeps the XLA gather (no width limits)

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 
 from hypergraphdb_tpu import verify as hgverify
-from hypergraphdb_tpu.ops import pallas_bfs as _pbfs
 from hypergraphdb_tpu.ops.incremental import DeviceDelta, bfs_levels_delta
 from hypergraphdb_tpu.ops.setops import SENTINEL, incident_intersection_ell
 from hypergraphdb_tpu.ops.snapshot import DeviceSnapshot
@@ -79,8 +78,7 @@ def first_r_dense(mask: jax.Array, top_r: int, base=0) -> jax.Array:
     """Per row of a dense ``(K, n)`` bool mask, the ``top_r`` SMALLEST set
     column ids (``base +`` column), ascending, SENTINEL-padded — the
     serving compaction, swept in :data:`FIRST_R_BLOCK`-column steps with
-    a per-step ``top_k`` + merge (the ``pallas_bfs.first_r_from_bitmap``
-    discipline).
+    a per-step ``top_k`` + merge.
 
     One ``top_k`` over the whole row is what this replaces: past ~270K
     columns the v5e compiler refuses it (``TopKBatchMajorSmallK`` runs
@@ -115,50 +113,6 @@ def first_r_dense(mask: jax.Array, top_r: int, base=0) -> jax.Array:
         return -jax.lax.top_k(-merged, top_r)[0]
 
     return jax.lax.fori_loop(1, -(-n // rb), body, first)
-
-
-@hgverify.entry(
-    shapes=_pbfs.exemplar_shapes,
-    statics={
-        "geom": _pbfs.EXEMPLAR_GEOM,
-        "kwp": 128, "max_hops": 2, "top_r": 4, "interpret": True,
-    },
-)
-@partial(jax.jit, static_argnames=(
-    "geom", "kwp", "max_hops", "top_r", "widths1", "widths2", "interpret",
-))
-def bfs_serve_batch_fused(
-    fused: "_pbfs.DeviceFusedPlan",
-    seeds: jax.Array,          # (K,) int32 — pad lanes carry n_atoms
-    n_atoms: jax.Array,        # scalar int32
-    overlay: "_pbfs.OverlayArrays" = None,
-    *,
-    geom: "_pbfs.FusedGeom",
-    kwp: int,
-    max_hops: int,
-    top_r: int,
-    widths1: tuple = None,
-    widths2: tuple = None,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """The fused-kernel twin of :func:`bfs_serve_batch`: same
-    ``(counts, first_r)`` contract, computed from the transposed-bitmap
-    Pallas hop chain (``ops/pallas_bfs``) instead of the dense
-    ``bfs_levels_delta`` sweep. Delta-added edges ride the ``overlay``
-    pull plan (host-built per delta refresh); tombstones do NOT — the
-    executor declines to route here while any tombstone is pending
-    (composed fused adjacency cannot neutralize a dead link). Pad lanes
-    keep their dummy-row seed bit, matching the dense path's
-    well-defined-garbage contract lane for lane."""
-    visited, _, reach = _pbfs._bfs_fused(
-        fused, seeds, n_atoms, geom, kwp, max_hops,
-        count_edges=False, clear_dummy=False, overlay=overlay,
-        widths1=widths1, widths2=widths2, interpret=interpret,
-    )
-    K = seeds.shape[0]
-    counts = reach[:K]
-    first_r = _pbfs.first_r_from_bitmap(visited, n_atoms + 1, top_r, K)
-    return counts, first_r
 
 
 @hgverify.entry(

@@ -131,8 +131,7 @@ class ServeConfig:
     transient_errors: tuple = ()            # extra types to retry
     sleep: Optional[Callable] = None        # injectable backoff sleeper
     faults: Optional[object] = None         # fault registry; None → global
-    # -- raw speed (pallas_bfs + aot_cache) ----------------------------------
-    use_pallas_bfs: bool = True             # fused kernel when it preflights
+    # -- raw speed (aot_cache) -----------------------------------------------
     aot_cache_dir: Optional[str] = None     # AOT compile cache; None → env
     prewarm_aot: bool = True                # compile K buckets at startup
     prewarm_hops: Optional[tuple] = None    # hops to warm; None → (default,)
@@ -280,8 +279,6 @@ class DeviceExecutor:
         #: to this graph generation (quiet rebuild on mismatch).
         self.aot = self._open_aot_cache()
         self._aot_failed = False
-        #: plan_supported reasons already logged (_note_fused_decline)
-        self._fused_declines: set = set()
         #: ((id space, edges), cap) — bfs_bucket_cap's memo per base shape
         self._bfs_cap: tuple = (None, None)
         #: (epoch, new_atoms scanned, touched set | "full") —
@@ -364,33 +361,6 @@ class DeviceExecutor:
         if compiled is not None:
             return compiled(*args)
         return bfs_serve_batch(*args, **statics)
-
-    def _serve_bfs_fused(self, kw: dict, seeds_dev, max_hops: int,
-                         top_r: int):
-        """The fused-kernel dispatch, through the AOT cache when the
-        batch carries no overlay (the steady read-heavy shape prewarm
-        covers); overlay batches take the plain jit — their array shapes
-        change per delta refresh, which would churn even the in-process
-        memo for executables jit retraces anyway."""
-        from hypergraphdb_tpu.ops.serving import bfs_serve_batch_fused
-
-        statics = {
-            "geom": kw["geom"], "kwp": kw["kwp"], "max_hops": max_hops,
-            "top_r": top_r, "widths1": kw["widths1"],
-            "widths2": kw["widths2"],
-        }
-        self.stats.record_bfs_fused_dispatch()
-        if kw["overlay"] is None:
-            args = (kw["fused"], seeds_dev, kw["n_atoms"])
-            compiled = self._aot_dispatch(
-                "ops.serving.bfs_serve_batch_fused",
-                bfs_serve_batch_fused, args, statics,
-            )
-            if compiled is not None:
-                return compiled(*args)
-        return bfs_serve_batch_fused(kw["fused"], seeds_dev,
-                                     kw["n_atoms"], kw["overlay"],
-                                     **statics)
 
     def _serve_pattern(self, view, ell, anchors, type_vec):
         """One pattern batch dispatch through the AOT cache when
@@ -507,22 +477,15 @@ class DeviceExecutor:
     def prewarm(self, buckets, max_hops: Optional[int] = None) -> int:
         """Compile (or load from the AOT cache) the BFS serving
         executables for every bucket width against the current pinned
-        view — the deploy-time half of the cold-start story. Warms the
-        unfused entry always (it serves tombstone/overlay windows and
-        every non-Pallas backend) and the fused entry wherever the fused
-        gates would route the first dispatch. Runs even with NO cache
-        configured: the fused host plan build (O(composed adjacency) —
-        seconds at benchmark scale) and the backend probe compile are
-        unrelated to AOT and must not land inside the first live
-        request's deadline window. Returns the number of executables
-        served from cache."""
+        view — the deploy-time half of the cold-start story. With NO
+        cache configured there is nothing to load or persist, and only
+        the BFS buckets are sized (``bfs_bucket_cap``: on a device with
+        bounded memory that compiles their programs, which must not land
+        inside the first live request's deadline window). Returns the
+        number of executables served from cache."""
         import jax.numpy as jnp
 
-        from hypergraphdb_tpu.ops import pallas_bfs as _pbfs
-        from hypergraphdb_tpu.ops.serving import (
-            bfs_serve_batch,
-            bfs_serve_batch_fused,
-        )
+        from hypergraphdb_tpu.ops.serving import bfs_serve_batch
 
         if self.config.prewarm_join_nbr:
             # the join lane's co-incidence CSR: built + uploaded at
@@ -565,11 +528,12 @@ class DeviceExecutor:
                         "range dispatch sorts it cold", int(dim),
                         exc_info=True,
                     )
-        if self.aot is None and not (self.config.use_pallas_bfs
-                                     and _pbfs.pallas_bfs_ok()):
-            # nothing to warm: no cache to load, and the fused path (the
-            # owner of the plan-build/probe cost) can never engage — skip
-            # the pinned_view so cache-less CPU construction stays free
+        # asked here whatever else is warmed: the first BFS flush would
+        # otherwise ask it on the dispatch thread (Batcher.key_cap)
+        bfs_cap = self.bfs_bucket_cap()
+        if self.aot is None:
+            # nothing to warm: no cache to load — skip the pinned_view so
+            # cache-less construction stays free
             return 0
 
         # the hops SET to warm: a deployment serving more than the default
@@ -586,15 +550,13 @@ class DeviceExecutor:
         # the pattern lane's ELL targets + executables (ROADMAP 4d):
         # without this, join/pattern traffic in a fresh process pays its
         # (bucket, P) compiles on the dispatch thread at first flush
-        arities = (tuple(self.config.prewarm_pattern_arities or ())
-                   if self.aot is not None else ())
+        arities = tuple(self.config.prewarm_pattern_arities or ())
         ell = None
         if arities:
             from hypergraphdb_tpu.ops.setops import ell_targets
 
             ell = ell_targets(view.base)
-        warm_dims = range_dims if self.aot is not None else ()
-        if warm_dims:
+        if range_dims:
             from hypergraphdb_tpu.storage.value_index import (
                 build_delta_column,
                 type_of_device,
@@ -604,15 +566,10 @@ class DeviceExecutor:
             # the executable depends on shapes, not contents
             empty_delta = build_delta_column(self.graph, [], 0, epoch=-1)
         warm = 0
-        bfs_cap = self.bfs_bucket_cap()
         for b in buckets:
             seeds = jnp.full((int(b),), n, dtype=jnp.int32)
             # a bucket no BFS batch will ever form at warms no BFS program
             bfs_fits = bfs_cap is None or int(b) <= bfs_cap
-            # plan build + backend probe happen HERE regardless of cache
-            fkw = self._fused_bfs_kwargs(view, int(b)) if bfs_fits else None
-            if self.aot is None:
-                continue
             if ell is not None:
                 from hypergraphdb_tpu.ops.serving import (
                     NO_TYPE,
@@ -633,7 +590,7 @@ class DeviceExecutor:
                         )
                     except Exception:  # noqa: BLE001 - never block startup
                         continue
-            for dim in warm_dims:
+            for dim in range_dims:
                 from hypergraphdb_tpu.ops.value_index import (
                     ordered_topk_batch,
                 )
@@ -668,10 +625,6 @@ class DeviceExecutor:
                 except Exception:  # noqa: BLE001 - never block startup
                     continue
             for hops in (hops_list if bfs_fits else ()):
-                # independent try blocks: a bucket whose unfused lowering
-                # fails must not forfeit the fused warm (or vice versa) —
-                # whichever entry the first dispatch routes to should be
-                # hot
                 try:
                     warm += self.aot.warm(
                         "ops.serving.bfs_serve_batch", bfs_serve_batch,
@@ -686,62 +639,7 @@ class DeviceExecutor:
                         "hops=%d); first dispatch compiles cold",
                         int(b), hops, exc_info=True,
                     )
-                if fkw is None or fkw["overlay"] is not None:
-                    continue
-                try:
-                    warm += self.aot.warm(
-                        "ops.serving.bfs_serve_batch_fused",
-                        bfs_serve_batch_fused,
-                        (fkw["fused"], seeds, fkw["n_atoms"]),
-                        {"geom": fkw["geom"], "kwp": fkw["kwp"],
-                         "max_hops": hops, "top_r": top_r,
-                         "widths1": fkw["widths1"],
-                         "widths2": fkw["widths2"]},
-                    )
-                except Exception:  # noqa: BLE001
-                    continue
         return warm
-
-    def _fused_bfs_kwargs(self, view, bucket: int):
-        """Route this batch through the fused Pallas kernel? None keeps
-        the unfused chain. Gates, in order: config, backend preflight,
-        pending tombstones (the composed adjacency cannot neutralize a
-        dead link — bounded by the next compaction), plan budgets /
-        overlay planability."""
-        if not self.config.use_pallas_bfs:
-            return None
-        from hypergraphdb_tpu.ops import pallas_bfs as _pbfs
-
-        # off-TPU this is False from the platform; on a TPU a kernel the
-        # chip refuses RAISES here and rides the launch's error ladder
-        # (serve.errors, the breaker) — never a quiet unfused answer
-        if not _pbfs.pallas_bfs_ok():
-            return None
-        if view.dead:
-            return None
-        kw = _pbfs.serve_fused_kwargs(view.base, view.delta, bucket)
-        if kw is None:
-            self._note_fused_decline(view, bucket)
-        return kw
-
-    def _note_fused_decline(self, view, bucket: int) -> None:
-        """The backend serves the fused kernel but this (snapshot,
-        bucket) is outside its plan windows: say so ONCE per reason —
-        the batch is about to be answered by the unfused chain, and
-        ``serve.bfs_fused_dispatches`` staying at zero should never be
-        the only trace of why."""
-        from hypergraphdb_tpu.ops import pallas_bfs as _pbfs
-
-        why = _pbfs.plan_supported(view.base, bucket)
-        if why is None or why in self._fused_declines:
-            return
-        self._fused_declines.add(why)
-        import logging
-
-        logging.getLogger("hypergraphdb_tpu.serve").warning(
-            "fused BFS declined for bucket %d; the unfused chain serves "
-            "it: %s", bucket, why,
-        )
 
     # -- which BFS buckets fit the chip ---------------------------------------
     def _device_memory_is_bounded(self) -> bool:
@@ -785,9 +683,7 @@ class DeviceExecutor:
         anyway — and stops at the first the compiler refuses: a bucket
         past the cap is neither prewarmed nor formed (``Batcher.key_cap``)
         — a burst rides more batches of the widest bucket that fits — and
-        the refusal is logged, once per base shape. The cap binds the
-        fused entry too: a fused batch falls to the dense entry whenever
-        a tombstone is pending."""
+        the refusal is logged, once per base shape."""
         base = self.mgr.base
         shape = (int(base.num_atoms), int(len(base.inc_links)))
         if self._bfs_cap[0] == shape:
@@ -882,16 +778,10 @@ class DeviceExecutor:
                 # drops its seed from the window, and the spare slot keeps
                 # the remaining prefix full-width (see _bfs_result)
                 top_r = min(self.config.top_r + 1, n + 1)
-                fused_kw = self._fused_bfs_kwargs(view, batch.bucket)
                 with self._dispatch_cm("bfs", batch.bucket, max_hops):
-                    if fused_kw is not None:
-                        out.dev_out = self._serve_bfs_fused(
-                            fused_kw, jnp.asarray(seeds), max_hops, top_r,
-                        )
-                    else:
-                        out.dev_out = self._serve_bfs(
-                            view, jnp.asarray(seeds), max_hops, top_r,
-                        )
+                    out.dev_out = self._serve_bfs(
+                        view, jnp.asarray(seeds), max_hops, top_r,
+                    )
         elif kind == "pattern":
             from hypergraphdb_tpu.ops.serving import NO_TYPE
 
@@ -1738,9 +1628,9 @@ class ServeRuntime:
         # deploy-time compile: load-or-build the serving executables for
         # every bucket BEFORE the dispatch thread takes traffic, so a
         # warm AOT cache reaches first dispatch without recompiling.
-        # Runs with no cache too — the fused plan build + backend probe
-        # must not wait for the first live request (injected executors
-        # without a prewarm hook are skipped)
+        # Runs with no cache too — sizing the BFS buckets must not wait
+        # for the first live request (injected executors without a
+        # prewarm hook are skipped)
         if (self.config.prewarm_aot and graph is not None
                 and callable(getattr(self.executor, "prewarm", None))):
             try:

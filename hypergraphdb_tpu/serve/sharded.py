@@ -89,9 +89,6 @@ class ShardedExecutor(DeviceExecutor):
         # a 1/n_dev share of the single-chip program's rows
         return None
 
-    def _fused_bfs_kwargs(self, view, bucket: int):
-        return None  # the fused Pallas chain is single-chip only
-
     def _serve_bfs(self, view, seeds_dev, max_hops: int, top_r: int):
         from hypergraphdb_tpu.ops.sharded_serving import (
             bfs_serve_batch_sharded,

@@ -168,7 +168,11 @@ class ServeStats:
         self._device_dispatches = r.counter("serve.device_dispatches")
         self._sharded_dispatches = r.counter("serve.sharded_dispatches")
         self._range_dispatches = r.counter("serve.range_dispatches")
-        self._bfs_fused = r.counter("serve.bfs_fused_dispatches")
+        # nothing increments it (the fused BFS path is gone): it stays,
+        # with its two table rows and its snapshot() key, because
+        # benchmarks/drivers/closed_loop.COUNTERS reads the key; a
+        # `benchmark` PR drops that name, then these go (ROADMAP.md)
+        self._fused_dispatches = r.counter("serve.bfs_fused_dispatches")
         self._retries = r.counter("serve.retries")
         self._perf_errors = r.counter("serve.perf_observe_errors")
         self._join_hub = r.counter("serve.join.hub_dispatches")
@@ -216,7 +220,6 @@ class ServeStats:
             self._gated, self._cancelled, self._errors, self._host_fallbacks,
             self._batches, self._device_dispatches,
             self._sharded_dispatches, self._range_dispatches,
-            self._bfs_fused,
             self._device_seconds,
             self._join_hub, self._join_partial,
             self._retries, self._perf_errors,
@@ -433,16 +436,6 @@ class ServeStats:
         with self._lock:
             self._range_dispatches.inc()
 
-    def record_bfs_fused_dispatch(self) -> None:
-        """One BFS batch served by the fused Pallas entry
-        (``ops.serving.bfs_serve_batch_fused``), counted at the
-        kernel-call site. BFS batches the unfused chain served are the
-        rest of the lane's dispatches — a fused path that declines
-        (budget, tombstones, backend) shows here as a flat zero instead
-        of nowhere."""
-        with self._lock:
-            self._bfs_fused.inc()
-
     def record_lane(self, kind: str, path: str) -> None:
         """One request RESOLVED through lane ``(kind, path)`` — counted
         at completion (beside ``record_complete``), so the family's sum
@@ -551,10 +544,6 @@ class ServeStats:
     def range_dispatches(self) -> int:
         return self._range_dispatches.value
 
-    @property
-    def bfs_fused_dispatches(self) -> int:
-        return self._bfs_fused.value
-
     # -- reading -------------------------------------------------------------
     def occupancy(self) -> Optional[float]:
         """Mean real-lane fraction over every dispatched bucket slot."""
@@ -596,7 +585,7 @@ class ServeStats:
                 "device_dispatches": self._device_dispatches.value,
                 "sharded_dispatches": self._sharded_dispatches.value,
                 "range_dispatches": self._range_dispatches.value,
-                "bfs_fused_dispatches": self._bfs_fused.value,
+                "bfs_fused_dispatches": self._fused_dispatches.value,
                 "retries": self._retries.value,
                 "breaker_trips": self._breaker_trips.value,
                 "breaker_state": self._breaker_state.value,

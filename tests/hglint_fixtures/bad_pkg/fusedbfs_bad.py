@@ -1,8 +1,8 @@
-"""Seeded HG501 + HG503 hazards shaped like the fused pull-BFS hop
-kernel (``ops/pallas_bfs._hop_call``): the scalar-prefetched chunk plan
+"""Seeded HG501 + HG503 hazards shaped like a fused pull-BFS hop
+kernel (one ``pallas_call`` a hop): the scalar-prefetched chunk plan
 overflowing SMEM, and DMA row scratch + double-buffered visited windows
-overflowing VMEM — the exact window math the real kernel guards with
-``_smem_bytes``/``_vmem_bytes`` at runtime."""
+overflowing VMEM — window math that such a kernel has to guard at
+runtime, and that Mosaic refuses on hardware only."""
 
 import functools
 

@@ -1,6 +1,6 @@
-"""Clean twin of fusedbfs_bad — the REAL fused hop geometry
-(``ops/pallas_bfs``: B=8 rows × 128 lanes, D*W=64-row DMA scratch,
-chunk plan inside half the SMEM budget). Zero findings allowed."""
+"""Clean twin of fusedbfs_bad — a fused hop geometry that fits
+(B=8 rows × 128 lanes, D*W=64-row DMA scratch, chunk plan inside
+half the SMEM budget). Zero findings allowed."""
 
 import functools
 

@@ -2,6 +2,7 @@
 pure-numpy host BFS, on random hypergraphs (the correctness oracle pattern
 from SURVEY §7 M4)."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -83,32 +84,62 @@ def test_pull_matches_bitfrontier():
     assert np.array_equal(np.asarray(res.edges_touched), cnt_old.astype(np.int32))
 
 
-def test_duplicate_and_padded_seeds():
+@pytest.mark.parametrize("seeds_are", ["duplicates", "isolated"])
+def test_duplicate_padded_and_isolated_seeds(seeds_are):
+    """Duplicates beside K % 32 != 0; and seeds no link touches — an empty
+    frontier after hop 1: nothing is placed, the later hops pull an
+    unchanged bitmap, and every seed reaches itself alone."""
     snap = random_snapshot(100, 80, 3, seed=9)
-    seeds = np.asarray([5, 5, 17], dtype=np.int32)  # dupes + K%32 != 0
-    res = bfs_pull(snap, seeds, 2)
+    deg = np.diff(snap.inc_offsets[:101].astype(np.int64))
+    seeds = (np.asarray([5, 5, 17], dtype=np.int32)
+             if seeds_are == "duplicates"
+             else np.flatnonzero(deg == 0)[:3].astype(np.int32))
+    assert len(seeds) == 3
+    res = bfs_pull(snap, seeds, 3)
     rows = visited_rows(res, snap.num_atoms)
-    assert set(rows[0].tolist()) == set(rows[1].tolist())
-    w0, _ = host_bfs(snap, 5, 2)
-    assert set(rows[0].tolist()) == w0
     assert res.edges_touched.shape == (3,)
+    if seeds_are == "duplicates":
+        assert set(rows[0].tolist()) == set(rows[1].tolist())
+    else:
+        assert [r.tolist() for r in rows[:3]] == [[int(s)] for s in seeds]
+        assert not res.edges_touched.any()
+    _assert_matches_host(snap, seeds, 3, res)
 
 
-def test_chunked_scan_and_multiblock():
+@pytest.mark.parametrize("first_hop,count_edges",
+                         [("by_rule", True), ("dense", False)])
+def test_chunked_scan_and_multiblock(first_hop, count_edges, monkeypatch):
     """Exercise the chunk-streamed _reduce_level scan path (E > chunk*w) and
     the multi-block k_block driver — the two paths that otherwise only
-    activate at benchmark scale."""
+    activate at benchmark scale. As the rule sends it (32 seeds a block: a
+    sparse first hop), counting edges; and on the dense chain from the
+    first hop with ``count_edges=False``: no degree pass runs, and the
+    bitmap and the reach counts are the counting run's, bit for bit."""
     snap = random_snapshot(500, 400, 5, seed=21, zipf=True)
     r = np.random.default_rng(17)
     seeds = r.integers(0, 500, size=96).astype(np.int32)
-    res = bfs_pull(snap, seeds, 2, chunk=4, k_block=32)
+    counted = bfs_pull(snap, seeds, 2, chunk=4, k_block=32)
+    if first_hop == "dense":
+        monkeypatch.setattr(eb, "SPARSE_SHARE", 1 << 62)
+    t0 = _phase_count("hg.bfs.hop.deg_sum")
+    with _Sides() as ran:
+        res = bfs_pull(snap, seeds, 2, chunk=4, k_block=32,
+                       count_edges=count_edges)
+    # three blocks, two hops each
+    assert (ran.sparse, ran.dense) == ((3, 3) if first_hop == "by_rule"
+                                       else (0, 6))
+    assert _phase_count("hg.bfs.hop.deg_sum") - t0 == 3 * count_edges
     rows = visited_rows(res, snap.num_atoms)
     counts = np.asarray(res.edges_touched)
     assert counts.dtype == np.int64
     for k in (0, 31, 32, 63, 64, 95):  # spans all three k-blocks
         want, edges = host_bfs(snap, int(seeds[k]), 2)
         assert set(rows[k].tolist()) == want
-        assert counts[k] == edges
+        assert counts[k] == (edges if count_edges else 0)
+    assert np.array_equal(np.asarray(res.visited_t),
+                          np.asarray(counted.visited_t))
+    assert np.array_equal(np.asarray(res.reach_counts),
+                          np.asarray(counted.reach_counts))
 
 
 def test_k_block_validation():
@@ -134,6 +165,102 @@ def test_reduce_plan_shapes():
 def test_plans_cached():
     snap = random_snapshot(50, 40, 3, seed=1)
     assert plans_for(snap) is plans_for(snap)
+
+
+# ------------------------------------------------------ the stage programs
+#
+# The programs a dense hop is made of, one at a time, against numpy: a
+# built ``ReducePlan`` run by ``_stage`` (stage 1's whole pyramid in one
+# program) and by ``_stage_lvl0_consume`` + ``_stage_upper`` (stage 2's, in
+# two), read through ``out_map``, is the segmented OR of the value rows;
+# ``_visited_update`` is ``visited | reach[out_map]``.
+
+STAGE_W = 4
+
+
+def _stage_rows(rows, r):
+    """(row degrees, levels the plan must have) of one case; level 0 and
+    the upper levels are 4 wide."""
+    if rows == "one_row":      # all empty but one: 6 chunks → 2 → 1
+        deg = np.zeros(9, np.int64)
+        deg[4] = 23
+        return deg, 3
+    if rows == "short":        # uniform short rows: level 0 alone
+        return np.full(12, 2, np.int64), 1
+    if rows == "hub":          # 18 chunks → 5 → 2 → 1: three upper levels
+        deg = r.integers(1, 4, size=30)
+        deg[11] = 69
+        return deg, 4
+    deg = r.integers(0, 10, size=23)  # "ragged": empty rows, rows past
+    deg[[0, 22]] = (0, 5)             # one chunk, an odd chunk count
+    return deg, 2
+
+
+@pytest.mark.parametrize("kw", [1, 4])
+@pytest.mark.parametrize("chunk", ["steps", "whole"])
+@pytest.mark.parametrize("rows", ["one_row", "short", "hub", "ragged"])
+def test_stage_matches_numpy_segmented_or(rows, chunk, kw):
+    """``chunk`` smaller than level 0 (the scan takes several steps, and
+    in the ragged case leaves a tail) and larger than it (one update)."""
+    r = np.random.default_rng([len(rows), kw])
+    deg, n_levels = _stage_rows(rows, r)
+    n_rows, S = len(deg), 40
+    offsets = np.concatenate([[0], np.cumsum(deg)])
+    flat = r.integers(0, S, size=int(offsets[-1]))
+    plan = build_reduce_plan(offsets, flat, n_rows, zero_row=S,
+                             w=STAGE_W, w_upper=STAGE_W)
+    n0 = len(plan.levels[0]) // STAGE_W
+    chunk = 2 if chunk == "steps" else 1 << 10
+    assert (n0 > 2 * chunk) if chunk == 2 else (n0 < chunk)
+    assert len(plan.levels) == n_levels
+    if rows == "ragged":
+        assert n0 % 2 and (deg % STAGE_W).any()
+
+    values = r.integers(0, 1 << 32, size=(S + 1, kw), dtype=np.uint32)
+    values[S] = 0  # the zero row level 0 pads with
+    want = np.zeros((n_rows, kw), np.uint32)
+    nz = np.flatnonzero(deg)
+    want[nz] = np.bitwise_or.reduceat(values[flat], offsets[nz], axis=0)
+
+    levels = tuple(np.asarray(l) for l in plan.levels)
+    whole = np.asarray(eb._stage(values, levels, plan.widths, chunk, False))
+    assert whole.shape == (plan.concat_size + 1, kw)
+    assert not whole[plan.concat_size].any()  # the global zero row
+    assert np.array_equal(whole[plan.out_map], want)
+
+    lvl0 = eb._stage_lvl0_consume(values, levels[0], STAGE_W, chunk, False)
+    assert lvl0.shape == (n0, kw)
+    split = np.asarray(eb._stage_upper(lvl0, levels[1:], plan.widths, chunk))
+    assert np.array_equal(split, whole)
+
+
+@pytest.mark.parametrize("case", ["no_fresh_bit", "every_row_fresh",
+                                  "zero_row"])
+def test_visited_update_matches_numpy(case):
+    """One full block of the row loop and a tail; the dummy row
+    (``n_atoms``) is zero on the way out whatever it was given."""
+    r = np.random.default_rng(len(case))
+    n_pad, kw, n_chunks = (1 << 18) + 40, 1, 50
+    n_atoms = n_pad - 3
+    reach = r.integers(1, 1 << 32, size=(n_chunks + 1, kw), dtype=np.uint32)
+    reach[n_chunks] = 0
+    out_map = r.integers(0, n_chunks, size=n_pad).astype(np.int32)
+    if case == "no_fresh_bit":      # every bit pulled is already there
+        visited = reach[out_map] | np.uint32(1 << 31)
+    elif case == "every_row_fresh":
+        visited = np.zeros((n_pad, kw), np.uint32)
+    else:                           # nothing reached: rows → the zero row
+        visited = r.integers(0, 1 << 32, size=(n_pad, kw), dtype=np.uint32)
+        out_map[:] = n_chunks
+    want = visited | reach[out_map]
+    want[n_atoms] = 0
+    got = np.asarray(eb._visited_update(
+        jnp.asarray(visited), jnp.asarray(reach), jnp.asarray(out_map),
+        jnp.int32(n_atoms)))
+    assert np.array_equal(got, want)
+    if case != "every_row_fresh":
+        keep = np.arange(n_pad) != n_atoms
+        assert np.array_equal(got[keep], visited[keep])
 
 
 # ------------------------------------------------------ the sparse first hop
@@ -373,18 +500,12 @@ def test_no_predicate_is_the_family_of_all_types_is_todays_answer(
                               np.asarray(typed.reach_counts))
 
 
-def test_one_restriction_and_plan_per_family(typed_graph, monkeypatch):
+def test_one_restriction_and_plan_per_family(typed_graph):
     """Phase ``hg.bfs.restrict`` fires once per (snapshot, family), its
-    plan's size lands in the two gauges, the restricted plan gathers
-    admitted entries only, and the fused path is asked about the restricted
-    snapshot, never the parent."""
-    from hypergraphdb_tpu.ops import pallas_bfs
-
+    plan's size lands in the two gauges, and the restricted plan gathers
+    admitted entries only."""
     g, snap, handle, seeds = typed_graph
     fam = [handle["knows"], handle["tags"]]
-    asked = []
-    monkeypatch.setattr(pallas_bfs, "fused_ready",
-                        lambda s, k: asked.append(s) and False)
     t0 = _phase_count("hg.bfs.restrict")
     sub = eb.restricted_for(snap, fam)
     assert sub is not snap and sub.num_atoms == snap.num_atoms
@@ -397,7 +518,6 @@ def test_one_restriction_and_plan_per_family(typed_graph, monkeypatch):
     for _ in range(2):
         bfs_pull(snap, seeds, 2, link_types=reversed(fam))
     assert _phase_count("hg.bfs.restrict") == t0 + 1
-    assert asked and all(s is sub for s in asked)
     # every entry the restricted relations hold belongs to an admitted link
     admitted = np.isin(snap.type_of, fam)
     assert admitted[sub.tgt_src[: sub.n_edges_tgt]].all()
@@ -412,7 +532,7 @@ def test_one_restriction_and_plan_per_family(typed_graph, monkeypatch):
 # ------------------------------------------------------ the counting passes
 #
 # ``_bitdot`` is every per-seed number the traversal returns (``_deg_sum``,
-# ``_reach_counts``, the fused path's): exact ``int32`` sums against the
+# ``_reach_counts``): exact ``int32`` sums against the
 # definition in ``int64`` numpy, at every row width the seed blocks take.
 
 

@@ -263,7 +263,6 @@ def test_failed_prewarm_logs_and_startup_still_serves(tmp_path, caplog,
             raise RuntimeError("prewarm torn")
 
         cfg = ServeConfig(buckets=(4,), max_linger_s=0.001,
-                          use_pallas_bfs=False,
                           aot_cache_dir=str(tmp_path),
                           prewarm_join_nbr=True,
                           prewarm_range_dims=(ord("i"),))

@@ -121,10 +121,11 @@ def test_smem_scalar_prefetch_budget():
 
 
 def test_fused_bfs_kernel_window_fixtures():
-    """The fused pull-BFS hop kernel's window math (ops/pallas_bfs): the
-    scalar-prefetched chunk plan overflowing SMEM and the scratch+window
-    set overflowing VMEM are both caught; the committed real geometry
-    folds clean."""
+    """The window math of a fused pull-BFS hop kernel, on two
+    self-contained fixtures (the kernel they were shaped after left the
+    tree at PR 29; HG501/HG503 are what is tested): a scalar-prefetched
+    chunk plan overflowing SMEM and a scratch+window set overflowing VMEM
+    are both caught; the twin that fits folds clean."""
     findings = run_lint([str(FIXTURES / "bad_pkg" / "fusedbfs_bad.py")])
     by_rule = {f.rule: f for f in findings}
     assert set(by_rule) == {"HG501", "HG503"}

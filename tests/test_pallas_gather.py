@@ -59,6 +59,26 @@ def test_pallas_ok_false_on_cpu():
     assert pg.pallas_ok() is False
 
 
+def test_probe_failure_on_tpu_raises(monkeypatch):
+    """On a TPU backend a kernel the chip refuses must RAISE out of the
+    gate (and keep raising: a failed probe is never cached as a quiet
+    False); off-TPU the gate is False from the platform alone."""
+    assert pg.pallas_ok() is False              # cpu: platform says no
+    monkeypatch.setattr(pg.jax, "default_backend", lambda: "tpu")
+
+    def refused(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    # the real kernel entry cannot run here; make it fail the way a
+    # refusing compiler does
+    monkeypatch.setattr(pg, "gather_or", refused)
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            pg.pallas_ok()
+    monkeypatch.setenv("HG_PALLAS_GATHER", "0")
+    assert pg.pallas_ok() is False              # the veto still wins
+
+
 def test_bfs_pull_wide_block_cpu_fallback(graph):
     """k_block=4096 on CPU: pallas preflight fails → XLA path, results must
     equal the narrow-block run."""

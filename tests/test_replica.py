@@ -208,11 +208,16 @@ def test_runtime_truncation_forces_in_place_rebootstrap():
 
 
 def wait_for_rebootstrap(node, gp, chunks_before, timeout=30.0):
+    """The repair ran to its end: the mark is cleared, a chunk moved, and
+    the node has set ``bootstrapped`` again — which ``_rebootstrap`` does a
+    moment after the transfer clears the mark, so a caller that waited for
+    the mark alone could read the flag too early."""
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         if ("primary" not in node.peer.replication.needs_full_sync
                 and gp.metrics.counters.get("peer.transfer_chunks", 0)
-                > chunks_before):
+                > chunks_before
+                and node.bootstrapped):
             return True
         time.sleep(0.02)
     return False
@@ -263,9 +268,6 @@ def test_truncation_lazy_rebootstrap_with_ae_loop_disabled():
         reason = node._read_gate()
         assert reason is not None and "re-bootstrapping" in reason
         assert wait_for_rebootstrap(node, gp, chunks_before)
-        deadline = time.monotonic() + 30
-        while time.monotonic() < deadline and not node.bootstrapped:
-            time.sleep(0.02)
         assert node.bootstrapped
         assert node._read_gate() is None
         # and the repaired replica still follows live pushes

@@ -297,6 +297,35 @@ def test_stats_surface_shape():
     assert snap["batch_occupancy"] == pytest.approx(0.25)
 
 
+def _benchmark_counters() -> tuple:
+    """``COUNTERS`` of ``benchmarks/drivers/closed_loop.py``, read from the
+    file's source: importing the driver needs ``benchmarks/`` on the path
+    and pulls in its harness."""
+    import ast
+    from pathlib import Path
+
+    src = (Path(__file__).resolve().parents[1] / "benchmarks" / "drivers"
+           / "closed_loop.py").read_text()
+    for node in ast.parse(src).body:
+        if (isinstance(node, ast.Assign)
+                and [getattr(t, "id", None) for t in node.targets]
+                == ["COUNTERS"]):
+            return tuple(ast.literal_eval(node.value))
+    raise AssertionError("closed_loop.py no longer assigns COUNTERS")
+
+
+@pytest.mark.parametrize("name", _benchmark_counters())
+def test_stats_snapshot_holds_every_counter_the_benchmark_reads(name):
+    """The served cell's driver reads ``snap[name]`` for every name of its
+    ``COUNTERS`` and subtracts two snapshots: each must be an integer key
+    of ``stats_snapshot()`` from the start, before any request. The guard
+    on a key that is kept for that reader alone (``serve/stats.py``)."""
+    rt, _, _ = make_runtime()
+    snap = rt.stats_snapshot()
+    assert name in snap
+    assert type(snap[name]) is int and snap[name] == 0
+
+
 # ---------------------------------------------------------------- requests
 
 

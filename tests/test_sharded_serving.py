@@ -28,7 +28,7 @@ BUCKETS = (16, 64)
 
 def _cfg(**kw):
     base = dict(buckets=BUCKETS, max_linger_s=0.001, top_r=16,
-                use_pallas_bfs=False, prewarm_aot=False)
+                prewarm_aot=False)
     base.update(kw)
     return ServeConfig(**base)
 
@@ -268,7 +268,7 @@ for _ in range(2):          # a pod, then a fresh pod over the same cache
     make_random_hypergraph(g, n_nodes=80, n_links=160, seed=2)
     rt = ServeRuntime(g, ServeConfig(
         sharded=True, buckets=(16,), max_linger_s=0.001, top_r=16,
-        use_pallas_bfs=False, prewarm_aot=True, aot_cache_dir=sys.argv[1],
+        prewarm_aot=True, aot_cache_dir=sys.argv[1],
         prewarm_pattern_arities=(2,)))
     out.append(rt.stats_snapshot()["aot"])
     rt.close()

@@ -31,13 +31,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 import chip_smoke
 from hypergraphdb_tpu import verify as hgverify
 
-#: chip_smoke.py's sizes, read from it: the kernels phase's row count
-#: (models.dbpedia_snapshot(2M, 8M)) and the serve phase's padded id space
+#: chip_smoke.py's sizes, read from it: the serve phase's padded id space
 #: and edge count (1M entities + 2M binary links through the store: every
 #: valued atom takes a second handle, the type system a few thousand, times
 #: the default headroom of 2, rounded up to the pad — SnapshotManager's
 #: arithmetic).
-ROWS_10M = 10_000_065
 _FULL = chip_smoke.SCALES["full"]
 _PAD = _FULL["pad_multiple"]
 SERVE_ATOMS = -(-2 * (2 * (_FULL["serve_entities"] + _FULL["serve_links"])
@@ -113,19 +111,6 @@ def _rescaled(entry_name: str, dims: dict):
 # test, after the fixture described the topology.
 
 
-def _case_hop_call(place, kwp=128, nb=512):
-    from hypergraphdb_tpu.ops import pallas_bfs as pb
-
-    cap = 4096
-    fn = jax.jit(partial(pb._hop_call, nb=nb, w=pb.W, interpret=False))
-    return fn, place((
-        _sds((nb + 1,), "int32"), _sds((cap,), "int32"),
-        _sds((cap * pb.W,), "int32"),
-        _sds((4 * nb * pb.B, kwp), "uint32"),
-        _sds((nb * pb.B, kwp), "uint32"),
-    )), {}
-
-
 def _case_gather_or(place, kw=128):
     from hypergraphdb_tpu.ops import pallas_gather as pg
 
@@ -139,37 +124,6 @@ def _case_membership(place):
 
     return _membership_call, place((_sds((1024, 128), "int32"),
                                     _sds((3, 65536), "int32"))), {}
-
-
-def _fused_plan_shapes(n_atoms: int, cap: int):
-    """DeviceFusedPlan avals + FusedGeom of an ADMITTED plan over
-    ``n_atoms`` rows (build_fused_plan's geometry arithmetic)."""
-    from hypergraphdb_tpu.ops import pallas_bfs as pb
-
-    n_blocks = -(-(n_atoms + 2) // pb.B)
-    nb = min(n_blocks, pb.SEG_BLOCKS)
-    n_seg = -(-n_blocks // nb)
-    n_rows = n_seg * nb * pb.B
-    geom = pb.FusedGeom(n_atoms=n_atoms, n_rows=n_rows, n_seg=n_seg, nb=nb,
-                        cap=cap, w=pb.W, zero_row=n_rows - 1,
-                        total_entries=n_seg * cap * pb.W)
-    assert pb._smem_bytes(cap, nb, pb.W) <= pb.SMEM_BUDGET // 2
-    plan = pb.DeviceFusedPlan(
-        blk_off=_sds((n_seg, nb + 1), "int32"),
-        chunk_rows=_sds((n_seg, cap), "int32"),
-        idx=_sds((n_seg, cap * pb.W), "int32"),
-        inc_deg=_sds((n_rows,), "int32"),
-    )
-    return plan, geom
-
-
-def _case_serve_fused(place, top_r=16):
-    from hypergraphdb_tpu.ops.serving import bfs_serve_batch_fused
-
-    plan, geom = _fused_plan_shapes(SERVE_ATOMS, cap=4096)
-    return bfs_serve_batch_fused, place(
-        (plan, _sds((1024,), "int32"), _sds((), "int32"))
-    ), dict(geom=geom, kwp=128, max_hops=2, top_r=top_r)
 
 
 def _case_serve_bfs(place, bucket, hops, atoms=SERVE_ATOMS,
@@ -214,12 +168,11 @@ def _case_join_hub_expand(place):
 
 
 CASES = {
-    # the four the served path and the kernels phase cannot do without
-    "hop_call[nb=512,kwp=128]": _case_hop_call,
+    # the two kernels the served path and the kernels phase cannot do
+    # without
     "gather_or[1Mx128,128K]": _case_gather_or,
     "membership[1024x128,3x65536]": _case_membership,
-    "bfs_serve_batch_fused[K=1024,hops=2,top_r=16]": _case_serve_fused,
-    # the unfused served BFS at the serve phase's graph and the widest
+    # the dense served BFS at the serve phase's graph and the widest
     # bucket the executor admits there (past ~270K atoms a single top_k
     # over the row was refused — scoped VMEM — hence first_r_dense). slow:
     # each of these dense programs keeps every core busy for ~25 s, and
@@ -242,8 +195,7 @@ def test_main_path_compiles_for_v5e(case, one_chip, no_compile_cache):
     # else the process holds. (memory_analysis() is not held to the chip's
     # size: it overstates what the temporaries take — PERF.md, PR 22.)
     compiled = fn.lower(*args, **kwargs).compile()
-    if "hop_call" in case or "gather_or" in case or "membership" in case \
-            or "fused" in case:
+    if "gather_or" in case or "membership" in case:
         assert "tpu_custom_call" in compiled.as_text()  # Mosaic, not interpret
 
 
@@ -266,29 +218,18 @@ def test_the_compiler_refuses_the_bfs_bucket_that_does_not_fit(
 # ------------------------------------------------- widths: gate == compiler
 
 
-@pytest.mark.parametrize("kernel", ["hop_call", "gather_or"])
-def test_wide_rows_gate_agrees_with_compiler(kernel, one_chip,
-                                             no_compile_cache):
-    """No row width exists that the gates admit and the compiler refuses:
-    128 words compiles (above) and is admitted; 256 words is refused by
-    Mosaic ('aligned to tiling (8), but is 1') and declined by the gate,
-    with the reason."""
+def test_wide_rows_gate_agrees_with_compiler(one_chip, no_compile_cache):
+    """No row width exists that the gate admits and the compiler refuses:
+    128 words compiles (above) and is admitted; 256 words — which Mosaic
+    refuses ('aligned to tiling (8), but is 1') — is declined by the
+    gate, with the reason, at trace time, before the compiler is asked."""
     from hypergraphdb_tpu.ops import pallas_gather as pg
 
-    place = partial(_place, sharding=one_chip)
-    build = _case_hop_call if kernel == "hop_call" else _case_gather_or
-    fn, args, kwargs = (build(place, kwp=256) if kernel == "hop_call"
-                        else build(place, kw=256))
-    if kernel == "gather_or":
-        # the gate raises at trace time, before the compiler is asked
-        with pytest.raises(ValueError, match="128"):
-            fn.lower(*args, **kwargs)
-        assert pg.declined(8, 128) is None and pg.declined(8, 256)
-        return
-    # _hop_call itself is ungated: plan_supported gates its callers and
-    # declines 8192 seeds (256 words) — tests/test_pallas_bfs.py
-    with pytest.raises(Exception, match="aligned to tiling"):
-        fn.lower(*args, **kwargs).compile()
+    fn, args, kwargs = _case_gather_or(partial(_place, sharding=one_chip),
+                                       kw=256)
+    with pytest.raises(ValueError, match="128"):
+        fn.lower(*args, **kwargs)
+    assert pg.declined(8, 128) is None and pg.declined(8, 256)
 
 
 @pytest.mark.slow  # ~25 s to be refused; the fix is guarded above
@@ -300,32 +241,6 @@ def test_whole_row_top_k_is_what_the_compiler_refuses(one_chip,
     x = _place(_sds((8, 300_001), "int32"), one_chip)
     with pytest.raises(Exception, match="vmem"):
         jax.jit(lambda m: jax.lax.top_k(m, 17)[0]).lower(x).compile()
-
-
-# --------------------------------------- the fused BFS at the 10M-row scale
-
-
-def test_fused_bfs_fits_one_chip_at_10m_rows(one_chip, no_compile_cache):
-    """The whole jitted ``_bfs_fused`` at the kernels phase's size —
-    10,000,065 rows, a 128-word bitmap (4096 seeds), 3 hops: an input and
-    an output bitmap of 5.1 GB each plus the composed adjacency, on a
-    16 GB chip. (On the zipf benchmark graph itself the plan declines —
-    hub rows overflow the SMEM window — so this is the geometry of an
-    admitted, hub-free plan at that row count.)"""
-    from hypergraphdb_tpu.ops import pallas_bfs as pb
-
-    plan, geom = _fused_plan_shapes(ROWS_10M, cap=4096)
-    args = _place((plan, _sds((4096,), "int32"), _sds((), "int32")),
-                  one_chip)
-    compiled = pb._bfs_fused.lower(
-        *args, geom=geom, kwp=128, max_hops=3, count_edges=True,
-        clear_dummy=True,
-    ).compile()
-    mem = compiled.memory_analysis()
-    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
-             + mem.output_size_in_bytes)
-    assert total < HBM_USABLE, (total, mem)
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 # ------------------------------------------------- four devices: the mesh

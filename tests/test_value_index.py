@@ -393,7 +393,7 @@ def test_range_prewarm_hits_aot_cache(graph, tmp_path):
     sorted column is built at startup, off the dispatch thread)."""
     _int_graph(graph, n=30)
     cfg = dict(buckets=(4,), max_linger_s=0.001, top_r=8,
-               aot_cache_dir=str(tmp_path), use_pallas_bfs=False,
+               aot_cache_dir=str(tmp_path),
                prewarm_range_dims=(ord("i"),))
     rt1 = ServeRuntime(graph, ServeConfig(**cfg))
     r1 = rt1.submit_range(lo=3, hi=9).result(timeout=60)

@@ -27,7 +27,6 @@ PRODUCT_MODULES = (
     "hypergraphdb_tpu.ops.ellbfs",
     "hypergraphdb_tpu.ops.setops",
     "hypergraphdb_tpu.ops.pallas_gather",
-    "hypergraphdb_tpu.ops.pallas_bfs",
     "hypergraphdb_tpu.ops.incremental",
     "hypergraphdb_tpu.ops.serving",
     "hypergraphdb_tpu.ops.join",

@@ -1,10 +1,9 @@
 #!/usr/bin/env bash
-# Performance-plane gate: the fused-vs-unfused differential suite (fused
-# Pallas pull-BFS megakernel == the staged ellbfs chain == the dense
-# serve sweep, bit for bit, incl. the delta-overlay path), the hgperf
-# suites (runtime perf sentinel + bench envelope/diff), an AOT-cache
-# cold/warm smoke over a REAL ServeRuntime, the bench --diff live gate
-# (a recorded c6 mini-run diffs clean against itself; the committed
+# Performance-plane gate: the Pallas gather's differential suite
+# (hg_gather_or == the XLA gather, bit for bit), the hgperf suites
+# (runtime perf sentinel + bench envelope/diff), an AOT-cache cold/warm
+# smoke over a REAL ServeRuntime, the bench --diff live gate (a recorded
+# c6 mini-run diffs clean against itself; the committed
 # injected-regression fixture pair must exit nonzero), and a live
 # sentinel drill (seeded serve.launch slowdown on a real runtime fires
 # exactly one incident with the flight window + profiler capture on
@@ -15,12 +14,12 @@
 # performance plane's correctness contracts.
 #
 # Usage: tools/perf.sh [extra pytest args]
-#   tools/perf.sh -k fused             # differential suite only
+#   tools/perf.sh -k gather            # differential suite only
 set -uo pipefail
 cd "$(dirname "$0")/.."
 
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
-    tests/test_pallas_bfs.py \
+    tests/test_aot_cache.py \
     tests/test_pallas_gather.py \
     tests/test_perf_sentinel.py \
     tests/test_bench_envelope.py \

@@ -9,8 +9,8 @@
 # (curl -f when present, stdlib urllib otherwise — degraded, never down).
 #
 # Sits beside lint.sh (AST hazards), verify.sh (jaxpr ground truth),
-# chaos.sh (fault injection), obs.sh (telemetry), and perf.sh (fused
-# kernel + AOT): this one gates the deployment tier.
+# chaos.sh (fault injection), obs.sh (telemetry), and perf.sh (Pallas
+# gather + AOT): this one gates the deployment tier.
 #
 # Usage: tools/replica.sh [extra pytest args]
 #   tools/replica.sh -k router         # one area, fast local run
