@@ -29,15 +29,24 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   VISITED (monotone closure — no separate frontier array, half the state):
   stage 1: ``link_live[l] = OR_{t ∈ targets(l)} V[t]``
   stage 2: ``reach[v]    = OR_{l ∈ incident(v)} link_live[l]``
-  Each is a gather of edge-many rows followed by a fixed-width tree
-  reduction over host-precomputed padded index plans (:class:`ReducePlan`):
-  every CSR row is padded to a multiple of ``w`` and aligned, so the
-  segment-OR is a plain ``reshape(-1, w, Kw) → OR(axis=1)`` — XLA's fused
-  streaming path, no segment ids, no conflicts, hub rows handled by
-  recursion (level ℓ reduces rows of up to ``w^(ℓ+1)`` entries).
-- levels compose: stage 2's level-0 indices are pre-composed with stage
-  1's output map on host, so link-space results are consumed directly
-  without materializing a per-link destination array.
+  Each is a gather of edge-many rows followed by a fixed-width reduction
+  over host-precomputed padded index plans (:class:`ReducePlan`). Level 0
+  is a few WIDTH CLASSES (``CLASS_WIDTHS``): a CSR row of degree d sits
+  whole in ONE chunk of the smallest class width >= d, so the segment-OR
+  of a class is a plain ``reshape(-1, w, Kw) → OR(axis=1)`` — XLA's fused
+  streaming path, no segment ids, no conflicts — and the row is finished
+  where it was gathered. Only a row above the widest class (``W_MAX``) is
+  cut into ``W_MAX``-wide chunks and handled by recursion (upper level ℓ
+  reduces rows of up to ``W_MAX · w_upper^ℓ`` entries): hubs, a few
+  thousand rows of the 10M-atom graph. A gathered index costs the chip
+  the same whether it is an entry or a pad (``ops/pallas_gather.py``), so
+  the plan's index count IS the hop's time: one width-8 level 0 gathered
+  1.63-1.81 indices an entry plus an upper pyramid over every row past 8
+  entries (PERF.md section 6, PR 30).
+- levels compose: stage 2's level-0 indices (every class) are
+  pre-composed with stage 1's output map on host, so link-space results
+  are consumed directly without materializing a per-link destination
+  array.
 - per-seed edge counts (the benchmark numerator) are one exact pass over
   the bitmap a seed block (``_deg_sum``: bit-unpack, weight by degree and
   sum in ``int32``, fused on the vector unit) — no gathers.
@@ -54,9 +63,10 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   and a predicate that differs by hop.
 
 Geometry note: each gather row is ``Kw = K/32`` uint32 words (32 lanes for
-K=1024). Gathers remain the dominant cost and are latency-bound, but the
-total index count per hop drops from ``K × E`` to ``~1.3 × E × (1 + 1/w)``
-— three orders of magnitude at K=1024.
+K=1024). Gathers remain the dominant cost and are bound by the indices
+issued, but the total index count per hop drops from ``K × E`` to the
+plan's ``~1.4 × E`` (the classes' padding) — three orders of magnitude at
+K=1024.
 """
 
 from __future__ import annotations
@@ -85,28 +95,61 @@ def _ceil_to(x: int, m: int) -> int:
 
 # ------------------------------------------------------------------ host plans
 
+#: Chunk widths of a plan's level 0, ascending: a CSR row of degree d is
+#: reduced in ONE chunk of the smallest width >= d, where it is gathered;
+#: only a row above the last width (``W_MAX``) is cut into chunks (of
+#: ``W_MAX``) and climbs the upper pyramid. Constants, not a knob: fixed
+#: from what an index costs ``hg_gather_or`` at each width on the chip
+#: (``benchmarks/tests/gather_width_probe.py``; PERF.md section 6, PR 30).
+#: Even widths to 10, then steps of about the square root of two, so that a
+#: row's padding stays under a third; none a multiple of 32, where the
+#: kernel pays 16.6 ns an index against 14.5-15.3 at 28, 40 and 56; odd
+#: widths cost what the next even one does. Every class earns its place on
+#: the 10M-atom cells' degree tables (without 28 an untyped hop is 154 ms
+#: longer, without 14 — the least — 7 ms).
+CLASS_WIDTHS = (2, 4, 6, 8, 10, 14, 20, 28, 40, 56)
+W_MAX = CLASS_WIDTHS[-1]
+for _w in CLASS_WIDTHS:
+    # a real raise, not an assert: the guard must survive `python -O`
+    if (_why := _pg.declined(_w, _pg.ROW_WORDS)) is not None:
+        raise ValueError(f"ellbfs.CLASS_WIDTHS: {_why}")
+del _w, _why
+
+#: Indices a scan step of a level-0 class moves, in units of the caller's
+#: ``chunk``: ``chunk`` output rows at width 8, as many indices at every
+#: other width, so that a step's gather transient does not follow the class
+STEP_WIDTH = 8
+
 
 @dataclass(frozen=True)
 class ReducePlan:
     """Padded-gather tree reduction over one CSR relation.
 
-    ``levels[0]`` indexes caller-provided value rows (with ``zero_row``
-    pointing at a guaranteed-all-zero row) and covers every row; level
-    ``ℓ>0`` covers ONLY rows still unfinished (more than one chunk) —
-    single-chunk rows would otherwise pay a ``w_upper×`` pass-through pad
-    per level, a 16× index blowup at hypergraph scale. Upper-level indices
-    are local to the previous level's chunk array, with index
-    ``len(prev_chunks)`` meaning the per-level appended zero row.
+    The first ``n_lvl0`` of ``levels`` are level 0's WIDTH CLASSES: they
+    index caller-provided value rows (with ``zero_row`` pointing at a
+    guaranteed-all-zero row), and between them cover every non-empty row
+    once — a row of degree d <= the widest class sits whole in one chunk
+    of the smallest class width >= d (rows in CSR order within a class)
+    and is finished there; a row above it is cut into chunks of the widest
+    class, in that class's array. A class no row falls in has no array.
+    The levels after them are the upper pyramid and cover ONLY those cut
+    rows, still unfinished (more than one chunk): their indices are local
+    to the previous level's chunk array (the first upper level's to the
+    widest class's), with index ``len(prev_chunks)`` meaning the per-level
+    appended zero row. A relation with no row above the widest class has
+    no upper level.
 
     A row's final chunk therefore lives in the chunk array of whichever
     level it finished at; ``out_map[r]`` addresses the **concatenation** of
-    all level chunk arrays (in order) with one global zero row at the very
-    end (``concat_size``). Empty rows map to the zero row. All index
-    arrays are int32; every level's length is a multiple of its width.
+    all level chunk arrays (in order, classes first) with one global zero
+    row at the very end (``concat_size``). Empty rows map to the zero row.
+    All index arrays are int32; every level's length is a multiple of its
+    width.
     """
 
     levels: tuple[np.ndarray, ...]
     widths: tuple[int, ...]
+    n_lvl0: int          # leading levels that read the caller's values
     out_map: np.ndarray  # (R,) int32 into concat space; empty rows → zero row
     n_rows: int
     concat_size: int     # total chunks across levels; zero row lives here
@@ -115,79 +158,84 @@ class ReducePlan:
     def total_indices(self) -> int:
         return int(sum(len(l) for l in self.levels))
 
+    @property
+    def upper_indices(self) -> int:
+        return int(sum(len(l) for l in self.levels[self.n_lvl0:]))
+
 
 def build_reduce_plan(
     offsets: np.ndarray,
     flat: np.ndarray,
     n_rows: int,
     zero_row: int,
-    w: int = 8,
+    classes: Sequence[int] = CLASS_WIDTHS,
     w_upper: int = 8,
 ) -> ReducePlan:
     """Build the padded index pyramid for ``reduce_or`` over CSR rows.
 
     ``offsets``/``flat`` describe rows ``0..n_rows``; ``zero_row`` indexes
-    an all-zero value row used for level-0 padding. Level 0 width is ``w``;
+    an all-zero value row used for level-0 padding. ``classes`` are level
+    0's chunk widths, ascending (one width: every row in chunks of it);
     upper levels use ``w_upper``.
     """
     offsets = np.asarray(offsets, dtype=np.int64)
+    flat = np.asarray(flat, dtype=np.int32)
     deg = offsets[1 : n_rows + 1] - offsets[:n_rows]
-    nchunk = -(-deg // w)  # ceil; 0 for empty rows
+    w_max = int(classes[-1])
+    cls = np.minimum(np.searchsorted(np.asarray(classes), deg),
+                     len(classes) - 1)  # rows above w_max: the widest class
+    cls[deg == 0] = -1
 
-    total = int(nchunk.sum()) * w
-    idx0 = np.full(total, zero_row, dtype=np.int32)
-    row_pad_starts = np.zeros(n_rows + 1, dtype=np.int64)
-    np.cumsum(nchunk * w, out=row_pad_starts[1:])
-    nz = np.nonzero(deg)[0]
-    if len(nz):
-        reps = deg[nz]
-        dst = _segmented_ranges(row_pad_starts[nz], reps)
-        src = _segmented_ranges(offsets[nz], reps)
-        idx0[dst] = np.asarray(flat, dtype=np.int32)[src]
-    levels = [idx0]
-    widths = [w]
-
-    # out_map in concat space; level offsets accumulate as levels are added
+    levels, widths = [], []
     out_map = np.full(n_rows, -1, dtype=np.int64)
-    level_offset = 0
-    n_prev = int(nchunk.sum())  # chunks in the previous (current last) level
-    # rows' chunk spans start contiguously in the previous level's array
-    cur_counts = nchunk
-    cur_starts = np.zeros(n_rows + 1, dtype=np.int64)
-    np.cumsum(cur_counts, out=cur_starts[1:])
-
-    done = cur_counts == 1
-    out_map[done] = level_offset + cur_starts[:n_rows][done]
-
-    while int(cur_counts.max(initial=0)) > 1:
-        wu = w_upper
-        live = np.nonzero(cur_counts > 1)[0]
-        live_counts = cur_counts[live]
-        nxt_counts_live = -(-live_counts // wu)
-        tot = int(nxt_counts_live.sum()) * wu
-        idx = np.full(tot, n_prev, dtype=np.int32)  # pad → prev zero row
-        pad_starts = np.zeros(len(live) + 1, dtype=np.int64)
-        np.cumsum(nxt_counts_live * wu, out=pad_starts[1:])
-        reps = live_counts
-        dst = _segmented_ranges(pad_starts[:-1], reps)
-        src = _segmented_ranges(cur_starts[live], reps)
-        idx[dst] = src.astype(np.int32)
-        levels.append(idx)
-        widths.append(wu)
-
+    level_offset = n_prev = 0  # the newest level's section, and its chunks
+    rows = counts = starts = np.empty(0, np.int64)
+    for c, w in enumerate(classes):
+        members = np.flatnonzero(cls == c)  # CSR order: stable in a class
+        if not len(members):
+            continue
+        rows = members
+        counts = -(-deg[rows] // w)  # 1, but for the widest class's cut rows
+        starts = np.zeros(len(rows), dtype=np.int64)
+        np.cumsum(counts[:-1], out=starts[1:])
         level_offset += n_prev
-        n_prev = int(nxt_counts_live.sum())
-        cur_counts = np.zeros(n_rows, dtype=np.int64)
-        cur_counts[live] = nxt_counts_live
-        cur_starts = np.zeros(n_rows + 1, dtype=np.int64)
-        np.cumsum(cur_counts, out=cur_starts[1:])
-        done = cur_counts == 1
-        out_map[done] = level_offset + cur_starts[:n_rows][done]
+        n_prev = int(starts[-1] + counts[-1])
+        idx = np.full(n_prev * w, zero_row, dtype=np.int32)
+        idx[_segmented_ranges(starts * w, deg[rows])] = \
+            flat[_segmented_ranges(offsets[rows], deg[rows])]
+        levels.append(idx)
+        widths.append(int(w))
+        done = counts == 1
+        out_map[rows[done]] = level_offset + starts[done]
+    if not levels:  # no entry at all: one empty class keeps the shapes
+        levels, widths = [np.empty(0, np.int32)], [w_max]
+    n_lvl0 = len(levels)
+
+    # the upper pyramid, over the cut rows alone: their chunk spans start
+    # contiguously (``starts``) in the previous level's array
+    live = counts > 1
+    rows, counts, starts = rows[live], counts[live], starts[live]
+    while len(rows):
+        nxt = -(-counts // w_upper)
+        nxt_starts = np.zeros(len(rows), dtype=np.int64)
+        np.cumsum(nxt[:-1], out=nxt_starts[1:])
+        n_nxt = int(nxt_starts[-1] + nxt[-1])
+        idx = np.full(n_nxt * w_upper, n_prev,
+                      dtype=np.int32)  # pad → prev zero row
+        idx[_segmented_ranges(nxt_starts * w_upper, counts)] = \
+            _segmented_ranges(starts, counts).astype(np.int32)
+        levels.append(idx)
+        widths.append(w_upper)
+        level_offset += n_prev
+        n_prev = n_nxt
+        done = nxt == 1
+        out_map[rows[done]] = level_offset + nxt_starts[done]
+        rows, counts, starts = rows[~done], nxt[~done], nxt_starts[~done]
 
     concat_size = level_offset + n_prev
     out_map = np.where(out_map >= 0, out_map, concat_size)
     return ReducePlan(
-        tuple(levels), tuple(widths), out_map.astype(np.int32),
+        tuple(levels), tuple(widths), n_lvl0, out_map.astype(np.int32),
         n_rows, concat_size,
     )
 
@@ -266,6 +314,7 @@ def _apply_plan(
     values: jax.Array,            # (S, Kw) uint32 — level-0 value rows
     levels: Sequence[jax.Array],
     widths: Sequence[int],
+    n_lvl0: int,
     chunk: int,
     use_pallas: bool,
     scopes: tuple[str, str],
@@ -276,8 +325,8 @@ def _apply_plan(
     point into.
 
     The concat buffer is allocated ONCE and level outputs are written into
-    their sections by dynamic-update-slice — level 0 in ``chunk``-row
-    blocks through a scan whose carry IS the buffer (XLA aliases scan
+    their sections by dynamic-update-slice — level 0 class by class, each
+    in blocks through a scan whose carry IS the buffer (XLA aliases scan
     carries in place). The old parts-then-concatenate shape held the
     dominant level-0 output alive twice, which at 10M atoms × 4096 seeds
     (512-byte rows) was the difference between ~13 GB peak and
@@ -293,11 +342,32 @@ def _apply_plan(
     lvl0_scope, upper_scope = map(jax.named_scope, scopes)
     with lvl0_scope:
         buf = jnp.zeros((total, Kw), dtype=values.dtype)
-        buf = _reduce_into(buf, 0, values, levels[0], widths[0], chunk,
-                           use_pallas)
+        buf = _reduce_classes(buf, values, levels[:n_lvl0], widths[:n_lvl0],
+                              chunk, use_pallas)
+    if n_lvl0 == len(levels):  # no row above the widest class
+        return buf
     with upper_scope:
-        return _upper_levels(buf, levels[1:], widths[1:], sizes, sizes[0],
-                             chunk)
+        return _upper_levels(buf, levels[n_lvl0:], widths[n_lvl0:],
+                             sizes[n_lvl0 - 1:], sum(sizes[:n_lvl0]), chunk)
+
+
+def _reduce_classes(buf, values, levels, widths, chunk, use_pallas):
+    """Level 0: every width class of ``values`` rows into its section of
+    ``buf``, in order from row 0. A scan step moves ``chunk * STEP_WIDTH``
+    indices whatever the class — ``chunk`` output rows at width 8, fewer
+    wider ones, more narrower ones — so the XLA path's gather transient
+    (``chunk * STEP_WIDTH`` rows) does not follow the width; on the kernel
+    the whole segments of that (a width that does not divide its segment
+    would pad every step otherwise)."""
+    off = 0
+    for idx, w in zip(levels, widths):
+        step = chunk * STEP_WIDTH
+        if use_pallas and _pg.declined(w, values.shape[1]) is None:
+            step = _pg.whole_segments(step, w)
+        buf = _reduce_into(buf, off, values, idx, w, max(1, step // w),
+                           use_pallas)
+        off += idx.shape[0] // w
+    return buf
 
 
 def _upper_levels(
@@ -309,14 +379,16 @@ def _upper_levels(
     chunk: int,
 ) -> jax.Array:
     """Run the upper levels of a pyramid over a concat buffer whose level-0
-    section is already in place. ``sizes`` lists EVERY level's chunk count
-    (level 0 first); ``off`` is the first upper section's offset; the
+    sections are already in place. ``sizes`` lists the chunk counts of
+    level 0's LAST class (the one the cut rows' chunks lie in) and of
+    every upper level; ``off`` is the first upper section's offset; the
     global zero row sits at ``buf.shape[0] - 1``. Prev-level-local indices
     are rebased into buffer space on device (pad marker ``len(prev)`` →
-    the global zero row). Upper levels stay on the XLA gather, where they
-    are 29% of the untyped 10M-atom traversal (``traverse_dev_s.upper``
-    2.18 s, PERF.md section 5); whether ``hg_gather_or`` would serve them
-    better is not measured."""
+    the global zero row). Upper levels stay on the XLA gather: since level
+    0 has width classes they hold the rows above ``W_MAX`` alone — 0.16M
+    indices a hop in the untyped 10M-atom cell, none in its stage 1, where
+    one width-8 level 0 left them 30.9M and 29% of the traversal
+    (``traverse_dev_s.upper`` 2.18 s; PERF.md section 6, PR 30)."""
     total = buf.shape[0]
     for i, (idx, w) in enumerate(zip(levels, widths)):
         n_prev = sizes[i]
@@ -395,8 +467,9 @@ class PullBFSPlans:
     n_atoms: int
     n_pad: int
     stage1: ReducePlan  # tgt relation: link rows ← atom value rows
-    stage2_levels: tuple[np.ndarray, ...]  # level0 composed into stage1 chunks
+    stage2_levels: tuple[np.ndarray, ...]  # level 0 composed into stage1 chunks
     stage2_widths: tuple[int, ...]
+    stage2_n_lvl0: int  # leading stage-2 levels that are level-0 classes
     out_map: np.ndarray
     inc_deg: np.ndarray  # (N_pad,) int32 — incidence degree (edge counting)
 
@@ -408,10 +481,15 @@ class PullBFSPlans:
             + len(self.out_map)
         )
 
+    @property
+    def upper_indices(self) -> int:
+        """Indices of both stages' upper pyramid levels: what a hop still
+        gathers for rows that did not finish where they were gathered."""
+        return self.stage1.upper_indices + int(
+            sum(len(l) for l in self.stage2_levels[self.stage2_n_lvl0:]))
 
-def build_pull_plans(
-    snap: CSRSnapshot, w1: int = 8, w2: int = 8, w_upper: int = 8
-) -> PullBFSPlans:
+
+def build_pull_plans(snap: CSRSnapshot, w_upper: int = 8) -> PullBFSPlans:
     N = snap.num_atoms
     n_pad = _ceil_to(N + 1, 8)
     e_tgt = snap.n_edges_tgt
@@ -419,7 +497,7 @@ def build_pull_plans(
     # stage 1: link_live = OR of F over target rows (tgt CSR, rows=atoms)
     s1 = build_reduce_plan(
         snap.tgt_offsets[: N + 2], snap.tgt_flat[:e_tgt], N + 1,
-        zero_row=N, w=w1, w_upper=w_upper,
+        zero_row=N, w_upper=w_upper,
     )
     # stage 2 runs over the incidence CSR; its level-0 entries are LINK ids.
     # Compose them through stage-1's concat-space out_map on host, so the
@@ -427,12 +505,12 @@ def build_pull_plans(
     # is ever materialized.
     s2 = build_reduce_plan(
         snap.inc_offsets[: N + 2], snap.inc_links[:e_inc], N + 1,
-        zero_row=N, w=w2, w_upper=w_upper,
+        zero_row=N, w_upper=w_upper,
     )
     # level-0 padding used zero_row=N (an atom id); atom N has no targets →
     # its out_map entry is stage-1's zero row. Non-link atoms likewise.
-    lvl0 = s1.out_map[s2.levels[0]]
-    s2_levels = (lvl0,) + s2.levels[1:]
+    s2_levels = tuple(s1.out_map[lvl] for lvl in s2.levels[: s2.n_lvl0]) \
+        + s2.levels[s2.n_lvl0:]
 
     out_map = np.full(n_pad, s2.concat_size, dtype=np.int32)
     out_map[: N + 1] = s2.out_map
@@ -449,12 +527,14 @@ def build_pull_plans(
         stage1=s1,
         stage2_levels=s2_levels,
         stage2_widths=s2.widths,
+        stage2_n_lvl0=s2.n_lvl0,
         out_map=out_map,
         inc_deg=inc_deg,
     )
 
 
-PLAN_FORMAT = 1
+# 2: level 0 in width classes (``n_lvl0``); a format-1 sidecar is stale
+PLAN_FORMAT = 2
 
 
 class StalePlans(ValueError):
@@ -480,10 +560,12 @@ def save_plans(plans: PullBFSPlans, path, fingerprint: str = "") -> None:
         "n_atoms": np.int64(plans.n_atoms),
         "n_pad": np.int64(plans.n_pad),
         "s1_widths": np.asarray(plans.stage1.widths, np.int64),
+        "s1_n_lvl0": np.int64(plans.stage1.n_lvl0),
         "s1_out_map": plans.stage1.out_map,
         "s1_n_rows": np.int64(plans.stage1.n_rows),
         "s1_concat": np.int64(plans.stage1.concat_size),
         "s2_widths": np.asarray(plans.stage2_widths, np.int64),
+        "s2_n_lvl0": np.int64(plans.stage2_n_lvl0),
         "out_map": plans.out_map,
         "inc_deg": plans.inc_deg,
     }
@@ -524,7 +606,8 @@ def load_plans(path: str,
         )
         s1 = ReducePlan(
             s1_levels, tuple(int(w) for w in z["s1_widths"]),
-            z["s1_out_map"], int(z["s1_n_rows"]), int(z["s1_concat"]),
+            int(z["s1_n_lvl0"]), z["s1_out_map"], int(z["s1_n_rows"]),
+            int(z["s1_concat"]),
         )
         return PullBFSPlans(
             n_atoms=int(z["n_atoms"]),
@@ -532,6 +615,7 @@ def load_plans(path: str,
             stage1=s1,
             stage2_levels=s2_levels,
             stage2_widths=tuple(int(w) for w in z["s2_widths"]),
+            stage2_n_lvl0=int(z["s2_n_lvl0"]),
             out_map=z["out_map"],
             inc_deg=z["inc_deg"],
         )
@@ -567,6 +651,9 @@ def plans_for(snap: CSRSnapshot) -> PullBFSPlans:
         # is the padding a hop's gathers pay for every entry
         reg = default_registry()
         reg.gauge("bfs.plan.total_indices").set(plans.total_indices)
+        # of which the upper pyramid levels': rows that did not finish in
+        # the chunk they were gathered in
+        reg.gauge("bfs.plan.upper_indices").set(plans.upper_indices)
         reg.gauge("bfs.plan.entries").set(snap.n_edges_inc
                                           + snap.n_edges_tgt)
     return plans
@@ -684,10 +771,12 @@ def _program(module: str, scope: Optional[str] = None):
 
 # The hop runs as FOUR host-sequenced jits instead of one scan. At 10M
 # atoms × 4096 seeds the hop's working set (visited 5.1 GB + stage-1
-# buffer 5.9 GB + stage-2 buffer 4.6 GB) only fits the 16 GiB HBM when
-# buffers are freed/reused the moment they are dead — a lax.scan keeps the
-# carry double-buffered and every intermediate alive for the compiler's
-# conservative lifetime, which measured 21 GB of temps (ResourceExhausted).
+# buffer 4.1 GB + stage-2 buffer 1.1 GB; 5.9 and 4.6 GB before level 0
+# had width classes, which is when this was measured) only fits the 16 GiB
+# HBM when buffers are freed/reused the moment they are dead — a lax.scan
+# keeps the carry double-buffered and every intermediate alive for the
+# compiler's conservative lifetime, which measured 21 GB of temps
+# (ResourceExhausted).
 # Host sequencing + donate_argnums makes each free explicit; dispatch cost
 # is a few RTTs per hop, noise against multi-second hops.
 #
@@ -732,44 +821,48 @@ def _deg_sum(visited: jax.Array, inc_deg: jax.Array) -> jax.Array:
 
 @hgverify.entry(
     shapes=lambda: (hgverify.sds((64, 1), "uint32"),
-                    (hgverify.sds((64,), "int32"),)),
-    statics={"widths": (8,), "chunk": 1 << 19, "use_pallas": False},
+                    (hgverify.sds((32,), "int32"),
+                     hgverify.sds((64,), "int32"))),
+    statics={"widths": (2, 8), "n_lvl0": 2, "chunk": 1 << 19,
+             "use_pallas": False},
 )
-@partial(jax.jit, static_argnames=("widths", "chunk", "use_pallas"))
+@partial(jax.jit,
+         static_argnames=("widths", "n_lvl0", "chunk", "use_pallas"))
 @_program("hg_bfs_stage1")
-def _stage(values, levels, widths, chunk, use_pallas):
-    return _apply_plan(values, levels, widths, chunk, use_pallas,
+def _stage(values, levels, widths, n_lvl0, chunk, use_pallas):
+    return _apply_plan(values, levels, widths, n_lvl0, chunk, use_pallas,
                        scopes=("hg.bfs.stage1.lvl0", "hg.bfs.stage1.upper"))
 
 
-@partial(jax.jit, static_argnames=("w", "chunk", "use_pallas"))
+@partial(jax.jit, static_argnames=("widths", "chunk", "use_pallas"))
 @_program("hg_bfs_stage2_lvl0", "hg.bfs.stage2.lvl0")
-def _stage_lvl0_consume(values, idx, w, chunk, use_pallas):
-    """Level-0 chunks only, into an exact-size buffer. ``values`` (the
-    previous stage's buffer, ~5.9 GB at benchmark scale) is genuinely dead
-    once this jit returns; the caller drops its ref and syncs — splitting
-    stage 2 here is what lets that buffer free before the full concat
-    buffer allocates. (No donate: the shapes can never alias, donation
-    would only warn.)"""
-    n0 = idx.shape[0] // w
+def _stage_lvl0_consume(values, levels, widths, chunk, use_pallas):
+    """Level-0 chunks only (every width class), into an exact-size buffer.
+    ``values`` (the previous stage's buffer, ~4.1 GB at benchmark scale)
+    is genuinely dead once this jit returns; the caller drops its ref and
+    syncs — splitting stage 2 here is what lets that buffer free before
+    the full concat buffer allocates. (No donate: the shapes can never
+    alias, donation would only warn.)"""
+    n0 = sum(idx.shape[0] // w for idx, w in zip(levels, widths))
     buf = jnp.zeros((n0, values.shape[1]), dtype=values.dtype)
-    return _reduce_into(buf, 0, values, idx, w, chunk, use_pallas)
+    return _reduce_classes(buf, values, levels, widths, chunk, use_pallas)
 
 
-@partial(jax.jit, static_argnames=("widths", "chunk"))
+@partial(jax.jit, static_argnames=("widths", "n_last", "chunk"))
 @_program("hg_bfs_stage2_upper", "hg.bfs.stage2.upper")
-def _stage_upper(lvl0, levels, widths, chunk):
+def _stage_upper(lvl0, levels, widths, n_last, chunk):
     """Assemble the stage's concat buffer from the level-0 chunks, then
-    run the (small) upper levels on the XLA gather path. ``widths``
-    includes the level-0 width at [0]; ``levels`` holds only the upper
-    index arrays."""
+    run the (small) upper levels on the XLA gather path. ``levels`` and
+    ``widths`` are the upper levels' alone (none where no row is above
+    ``W_MAX``: the buffer is then level 0 and the zero row); ``n_last``
+    is the chunk count of level 0's last class, whose section the first
+    upper level reads."""
     n0, Kw = lvl0.shape
-    sizes = [n0] + [lvl.shape[0] // w
-                    for lvl, w in zip(levels, widths[1:])]
-    total = sum(sizes) + 1
+    sizes = [n_last] + [lvl.shape[0] // w for lvl, w in zip(levels, widths)]
+    total = n0 + sum(sizes[1:]) + 1
     buf = jnp.zeros((total, Kw), dtype=lvl0.dtype)
     buf = jax.lax.dynamic_update_slice(buf, lvl0, (0, 0))
-    return _upper_levels(buf, levels, widths[1:], sizes, n0, chunk)
+    return _upper_levels(buf, levels, widths, sizes, n0, chunk)
 
 
 @hgverify.entry(
@@ -921,8 +1014,10 @@ def _bfs_pull_device(
     count_edges: bool = True,
     use_pallas: bool = False,
 ) -> tuple[jax.Array, list, jax.Array]:
-    levels1, widths1 = dev["levels1"], plans.stage1.widths
-    levels2, widths2 = dev["levels2"], plans.stage2_widths
+    s1 = plans.stage1
+    levels1, levels2 = dev["levels1"], dev["levels2"]
+    widths2, n2 = plans.stage2_widths, plans.stage2_n_lvl0
+    n2_last = len(plans.stage2_levels[n2 - 1]) // widths2[n2 - 1]
     n_atoms = jnp.int32(plans.n_atoms)
     with phase("hg.bfs.seeds_upload"):
         seeds_dev = jnp.asarray(seeds)
@@ -954,21 +1049,23 @@ def _bfs_pull_device(
                 s_ins.append(_deg_sum(visited, dev["inc_deg"]))
                 jax.block_until_ready(s_ins[-1])
         with phase("hg.bfs.hop.stage1"):
-            live = _stage(visited, levels1, widths1, chunk, use_pallas)
+            live = _stage(visited, levels1, s1.widths, s1.n_lvl0, chunk,
+                          use_pallas)
             jax.block_until_ready(live)
         with phase("hg.bfs.hop.stage2_lvl0"):
-            lvl0b = _stage_lvl0_consume(live, levels2[0], widths2[0], chunk,
-                                        use_pallas)
+            lvl0b = _stage_lvl0_consume(live, levels2[:n2], widths2[:n2],
+                                        chunk, use_pallas)
             # the donations can't alias (shapes differ), so the host ref
             # is what keeps each dead buffer resident — drop it AND sync
             # before the next dispatch: async dispatch would let the
             # allocator grab stage-upper's buffers while the consume step
-            # (and therefore `live`'s 5.9 GB) is still in flight. The sync
+            # (and therefore `live`'s 4.1 GB) is still in flight. The sync
             # costs one RTT per hop against multi-second hops.
             del live
             jax.block_until_ready(lvl0b)
         with phase("hg.bfs.hop.stage2_upper_update"):
-            reach_chunks = _stage_upper(lvl0b, levels2[1:], widths2, chunk)
+            reach_chunks = _stage_upper(lvl0b, levels2[n2:], widths2[n2:],
+                                        n2_last, chunk)
             del lvl0b
             visited = _visited_update(visited, reach_chunks, dev["out_map"],
                                       n_atoms)

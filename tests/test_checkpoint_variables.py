@@ -162,6 +162,16 @@ def test_plans_persist_with_snapshot(tmp_path, graph):
     for x, y in zip(p0.stage1.levels, p1.stage1.levels):
         assert np.array_equal(x, y)
     assert np.array_equal(p0.out_map, p1.out_map)
+    # the classes too: which levels are level 0, at which widths
+    assert p0.stage1.n_lvl0 == p1.stage1.n_lvl0 > 1
+    assert p0.stage2_n_lvl0 == p1.stage2_n_lvl0 > 1
+    assert p0.stage1.widths == p1.stage1.widths
+    assert len(p0.stage2_levels) == len(p1.stage2_levels)
+    for x, y in zip(p0.stage2_levels, p1.stage2_levels):
+        assert np.array_equal(x, y)
+    assert np.array_equal(p0.stage1.out_map, p1.stage1.out_map)
+    assert (p0.total_indices, p0.upper_indices) == \
+        (p1.total_indices, p1.upper_indices)
 
 
 # ------------------------------------------- crash-atomic saves (hgfault)
@@ -293,6 +303,44 @@ def test_stale_sidecar_rebuilds_quietly_corrupt_sidecar_counts(
     assert back.num_atoms == snap_b.num_atoms
     assert getattr(back, "_pull_plans", None) is None
     assert c.value == before + 1
+
+
+@pytest.mark.parametrize("sidecar_format", [1, 3])
+def test_sidecar_of_another_plan_format_rebuilds_quietly(
+        graph, tmp_path, faults, sidecar_format):
+    """A sidecar written before level 0 had width classes (format 1: one
+    width-8 array a stage, no ``n_lvl0``), or by a later layout, beside a
+    checkpoint: ``StalePlans`` — no plan attached, ``plans_for`` rebuilds,
+    ``fault.sidecar_corrupt`` untouched — whatever its arrays hold."""
+    import numpy as np
+
+    from hypergraphdb_tpu.ops import ellbfs as E
+    from hypergraphdb_tpu.ops.checkpoint import _plans_path
+    from hypergraphdb_tpu.utils.metrics import global_metrics
+
+    assert E.PLAN_FORMAT == 2
+    snap, _ = _two_snapshots(graph)
+    path = str(tmp_path / "a.npz")
+    save_snapshot(snap, path, with_plans=True)
+    with np.load(_plans_path(path)) as z:
+        arrs = {k: z[k] for k in z.files}
+    arrs["format"] = np.int64(sidecar_format)
+    if sidecar_format == 1:  # as PR 29's save_plans wrote it
+        for k in ("s1_n_lvl0", "s2_n_lvl0"):
+            del arrs[k]
+    with open(_plans_path(path), "wb") as f:
+        np.savez(f, **arrs)
+    with pytest.raises(E.StalePlans, match="format"):
+        E.load_plans(_plans_path(path))
+
+    c = global_metrics.registry.counter("fault.sidecar_corrupt")
+    before = c.value
+    back = load_snapshot(path)
+    assert getattr(back, "_pull_plans", None) is None
+    assert c.value == before
+    rebuilt = E.plans_for(back)
+    assert rebuilt.stage1.n_lvl0 == E.plans_for(snap).stage1.n_lvl0
+    assert np.array_equal(rebuilt.out_map, E.plans_for(snap).out_map)
 
 
 def test_plan_cache_env_roundtrip(tmp_path, graph, monkeypatch):

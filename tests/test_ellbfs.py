@@ -17,10 +17,13 @@ from hypergraphdb_tpu.ops.ellbfs import (
 )
 
 
-def random_snapshot(n_nodes, n_links, max_arity, seed, zipf=False):
+def random_snapshot(n_nodes, n_links, max_arity, seed, zipf=False,
+                    n_types=0):
     r = np.random.default_rng(seed)
     N = n_nodes + n_links
     type_of = np.zeros(N, dtype=np.int32)
+    if n_types:  # link types 1..n_types (atoms 1.. stand in as type atoms)
+        type_of[n_nodes:] = 1 + r.integers(0, n_types, size=n_links)
     is_link = np.zeros(N, dtype=bool)
     is_link[n_nodes:] = True
     arities = r.integers(2, max_arity + 1, size=n_links)
@@ -33,8 +36,9 @@ def random_snapshot(n_nodes, n_links, max_arity, seed, zipf=False):
     return CSRSnapshot.from_tables(type_of, is_link, offsets, flat)
 
 
-def host_bfs(snap, seed_atom, hops):
-    """Reference semantics: atom → incident links → targets."""
+def host_bfs(snap, seed_atom, hops, family=None):
+    """Reference semantics: atom → incident links → targets; under a
+    ``family`` of link types, the links of those types alone."""
     visited = {int(seed_atom)}
     frontier = {int(seed_atom)}
     edges = 0
@@ -42,6 +46,8 @@ def host_bfs(snap, seed_atom, hops):
         nxt = set()
         for a in frontier:
             row = snap.incidence_row(a)
+            if family is not None:
+                row = row[np.isin(snap.type_of[row], list(family))]
             edges += len(row)
             for l in row.tolist():
                 for t in snap.targets_row(int(l)).tolist():
@@ -153,13 +159,14 @@ def test_k_block_validation():
 def test_reduce_plan_shapes():
     offsets = np.asarray([0, 0, 3, 3, 20])  # empty, 3-row, empty, 17-row
     flat = np.arange(20, dtype=np.int64) % 7
-    plan = build_reduce_plan(offsets, flat, 4, zero_row=7, w=4, w_upper=4)
+    plan = build_reduce_plan(offsets, flat, 4, zero_row=7, classes=(4,),
+                             w_upper=4)
     # empty rows address the global zero row at concat_size
     assert plan.out_map[0] == plan.concat_size
     assert plan.out_map[2] == plan.concat_size
     assert all(len(l) % w == 0 for l, w in zip(plan.levels, plan.widths))
     # row 3 has 17 entries → 5 chunks at w=4 → needs 2 levels above level 0
-    assert len(plan.levels) >= 3
+    assert plan.n_lvl0 == 1 and len(plan.levels) >= 3
 
 
 def test_plans_cached():
@@ -196,42 +203,180 @@ def _stage_rows(rows, r):
     return deg, 2
 
 
+def _segment_or(values, offsets, flat, deg):
+    want = np.zeros((len(deg), values.shape[1]), np.uint32)
+    nz = np.flatnonzero(deg)
+    want[nz] = np.bitwise_or.reduceat(values[flat], offsets[nz], axis=0)
+    return want
+
+
+def _run_stages(plan, values, chunk):
+    """The plan through ``_stage`` (one program) and through
+    ``_stage_lvl0_consume`` + ``_stage_upper`` (two): the same buffer."""
+    n = plan.n_lvl0
+    levels = tuple(np.asarray(l) for l in plan.levels)
+    whole = np.asarray(eb._stage(values, levels, plan.widths, n, chunk,
+                                 False))
+    assert whole.shape == (plan.concat_size + 1, values.shape[1])
+    assert not whole[plan.concat_size].any()  # the global zero row
+    sizes = [len(l) // w for l, w in zip(plan.levels, plan.widths)]
+    lvl0 = eb._stage_lvl0_consume(values, levels[:n], plan.widths[:n],
+                                  chunk, False)
+    assert lvl0.shape == (sum(sizes[:n]), values.shape[1])
+    split = np.asarray(eb._stage_upper(lvl0, levels[n:], plan.widths[n:],
+                                       sizes[n - 1], chunk))
+    assert np.array_equal(split, whole)
+    return whole
+
+
 @pytest.mark.parametrize("kw", [1, 4])
 @pytest.mark.parametrize("chunk", ["steps", "whole"])
+@pytest.mark.parametrize("classes", [(STAGE_W,), (2, STAGE_W)],
+                         ids=["one_width", "classed"])
 @pytest.mark.parametrize("rows", ["one_row", "short", "hub", "ragged"])
-def test_stage_matches_numpy_segmented_or(rows, chunk, kw):
+def test_stage_matches_numpy_segmented_or(rows, classes, chunk, kw):
     """``chunk`` smaller than level 0 (the scan takes several steps, and
-    in the ragged case leaves a tail) and larger than it (one update)."""
+    in the ragged case leaves a tail) and larger than it (one update);
+    level 0 as one width-4 array (the plan before it had classes) and as
+    classes 2 and 4."""
     r = np.random.default_rng([len(rows), kw])
     deg, n_levels = _stage_rows(rows, r)
     n_rows, S = len(deg), 40
     offsets = np.concatenate([[0], np.cumsum(deg)])
     flat = r.integers(0, S, size=int(offsets[-1]))
     plan = build_reduce_plan(offsets, flat, n_rows, zero_row=S,
-                             w=STAGE_W, w_upper=STAGE_W)
-    n0 = len(plan.levels[0]) // STAGE_W
-    chunk = 2 if chunk == "steps" else 1 << 10
-    assert (n0 > 2 * chunk) if chunk == 2 else (n0 < chunk)
-    assert len(plan.levels) == n_levels
+                             classes=classes, w_upper=STAGE_W)
+    n0 = len(plan.levels[plan.n_lvl0 - 1]) // STAGE_W
+    # a level-0 step moves chunk * 8 indices: 2 rows of width 4 at chunk 1
+    chunk = 1 if chunk == "steps" else 1 << 10
+    assert (n0 > 4) if chunk == 1 else (n0 < chunk)
+    assert len(plan.levels) - plan.n_lvl0 == n_levels - 1
+    held = np.minimum(np.searchsorted(classes, deg[deg > 0]),
+                      len(classes) - 1)  # a class no row falls in has none
+    assert plan.widths[: plan.n_lvl0] == tuple(
+        classes[c] for c in np.unique(held))
     if rows == "ragged":
         assert n0 % 2 and (deg % STAGE_W).any()
 
     values = r.integers(0, 1 << 32, size=(S + 1, kw), dtype=np.uint32)
     values[S] = 0  # the zero row level 0 pads with
-    want = np.zeros((n_rows, kw), np.uint32)
-    nz = np.flatnonzero(deg)
-    want[nz] = np.bitwise_or.reduceat(values[flat], offsets[nz], axis=0)
+    whole = _run_stages(plan, values, chunk)
+    assert np.array_equal(whole[plan.out_map],
+                          _segment_or(values, offsets, flat, deg))
 
-    levels = tuple(np.asarray(l) for l in plan.levels)
-    whole = np.asarray(eb._stage(values, levels, plan.widths, chunk, False))
-    assert whole.shape == (plan.concat_size + 1, kw)
-    assert not whole[plan.concat_size].any()  # the global zero row
-    assert np.array_equal(whole[plan.out_map], want)
 
-    lvl0 = eb._stage_lvl0_consume(values, levels[0], STAGE_W, chunk, False)
-    assert lvl0.shape == (n0, kw)
-    split = np.asarray(eb._stage_upper(lvl0, levels[1:], plan.widths, chunk))
-    assert np.array_equal(split, whole)
+# Level 0 in the module's own width classes: rows of every degree from
+# empty to past three chunks of the widest class, and a hub above W_MAX².
+
+def _classed_case(r, top_degree):
+    deg = np.arange(0, top_degree + 1)
+    r.shuffle(deg)
+    S = 300
+    offsets = np.concatenate([[0], np.cumsum(deg)])
+    flat = r.integers(0, S, size=int(offsets[-1]))
+    values = r.integers(0, 1 << 32, size=(S + 1, 2), dtype=np.uint32)
+    values[S] = 0
+    plan = build_reduce_plan(offsets, flat, len(deg), zero_row=S)
+    return deg, offsets, flat, values, plan
+
+
+@pytest.mark.parametrize("chunk", [16, 1 << 12])
+@pytest.mark.parametrize("rows", ["every_degree", "with_hub"])
+def test_classed_plan_matches_numpy_segmented_or(rows, chunk):
+    """Every row of degree at most ``W_MAX`` is one chunk of the smallest
+    class width that holds it, finished in that class's section; a row
+    above is cut at ``W_MAX`` and finishes in an upper level; an empty row
+    maps to the zero row; and the reduction is numpy's segmented OR."""
+    r = np.random.default_rng(len(rows))
+    deg, offsets, flat, values, plan = _classed_case(r, 3 * eb.W_MAX + 1)
+    if rows == "with_hub":
+        deg = np.concatenate([deg, [eb.W_MAX ** 2 + 5]])
+        offsets = np.concatenate([[0], np.cumsum(deg)])
+        flat = r.integers(0, 300, size=int(offsets[-1]))
+        plan = build_reduce_plan(offsets, flat, len(deg), zero_row=300)
+    assert plan.widths[: plan.n_lvl0] == eb.CLASS_WIDTHS
+    assert all(len(l) % w == 0 for l, w in zip(plan.levels, plan.widths))
+    sizes = [len(l) // w for l, w in zip(plan.levels, plan.widths)]
+    ends = np.cumsum(sizes)
+    assert plan.concat_size == ends[-1]
+    # rows above W_MAX: 2-4 chunks each → one upper level of 8; the hub's
+    # W_MAX + 1 chunks climb until one is left
+    n_upper, chunks = 0, -(-int(deg.max()) // eb.W_MAX)
+    while chunks > 1:
+        n_upper, chunks = n_upper + 1, -(-chunks // 8)
+    assert len(plan.levels) - plan.n_lvl0 == n_upper >= (
+        2 if rows == "with_hub" else 1)
+    for c, w in enumerate(eb.CLASS_WIDTHS):
+        lo = eb.CLASS_WIDTHS[c - 1] if c else 0
+        mine = (deg > lo) & (deg <= w)
+        assert mine.any()
+        assert ((plan.out_map[mine] >= ends[c] - sizes[c])
+                & (plan.out_map[mine] < ends[c])).all()
+        assert len(np.unique(plan.out_map[mine])) == mine.sum()
+    above = deg > eb.W_MAX
+    assert ((plan.out_map[above] >= ends[plan.n_lvl0 - 1])
+            & (plan.out_map[above] < plan.concat_size)).all()
+    assert (plan.out_map[deg == 0] == plan.concat_size).all()
+    assert plan.upper_indices == sum(
+        len(l) for l in plan.levels[plan.n_lvl0:]) > 0
+    # level 0 holds every entry once, the rest pads
+    real = sum(int(np.count_nonzero(l != 300))
+               for l in plan.levels[: plan.n_lvl0])
+    assert real == int(np.count_nonzero(flat != 300))
+
+    whole = _run_stages(plan, values, chunk)
+    assert np.array_equal(whole[plan.out_map],
+                          _segment_or(values, offsets, flat, deg))
+
+
+@pytest.mark.parametrize("top_degree", [1, 7, eb.W_MAX])
+def test_no_row_above_w_max_no_upper_level(top_degree):
+    """A relation whose rows all fit a class runs no upper level: the
+    plan has none, ``_stage`` traces none, and the buffer is level 0 and
+    the zero row."""
+    r = np.random.default_rng(top_degree)
+    deg, offsets, flat, values, plan = _classed_case(r, top_degree)
+    assert plan.n_lvl0 == len(plan.levels) and plan.upper_indices == 0
+    assert plan.widths == tuple(
+        w for lo, w in zip((0,) + eb.CLASS_WIDTHS, eb.CLASS_WIDTHS)
+        if lo < top_degree)
+    assert plan.concat_size == np.count_nonzero(deg)
+    text = eb._stage.lower(
+        values, tuple(np.asarray(l) for l in plan.levels), plan.widths,
+        plan.n_lvl0, 16, False).as_text(debug_info=True)
+    assert "hg.bfs.stage1.lvl0" in text
+    assert "hg.bfs.stage1.upper" not in text
+    whole = _run_stages(plan, values, 16)
+    assert np.array_equal(whole[plan.out_map],
+                          _segment_or(values, offsets, flat, deg))
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("chunk", [8, 1 << 6, 1 << 16])
+def test_a_scan_step_moves_the_same_indices_at_every_class(
+        chunk, use_pallas, monkeypatch):
+    """``chunk * STEP_WIDTH`` indices a step whatever the class width (less
+    than one chunk short where the width does not divide it), so the XLA
+    gather's transient does not follow the class; on the kernel, the whole
+    segments of that, so that no step pads a segment."""
+    steps = []
+    monkeypatch.setattr(
+        eb, "_reduce_into",
+        lambda buf, off, values, idx, w, rows, use_pallas:
+        steps.append((off, w, rows * w)) or buf)
+    levels = tuple(np.zeros(w * (3 + c), np.int32)
+                   for c, w in enumerate(eb.CLASS_WIDTHS))
+    eb._reduce_classes(None, np.zeros((1, 128), np.uint32), levels,
+                       eb.CLASS_WIDTHS, chunk, use_pallas)
+    assert [w for _, w, _ in steps] == list(eb.CLASS_WIDTHS)
+    assert [off for off, _, _ in steps] == [
+        sum(range(3, 3 + c)) for c in range(len(steps))]
+    whole = chunk * eb.STEP_WIDTH
+    for _, w, n in steps:
+        if use_pallas:
+            assert n == eb._pg.whole_segments(whole, w) // w * w
+            assert n % eb._pg._seg(w) == 0 or n < eb._pg._seg(w)
+        assert whole - max(w, whole // 8) < n <= whole
 
 
 @pytest.mark.parametrize("case", ["no_fresh_bit", "every_row_fresh",
@@ -317,14 +462,17 @@ def test_sparse_first_hop_matches_host_and_dense_chain(zipf, hops,
     a duplicate, pad seeds (explicit and from K % 32), a seed nothing
     points at — over two seed blocks: the answers are the host BFS's and,
     bit for bit, the dense chain's."""
-    snap = random_snapshot(30000, 3000, 4, seed=31 + hops, zipf=zipf)
-    deg = np.diff(snap.inc_offsets[: 30001].astype(np.int64))
+    # enough atoms that the zipf hub's pairs stay under the rule's share of
+    # the plan (its out_map is an index an atom)
+    n = 80000 if zipf else 30000
+    snap = random_snapshot(n, 3000, 4, seed=31 + hops, zipf=zipf)
+    deg = np.diff(snap.inc_offsets[: n + 1].astype(np.int64))
     hub, lonely = int(np.argmax(deg)), int(np.argmin(deg))
     assert deg[lonely] == 0 and deg[hub] > (200 if zipf else 3)
     r = np.random.default_rng(hops)
     seeds = np.concatenate([
         [hub, lonely, 7, 7, snap.num_atoms],
-        r.integers(0, 30000, size=35),
+        r.integers(0, n, size=35),
     ]).astype(np.int32)  # 40 seeds: blocks of 32 and 8 (+ 24 pad columns)
     with _Sides() as ran:
         res = bfs_pull(snap, seeds, hops, k_block=32)
@@ -371,7 +519,7 @@ def test_sparse_first_hop_in_several_placement_blocks(count_edges,
     monkeypatch.setattr(
         eb, "_sparse_hop",
         lambda v, pairs, n: calls.append(pairs.shape) or placed(v, pairs, n))
-    snap = random_snapshot(30000, 3000, 4, seed=8, zipf=True)
+    snap = random_snapshot(80000, 3000, 4, seed=8, zipf=True)
     seeds = np.asarray([1, 40, 41, 42, 43, 44], np.int32)  # 1: the hub
     pairs = _first_hop_pairs(snap, seeds)
     with _Sides() as ran:
@@ -384,6 +532,68 @@ def test_sparse_first_hop_in_several_placement_blocks(count_edges,
         want, edges = host_bfs(snap, s, 1)
         assert set(rows[k].tolist()) == want
         assert res.edges_touched[k] == (edges if count_edges else 0)
+
+
+# ------------------------------------------------------ a hub above W_MAX²
+#
+# A zipf graph whose top entity has more than ``W_MAX ** 2`` incident links
+# (under the predicate too), so that stage 2 cuts its row at ``W_MAX`` and
+# climbs two upper levels while every other row finishes in its class: the
+# dense chain from the first hop, at a 64-seed block (the XLA gather) and
+# at a 4096-seed block with ``hg_gather_or`` run by the Pallas interpreter.
+
+
+@pytest.fixture(scope="module")
+def hub_graph():
+    snap = random_snapshot(20000, 14000, 4, seed=5, zipf=True, n_types=3)
+    deg = np.diff(snap.inc_offsets[:20001].astype(np.int64))
+    return snap, int(np.argmax(deg))
+
+
+@pytest.mark.parametrize("typed", [False, True])
+@pytest.mark.parametrize("k", [64, 4096])
+def test_hub_above_w_max_squared_matches_host(hub_graph, k, typed,
+                                              monkeypatch):
+    snap, hub = hub_graph
+    family = (1, 3) if typed else None
+    sub = eb.restricted_for(snap, family) if typed else snap
+    assert sub.inc_offsets[hub + 1] - sub.inc_offsets[hub] > eb.W_MAX ** 2
+    plans = plans_for(sub)
+    assert plans.stage1.n_lvl0 == len(plans.stage1.levels)  # arity <= 4
+    assert len(plans.stage2_levels) - plans.stage2_n_lvl0 >= 2
+    assert 0 < plans.upper_indices < plans.total_indices // 50
+    monkeypatch.setattr(eb, "SPARSE_SHARE", 1 << 62)  # the chain alone
+    r = np.random.default_rng(k)
+    seeds = r.integers(0, 20000, size=k).astype(np.int32)
+    seeds[3] = hub
+    res = bfs_pull(snap, seeds, 2, chunk=1 << 12, k_block=k,
+                   link_types=family)
+    if k == 4096:  # the same block again, through the kernel
+        calls, gather = [], eb._pg.gather_or
+        monkeypatch.setattr(eb._pg, "pallas_ok", lambda: True)
+        monkeypatch.setattr(
+            eb._pg, "gather_or",
+            lambda v, i, w: calls.append(w) or gather(v, i, w,
+                                                      interpret=True))
+        # the long classes alone: each width the interpreter runs is
+        # seconds of compile here
+        monkeypatch.setattr(eb._pg, "MIN_INDICES", 1 << 13)
+        kernel = bfs_pull(snap, seeds, 2, chunk=1 << 12, k_block=k,
+                          link_types=family)
+        assert set(calls) >= {4, eb.W_MAX}
+        assert np.array_equal(np.asarray(res.visited_t),
+                              np.asarray(kernel.visited_t))
+        assert np.array_equal(res.edges_touched, kernel.edges_touched)
+        assert np.array_equal(np.asarray(res.reach_counts),
+                              np.asarray(kernel.reach_counts))
+    cols = [3] + r.choice(np.arange(4, k), 7, replace=False).tolist()
+    vt = np.asarray(res.visited_t)[: snap.num_atoms]
+    for c in cols:
+        want, edges = host_bfs(snap, int(seeds[c]), 2, family)
+        got = np.flatnonzero((vt[:, c >> 5] >> np.uint32(c & 31)) & 1)
+        assert set(got.tolist()) == want, f"seed {seeds[c]} (column {c})"
+        assert res.edges_touched[c] == edges
+        assert int(res.reach_counts[c]) == len(want)
 
 
 # ------------------------------------------------------ the link predicate

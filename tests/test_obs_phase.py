@@ -179,7 +179,7 @@ def test_bfs_pull_leaves_every_phase_with_the_right_counts():
     snap = _small_snapshot()
     # few seeds: the rule takes the sparse side, the first hop is the
     # seeds' own neighbourhood and the pull chain runs hops 2..H
-    seeds = np.arange(40, dtype=np.int32)
+    seeds = np.arange(16, dtype=np.int32)
     hops = 3
     eb.bfs_pull(snap, seeds, hops)
     grew = {n: _hist(n)["count"] - before[n] for n in names}
@@ -238,14 +238,16 @@ STAGE_PROGRAMS = {
                     (_u32(64, 1), _i32(2, 16), _i32()), {}),
     "_stage": ("hg_bfs_stage1",
                ("hg.bfs.stage1.lvl0", "hg.bfs.stage1.upper"),
-               (_u32(64, 1), (_i32(128), _i32(16))),
-               {"widths": (8, 8), "chunk": 4, "use_pallas": False}),
+               (_u32(64, 1), (_i32(32), _i32(128), _i32(16))),
+               {"widths": (2, 8, 8), "n_lvl0": 2, "chunk": 4,
+                "use_pallas": False}),
     "_stage_lvl0_consume": ("hg_bfs_stage2_lvl0", ("hg.bfs.stage2.lvl0",),
-                            (_u32(64, 1), _i32(128)),
-                            {"w": 8, "chunk": 4, "use_pallas": False}),
+                            (_u32(64, 1), (_i32(32), _i32(128))),
+                            {"widths": (2, 8), "chunk": 4,
+                             "use_pallas": False}),
     "_stage_upper": ("hg_bfs_stage2_upper", ("hg.bfs.stage2.upper",),
-                     (_u32(16, 1), (_i32(16),)),
-                     {"widths": (8, 8), "chunk": 4}),
+                     (_u32(32, 1), (_i32(16),)),
+                     {"widths": (8,), "n_last": 16, "chunk": 4}),
     "_visited_update": ("hg_bfs_visited_update", ("hg.bfs.visited_update",),
                         (_u32(64, 1), _u32(9, 1), _i32(64), _i32()), {}),
     "_reach_counts": ("hg_bfs_reach_counts", ("hg.bfs.reach_counts",),

@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 
 import chip_smoke
 from hypergraphdb_tpu import verify as hgverify
+from hypergraphdb_tpu.ops.ellbfs import CLASS_WIDTHS
 
 #: chip_smoke.py's sizes, read from it: the serve phase's padded id space
 #: and edge count (1M entities + 2M binary links through the store: every
@@ -111,12 +112,13 @@ def _rescaled(entry_name: str, dims: dict):
 # test, after the fixture described the topology.
 
 
-def _case_gather_or(place, kw=128):
+def _case_gather_or(place, kw=128, w=8):
     from hypergraphdb_tpu.ops import pallas_gather as pg
 
-    fn = jax.jit(partial(pg.gather_or, w=8))
+    fn = jax.jit(partial(pg.gather_or, w=w))
+    # one whole segment of indices at the width (2^17 at a power of two)
     return fn, place((_sds((1 << 20, kw), "uint32"),
-                      _sds((1 << 17,), "int32"))), {}
+                      _sds((pg._seg(w),), "int32"))), {}
 
 
 def _case_membership(place):
@@ -232,6 +234,20 @@ def test_wide_rows_gate_agrees_with_compiler(one_chip, no_compile_cache):
     assert pg.declined(8, 128) is None and pg.declined(8, 256)
 
 
+@pytest.mark.parametrize("w", CLASS_WIDTHS)
+def test_every_class_width_compiles_for_v5e(w, one_chip, no_compile_cache):
+    """Each level-0 class width of the pull plan (``ellbfs.CLASS_WIDTHS``,
+    held at import to what the gate admits) is a kernel the v5e compiler
+    accepts at 128-word rows, with the slots that width holds in flight."""
+    from hypergraphdb_tpu.ops import pallas_gather as pg
+
+    assert pg.declined(w, 128) is None
+    fn, args, kwargs = _case_gather_or(partial(_place, sharding=one_chip),
+                                       w=w)
+    compiled = fn.lower(*args, **kwargs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.slow  # ~25 s to be refused; the fix is guarded above
 def test_whole_row_top_k_is_what_the_compiler_refuses(one_chip,
                                                       no_compile_cache):
@@ -302,10 +318,18 @@ def test_sharded_bfs_compiles_for_four_chips_with_a_collective(
 # --------------------- the staged hop at the benchmark graph's real shapes
 
 
-#: level lengths of ``plans_for(models.dbpedia_snapshot(2M, 8M))`` — the
-#: index pyramids of the 10,000,065-atom benchmark graph
-_L1 = (78_239_528, 14_239_528)
-_L2 = (55_021_736, 16_594_768, 120_696, 12_328, 1_432, 160, 16)
+#: level lengths and widths of ``plans_for(models.dbpedia_snapshot(2M,
+#: 8M))`` — the index plans of the 10,000,065-atom benchmark graph: level 0
+#: in width classes (stage 1's five hold every link, arity 2-10, and it has
+#: no upper level; stage 2's nine, then the pyramid of its 8,813 rows above
+#: ``W_MAX``). One width-8 level 0 a stage was (78,239,528, 14,239,528) and
+#: (55,021,736, 16,594,768, 120,696, 12,328, 1,432, 160, 16).
+_L1 = (1_775_280, 7_106_828, 10_673_640, 14_214_176, 17_799_410)
+_W1 = (2, 4, 6, 8, 10)
+_L2 = (32, 1_146, 13_696, 90_280, 1_640_828, 14_970_700, 26_817_756,
+       5_963_480, 5_884_704, 142_600, 14_256, 1_640, 184, 16)
+_W2 = (4, 6, 8, 10, 14, 20, 28, 40, 56, 8, 8, 8, 8, 8)
+_N2 = 9  # stage 2's level-0 classes
 _N_PAD, _KW, _CHUNK = 10_000_072, 128, 1 << 16
 
 
@@ -313,7 +337,8 @@ def _staged_step(step: str):
     """(jitted step, exemplar args, statics, bytes resident beside it)."""
     from hypergraphdb_tpu.ops import ellbfs as eb
 
-    rows = lambda ls: sum(n // 8 for n in ls) + 1  # noqa: E731
+    rows = lambda ls, ws: sum(n // w for n, w in zip(ls, ws))  # noqa: E731
+    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
     bitmap = _N_PAD * _KW * 4
     visited = _sds((_N_PAD, _KW), "uint32")
     if step == "_sparse_hop":
@@ -323,19 +348,37 @@ def _staged_step(step: str):
                 {}, 4 * (sum(_L1) + sum(_L2)))
     if step == "_stage":
         return (eb._stage,
-                (visited, tuple(_sds((n,), "int32") for n in _L1)),
-                dict(widths=(8, 8), chunk=_CHUNK, use_pallas=True),
+                (visited, ints(_L1)),
+                dict(widths=_W1, n_lvl0=len(_L1), chunk=_CHUNK,
+                     use_pallas=True),
                 4 * sum(_L2))
     if step == "_stage_lvl0_consume":
         return (eb._stage_lvl0_consume,
-                (_sds((rows(_L1), _KW), "uint32"), _sds((_L2[0],), "int32")),
-                dict(w=8, chunk=_CHUNK, use_pallas=True),
-                bitmap + 4 * (sum(_L1) + sum(_L2[1:])))
+                (_sds((rows(_L1, _W1) + 1, _KW), "uint32"), ints(_L2[:_N2])),
+                dict(widths=_W2[:_N2], chunk=_CHUNK, use_pallas=True),
+                bitmap + 4 * (sum(_L1) + sum(_L2[_N2:])))
     return (eb._stage_upper,
-            (_sds((_L2[0] // 8, _KW), "uint32"),
-             tuple(_sds((n,), "int32") for n in _L2[1:])),
-            dict(widths=(8,) * 7, chunk=_CHUNK),
-            bitmap + 4 * (sum(_L1) + _L2[0]))
+            (_sds((rows(_L2[:_N2], _W2), _KW), "uint32"), ints(_L2[_N2:])),
+            dict(widths=_W2[_N2:], n_last=_L2[_N2 - 1] // _W2[_N2 - 1],
+                 chunk=_CHUNK),
+            bitmap + 4 * (sum(_L1) + sum(_L2[:_N2])))
+
+
+def test_the_cell_shape_is_the_modules_classes():
+    """``_L1`` / ``_L2`` describe a plan the module would build: level 0's
+    widths are its class constants, ascending, the widest last where a
+    pyramid follows; every length a multiple of its width; the stage-1
+    buffer (8,000,000 link rows and the zero row) and stage 2's (2.11M
+    rows) are what the classes leave of 11.55M and 8.97M rows."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    assert set(_W1) | set(_W2[:_N2]) <= set(eb.CLASS_WIDTHS)
+    assert _W2[_N2 - 1] == eb.W_MAX and set(_W2[_N2:]) == {8}
+    assert list(_W1) == sorted(_W1) and list(_W2[:_N2]) == sorted(_W2[:_N2])
+    assert all(n % w == 0 for n, w in zip(_L1 + _L2, _W1 + _W2))
+    assert sum(n // w for n, w in zip(_L1, _W1)) == 8_000_000
+    assert sum(n // w for n, w in zip(_L2, _W2)) == 2_108_461
+    assert sum(_L2[_N2:]) == 158_696  # the upper pyramid's indices a hop
 
 
 @pytest.mark.parametrize("step", ["_sparse_hop", "_stage",
@@ -347,16 +390,20 @@ def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
     lane-padded to 128 on the chip and save nothing) and the chunk
     ``bench.py`` c4 runs: the step's program PLUS what the hop keeps
     resident beside it (the visited bitmap, the other index plans) fits
-    the chip. The widest is ``_stage_lvl0_consume`` at ~15.5 of 16.9 GB;
-    before the upper levels wrote in place ``_stage_upper`` planned
-    17.2. The sparse first hop's placement (``_sparse_hop``, one block of
+    the chip, and three quarters of it. The widest is
+    ``_stage_lvl0_consume`` at ~11.2 of 16.9 GB (15.5 before level 0 had
+    width classes; before the upper levels wrote in place ``_stage_upper``
+    planned 17.2). The sparse first hop's placement (``_sparse_hop``, one block of
     pairs) updates the donated bitmap where it lies."""
     fn, args, statics, resident = _staged_step(step)
     mem = fn.lower(*_place(args, one_chip), **statics).compile() \
         .memory_analysis()
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes + resident)
-    assert total < HBM_USABLE, (step, total)
+    # with level 0 in width classes the widest step holds 11.2 GB
+    # (``_stage_lvl0_consume``: the bitmap, stage 1's 8.0M-row buffer and
+    # stage 2's 2.1M rows), where one width-8 level 0 a stage held 15.5
+    assert total < 0.75 * HBM_USABLE, (step, total)
     if step == "_sparse_hop":
         assert mem.alias_size_in_bytes >= _N_PAD * _KW * 4
         assert mem.temp_size_in_bytes < 2**30
