@@ -204,6 +204,32 @@ class HyperTraversal:
 # ---------------------------------------------------------------- classics
 
 
+def match_path(graph, start: HGHandle, link_predicates) -> set:
+    """The distinct end points of the path pattern ``F_1 / … / F_H`` from
+    ``start``: step h follows an incident link only if
+    ``link_predicates[h](graph, link)`` holds (``None``: every link) and
+    reaches every target of it. ``X_0 = {start}``; ``X_h`` is the union of
+    the targets of the admitted links incident to an atom of ``X_{h-1}``;
+    the answer is ``X_H``. The query engine's set semantics — a chain of
+    ``And(type(T), incident(x), incident(y))`` through the shared variable,
+    SPARQL 1.1's SequencePath under ``SELECT DISTINCT`` — so the chain's
+    variables may bind the same atom: an atom of ``X_{h-1}`` that lies in
+    an admitted link is itself in ``X_h`` (no ``t != atom``, where
+    :class:`DefaultALGenerator` has one). Not a traversal: no visited set,
+    nothing accumulates; a step that admits no link gives the empty set,
+    no step gives ``{start}``. The plain reference of
+    ``ops.ellbfs.path_match``, independent of it."""
+    ends = {int(start)}
+    for admits in link_predicates:
+        nxt: set = set()
+        for atom in ends:
+            for link in graph.get_incidence_set(atom):
+                if admits is None or admits(graph, link):
+                    nxt.update(int(t) for t in graph.get_targets(link))
+        ends = nxt
+    return ends
+
+
 def dijkstra(
     graph,
     start: HGHandle,

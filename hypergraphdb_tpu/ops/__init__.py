@@ -8,7 +8,13 @@ from hypergraphdb_tpu.ops.bitfrontier import (
     bfs_packed,
     unpack_visited,
 )
-from hypergraphdb_tpu.ops.ellbfs import PullBFSResult, bfs_pull, visited_rows
+from hypergraphdb_tpu.ops.ellbfs import (
+    PathMatchResult,
+    PullBFSResult,
+    bfs_pull,
+    path_match,
+    visited_rows,
+)
 from hypergraphdb_tpu.ops.incremental import (
     PinnedView,
     SnapshotManager,
@@ -34,6 +40,7 @@ __all__ = [
     "AOTCache",
     "CSRSnapshot",
     "DeviceSnapshot",
+    "PathMatchResult",
     "PinnedView",
     "PullBFSResult",
     "SnapshotManager",
@@ -44,6 +51,7 @@ __all__ = [
     "bfs_pull",
     "collect_pattern",
     "execute_pattern",
+    "path_match",
     "plan_pattern",
     "visited_rows",
     "bfs_memory_bytes",

@@ -489,9 +489,14 @@ class PullBFSPlans:
             sum(len(l) for l in self.stage2_levels[self.stage2_n_lvl0:]))
 
 
+def _n_pad(n_atoms: int) -> int:
+    """Rows of a bitmap over ``n_atoms`` atoms and the dummy row."""
+    return _ceil_to(n_atoms + 1, 8)
+
+
 def build_pull_plans(snap: CSRSnapshot, w_upper: int = 8) -> PullBFSPlans:
     N = snap.num_atoms
-    n_pad = _ceil_to(N + 1, 8)
+    n_pad = _n_pad(N)
     e_tgt = snap.n_edges_tgt
     e_inc = snap.n_edges_inc
     # stage 1: link_live = OR of F over target rows (tgt CSR, rows=atoms)
@@ -659,19 +664,35 @@ def plans_for(snap: CSRSnapshot) -> PullBFSPlans:
     return plans
 
 
+#: Families a parent keeps restricted at once: the least recently used goes
+#: first. A constant, not an option: a 3-step match and a typed traversal
+#: beside it are four, and never evict each other.
+RESTRICT_RESIDENT = 4
+
+
 def restricted_for(snap: CSRSnapshot, link_types) -> CSRSnapshot:
     """The snapshot a traversal under a link predicate runs over
-    (``CSRSnapshot.restrict_links``), with its plan: built once per
-    (snapshot, family) under phase ``hg.bfs.restrict`` and kept on the
-    parent, as :func:`plans_for` keeps a plan — a family's host and device
-    arrays live as long as the parent does. A hit records nothing."""
+    (``CSRSnapshot.restrict_links``), with its plan: built under phase
+    ``hg.bfs.restrict`` and kept on the parent, as :func:`plans_for` keeps
+    a plan — the ``RESTRICT_RESIDENT`` families used last. Past that the
+    least recently used one is let go (counter ``bfs.restrict.evictions``):
+    its host arrays, its plan and its ``_pull_device`` arrays hang on the
+    restricted snapshot alone, so they are freed with the parent's
+    reference unless a running traversal still holds them; a family that
+    comes back is rebuilt and answers the same. A hit records nothing."""
     family = frozenset(int(t) for t in link_types)
     memo = vars(snap).setdefault("_pull_restricted", {})
-    sub = memo.get(family)
+    sub = memo.pop(family, None)
     if sub is None:
         with phase("hg.bfs.restrict"):
-            sub = memo[family] = snap.restrict_links(family)
+            sub = snap.restrict_links(family)
             plans_for(sub)
+        reg = default_registry()
+        while len(memo) >= RESTRICT_RESIDENT:
+            del memo[next(iter(memo))]  # insertion order: the oldest use
+            reg.counter("bfs.restrict.evictions").inc()
+        reg.gauge("bfs.restrict.resident").set(len(memo) + 1)
+    memo[family] = sub  # the newest use last
     return sub
 
 
@@ -865,40 +886,53 @@ def _stage_upper(lvl0, levels, widths, n_last, chunk):
     return _upper_levels(buf, levels, widths, sizes, n0, chunk)
 
 
-@hgverify.entry(
-    shapes=lambda: (hgverify.sds((64, 1), "uint32"),
-                    hgverify.sds((9, 1), "uint32"),
-                    hgverify.sds((64,), "int32"),
-                    hgverify.sds((), "int32")),
-    donate=True,
-)
-@partial(jax.jit, donate_argnums=(0,))  # visited aliases the output
-@_program("hg_bfs_visited_update", "hg.bfs.visited_update")
-def _visited_update(visited, reach_chunks, out_map, n_atoms):
-    """visited | reach_chunks[out_map], folded in row blocks so no second
-    (n_pad, Kw) array materializes while the stage buffer is alive;
-    fori_loop carries alias in place."""
-    n_pad, Kw = visited.shape
+def _fold_rows(state, reach_chunks, out_map, n_atoms, combine):
+    """``state[v] = combine(state[v], reach_chunks[out_map[v]])`` for every
+    row, folded in row blocks so no second (n_pad, Kw) array materializes
+    while the stage buffer is alive (fori_loop carries alias in place);
+    the dummy row (``n_atoms``) is zeroed last."""
+    n_pad, Kw = state.shape
     ub = 1 << 18
     n_full = n_pad // ub
 
-    def upd(i, vis):
-        sl = jax.lax.dynamic_slice(out_map, (i * ub,), (ub,))
-        cur = jax.lax.dynamic_slice(vis, (i * ub, 0), (ub, Kw))
+    def fold(nxt, start, rows):
+        cur = jax.lax.dynamic_slice(nxt, (start, 0), (rows, Kw))
+        sl = jax.lax.dynamic_slice(out_map, (start,), (rows,))
         return jax.lax.dynamic_update_slice(
-            vis, cur | reach_chunks[sl], (i * ub, 0)
+            nxt, combine(cur, reach_chunks[sl]), (start, 0)
         )
 
-    nxt = (jax.lax.fori_loop(0, n_full, upd, visited)
-           if n_full else visited)
+    nxt = (jax.lax.fori_loop(0, n_full, lambda i, v: fold(v, i * ub, ub),
+                             state)
+           if n_full else state)
     tail = n_pad - n_full * ub
     if tail:
-        sl = out_map[n_full * ub:]
-        cur = jax.lax.dynamic_slice(nxt, (n_full * ub, 0), (tail, Kw))
-        nxt = jax.lax.dynamic_update_slice(
-            nxt, cur | reach_chunks[sl], (n_full * ub, 0)
-        )
+        nxt = fold(nxt, n_full * ub, tail)
     return nxt.at[n_atoms].set(jnp.uint32(0))
+
+
+def _update_shapes():
+    return (hgverify.sds((64, 1), "uint32"), hgverify.sds((9, 1), "uint32"),
+            hgverify.sds((64,), "int32"), hgverify.sds((), "int32"))
+
+
+@hgverify.entry(shapes=_update_shapes, donate=True)
+@partial(jax.jit, donate_argnums=(0,))  # visited aliases the output
+@_program("hg_bfs_visited_update", "hg.bfs.visited_update")
+def _visited_update(visited, reach_chunks, out_map, n_atoms):
+    """A traversal's hop ends here: visited | reach_chunks[out_map]."""
+    return _fold_rows(visited, reach_chunks, out_map, n_atoms,
+                      lambda cur, reached: cur | reached)
+
+
+@hgverify.entry(shapes=_update_shapes, donate=True)
+@partial(jax.jit, donate_argnums=(0,))  # the old frontier's buffer is reused
+@_program("hg_bfs_frontier_replace", "hg.bfs.frontier_replace")
+def _frontier_replace(frontier, reach_chunks, out_map, n_atoms):
+    """A match's step ends here: the new state IS reach_chunks[out_map],
+    written into the donated old frontier, of which no bit is read."""
+    return _fold_rows(frontier, reach_chunks, out_map, n_atoms,
+                      lambda cur, reached: reached)
 
 
 # Pairs a placement dispatch carries: the one shape `_sparse_hop` compiles at
@@ -982,18 +1016,23 @@ def _seed_links(snap: CSRSnapshot, seeds: np.ndarray,
 
 def _sparse_first_hop(visited: jax.Array, snap: CSRSnapshot,
                       seeds: np.ndarray, sl: _SeedLinks,
-                      n_atoms: jax.Array) -> jax.Array:
-    """``visited_0`` → ``visited_1``: every target of every link incident to
-    seed k gets bit k. The pairs are made unique and sorted by row, the
-    seeds' own bits (always among them, and already set) are left out, and
-    they go up in SPARSE_BLOCK-wide blocks to one program."""
+                      n_atoms: jax.Array, own_bits: bool) -> jax.Array:
+    """Every target of every link incident to seed k gets bit k: a
+    traversal's ``visited_0`` → ``visited_1``, a match's ``X_1`` onto an
+    empty bitmap. The pairs are made unique and sorted by row and go up in
+    SPARSE_BLOCK-wide blocks to one program. ``own_bits``: whether a seed's
+    own bit (among the pairs wherever the seed lies in a link) is placed —
+    not on a seed bitmap, which holds it (the placement adds, and no bit
+    may be added twice); on an empty one it is part of the answer."""
     rows = snap.tgt_flat[
         _segmented_ranges(snap.tgt_offsets[sl.links], sl.arity)
     ].astype(np.int64)
     ks = np.repeat(sl.cols, sl.arity)
-    fresh = rows != seeds[ks]
+    if not own_bits:
+        fresh = rows != seeds[ks]
+        rows, ks = rows[fresh], ks[fresh]
     K = len(seeds)
-    keys = np.unique(rows[fresh] * K + ks[fresh])
+    keys = np.unique(rows * K + ks)
     n_blocks = -(-len(keys) // SPARSE_BLOCK)
     pairs = np.zeros((2, n_blocks * SPARSE_BLOCK), dtype=np.int32)
     pairs[0] = snap.num_atoms  # pad pairs: (dummy row, column 0)
@@ -1004,47 +1043,83 @@ def _sparse_first_hop(visited: jax.Array, snap: CSRSnapshot,
     return visited
 
 
+class _Hop(NamedTuple):
+    """What one hop of the chain runs over: the snapshot its links come
+    from (the parent, or a family's restriction), its plan, and the plan's
+    device arrays (:func:`_hop_over`)."""
+
+    snap: CSRSnapshot
+    plans: PullBFSPlans
+    dev: dict
+
+
+def _hop_over(snap: CSRSnapshot) -> _Hop:
+    plans = plans_for(snap)
+    return _Hop(snap, plans, _device_plans(snap, plans))
+
+
 def _bfs_pull_device(
-    snap: CSRSnapshot,
-    dev: dict,               # the plan's device arrays (_device_plans)
-    plans: PullBFSPlans,
+    hops: Sequence[_Hop],    # one a hop, in order; all over one id space
+    n_atoms: int,
+    n_pad: int,
     seeds: np.ndarray,       # (K,) int32 — K % 32 == 0
-    max_hops: int,
+    update,                  # what ends a hop: the operator's
     chunk: int = 1 << 19,
     count_edges: bool = True,
     use_pallas: bool = False,
 ) -> tuple[jax.Array, list, jax.Array]:
-    s1 = plans.stage1
-    levels1, levels2 = dev["levels1"], dev["levels2"]
-    widths2, n2 = plans.stage2_widths, plans.stage2_n_lvl0
-    n2_last = len(plans.stage2_levels[n2 - 1]) // widths2[n2 - 1]
-    n_atoms = jnp.int32(plans.n_atoms)
-    with phase("hg.bfs.seeds_upload"):
-        seeds_dev = jnp.asarray(seeds)
-    visited = _seed_bitmap(seeds_dev, n_atoms, plans.n_pad)
+    """The hop chain of one seed block. ``update`` is ``_visited_update``
+    (a traversal: the state is the visited set, and grows) or
+    ``_frontier_replace`` (a match: the state is the newest step's end
+    points alone, and ``count_edges`` has no meaning)."""
+    grows = update is _visited_update
+    n_atoms_dev = jnp.int32(n_atoms)
+
+    def bitmap_of(start: np.ndarray) -> jax.Array:
+        with phase("hg.bfs.seeds_upload"):
+            start_dev = jnp.asarray(start)
+        return _seed_bitmap(start_dev, n_atoms_dev, n_pad)
+
+    def rule() -> Optional[_SeedLinks]:  # it reads the FIRST hop's plan
+        return (_seed_links(hops[0].snap, seeds,
+                            hops[0].plans.total_indices // SPARSE_SHARE)
+                if hops else None)
+
+    if grows:
+        # the rule's look at the seeds runs beside the bitmap's zero fill
+        visited = bitmap_of(seeds)
+        sl = rule()
+    else:
+        # a match's X_1 holds a seed's bit only where the seed lies in an
+        # admitted link: the sparse pairs go onto an EMPTY bitmap — the
+        # seed bitmap of pad seeds, whose bits fall on the dummy row
+        sl = rule()
+        visited = bitmap_of(seeds if sl is None
+                            else np.full_like(seeds, n_atoms))
     # S entering the block's last hop, the one Σ deg that `total_edges`
     # reads (it telescopes over the hops before): one entry, or none where
     # nothing counts edges or no hop runs
     s_ins: list = []
-    dense_hops = max_hops
-    # the rule's look at the seeds runs beside the bitmap's zero fill
-    sl = (_seed_links(snap, seeds, plans.total_indices // SPARSE_SHARE)
-          if max_hops >= 1 else None)
     if sl is not None:
         # once a sparse hop, around expansion, upload, dispatch and sync:
         # its count beside hg.bfs.hop.stage1's says how often the rule
         # took this side
         with phase("hg.bfs.hop.sparse"):
-            visited = _sparse_first_hop(visited, snap, seeds, sl, n_atoms)
+            visited = _sparse_first_hop(visited, hops[0].snap, seeds, sl,
+                                        n_atoms_dev, own_bits=not grows)
             jax.block_until_ready(visited)
-        dense_hops -= 1
-        if count_edges and not dense_hops:
+        hops = hops[1:]
+        if count_edges and not hops:
             s_ins.append(sl.deg)  # S_0 = deg(seed), which the host holds
     # one obs.phase per synced step, three a hop and the degree sum once a
     # block: a traversal's seconds by stage in the default registry, and
     # under a profiler the host span that a device idle gap is charged to
-    for hop in range(dense_hops):
-        if count_edges and hop == dense_hops - 1:
+    for i, (_, plans, dev) in enumerate(hops):
+        s1 = plans.stage1
+        levels1, levels2 = dev["levels1"], dev["levels2"]
+        widths2, n2 = plans.stage2_widths, plans.stage2_n_lvl0
+        n2_last = len(plans.stage2_levels[n2 - 1]) // widths2[n2 - 1]
+        if count_edges and i == len(hops) - 1:
             with phase("hg.bfs.hop.deg_sum"):
                 s_ins.append(_deg_sum(visited, dev["inc_deg"]))
                 jax.block_until_ready(s_ins[-1])
@@ -1067,8 +1142,8 @@ def _bfs_pull_device(
             reach_chunks = _stage_upper(lvl0b, levels2[n2:], widths2[n2:],
                                         n2_last, chunk)
             del lvl0b
-            visited = _visited_update(visited, reach_chunks, dev["out_map"],
-                                      n_atoms)
+            visited = update(visited, reach_chunks, dev["out_map"],
+                             n_atoms_dev)
             del reach_chunks
             jax.block_until_ready(visited)
     with phase("hg.bfs.reach_counts"):  # the dispatch: nothing syncs here
@@ -1086,6 +1161,46 @@ def block_layout(K: int, k_block: int) -> list[int]:
     models (bench.py) stay tied to the kernel's actual layout."""
     K_pad = _ceil_to(max(K, WORD), WORD)
     return [min(k_block, K_pad - s) for s in range(0, K_pad, k_block)]
+
+
+def _check_k_block(k_block: int) -> None:
+    """Before any plan is built for the call."""
+    if k_block <= 0 or k_block % WORD:
+        raise ValueError(
+            f"k_block must be a positive multiple of {WORD} (device words "
+            f"pack {WORD} seeds); got {k_block}"
+        )
+
+
+def _seed_blocks(hops: Sequence[_Hop], snap: CSRSnapshot,
+                 seeds: np.ndarray, update, chunk: int, k_block: int,
+                 count_edges: bool) -> tuple[list, int]:
+    """The chain over every seed block of ``seeds``: the blocks' (bitmap,
+    S entering the last hop, counts) in order, and the seeds' real number
+    (the last block's columns past it are pad seeds)."""
+    seeds = np.asarray(seeds, dtype=np.int32)
+    K = len(seeds)
+    K_pad = _ceil_to(max(K, WORD), WORD)
+    if K_pad != K:
+        seeds = np.concatenate(
+            [seeds, np.full(K_pad - K, snap.num_atoms, dtype=np.int32)]
+        )
+    n_pad = _n_pad(snap.num_atoms)
+    blocks = []
+    for s in range(0, K_pad, k_block):
+        block = seeds[s : s + k_block]
+        # 4096-seed blocks (128-lane rows, the one width the kernel
+        # compiles at) run the Pallas gather on a TPU; everything else
+        # keeps the XLA gather (no width limits)
+        use_pallas = (len(block) == _pg.ROW_WORDS * WORD
+                      and _pg.pallas_ok())
+        blocks.append(
+            _bfs_pull_device(
+                hops, snap.num_atoms, n_pad, block, update,
+                chunk=chunk, count_edges=count_edges, use_pallas=use_pallas,
+            )
+        )
+    return blocks, K
 
 
 def bfs_pull(
@@ -1131,43 +1246,22 @@ def bfs_pull(
     the restricted plan's size). ``None`` follows every link (the
     reference's ``SimpleALGenerator``); the family of all link types
     answers the same bit for bit, an empty one returns the seeds, a type
-    atom no link has is ignored. Still host-only
-    (``algorithms/traversals.DefaultALGenerator``): the sibling predicate,
-    the ordered-link directions (``return_preceeding`` /
-    ``return_succeeding``, ``reverse_order``), a predicate per hop.
+    atom no link has is ignored. A predicate per hop — the path
+    ``F1/F2/F3``, which keeps no visited set — is :func:`path_match`.
+    Still host-only (``algorithms/traversals.DefaultALGenerator``): the
+    sibling predicate and the ordered-link directions
+    (``return_preceeding`` / ``return_succeeding``, ``reverse_order``).
     """
-    if k_block <= 0 or k_block % WORD:
-        raise ValueError(
-            f"k_block must be a positive multiple of {WORD} (device words "
-            f"pack {WORD} seeds); got {k_block}"
-        )
+    _check_k_block(k_block)
     if link_types is not None:
         snap = restricted_for(snap, link_types)
     if not snap.n_edges_tgt:  # no link to follow: the seeds are the answer
         max_hops = 0
-    plans = plans_for(snap)
-    seeds = np.asarray(seeds, dtype=np.int32)
-    K = len(seeds)
-    K_pad = _ceil_to(max(K, WORD), WORD)
-    if K_pad != K:
-        seeds = np.concatenate(
-            [seeds, np.full(K_pad - K, snap.num_atoms, dtype=np.int32)]
-        )
-    dev = _device_plans(snap, plans)
-    blocks = []
-    for s in range(0, K_pad, k_block):
-        block = seeds[s : s + k_block]
-        # 4096-seed blocks (128-lane rows, the one width the kernel
-        # compiles at) run the Pallas gather on a TPU; everything else
-        # keeps the XLA gather (no width limits)
-        use_pallas = (len(block) == _pg.ROW_WORDS * WORD
-                      and _pg.pallas_ok())
-        blocks.append(
-            _bfs_pull_device(
-                snap, dev, plans, block, max_hops,
-                chunk=chunk, count_edges=count_edges, use_pallas=use_pallas,
-            )
-        )
+    # one plan for every hop: the chain is handed the same one H times
+    blocks, K = _seed_blocks(
+        [_hop_over(snap)] * max_hops, snap, seeds, _visited_update, chunk,
+        k_block, count_edges)
+
     # The device emits S_h (Σ deg over visited entering each hop);
     # frontiers partition visited, so the total over all hops telescopes
     # to the LAST emitted S — one (K,) download per block.
@@ -1178,20 +1272,79 @@ def bfs_pull(
         with phase("hg.bfs.edges_to_host"):
             return np.asarray(s_ins[-1]).astype(np.int64)
 
+    visited_t, reach = _joined(blocks, K)
+    edges = np.concatenate([total_edges(b) for b in blocks])[:K]
+    return PullBFSResult(visited_t, edges, reach)
+
+
+def _joined(blocks: list, K: int) -> tuple[jax.Array, jax.Array]:
+    """The seed blocks' bitmaps side by side, and their counts end to end
+    without the pad seeds' (past the seeds' real number ``K``); one whole
+    block's are handed on as they are, with no device operation."""
     if len(blocks) == 1:
-        visited_t, _, reach = blocks[0]
-        res = PullBFSResult(visited_t, total_edges(blocks[0]), reach)
+        bitmap, _, counts = blocks[0]
     else:
-        res = PullBFSResult(
-            jnp.concatenate([b[0] for b in blocks], axis=1),
-            np.concatenate([total_edges(b) for b in blocks]),
-            jnp.concatenate([b[2] for b in blocks]),
-        )
-    if K_pad != K:
-        res = PullBFSResult(
-            res.visited_t, res.edges_touched[:K], res.reach_counts[:K]
-        )
-    return res
+        bitmap = jnp.concatenate([b[0] for b in blocks], axis=1)
+        counts = jnp.concatenate([b[2] for b in blocks])
+    return bitmap, (counts if counts.shape[0] == K else counts[:K])
+
+
+class PathMatchResult(NamedTuple):
+    frontier_t: jax.Array    # (N_pad, Kw) uint32 — X_H, TRANSPOSED and packed
+    match_counts: jax.Array  # (K,) int32 — |X_H[k]|
+
+
+def path_match(
+    snap: CSRSnapshot,
+    seeds: np.ndarray,
+    steps: Sequence,
+    chunk: int = 1 << 19,
+    k_block: int = 1024,
+) -> PathMatchResult:
+    """The end points of the path pattern ``F_1 / F_2 / … / F_H`` from every
+    seed at once: ``steps[h]`` is step h's family of link type atoms
+    (``None``: every link), a predicate PER HOP — SPARQL 1.1's
+    SequencePath, a chain of ``And(type(T), incident(x), incident(y))``
+    through the shared variable. ``X_0[k] = {seeds[k]}`` and, step by step,
+
+        live_h[k] = {L : type_of[L] in F_h, targets(L) ∩ X_{h-1}[k] ≠ ∅}
+        X_h[k]    = ∪ {targets(L) : L in live_h[k]}
+
+    Returns ``PathMatchResult(frontier_t, match_counts)``: the device
+    (N_pad, K/32) uint32 transposed bitmap of ``X_H`` (:func:`visited_rows`
+    reads its rows) and the device (K,) int32 ``|X_H[k]|``; the reference
+    is ``algorithms/traversals.match_path``. These are the query engine's
+    set semantics, a homomorphism — two-stage hypergraph message passing,
+    node → hyperedge → node, OR as the aggregate and the node's own
+    message included: an atom of ``X_{h-1}`` that lies in an admitted link
+    is itself in ``X_h``. It is NOT a traversal: no visited set (an atom
+    left at step 1 may be entered again at step 3), nothing accumulates, a
+    step whose family admits no link gives the empty answer (not the
+    seeds), zero steps give the seeds. Departure from
+    ``DefaultALGenerator``: its exclusive step (``t != atom`` per hop) is
+    not built — it needs a second reduction per link — and stays
+    host-only.
+
+    It is :func:`bfs_pull`'s hop chain over a plan PER STEP
+    (:func:`restricted_for` a family: the steps' restricted snapshots,
+    plans and device arrays are alive at once), each step ended by
+    ``_frontier_replace`` where a traversal's ends in ``_visited_update``.
+    Seed blocks, the gather's choice and the first step's rule (the sparse
+    side when the seeds' pairs are few beside STEP 1's plan, its pairs
+    placed on an empty bitmap; else the dense chain from the seed bitmap)
+    are ``bfs_pull``'s.
+    """
+    _check_k_block(k_block)
+    subs = [snap if family is None else restricted_for(snap, family)
+            for family in steps]
+    if any(not sub.n_edges_tgt for sub in subs):
+        # a step no link passes: no end point — no hop, from pad seeds
+        # alone, and no plan goes up
+        subs, seeds = [], np.full(len(seeds), snap.num_atoms, np.int32)
+    blocks, K = _seed_blocks([_hop_over(sub) for sub in subs], snap, seeds,
+                             _frontier_replace, chunk, k_block,
+                             count_edges=False)
+    return PathMatchResult(*_joined(blocks, K))
 
 
 def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:
@@ -1213,9 +1366,10 @@ def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:
     return cache
 
 
-def visited_rows(res: PullBFSResult, n_atoms: int) -> list[np.ndarray]:
-    """Per-seed sorted reachable-atom arrays from the transposed bitmap."""
-    vt = np.asarray(res.visited_t)[: n_atoms]  # drop dummy+pad rows
+def visited_rows(res, n_atoms: int) -> list[np.ndarray]:
+    """Per-seed sorted atom arrays from the transposed bitmap a traversal
+    (``visited_t``) or a match (``frontier_t``) returns first."""
+    vt = np.asarray(res[0])[: n_atoms]  # drop dummy+pad rows
     K = vt.shape[1] * WORD
     out = []
     for k in range(K):

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from hypergraphdb_tpu import obs
+from hypergraphdb_tpu.algorithms.traversals import match_path
 from hypergraphdb_tpu.ops import ellbfs as eb
 from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 from hypergraphdb_tpu.ops.ellbfs import (
@@ -148,12 +149,19 @@ def test_chunked_scan_and_multiblock(first_hop, count_edges, monkeypatch):
                           np.asarray(counted.reach_counts))
 
 
-def test_k_block_validation():
-    snap = random_snapshot(50, 40, 3, seed=2)
-    with pytest.raises(ValueError, match="k_block"):
-        bfs_pull(snap, np.arange(8, dtype=np.int32), 1, k_block=48)
-    with pytest.raises(ValueError, match="k_block"):
-        bfs_pull(snap, np.arange(8, dtype=np.int32), 1, k_block=0)
+@pytest.mark.parametrize("operator", ["bfs_pull", "path_match"])
+def test_k_block_validation(operator):
+    """Refused before a plan is built for the call."""
+    snap = random_snapshot(50, 40, 3, seed=2, n_types=2)
+    seeds = np.arange(8, dtype=np.int32)
+    for k_block in (48, 0):
+        with pytest.raises(ValueError, match="k_block"):
+            if operator == "bfs_pull":
+                bfs_pull(snap, seeds, 1, k_block=k_block, link_types=(1,))
+            else:
+                eb.path_match(snap, seeds, [(1,), None], k_block=k_block)
+    assert not hasattr(snap, "_pull_plans")
+    assert not hasattr(snap, "_pull_restricted")
 
 
 def test_reduce_plan_shapes():
@@ -807,3 +815,308 @@ def test_counts_are_exact_under_the_counting_schedule(
                        else host_bfs(snap, s, hops))
         assert int(res.reach_counts[k]) == len(want)
         assert res.edges_touched[k] == (edges if count_edges else 0)
+
+
+# ------------------------------------------------------ a predicate per hop
+#
+# ``path_match(snap, seeds, [F1, F2, F3])`` against the plain reference
+# ``algorithms/traversals.match_path``: on the ``HyperGraph`` of the link
+# predicate's tests, and — through an adapter that hands ``match_path`` a
+# snapshot's rows — on seeded random typed snapshots. Both sides of the
+# first step's rule, chosen through the rule's constant as above.
+
+PATHS = {
+    "disjoint": [("knows",), ("likes",), ("cites",)],
+    "overlapping": [("knows", "likes"), ("likes", "cites"),
+                    ("cites", "knows")],
+    "repeated": [("knows", "cites")] * 3,
+    "with_none": [("likes",), None, ("tags", "knows")],
+}
+
+
+def _admits(family):
+    """A ``match_path`` link predicate for a family of type handles."""
+    if family is None:
+        return None
+    return lambda graph, link: int(graph.get_type_handle_of(link)) in family
+
+
+def _families(handle, path):
+    return [None if f is None else {handle[n] for n in f} for f in path]
+
+
+def _assert_matches_reference(graph, n_atoms, seeds, steps, res):
+    rows = visited_rows(res, n_atoms)
+    counts = np.asarray(res.match_counts)
+    assert counts.shape == (len(seeds),) and counts.dtype == np.int32
+    for k, s in enumerate(np.asarray(seeds).tolist()):
+        # a pad seed is no atom: it matches nothing, itself included
+        want = set() if s == n_atoms else match_path(
+            graph, s, [_admits(f) for f in steps])
+        assert set(rows[k].tolist()) == want, f"seed {s} (column {k})"
+        assert counts[k] == len(want)
+    for k in range(len(seeds), len(rows)):  # pad columns past the seeds
+        assert not len(rows[k])
+
+
+@pytest.mark.parametrize("first_step", ["sparse", "dense"])
+@pytest.mark.parametrize("n_steps", [1, 2, 3])
+@pytest.mark.parametrize("path", list(PATHS))
+def test_path_match_matches_match_path(typed_graph, path, n_steps,
+                                       first_step, monkeypatch):
+    g, snap, handle, seeds = typed_graph
+    steps = _families(handle, PATHS[path][:n_steps])
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_step == "sparse" else 1 << 62)
+    with _Sides() as ran:
+        res = eb.path_match(snap, seeds, steps)
+    assert (ran.sparse, ran.dense) == (
+        (1, n_steps - 1) if first_step == "sparse" else (0, n_steps))
+    assert res.frontier_t.shape == (plans_for(snap).n_pad, 1)
+    _assert_matches_reference(g, snap.num_atoms, seeds, steps, res)
+
+
+@pytest.mark.parametrize("first_step", ["sparse", "dense"])
+@pytest.mark.parametrize("case", ["zero_steps", "empty_family_first",
+                                  "empty_family_last", "type_no_link_has"])
+def test_path_match_with_no_step_or_no_admitted_link(typed_graph, case,
+                                                     first_step,
+                                                     monkeypatch):
+    """Zero steps give the seeds; a step whose family admits no link — an
+    empty family, a type atom no link has — gives the empty answer (a
+    traversal under it gives the seeds), and no hop runs."""
+    g, snap, handle, seeds = typed_graph
+    entity_type = int(snap.type_of[int(seeds[0])])
+    steps = {"zero_steps": [],
+             "empty_family_first": [(), {handle["knows"]}],
+             "empty_family_last": [{handle["knows"]}, None, ()],
+             "type_no_link_has": [None, {entity_type, 10 ** 6}]}[case]
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_step == "sparse" else 1 << 62)
+    with _Sides() as ran:
+        res = eb.path_match(snap, seeds, steps)
+    assert (ran.sparse, ran.dense) == (0, 0)
+    _assert_matches_reference(g, snap.num_atoms, seeds, steps, res)
+    rows = visited_rows(res, snap.num_atoms)
+    for k, s in enumerate(seeds.tolist()):
+        assert rows[k].tolist() == ([s] if case == "zero_steps" else [])
+    assert np.asarray(res.match_counts).tolist() == \
+        [int(case == "zero_steps")] * len(seeds)
+
+
+@pytest.mark.parametrize("first_step", ["sparse", "dense"])
+def test_a_seed_keeps_its_bit_only_where_it_lies_in_an_admitted_link(
+        typed_graph, first_step, monkeypatch):
+    """The lonely atom lies in one "tags" link: under ``tags`` it is an end
+    point of its own step (the chain's variables may bind one atom), under
+    ``likes`` it is not, on either side of the rule; the hub is in both."""
+    g, snap, handle, seeds = typed_graph
+    hub, lonely = int(seeds[0]), int(seeds[1])
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_step == "sparse" else 1 << 62)
+    for name, lonely_stays in (("tags", True), ("likes", False)):
+        steps = [{handle[name]}]
+        res = eb.path_match(snap, seeds, steps)
+        _assert_matches_reference(g, snap.num_atoms, seeds, steps, res)
+        rows = visited_rows(res, snap.num_atoms)
+        assert hub in rows[0]
+        assert (lonely in rows[1]) == lonely_stays
+        assert len(rows[1]) == (2 if lonely_stays else 0)
+
+
+class _SnapshotGraph:
+    """What ``match_path`` asks of a graph, answered from a snapshot."""
+
+    def __init__(self, snap):
+        self.snap = snap
+
+    def get_incidence_set(self, atom):
+        return self.snap.incidence_row(int(atom)).tolist()
+
+    def get_targets(self, link):
+        return self.snap.targets_row(int(link)).tolist()
+
+    def get_type_handle_of(self, atom):
+        return int(self.snap.type_of[int(atom)])
+
+
+RANDOM_PATHS = {
+    "disjoint": [(1,), (2, 3), (4, 5)],
+    "overlapping": [(1, 2, 3), (3, 4), (1, 4, 5)],
+    "repeated": [(2, 5)] * 3,
+    "with_none": [None, (1, 2), None],
+}
+
+
+@pytest.mark.parametrize("zipf", [False, True])
+@pytest.mark.parametrize("n_steps", [1, 2, 3])
+@pytest.mark.parametrize("path", list(RANDOM_PATHS))
+def test_path_match_on_random_typed_graph_in_two_ragged_blocks(
+        path, n_steps, zipf, monkeypatch):
+    """40 seeds — the hub, an atom no link targets, a duplicate, a pad seed
+    — in blocks of 32 and 8 (+ 24 pad columns): the sparse side by the
+    rule as it stands, then the dense chain, the same bits."""
+    n = 80000 if zipf else 30000
+    snap = random_snapshot(n, 3000, 4, seed=41 + n_steps, zipf=zipf,
+                           n_types=5)
+    deg = np.diff(snap.inc_offsets[: n + 1].astype(np.int64))
+    hub, lonely = int(np.argmax(deg)), int(np.argmin(deg))
+    seeds = np.concatenate([
+        [hub, lonely, 7, 7, snap.num_atoms],
+        np.random.default_rng(n_steps).integers(0, n, size=35),
+    ]).astype(np.int32)
+    steps = RANDOM_PATHS[path][:n_steps]
+    with _Sides() as ran:
+        res = eb.path_match(snap, seeds, steps, k_block=32)
+    assert (ran.sparse, ran.dense) == (2, 2 * (n_steps - 1))
+    assert res.frontier_t.shape[1] == 2
+    _assert_matches_reference(_SnapshotGraph(snap), snap.num_atoms, seeds,
+                              steps, res)
+
+    monkeypatch.setattr(eb, "SPARSE_SHARE", 1 << 62)  # no input is sparse
+    with _Sides() as ran:
+        dense = eb.path_match(snap, seeds, steps, k_block=32)
+    assert (ran.sparse, ran.dense) == (0, 2 * n_steps)
+    assert np.array_equal(np.asarray(res.frontier_t),
+                          np.asarray(dense.frontier_t))
+    assert np.array_equal(np.asarray(res.match_counts),
+                          np.asarray(dense.match_counts))
+
+
+def test_the_first_step_rule_reads_step_ones_plan(monkeypatch):
+    """Seeds whose pairs under step 1's family are few beside step 1's
+    restricted plan, but not beside a plan a tenth its size: the same
+    seeds take the sparse side under one constant and the dense under the
+    other, and the threshold is ``restricted plan // SPARSE_SHARE``."""
+    snap = random_snapshot(400, 300, 4, seed=12, n_types=3)
+    steps = [(1,), (2,)]
+    sub = eb.restricted_for(snap, steps[0])
+    limit = plans_for(sub).total_indices // eb.SPARSE_SHARE
+    assert limit < plans_for(snap).total_indices // eb.SPARSE_SHARE
+    order = np.random.default_rng(3).permutation(400).astype(np.int32)
+    cum = np.cumsum([_first_hop_pairs(sub, [s]) for s in order])
+    m = int(np.searchsorted(cum, limit))  # cum[m-1] < limit <= cum[m]
+    assert 8 < m < len(order) and cum[m] > cum[m - 1]
+    for seeds, sides in ((order[:m], (1, 1)), (order[: m + 1], (0, 2))):
+        with _Sides() as ran:
+            res = eb.path_match(snap, seeds, steps)
+        assert (ran.sparse, ran.dense) == sides
+        _assert_matches_reference(_SnapshotGraph(snap), snap.num_atoms,
+                                  seeds, steps, res)
+
+
+@pytest.mark.parametrize("case", ["no_fresh_bit", "every_row_fresh",
+                                  "zero_row"])
+def test_frontier_replace_matches_numpy(case):
+    """``_visited_update``'s cases: one full block of the row loop and a
+    tail; the new state is the reached rows alone, whatever the old
+    frontier held, and the dummy row is zero on the way out."""
+    r = np.random.default_rng(len(case))
+    n_pad, kw, n_chunks = (1 << 18) + 40, 1, 50
+    n_atoms = n_pad - 3
+    reach = r.integers(1, 1 << 32, size=(n_chunks + 1, kw), dtype=np.uint32)
+    reach[n_chunks] = 0
+    out_map = r.integers(0, n_chunks, size=n_pad).astype(np.int32)
+    if case == "no_fresh_bit":      # the old frontier holds them, and more
+        frontier = reach[out_map] | np.uint32(1 << 31)
+    elif case == "every_row_fresh":
+        frontier = np.zeros((n_pad, kw), np.uint32)
+    else:                           # nothing reached: rows → the zero row
+        frontier = r.integers(0, 1 << 32, size=(n_pad, kw), dtype=np.uint32)
+        out_map[:] = n_chunks
+    want = reach[out_map]
+    want[n_atoms] = 0
+    got = np.asarray(eb._frontier_replace(
+        jnp.asarray(frontier), jnp.asarray(reach), jnp.asarray(out_map),
+        jnp.int32(n_atoms)))
+    assert np.array_equal(got, want)
+    if case == "zero_row":
+        assert not got.any()
+
+
+@pytest.mark.parametrize("typed", [False, True])
+@pytest.mark.parametrize("hops", [0, 1, 2, 3])
+def test_bfs_pull_is_unchanged_and_is_the_union_of_the_matches(
+        typed_graph, hops, typed):
+    """The hop loop takes a sequence of plans, and ``bfs_pull`` hands it
+    the same one H times: its answers are the host traversal's as before,
+    and — one chain, two updates — the visited set after H hops is the
+    seeds with the end points of the paths ``F``, ``F/F`` … ``F/…/F``."""
+    g, snap, handle, seeds = typed_graph
+    fam = {handle[n] for n in FAMILIES["several"]} if typed else None
+    res = bfs_pull(snap, seeds, hops, link_types=fam)
+    for k, s in enumerate(seeds.tolist()):
+        want, edges = (_oracle(g, fam, s, hops) if typed and hops
+                       else host_bfs(snap, s, hops))
+        assert set(visited_rows(res, snap.num_atoms)[k].tolist()) == want
+        assert res.edges_touched[k] == edges
+        assert int(res.reach_counts[k]) == len(want)
+    union = np.asarray(eb.path_match(snap, seeds, []).frontier_t)
+    for h in range(1, hops + 1):
+        union = union | np.asarray(
+            eb.path_match(snap, seeds, [fam] * h).frontier_t)
+    assert np.array_equal(np.asarray(res.visited_t), union)
+
+
+# ------------------------------------------------------ the memo's bound
+
+
+def _counter(name):
+    c = obs.default_registry().get(name)
+    return c.value if c is not None else 0
+
+
+def test_restricted_for_lets_the_least_recently_used_family_go():
+    import gc
+    import weakref
+
+    snap = random_snapshot(400, 300, 4, seed=21, n_types=8)
+    seeds = np.arange(0, 64, dtype=np.int32)
+    assert eb.RESTRICT_RESIDENT >= 4
+    fams = [(t,) for t in range(1, eb.RESTRICT_RESIDENT + 2)]
+    e0, r0 = (_counter("bfs.restrict.evictions"),
+              _phase_count("hg.bfs.restrict"))
+    before = bfs_pull(snap, seeds, 2, link_types=fams[1])
+    subs = [eb.restricted_for(snap, f) for f in fams[:-1]]
+    assert _counter("bfs.restrict.evictions") == e0
+    assert obs.default_registry().get("bfs.restrict.resident").value == \
+        eb.RESTRICT_RESIDENT
+    assert eb.restricted_for(snap, fams[0]) is subs[0]  # a use: fams[1] is
+    gone = weakref.ref(subs[1])                         # now the oldest
+    gone_plan = weakref.ref(plans_for(subs[1]))
+    gone_dev = weakref.ref(subs[1]._pull_device["out_map"])
+    del subs
+    assert gone() is not None
+
+    eb.restricted_for(snap, fams[-1])  # one past the bound
+    gc.collect()
+    assert _counter("bfs.restrict.evictions") == e0 + 1
+    assert obs.default_registry().get("bfs.restrict.resident").value == \
+        eb.RESTRICT_RESIDENT
+    assert gone() is None and gone_plan() is None and gone_dev() is None
+    assert set(snap._pull_restricted) == \
+        {frozenset(f) for f in fams if f != fams[1]}
+    assert _phase_count("hg.bfs.restrict") == r0 + len(fams)
+
+    # it comes back: rebuilt, and the same answer
+    after = bfs_pull(snap, seeds, 2, link_types=fams[1])
+    assert _phase_count("hg.bfs.restrict") == r0 + len(fams) + 1
+    assert _counter("bfs.restrict.evictions") == e0 + 2
+    assert np.array_equal(np.asarray(before.visited_t),
+                          np.asarray(after.visited_t))
+    assert np.array_equal(before.edges_touched, after.edges_touched)
+    assert np.array_equal(np.asarray(before.reach_counts),
+                          np.asarray(after.reach_counts))
+
+
+def test_a_three_step_match_beside_a_typed_traversal_evicts_nothing():
+    snap = random_snapshot(400, 300, 4, seed=22, n_types=8)
+    seeds = np.arange(0, 32, dtype=np.int32)
+    e0, r0 = (_counter("bfs.restrict.evictions"),
+              _phase_count("hg.bfs.restrict"))
+    for _ in range(3):
+        bfs_pull(snap, seeds, 3, link_types=(1, 2))
+        eb.path_match(snap, seeds, [(3,), (4, 5), (6,)])
+    assert _counter("bfs.restrict.evictions") == e0
+    assert _phase_count("hg.bfs.restrict") == r0 + 4
+    assert len(snap._pull_restricted) == 4
