@@ -47,6 +47,11 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   pre-composed with stage 1's output map on host, so link-space results
   are consumed directly without materializing a per-link destination
   array.
+- a hop ends in an update of the state from the stage buffer
+  (``_visited_update``, a match's ``_frontier_replace``), over the row
+  blocks the hop's plan can reach and no other (``_active_blocks``: a row
+  is reached only if its atom has an incidence set, so a store that lays
+  entities out before links folds the entities' blocks alone).
 - per-seed edge counts (the benchmark numerator) are one exact pass over
   the bitmap a seed block (``_deg_sum``: bit-unpack, weight by degree and
   sum in ``int32``, fused on the vector unit) — no gathers.
@@ -886,52 +891,122 @@ def _stage_upper(lvl0, levels, widths, n_last, chunk):
     return _upper_levels(buf, levels, widths, sizes, n0, chunk)
 
 
-def _fold_rows(state, reach_chunks, out_map, n_atoms, combine):
-    """``state[v] = combine(state[v], reach_chunks[out_map[v]])`` for every
-    row, folded in row blocks so no second (n_pad, Kw) array materializes
-    while the stage buffer is alive (fori_loop carries alias in place);
-    the dummy row (``n_atoms``) is zeroed last."""
-    n_pad, Kw = state.shape
-    ub = 1 << 18
-    n_full = n_pad // ub
+#: Rows of the bitmap a step of an update's loop folds, and the grain at
+#: which a plan says which rows a hop can reach (``_active_blocks``). A
+#: constant, not a knob: read on the chip at the 10M-atom cells' shapes
+#: (``benchmarks/tests/update_blocks_probe.py``; PERF.md section 6, PR 32).
+#: A pass over the cells' listed fifth took 23.3 / 23.2 / 23.1 / 27.5 /
+#: 32.3 ms at 2^14 … 2^18 rows a block, and with EVERY block listed 166 /
+#: 165 / 165 / 181 / 211 ms where the counted loop of 2^18-row blocks it
+#: replaces took 183: flat up to 2^16, the fewest trips among the flat.
+UPDATE_ROWS = 1 << 16
 
-    def fold(nxt, start, rows):
-        cur = jax.lax.dynamic_slice(nxt, (start, 0), (rows, Kw))
-        sl = jax.lax.dynamic_slice(out_map, (start,), (rows,))
+
+class _UpdateRows(NamedTuple):
+    """What an update is handed of a plan, as one argument: where each row
+    of the bitmap reads the stage buffer, and the row blocks worth folding.
+    The list has one slot a block of the bitmap, so a program's shapes
+    follow ``n_pad`` alone and never the graph."""
+
+    out_map: jax.Array   # (n_pad,) int32 into reach_chunks, → its zero row
+    starts: jax.Array    # (blocks,) int32: the listed blocks' first rows
+    n_listed: jax.Array  # () int32: what follows them in starts is unread
+
+
+def _block_rows(n_pad: int, block_rows: int = UPDATE_ROWS) -> int:
+    return min(block_rows, n_pad)
+
+
+def _active_blocks(plans: PullBFSPlans) -> np.ndarray:
+    """``(blocks,) bool``: the row blocks of the bitmap in which a hop over
+    ``plans`` can reach a row at all — some ``out_map[v]`` is not the stage
+    buffer's zero row, which is where the dummy row always points. A row
+    is reached only if its atom has an incidence set (under the plan's
+    link predicate), so in a store that lays entities out before links
+    these are the entities' blocks, a fifth of DBpedia's shape."""
+    reached = plans.out_map != plans.out_map[plans.n_atoms]
+    return np.logical_or.reduceat(
+        reached, np.arange(0, plans.n_pad, _block_rows(plans.n_pad)))
+
+
+def _blocks_of(rows: np.ndarray, n_pad: int) -> np.ndarray:
+    """``(blocks,) bool``: the row blocks these rows of the bitmap lie in."""
+    ub = _block_rows(n_pad)
+    blocks = np.zeros(-(-n_pad // ub), dtype=bool)
+    blocks[np.asarray(rows, dtype=np.int64) // ub] = True
+    return blocks
+
+
+def _listed(out_map: jax.Array, blocks: np.ndarray,
+            block_rows: int = UPDATE_ROWS) -> _UpdateRows:
+    """The update's argument for ``out_map`` over the row blocks ``blocks``
+    marks."""
+    first = np.flatnonzero(blocks) * _block_rows(out_map.shape[0], block_rows)
+    starts = np.zeros(len(blocks), dtype=np.int32)
+    starts[: len(first)] = first
+    return _UpdateRows(out_map, jnp.asarray(starts),
+                       jnp.asarray(np.int32(len(first))))
+
+
+def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
+               block_rows: int = UPDATE_ROWS):
+    """``state[v] = combine(state[v], reach_chunks[out_map[v]])`` for every
+    row of the LISTED row blocks and no other, folded a block at a time so
+    no second (n_pad, Kw) array materializes while the stage buffer is
+    alive (the loop's carry aliases in place, whatever its trip count);
+    the dummy row (``n_atoms``) is zeroed last. The bitmap's ragged last
+    block is folded from ``n_pad - block_rows``: the rows it shares with
+    the block before are folded twice in one pass at most, and both
+    ``combine``s give the same row both times."""
+    n_pad, Kw = state.shape
+    ub = _block_rows(n_pad, block_rows)
+
+    def fold(i, nxt):
+        start = jnp.minimum(rows.starts[i], n_pad - ub)
+        cur = jax.lax.dynamic_slice(nxt, (start, 0), (ub, Kw))
+        sl = jax.lax.dynamic_slice(rows.out_map, (start,), (ub,))
         return jax.lax.dynamic_update_slice(
             nxt, combine(cur, reach_chunks[sl]), (start, 0)
         )
 
-    nxt = (jax.lax.fori_loop(0, n_full, lambda i, v: fold(v, i * ub, ub),
-                             state)
-           if n_full else state)
-    tail = n_pad - n_full * ub
-    if tail:
-        nxt = fold(nxt, n_full * ub, tail)
+    nxt = jax.lax.fori_loop(0, rows.n_listed, fold, state)
     return nxt.at[n_atoms].set(jnp.uint32(0))
 
 
 def _update_shapes():
     return (hgverify.sds((64, 1), "uint32"), hgverify.sds((9, 1), "uint32"),
-            hgverify.sds((64,), "int32"), hgverify.sds((), "int32"))
+            _UpdateRows(hgverify.sds((64,), "int32"),
+                        hgverify.sds((1,), "int32"),
+                        hgverify.sds((), "int32")),
+            hgverify.sds((), "int32"))
 
 
 @hgverify.entry(shapes=_update_shapes, donate=True)
 @partial(jax.jit, donate_argnums=(0,))  # visited aliases the output
 @_program("hg_bfs_visited_update", "hg.bfs.visited_update")
-def _visited_update(visited, reach_chunks, out_map, n_atoms):
-    """A traversal's hop ends here: visited | reach_chunks[out_map]."""
-    return _fold_rows(visited, reach_chunks, out_map, n_atoms,
+def _visited_update(visited, reach_chunks, rows, n_atoms):
+    """A traversal's hop ends here: visited | reach_chunks[out_map], over
+    the hop's plan's active blocks (``rows``). A row outside them would OR
+    in the zero row: what it holds — a seed's own bit, an earlier hop's
+    bits — is left where it is."""
+    return _fold_rows(visited, reach_chunks, rows, n_atoms,
                       lambda cur, reached: cur | reached)
 
 
 @hgverify.entry(shapes=_update_shapes, donate=True)
 @partial(jax.jit, donate_argnums=(0,))  # the old frontier's buffer is reused
 @_program("hg_bfs_frontier_replace", "hg.bfs.frontier_replace")
-def _frontier_replace(frontier, reach_chunks, out_map, n_atoms):
+def _frontier_replace(frontier, reach_chunks, rows, n_atoms):
     """A match's step ends here: the new state IS reach_chunks[out_map],
-    written into the donated old frontier, of which no bit is read."""
-    return _fold_rows(frontier, reach_chunks, out_map, n_atoms,
+    written into the donated old frontier, of which no bit is read.
+
+    Invariant: every row outside the new frontier is zero on the way out,
+    PROVIDED ``rows`` lists every block in which the old frontier holds a
+    bit beside the step's plan's active blocks (``_bfs_pull_device`` keeps
+    that account). Inside a listed block a row nothing reaches reads the
+    zero row and is cleared; an unlisted block is neither read nor
+    written, and was zero."""
+    return _fold_rows(frontier, reach_chunks, rows, n_atoms,
                       lambda cur, reached: reached)
 
 
@@ -1071,9 +1146,18 @@ def _bfs_pull_device(
     """The hop chain of one seed block. ``update`` is ``_visited_update``
     (a traversal: the state is the visited set, and grows) or
     ``_frontier_replace`` (a match: the state is the newest step's end
-    points alone, and ``count_edges`` has no meaning)."""
+    points alone, and ``count_edges`` has no meaning).
+
+    Either update folds the row blocks it is handed and no other. A
+    traversal's gets the hop's plan's active blocks. A match's has to leave
+    every row outside the new frontier zero, so it gets those AND the
+    blocks in which the state it replaces can hold a bit (``held``): the
+    seeds' own after no step, step 1's plan's active blocks after a sparse
+    first step (its pairs are targets of admitted links, rows with an
+    incidence set under ``F_1``), the step's plan's after a dense one."""
     grows = update is _visited_update
     n_atoms_dev = jnp.int32(n_atoms)
+    reg = default_registry()
 
     def bitmap_of(start: np.ndarray) -> jax.Array:
         with phase("hg.bfs.seeds_upload"):
@@ -1096,6 +1180,8 @@ def _bfs_pull_device(
         sl = rule()
         visited = bitmap_of(seeds if sl is None
                             else np.full_like(seeds, n_atoms))
+        held = (_blocks_of(seeds[seeds < n_atoms], n_pad) if sl is None
+                else hops[0].dev["blocks"])
     # S entering the block's last hop, the one Σ deg that `total_edges`
     # reads (it telescopes over the hops before): one entry, or none where
     # nothing counts edges or no hop runs
@@ -1142,10 +1228,20 @@ def _bfs_pull_device(
             reach_chunks = _stage_upper(lvl0b, levels2[n2:], widths2[n2:],
                                         n2_last, chunk)
             del lvl0b
-            visited = update(visited, reach_chunks, dev["out_map"],
-                             n_atoms_dev)
+            if grows:
+                listed, rows = dev["blocks"], dev["rows"]
+            else:
+                listed = dev["blocks"] | held
+                rows = _listed(dev["out_map"], listed)
+                held = dev["blocks"]
+            visited = update(visited, reach_chunks, rows, n_atoms_dev)
             del reach_chunks
             jax.block_until_ready(visited)
+            # what the update's loop folded beside the whole bitmap: their
+            # ratio says how far the plan's block list engages
+            reg.counter("bfs.update.rows_visited").inc(
+                int(listed.sum()) * _block_rows(n_pad))
+            reg.counter("bfs.update.rows_total").inc(n_pad)
     with phase("hg.bfs.reach_counts"):  # the dispatch: nothing syncs here
         reach = _reach_counts(visited)
     return visited, s_ins, reach
@@ -1358,7 +1454,11 @@ def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:
                                  for l in plans.stage2_levels),
                 "out_map": jnp.asarray(plans.out_map),
                 "inc_deg": jnp.asarray(plans.inc_deg),
+                # the row blocks a hop over this plan can reach, on the
+                # host: derived here and not kept in the sidecar
+                "blocks": _active_blocks(plans),
             }
+            cache["rows"] = _listed(cache["out_map"], cache["blocks"])
             # the first stage needs them all: waiting here moves no work,
             # it puts the upload's seconds under the upload's name
             jax.block_until_ready(cache)

@@ -387,13 +387,23 @@ def test_a_scan_step_moves_the_same_indices_at_every_class(
         assert whole - max(w, whole // 8) < n <= whole
 
 
+def _listed_rows(out_map, blocks=None):
+    """An update's third argument over ``out_map``: every row block of
+    the bitmap listed, or the blocks numbered in ``blocks`` alone."""
+    n_blocks = -(-len(out_map) // min(eb.UPDATE_ROWS, len(out_map)))
+    listed = (np.ones(n_blocks, bool) if blocks is None
+              else np.isin(np.arange(n_blocks), blocks))
+    return eb._listed(jnp.asarray(out_map), listed)
+
+
 @pytest.mark.parametrize("case", ["no_fresh_bit", "every_row_fresh",
                                   "zero_row"])
 def test_visited_update_matches_numpy(case):
-    """One full block of the row loop and a tail; the dummy row
-    (``n_atoms``) is zero on the way out whatever it was given."""
+    """Every block listed — one full block of the row loop and the ragged
+    one after it; the dummy row (``n_atoms``) is zero on the way out
+    whatever it was given."""
     r = np.random.default_rng(len(case))
-    n_pad, kw, n_chunks = (1 << 18) + 40, 1, 50
+    n_pad, kw, n_chunks = eb.UPDATE_ROWS + 40, 1, 50
     n_atoms = n_pad - 3
     reach = r.integers(1, 1 << 32, size=(n_chunks + 1, kw), dtype=np.uint32)
     reach[n_chunks] = 0
@@ -408,12 +418,57 @@ def test_visited_update_matches_numpy(case):
     want = visited | reach[out_map]
     want[n_atoms] = 0
     got = np.asarray(eb._visited_update(
-        jnp.asarray(visited), jnp.asarray(reach), jnp.asarray(out_map),
+        jnp.asarray(visited), jnp.asarray(reach), _listed_rows(out_map),
         jnp.int32(n_atoms)))
     assert np.array_equal(got, want)
     if case != "every_row_fresh":
         keep = np.arange(n_pad) != n_atoms
         assert np.array_equal(got[keep], visited[keep])
+
+
+#: block numbers listed, of a bitmap of four whole row blocks and a ragged
+#: fifth of 40 rows that holds the dummy row
+BLOCK_LISTS = {
+    "prefix": [0, 1],
+    "scattered": [3, 0],
+    "none": [],
+    "all": [0, 1, 2, 3, 4],
+    "clamped_last": [4],            # folded from n_pad - UPDATE_ROWS
+    "dummy_row_unlisted": [1, 2],
+}
+
+
+@pytest.mark.parametrize("blocks", list(BLOCK_LISTS))
+@pytest.mark.parametrize("update", ["_visited_update", "_frontier_replace"])
+def test_update_folds_the_listed_blocks_and_no_other(update, blocks):
+    """Both updates over random rows and a random old state, against
+    numpy: a row of a listed block is folded (the ragged last block from
+    ``n_pad - UPDATE_ROWS``, so with the rows before it that share its
+    slice), every other row is handed back as it came, the dummy row
+    zero wherever it lies."""
+    ub = eb.UPDATE_ROWS
+    r = np.random.default_rng(sorted(BLOCK_LISTS).index(blocks))
+    n_pad, kw, n_chunks = 4 * ub + 40, 1, 50
+    n_atoms = n_pad - 3
+    reach = r.integers(1, 1 << 32, size=(n_chunks + 1, kw), dtype=np.uint32)
+    reach[n_chunks] = 0
+    out_map = r.integers(0, n_chunks + 1, size=n_pad).astype(np.int32)
+    state = r.integers(0, 1 << 32, size=(n_pad, kw), dtype=np.uint32)
+    folded = np.zeros(n_pad, bool)
+    for b in BLOCK_LISTS[blocks]:
+        start = min(b * ub, n_pad - ub)
+        folded[start : start + ub] = True
+    new = reach[out_map]
+    want = np.where(folded[:, None],
+                    state | new if update == "_visited_update" else new,
+                    state)
+    want[n_atoms] = 0
+    rows = _listed_rows(out_map, BLOCK_LISTS[blocks])
+    assert rows.starts.shape == (5,)  # a slot a block, whatever is listed
+    assert int(rows.n_listed) == len(BLOCK_LISTS[blocks])
+    got = np.asarray(getattr(eb, update)(
+        jnp.asarray(state), jnp.asarray(reach), rows, jnp.int32(n_atoms)))
+    assert np.array_equal(got, want)
 
 
 # ------------------------------------------------------ the sparse first hop
@@ -1008,11 +1063,11 @@ def test_the_first_step_rule_reads_step_ones_plan(monkeypatch):
 @pytest.mark.parametrize("case", ["no_fresh_bit", "every_row_fresh",
                                   "zero_row"])
 def test_frontier_replace_matches_numpy(case):
-    """``_visited_update``'s cases: one full block of the row loop and a
-    tail; the new state is the reached rows alone, whatever the old
-    frontier held, and the dummy row is zero on the way out."""
+    """``_visited_update``'s cases, every block listed: the new state is
+    the reached rows alone, whatever the old frontier held, and the dummy
+    row is zero on the way out."""
     r = np.random.default_rng(len(case))
-    n_pad, kw, n_chunks = (1 << 18) + 40, 1, 50
+    n_pad, kw, n_chunks = eb.UPDATE_ROWS + 40, 1, 50
     n_atoms = n_pad - 3
     reach = r.integers(1, 1 << 32, size=(n_chunks + 1, kw), dtype=np.uint32)
     reach[n_chunks] = 0
@@ -1027,7 +1082,7 @@ def test_frontier_replace_matches_numpy(case):
     want = reach[out_map]
     want[n_atoms] = 0
     got = np.asarray(eb._frontier_replace(
-        jnp.asarray(frontier), jnp.asarray(reach), jnp.asarray(out_map),
+        jnp.asarray(frontier), jnp.asarray(reach), _listed_rows(out_map),
         jnp.int32(n_atoms)))
     assert np.array_equal(got, want)
     if case == "zero_row":
@@ -1120,3 +1175,185 @@ def test_a_three_step_match_beside_a_typed_traversal_evicts_nothing():
     assert _counter("bfs.restrict.evictions") == e0
     assert _phase_count("hg.bfs.restrict") == r0 + 4
     assert len(snap._pull_restricted) == 4
+
+
+# ------------------------------------------- the update's active row blocks
+#
+# An update folds the row blocks it is handed and no other
+# (``UPDATE_ROWS`` rows a block). The graphs here are wide enough to have
+# several: entities in two ranges more than a block apart, atoms nothing
+# touches between them, the links — which nothing targets — last, in the
+# ragged block that also holds the dummy row.
+
+
+def spread_snapshot(seed, n_links=3000):
+    """(snapshot, range A, range B, links): a link's type says where its
+    targets lie — 1: in A alone, 2: in B alone, 3: in both ranges."""
+    ub = eb.UPDATE_ROWS
+    r = np.random.default_rng(seed)
+    a = np.arange(100, 1100)
+    b = np.arange(2 * ub + 50, 2 * ub + 1050)
+    n = 3 * ub + 5000
+    links = np.arange(n - n_links, n)
+    type_of = np.zeros(n, dtype=np.int32)
+    is_link = np.zeros(n, dtype=bool)
+    is_link[links] = True
+    type_of[links] = 1 + r.integers(0, 3, size=n_links)
+    arity = r.integers(2, 5, size=n_links)
+    offsets = np.zeros(n + 1, dtype=np.int64)
+    offsets[links[0] + 1 :] = np.cumsum(arity)
+    kind = np.repeat(type_of[links], arity)
+    pick = r.integers(0, len(a), size=len(kind))
+    from_b = (kind == 2) | ((kind == 3) & (r.random(len(kind)) < 0.5))
+    flat = np.where(from_b, b[pick], a[pick])
+    snap = CSRSnapshot.from_tables(type_of, is_link, offsets, flat)
+    return snap, a, b, links
+
+
+@pytest.fixture(scope="module")
+def spread():
+    return spread_snapshot(61)
+
+
+def _blocks_with(rows):
+    return sorted({int(v) // eb.UPDATE_ROWS for v in rows})
+
+
+@pytest.mark.parametrize("graph", ["random", "two_ranges", "one_family"])
+def test_plan_block_list_covers_every_row_a_hop_can_reach(graph, spread):
+    """The list derived from a built plan holds a block if and only if
+    some row of it reads another row of the stage buffer than the zero
+    row; what goes up beside ``out_map`` is that list, a slot a block."""
+    if graph == "random":
+        snap = random_snapshot(2 * eb.UPDATE_ROWS + 999, 5000, 4, seed=62)
+    else:
+        snap = spread[0]
+        if graph == "one_family":  # type-2 links: targets in B alone
+            snap = eb.restricted_for(snap, (2,))
+    plans = plans_for(snap)
+    ub = eb.UPDATE_ROWS
+    zero_row = plans.out_map[plans.n_atoms]
+    assert zero_row == plans.out_map.max()
+    reached = np.flatnonzero(plans.out_map != zero_row)
+    has_incidence = np.flatnonzero(
+        np.diff(snap.inc_offsets[: snap.num_atoms + 1].astype(np.int64)))
+    assert np.array_equal(reached, has_incidence)
+    blocks = eb._active_blocks(plans)
+    assert blocks.shape == (-(-plans.n_pad // ub),) and blocks.dtype == bool
+    assert np.flatnonzero(blocks).tolist() == _blocks_with(reached)
+    if graph == "two_ranges":
+        assert blocks.tolist() == [True, False, True, False]
+    if graph == "one_family":
+        assert blocks.tolist() == [False, False, True, False]
+    dev = eb._device_plans(snap, plans)
+    assert np.array_equal(dev["blocks"], blocks)
+    n = int(dev["rows"].n_listed)
+    assert dev["rows"].out_map is dev["out_map"]
+    assert dev["rows"].starts.shape == blocks.shape
+    assert np.asarray(dev["rows"].starts)[:n].tolist() == \
+        (np.flatnonzero(blocks) * ub).tolist()
+
+
+class _UpdateRowsCounted:
+    """(rows folded, rows of the bitmaps) by the updates dispatched inside
+    the ``with`` block, from the program's two counters."""
+
+    NAMES = ("bfs.update.rows_visited", "bfs.update.rows_total")
+
+    def __enter__(self):
+        self._t0 = [_counter(n) for n in self.NAMES]
+        return self
+
+    def __exit__(self, *exc):
+        self.visited, self.total = (
+            _counter(n) - t for n, t in zip(self.NAMES, self._t0))
+
+
+@pytest.mark.parametrize("typed", [False, True])
+@pytest.mark.parametrize("first_hop", ["sparse", "dense"])
+def test_bfs_pull_where_seeds_lie_outside_every_active_block(
+        spread, first_hop, typed, monkeypatch):
+    """Seeds that are links, atoms nothing touches and the last atom
+    before the dummy row, beside entities of both ranges: a row outside
+    the plan's active blocks is never folded, keeps its own bit and is
+    counted; the answers are the host's, and the counters say two of the
+    bitmap's four blocks were folded a dense hop (one under the family
+    whose links lie in B alone)."""
+    snap, a, b, links = spread
+    fam = (2,) if typed else None
+    seeds = np.concatenate([
+        a[:9], b[:9], links[:6], [5, eb.UPDATE_ROWS + 7, links[-1]],
+        [snap.num_atoms] * 5]).astype(np.int32)
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_hop == "sparse" else 1 << 62)
+    hops = 3
+    with _Sides() as ran, _UpdateRowsCounted() as rows:
+        res = bfs_pull(snap, seeds, hops, link_types=fam)
+    assert ran.dense == (hops - 1 if first_hop == "sparse" else hops)
+    n_pad = plans_for(snap).n_pad
+    assert rows.total == ran.dense * n_pad
+    assert rows.visited == ran.dense * (1 if typed else 2) * eb.UPDATE_ROWS
+    got = visited_rows(res, snap.num_atoms)
+    for k, s in enumerate(seeds.tolist()):
+        want, edges = host_bfs(snap, s, hops, family=fam)
+        if s == snap.num_atoms:
+            want, edges = set(), 0
+        assert set(got[k].tolist()) == want, f"seed {s} (column {k})"
+        assert res.edges_touched[k] == edges
+        assert int(res.reach_counts[k]) == len(want)
+    for k in range(18, 27):  # the links and the untouched atoms: themselves
+        assert got[k].tolist() == [int(seeds[k])]
+
+
+#: paths over ``spread_snapshot``'s types, by what the active blocks do
+#: from step to step: block 0 holds A, block 2 holds B
+SPREAD_PATHS = {
+    "moves": [(1,), (2,)],            # A, then B alone: nothing matches
+    "moves_back": [(2,), (1,)],
+    "grows": [(1,), (3,)],            # A, then A and B
+    "shrinks": [(3,), (1,)],          # A and B, then A: B is cleared
+    "there_and_back": [(1,), (3,), (2,)],
+    "back_and_there": [(2,), (3,), (1,)],
+    "every_link_then_b": [None, (2,), (3,)],
+}
+
+
+@pytest.mark.parametrize("first_step", ["sparse", "dense"])
+@pytest.mark.parametrize("path", list(SPREAD_PATHS))
+def test_path_match_where_the_active_blocks_differ_by_step(
+        spread, path, first_step, monkeypatch):
+    """A match's update gets its step's active blocks AND those in which
+    the frontier it replaces can hold a bit — the seeds' own before a
+    dense first step (links, untouched atoms: zero in ``X_1``), step 1's
+    plan's after a sparse one, the previous step's after a dense one — so
+    every row outside the new frontier is zero, whichever way the blocks
+    move; the counters say which blocks were folded."""
+    snap, a, b, links = spread
+    steps = SPREAD_PATHS[path]
+    seeds = np.concatenate([
+        a[:12], b[:12], links[:4], [5, eb.UPDATE_ROWS + 7, links[-1]],
+        [snap.num_atoms]]).astype(np.int32)
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_step == "sparse" else 1 << 62)
+    with _Sides() as ran, _UpdateRowsCounted() as rows:
+        res = eb.path_match(snap, seeds, steps)
+    n_dense = len(steps) - (first_step == "sparse")
+    assert (ran.sparse, ran.dense) == (len(steps) - n_dense, n_dense)
+    _assert_matches_reference(_SnapshotGraph(snap), snap.num_atoms, seeds,
+                              steps, res)
+
+    def active(family):
+        sub = snap if family is None else eb.restricted_for(snap, family)
+        return eb._active_blocks(plans_for(sub))
+
+    # the seeds lie in blocks 0 (A, atom 5), 1, 2 (B) and 3 (the links)
+    held = (active(steps[0]) if first_step == "sparse"
+            else np.ones(4, dtype=bool))
+    folded = 0
+    for family in steps[len(steps) - n_dense:]:
+        folded += int((active(family) | held).sum())
+        held = active(family)
+    assert rows.visited == folded * eb.UPDATE_ROWS
+    assert rows.total == n_dense * plans_for(snap).n_pad
+    bitmap = np.asarray(res.frontier_t)
+    assert not bitmap[np.repeat(~held, eb.UPDATE_ROWS)[: len(bitmap)]].any()

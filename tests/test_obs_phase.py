@@ -220,6 +220,32 @@ def test_bfs_pull_leaves_every_phase_with_the_right_counts():
     assert grew[DEG_SUM_PHASE] == 3 and grew["hg.bfs.edges_to_host"] == 4
 
 
+UPDATE_COUNTERS = ("bfs.update.rows_visited", "bfs.update.rows_total")
+
+
+@pytest.mark.parametrize("operator", ["bfs_pull", "path_match"])
+def test_update_counters_grow_once_a_dense_hop(operator):
+    """Beside the phases: what an update's loop folded and the bitmap's
+    rows, from numbers the host holds, once an update dispatch — a sparse
+    first hop dispatches none. On a graph of one row block the two are
+    the bitmap's rows both."""
+    def read():
+        got = [obs.default_registry().get(n) for n in UPDATE_COUNTERS]
+        return [0 if c is None else c.value for c in got]
+
+    snap = _small_snapshot(11)
+    n_pad = eb.plans_for(snap).n_pad
+    assert n_pad <= eb.UPDATE_ROWS
+    seeds = np.arange(16, dtype=np.int32)
+    before = read()
+    if operator == "bfs_pull":
+        eb.bfs_pull(snap, seeds, 3)        # sparse, dense, dense
+    else:
+        eb.path_match(snap, seeds, [None] * 3)
+    assert [now - was for now, was in zip(read(), before)] == \
+        [2 * n_pad, 2 * n_pad]
+
+
 def _u32(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.uint32)
 
@@ -227,6 +253,10 @@ def _u32(*shape):
 def _i32(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32)
 
+
+#: either update's: a bitmap of one row block, listed or not
+_UPDATE_ARGS = (_u32(64, 1), _u32(9, 1),
+                eb._UpdateRows(_i32(64), _i32(1), _i32()), _i32())
 
 #: attribute -> (module name, scopes in its operations' paths, args, statics)
 STAGE_PROGRAMS = {
@@ -249,7 +279,9 @@ STAGE_PROGRAMS = {
                      (_u32(32, 1), (_i32(16),)),
                      {"widths": (8,), "n_last": 16, "chunk": 4}),
     "_visited_update": ("hg_bfs_visited_update", ("hg.bfs.visited_update",),
-                        (_u32(64, 1), _u32(9, 1), _i32(64), _i32()), {}),
+                        _UPDATE_ARGS, {}),
+    "_frontier_replace": ("hg_bfs_frontier_replace",
+                          ("hg.bfs.frontier_replace",), _UPDATE_ARGS, {}),
     "_reach_counts": ("hg_bfs_reach_counts", ("hg.bfs.reach_counts",),
                       (_u32(64, 1),), {}),
 }
