@@ -230,6 +230,38 @@ def match_path(graph, start: HGHandle, link_predicates) -> set:
     return ends
 
 
+def shortest_path_length(
+    graph,
+    start: HGHandle,
+    goal: HGHandle,
+    generator: Optional[HGALGenerator] = None,
+    max_distance: Optional[int] = None,
+) -> int:
+    """How many hops from ``start`` to ``goal``: ``len(dijkstra(start,
+    goal, generator)) - 1`` at unit weights, 0 for ``start == goal``, -1
+    where there is no path or the shortest is longer than ``max_distance``
+    (:class:`HGBreadthFirstTraversal`'s cap). A forward search in that
+    traversal's order with a distance per atom — the least h at which the
+    traversal with ``max_distance = h`` yields ``goal``. Lengths only, no
+    predecessor map. The plain reference of ``ops.ellbfs.pair_distances``,
+    independent of it: one ball, from ``start`` alone."""
+    gen = generator or SimpleALGenerator(graph)
+    start, goal = int(start), int(goal)
+    dist = {start: 0}
+    q: deque[int] = deque([start])
+    while q:
+        atom = q.popleft()
+        if atom == goal:
+            return dist[atom]
+        if max_distance is not None and dist[atom] >= max_distance:
+            continue
+        for _, nbr in gen.generate(atom):
+            if nbr not in dist:
+                dist[nbr] = dist[atom] + 1
+                q.append(nbr)
+    return -1
+
+
 def dijkstra(
     graph,
     start: HGHandle,

@@ -9,9 +9,11 @@ from hypergraphdb_tpu.ops.bitfrontier import (
     unpack_visited,
 )
 from hypergraphdb_tpu.ops.ellbfs import (
+    PairDistResult,
     PathMatchResult,
     PullBFSResult,
     bfs_pull,
+    pair_distances,
     path_match,
     visited_rows,
 )
@@ -40,6 +42,7 @@ __all__ = [
     "AOTCache",
     "CSRSnapshot",
     "DeviceSnapshot",
+    "PairDistResult",
     "PathMatchResult",
     "PinnedView",
     "PullBFSResult",
@@ -51,6 +54,7 @@ __all__ = [
     "bfs_pull",
     "collect_pattern",
     "execute_pattern",
+    "pair_distances",
     "path_match",
     "plan_pattern",
     "visited_rows",

@@ -21,7 +21,8 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   known exactly, so the seeds' own neighbourhood (incident links → their
   targets) is expanded on the host from the snapshot's CSR arrays and its
   bits are placed into the seed bitmap — work ∝ Σ deg(seeds), not ∝ E
-  (``_sparse_first_hop``; the sparse step of a direction-optimising BFS).
+  (``_seed_pairs``, ``_sparse_first_hop``; the sparse step of a
+  direction-optimising BFS).
   Only seeds whose neighbourhood is a sizeable share of the plan (hubs on
   a small graph) send hop 1 down the pull chain too; the rule reads the
   input alone (``SPARSE_SHARE``).
@@ -66,6 +67,15 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   host-only (``algorithms/traversals.py``): the sibling predicate, the
   ordered-link directions (preceding / succeeding targets, reverse order)
   and a predicate that differs by hop.
+- every operator runs ONE expansion step (:func:`_expand`: the sparse
+  placement, or stage 1 → stage 2's level 0 → its upper levels and the
+  operator's update): :func:`bfs_pull` H times on a visited set,
+  :func:`path_match` over a plan per step on a frontier, and
+  :func:`pair_distances` alternately on TWO balls, one grown from each
+  end of a pair, with a meet test (``_meet``: do the balls share a row,
+  column by column) after every expansion — HOW FAR apart, a length per
+  pair (``GraphClassics.dijkstra`` at unit weights), where the hop count
+  follows the data: a batch ends when its last pair is met or exhausted.
 
 Geometry note: each gather row is ``Kw = K/32`` uint32 words (32 lanes for
 K=1024). Gathers remain the dominant cost and are bound by the indices
@@ -949,7 +959,7 @@ def _listed(out_map: jax.Array, blocks: np.ndarray,
 
 
 def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
-               block_rows: int = UPDATE_ROWS):
+               block_rows: int = UPDATE_ROWS, grew: bool = False):
     """``state[v] = combine(state[v], reach_chunks[out_map[v]])`` for every
     row of the LISTED row blocks and no other, folded a block at a time so
     no second (n_pad, Kw) array materializes while the stage buffer is
@@ -957,20 +967,38 @@ def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
     the dummy row (``n_atoms``) is zeroed last. The bitmap's ragged last
     block is folded from ``n_pad - block_rows``: the rows it shares with
     the block before are folded twice in one pass at most, and both
-    ``combine``s give the same row both times."""
+    ``combine``s give the same row both times.
+
+    ``grew``: also return ``(Kw,) uint32``, the OR over the folded rows of
+    the bits the stage buffer holds and the state did not — bit k of word
+    w says column ``32 w + k`` gained a row. Both operands are at hand in
+    the fold, so it costs no pass of its own; without it the carry is the
+    state alone and the program is what it was."""
     n_pad, Kw = state.shape
     ub = _block_rows(n_pad, block_rows)
 
-    def fold(i, nxt):
+    def fold(i, carry):
+        nxt, new = carry
         start = jnp.minimum(rows.starts[i], n_pad - ub)
         cur = jax.lax.dynamic_slice(nxt, (start, 0), (ub, Kw))
         sl = jax.lax.dynamic_slice(rows.out_map, (start,), (ub,))
+        reached = reach_chunks[sl]
+        if grew:
+            new = new | _or_rows(reached & ~cur)
         return jax.lax.dynamic_update_slice(
-            nxt, combine(cur, reach_chunks[sl]), (start, 0)
-        )
+            nxt, combine(cur, reached), (start, 0)
+        ), new
 
-    nxt = jax.lax.fori_loop(0, rows.n_listed, fold, state)
-    return nxt.at[n_atoms].set(jnp.uint32(0))
+    nxt, new = jax.lax.fori_loop(
+        0, rows.n_listed, fold,
+        (state, jnp.zeros((Kw,), jnp.uint32) if grew else None))
+    nxt = nxt.at[n_atoms].set(jnp.uint32(0))
+    return (nxt, new) if grew else nxt
+
+
+def _or_rows(x: jax.Array) -> jax.Array:
+    """``(R, Kw) → (Kw,)``: the OR of the rows, words still packed."""
+    return jax.lax.reduce(x, jnp.uint32(0), jax.lax.bitwise_or, (0,))
 
 
 def _update_shapes():
@@ -1008,6 +1036,60 @@ def _frontier_replace(frontier, reach_chunks, rows, n_atoms):
     written, and was zero."""
     return _fold_rows(frontier, reach_chunks, rows, n_atoms,
                       lambda cur, reached: reached)
+
+
+@hgverify.entry(shapes=_update_shapes, donate=True)
+@partial(jax.jit, donate_argnums=(0,))  # the ball aliases the output
+@_program("hg_bfs_ball_update", "hg.bfs.visited_update")
+def _ball_update(ball, reach_chunks, rows, n_atoms):
+    """A pair search's expansion ends here: ``_visited_update`` (the same
+    fold, under the same scope), and beside the grown ball the columns that
+    GREW, ``(Kw,) uint32`` — a column that did not has its whole component
+    (:func:`pair_distances`' exhaustion). A program of its own because the
+    traversal's update returns the bitmap alone and is left as it is."""
+    return _fold_rows(ball, reach_chunks, rows, n_atoms,
+                      lambda cur, reached: cur | reached, grew=True)
+
+
+#: Rows of the two bitmaps a step of the meet test's loop folds: one AND
+#: and one OR-fold the compiler fuses over the slices, nothing written but
+#: a row of words. Blocked as ``_bitdot`` is, for the CPU backend's sake
+#: (whole, it would hold ``fwd & bwd``, a third bitmap).
+MEET_ROWS = 1 << 16
+
+
+def _meet_words(fwd: jax.Array, bwd: jax.Array,
+                block_rows: int = MEET_ROWS) -> jax.Array:
+    n_pad, Kw = fwd.shape
+    block_rows = min(block_rows, n_pad)
+
+    # the last block's clamped start overlaps the block before (the
+    # pattern of ``_bitdot``): OR takes a row twice and says the same
+    def body(i, acc):
+        start = jnp.minimum(i * block_rows, n_pad - block_rows)
+        f = jax.lax.dynamic_slice(fwd, (start, 0), (block_rows, Kw))
+        b = jax.lax.dynamic_slice(bwd, (start, 0), (block_rows, Kw))
+        return acc | _or_rows(f & b)
+
+    return jax.lax.fori_loop(0, -(-n_pad // block_rows), body,
+                             jnp.zeros((Kw,), jnp.uint32))
+
+
+@hgverify.entry(shapes=lambda: (hgverify.sds((64, 1), "uint32"),
+                                hgverify.sds((64, 1), "uint32")))
+@jax.jit
+@_program("hg_bfs_meet", "hg.bfs.meet")
+def _meet(fwd: jax.Array, bwd: jax.Array) -> jax.Array:
+    """The meet test of a two-sided search: ``(n_pad, Kw)`` twice →
+    ``(Kw,) uint32``, word w = OR over the rows of ``fwd[r, w] & bwd[r,
+    w]`` — bit k of word w says the two balls of column ``32 w + k`` share
+    an atom. Bit-exact, nothing unpacked, nothing donated: the host reads
+    ``4 Kw`` bytes and looks at bits. Every row is folded (bound by bytes,
+    both bitmaps read once); a row outside the plan's active blocks holds
+    a seed's own bit at most, so folding those blocks alone would do
+    beside the host's ``s == t`` — PERF.md section 7 (PR 33) has why it
+    is not taken."""
+    return _meet_words(fwd, bwd)
 
 
 # Pairs a placement dispatch carries: the one shape `_sparse_hop` compiles at
@@ -1089,16 +1171,25 @@ def _seed_links(snap: CSRSnapshot, seeds: np.ndarray,
     return _SeedLinks(links, np.repeat(nz, deg[nz]), arity, deg)
 
 
-def _sparse_first_hop(visited: jax.Array, snap: CSRSnapshot,
-                      seeds: np.ndarray, sl: _SeedLinks,
-                      n_atoms: jax.Array, own_bits: bool) -> jax.Array:
-    """Every target of every link incident to seed k gets bit k: a
-    traversal's ``visited_0`` → ``visited_1``, a match's ``X_1`` onto an
-    empty bitmap. The pairs are made unique and sorted by row and go up in
-    SPARSE_BLOCK-wide blocks to one program. ``own_bits``: whether a seed's
-    own bit (among the pairs wherever the seed lies in a link) is placed —
-    not on a seed bitmap, which holds it (the placement adds, and no bit
-    may be added twice); on an empty one it is part of the answer."""
+class _SeedPairs(NamedTuple):
+    """The host's half of a sparse first hop (:func:`_seed_pairs`)."""
+
+    blocks: np.ndarray  # (2, n_blocks, SPARSE_BLOCK) int32: rows, columns
+    placed: np.ndarray  # (K,) bool: the columns that hold a pair
+
+
+def _seed_pairs(snap: CSRSnapshot, seeds: np.ndarray, sl: _SeedLinks,
+                own_bits: bool) -> _SeedPairs:
+    """Every target of every link incident to seed k is to get bit k: the
+    (row, column) pairs of a traversal's ``visited_0`` → ``visited_1``, of a
+    match's ``X_1`` onto an empty bitmap — unique, sorted by row, cut into
+    SPARSE_BLOCK-wide blocks for the one program that places them.
+    ``own_bits``: whether a seed's own bit (among the pairs wherever the
+    seed lies in a link) is placed — not on a seed bitmap, which holds it
+    (the placement adds, and no bit may be added twice); on an empty one it
+    is part of the answer. ``placed`` is the columns a pair falls in —
+    without ``own_bits`` the columns that GROW, which the host knows here
+    and no pass over the bitmap has to find."""
     rows = snap.tgt_flat[
         _segmented_ranges(snap.tgt_offsets[sl.links], sl.arity)
     ].astype(np.int64)
@@ -1107,14 +1198,22 @@ def _sparse_first_hop(visited: jax.Array, snap: CSRSnapshot,
         fresh = rows != seeds[ks]
         rows, ks = rows[fresh], ks[fresh]
     K = len(seeds)
+    placed = np.zeros(K, dtype=bool)
+    placed[ks] = True
     keys = np.unique(rows * K + ks)
     n_blocks = -(-len(keys) // SPARSE_BLOCK)
     pairs = np.zeros((2, n_blocks * SPARSE_BLOCK), dtype=np.int32)
     pairs[0] = snap.num_atoms  # pad pairs: (dummy row, column 0)
     pairs[:, : len(keys)] = np.divmod(keys, K)
-    pairs = pairs.reshape(2, n_blocks, SPARSE_BLOCK)
-    for b in range(n_blocks):
-        visited = _sparse_hop(visited, jnp.asarray(pairs[:, b]), n_atoms)
+    return _SeedPairs(pairs.reshape(2, n_blocks, SPARSE_BLOCK), placed)
+
+
+def _sparse_first_hop(visited: jax.Array, pairs: _SeedPairs,
+                      n_atoms: jax.Array) -> jax.Array:
+    """The device's half: the pairs go up block by block to one program."""
+    for b in range(pairs.blocks.shape[1]):
+        visited = _sparse_hop(visited, jnp.asarray(pairs.blocks[:, b]),
+                              n_atoms)
     return visited
 
 
@@ -1133,6 +1232,108 @@ def _hop_over(snap: CSRSnapshot) -> _Hop:
     return _Hop(snap, plans, _device_plans(snap, plans))
 
 
+def _columns(words) -> np.ndarray:
+    """``(Kw,) uint32`` read from the device → ``(K,) bool``, column
+    ``32 w + k`` from bit k of word w."""
+    words = np.asarray(words)
+    bits = (words[:, None] >> np.arange(WORD, dtype=np.uint32)) & 1
+    return bits.reshape(-1).astype(bool)
+
+
+def _first_hop_rule(hop: _Hop, seeds: np.ndarray) -> Optional[_SeedLinks]:
+    """The seeds' links if their first expansion over ``hop`` is sparse —
+    the rule reads the hop's own (restricted) plan — else None."""
+    return _seed_links(hop.snap, seeds,
+                       hop.plans.total_indices // SPARSE_SHARE)
+
+
+def _expand(
+    state: jax.Array,            # (n_pad, Kw): a visited set, a frontier, a ball
+    hop: _Hop,
+    sl,                          # the seeds' links, or already their pairs:
+                                 # this is their SPARSE first hop
+    update,                      # what ends a dense expansion: the operator's
+    *,
+    seeds: np.ndarray,           # the block's seeds (a sparse hop reads them)
+    n_atoms: jax.Array,          # () int32, on the device
+    chunk: int,
+    use_pallas: bool,
+    own_bits: bool = False,      # a sparse hop onto an EMPTY bitmap
+    listed: Optional[np.ndarray] = None,
+) -> tuple[jax.Array, object]:
+    """ONE expansion of ``state`` over ``hop``, the step every operator of
+    the chain calls: a traversal's hop, a match's step, one side of a pair
+    search. Sparse (``sl``: the rule's answer for a block's first hop, a
+    ``_SeedLinks``, or the ``_SeedPairs`` a caller has expanded them to
+    ahead of time) it is the seeds' own neighbourhood placed by
+    ``_sparse_hop``; else the
+    pull chain — stage 1, stage 2's level 0, its upper levels and
+    ``update`` — each step synced and the dead stage buffer dropped before
+    the next is dispatched.
+
+    ``listed``: the row blocks ``update`` folds where they are not the
+    hop's plan's own active blocks (a match's update, which has to clear
+    what the frontier it replaces holds). Returns the new state and what
+    the expansion knows of the columns that GREW, a host ``(K,) bool``:
+    after a sparse hop the columns of the host's own pairs
+    (``_seed_pairs``), after a dense one the words ``update`` returns
+    beside the state (``_ball_update``), None from an update that returns
+    the state alone."""
+    # one obs.phase per synced step, one a sparse hop and three a dense
+    # one: an expansion's seconds by stage in the default registry, and
+    # under a profiler the host span that a device idle gap is charged to
+    if sl is not None:
+        # around expansion, upload, dispatch and sync: its count beside
+        # hg.bfs.hop.stage1's says how often the rule took this side
+        with phase("hg.bfs.hop.sparse"):
+            pairs = (sl if isinstance(sl, _SeedPairs)
+                     else _seed_pairs(hop.snap, seeds, sl, own_bits))
+            state = _sparse_first_hop(state, pairs, n_atoms)
+            jax.block_until_ready(state)
+        return state, pairs.placed
+    _, plans, dev = hop
+    s1 = plans.stage1
+    levels1, levels2 = dev["levels1"], dev["levels2"]
+    widths2, n2 = plans.stage2_widths, plans.stage2_n_lvl0
+    n2_last = len(plans.stage2_levels[n2 - 1]) // widths2[n2 - 1]
+    with phase("hg.bfs.hop.stage1"):
+        live = _stage(state, levels1, s1.widths, s1.n_lvl0, chunk,
+                      use_pallas)
+        jax.block_until_ready(live)
+    with phase("hg.bfs.hop.stage2_lvl0"):
+        lvl0b = _stage_lvl0_consume(live, levels2[:n2], widths2[:n2],
+                                    chunk, use_pallas)
+        # the donations can't alias (shapes differ), so the host ref
+        # is what keeps each dead buffer resident — drop it AND sync
+        # before the next dispatch: async dispatch would let the
+        # allocator grab stage-upper's buffers while the consume step
+        # (and therefore `live`'s 4.1 GB) is still in flight. The sync
+        # costs one RTT per hop against multi-second hops.
+        del live
+        jax.block_until_ready(lvl0b)
+    with phase("hg.bfs.hop.stage2_upper_update"):
+        reach_chunks = _stage_upper(lvl0b, levels2[n2:], widths2[n2:],
+                                    n2_last, chunk)
+        del lvl0b
+        if listed is None:
+            listed, rows = dev["blocks"], dev["rows"]
+        else:
+            rows = _listed(dev["out_map"], listed)
+        out = update(state, reach_chunks, rows, n_atoms)
+        del reach_chunks
+        jax.block_until_ready(out)
+        # what the update's loop folded beside the whole bitmap: their
+        # ratio says how far the plan's block list engages
+        n_pad = state.shape[0]
+        reg = default_registry()
+        reg.counter("bfs.update.rows_visited").inc(
+            int(listed.sum()) * _block_rows(n_pad))
+        reg.counter("bfs.update.rows_total").inc(n_pad)
+    if isinstance(out, tuple):  # the state, and the words of what grew
+        return out[0], _columns(out[1])
+    return out, None
+
+
 def _bfs_pull_device(
     hops: Sequence[_Hop],    # one a hop, in order; all over one id space
     n_atoms: int,
@@ -1143,8 +1344,9 @@ def _bfs_pull_device(
     count_edges: bool = True,
     use_pallas: bool = False,
 ) -> tuple[jax.Array, list, jax.Array]:
-    """The hop chain of one seed block. ``update`` is ``_visited_update``
-    (a traversal: the state is the visited set, and grows) or
+    """The hop chain of one seed block: the state, the first hop's side and
+    H calls of :func:`_expand`. ``update`` is ``_visited_update`` (a
+    traversal: the state is the visited set, and grows) or
     ``_frontier_replace`` (a match: the state is the newest step's end
     points alone, and ``count_edges`` has no meaning).
 
@@ -1157,29 +1359,24 @@ def _bfs_pull_device(
     incidence set under ``F_1``), the step's plan's after a dense one."""
     grows = update is _visited_update
     n_atoms_dev = jnp.int32(n_atoms)
-    reg = default_registry()
-
-    def bitmap_of(start: np.ndarray) -> jax.Array:
-        with phase("hg.bfs.seeds_upload"):
-            start_dev = jnp.asarray(start)
-        return _seed_bitmap(start_dev, n_atoms_dev, n_pad)
+    expand = partial(_expand, update=update, seeds=seeds, n_atoms=n_atoms_dev,
+                     chunk=chunk, use_pallas=use_pallas)
 
     def rule() -> Optional[_SeedLinks]:  # it reads the FIRST hop's plan
-        return (_seed_links(hops[0].snap, seeds,
-                            hops[0].plans.total_indices // SPARSE_SHARE)
-                if hops else None)
+        return _first_hop_rule(hops[0], seeds) if hops else None
 
     if grows:
         # the rule's look at the seeds runs beside the bitmap's zero fill
-        visited = bitmap_of(seeds)
+        visited = _bitmap_of(seeds, n_atoms_dev, n_pad)
         sl = rule()
     else:
         # a match's X_1 holds a seed's bit only where the seed lies in an
         # admitted link: the sparse pairs go onto an EMPTY bitmap — the
         # seed bitmap of pad seeds, whose bits fall on the dummy row
         sl = rule()
-        visited = bitmap_of(seeds if sl is None
-                            else np.full_like(seeds, n_atoms))
+        visited = _bitmap_of(seeds if sl is None
+                             else np.full_like(seeds, n_atoms),
+                             n_atoms_dev, n_pad)
         held = (_blocks_of(seeds[seeds < n_atoms], n_pad) if sl is None
                 else hops[0].dev["blocks"])
     # S entering the block's last hop, the one Σ deg that `total_edges`
@@ -1187,64 +1384,31 @@ def _bfs_pull_device(
     # nothing counts edges or no hop runs
     s_ins: list = []
     if sl is not None:
-        # once a sparse hop, around expansion, upload, dispatch and sync:
-        # its count beside hg.bfs.hop.stage1's says how often the rule
-        # took this side
-        with phase("hg.bfs.hop.sparse"):
-            visited = _sparse_first_hop(visited, hops[0].snap, seeds, sl,
-                                        n_atoms_dev, own_bits=not grows)
-            jax.block_until_ready(visited)
+        visited, _ = expand(visited, hops[0], sl, own_bits=not grows)
         hops = hops[1:]
         if count_edges and not hops:
             s_ins.append(sl.deg)  # S_0 = deg(seed), which the host holds
-    # one obs.phase per synced step, three a hop and the degree sum once a
-    # block: a traversal's seconds by stage in the default registry, and
-    # under a profiler the host span that a device idle gap is charged to
-    for i, (_, plans, dev) in enumerate(hops):
-        s1 = plans.stage1
-        levels1, levels2 = dev["levels1"], dev["levels2"]
-        widths2, n2 = plans.stage2_widths, plans.stage2_n_lvl0
-        n2_last = len(plans.stage2_levels[n2 - 1]) // widths2[n2 - 1]
+    for i, hop in enumerate(hops):
         if count_edges and i == len(hops) - 1:
+            # the degree sum once a block, a phase of its own
             with phase("hg.bfs.hop.deg_sum"):
-                s_ins.append(_deg_sum(visited, dev["inc_deg"]))
+                s_ins.append(_deg_sum(visited, hop.dev["inc_deg"]))
                 jax.block_until_ready(s_ins[-1])
-        with phase("hg.bfs.hop.stage1"):
-            live = _stage(visited, levels1, s1.widths, s1.n_lvl0, chunk,
-                          use_pallas)
-            jax.block_until_ready(live)
-        with phase("hg.bfs.hop.stage2_lvl0"):
-            lvl0b = _stage_lvl0_consume(live, levels2[:n2], widths2[:n2],
-                                        chunk, use_pallas)
-            # the donations can't alias (shapes differ), so the host ref
-            # is what keeps each dead buffer resident — drop it AND sync
-            # before the next dispatch: async dispatch would let the
-            # allocator grab stage-upper's buffers while the consume step
-            # (and therefore `live`'s 4.1 GB) is still in flight. The sync
-            # costs one RTT per hop against multi-second hops.
-            del live
-            jax.block_until_ready(lvl0b)
-        with phase("hg.bfs.hop.stage2_upper_update"):
-            reach_chunks = _stage_upper(lvl0b, levels2[n2:], widths2[n2:],
-                                        n2_last, chunk)
-            del lvl0b
-            if grows:
-                listed, rows = dev["blocks"], dev["rows"]
-            else:
-                listed = dev["blocks"] | held
-                rows = _listed(dev["out_map"], listed)
-                held = dev["blocks"]
-            visited = update(visited, reach_chunks, rows, n_atoms_dev)
-            del reach_chunks
-            jax.block_until_ready(visited)
-            # what the update's loop folded beside the whole bitmap: their
-            # ratio says how far the plan's block list engages
-            reg.counter("bfs.update.rows_visited").inc(
-                int(listed.sum()) * _block_rows(n_pad))
-            reg.counter("bfs.update.rows_total").inc(n_pad)
+        listed = None
+        if not grows:
+            listed, held = hop.dev["blocks"] | held, hop.dev["blocks"]
+        visited, _ = expand(visited, hop, None, listed=listed)
     with phase("hg.bfs.reach_counts"):  # the dispatch: nothing syncs here
         reach = _reach_counts(visited)
     return visited, s_ins, reach
+
+
+def _bitmap_of(seeds: np.ndarray, n_atoms: jax.Array,
+               n_pad: int) -> jax.Array:
+    """The seed bitmap of one block (pad seeds: an empty one)."""
+    with phase("hg.bfs.seeds_upload"):
+        seeds_dev = jnp.asarray(seeds)
+    return _seed_bitmap(seeds_dev, n_atoms, n_pad)
 
 
 # ------------------------------------------------------------------ host API
@@ -1268,6 +1432,13 @@ def _check_k_block(k_block: int) -> None:
         )
 
 
+def _kernel_gathers(width: int) -> bool:
+    """4096-seed blocks (128-lane rows, the one width the kernel compiles
+    at) run the Pallas gather on a TPU; everything else keeps the XLA
+    gather (no width limits)."""
+    return width == _pg.ROW_WORDS * WORD and _pg.pallas_ok()
+
+
 def _seed_blocks(hops: Sequence[_Hop], snap: CSRSnapshot,
                  seeds: np.ndarray, update, chunk: int, k_block: int,
                  count_edges: bool) -> tuple[list, int]:
@@ -1285,15 +1456,11 @@ def _seed_blocks(hops: Sequence[_Hop], snap: CSRSnapshot,
     blocks = []
     for s in range(0, K_pad, k_block):
         block = seeds[s : s + k_block]
-        # 4096-seed blocks (128-lane rows, the one width the kernel
-        # compiles at) run the Pallas gather on a TPU; everything else
-        # keeps the XLA gather (no width limits)
-        use_pallas = (len(block) == _pg.ROW_WORDS * WORD
-                      and _pg.pallas_ok())
         blocks.append(
             _bfs_pull_device(
                 hops, snap.num_atoms, n_pad, block, update,
-                chunk=chunk, count_edges=count_edges, use_pallas=use_pallas,
+                chunk=chunk, count_edges=count_edges,
+                use_pallas=_kernel_gathers(len(block)),
             )
         )
     return blocks, K
@@ -1441,6 +1608,139 @@ def path_match(
                              _frontier_replace, chunk, k_block,
                              count_edges=False)
     return PathMatchResult(*_joined(blocks, K))
+
+
+class PairDistResult(NamedTuple):
+    dist: np.ndarray   # HOST (K,) int32 — hops from s_k to t_k, -1: none
+    expansions: int    # ball expansions the batch ran, over its blocks
+
+
+def pair_distances(
+    snap: CSRSnapshot,
+    sources: np.ndarray,
+    targets: np.ndarray,
+    max_hops: int,
+    link_types=None,
+    chunk: int = 1 << 19,
+    k_block: int = 1024,
+) -> PairDistResult:
+    """How far apart: the shortest-path LENGTH of every pair ``(sources[k],
+    targets[k])`` at once — ``GraphClassics.dijkstra`` at unit weights
+    (``algorithms/traversals.dijkstra``; the plain reference here is
+    ``traversals.shortest_path_length``), LDBC SNB Interactive IC13's
+    answer, a length per pair and not a set. With ``N_0(a) = {a}`` and
+    ``N_h(a)`` = :func:`bfs_pull`'s visited set after h hops from ``a``
+    (``link_types`` its link predicate, ``None`` every link),
+
+        dist[k] = min {h <= max_hops : t_k in N_h(s_k)},  -1 if none
+
+    so ``s_k == t_k`` gives 0, and a pair further apart than the cap gives
+    -1 as a pair with no path does (the reference's ``maxDistance``). An
+    end that is no atom (``snap.num_atoms``, the pad seed) gives -1. It
+    equals ``len(dijkstra(s_k, t_k, generator)) - 1`` wherever that is at
+    most ``max_hops``. Returns ``PairDistResult(dist, expansions)``:
+    ``dist`` a HOST (K,) int32, ``expansions`` how many ball expansions
+    the batch ran over its seed blocks. Departures from ``dijkstra``:
+    lengths only — the path itself (its predecessor map) is not built;
+    unit weights only; and, as everywhere on the device, the adjacency is
+    the symmetric one (two atoms are neighbours iff an admitted link holds
+    both among its targets; ``DefaultALGenerator``'s ordered-link
+    directions and sibling predicate stay host-only).
+
+    That symmetry is what the search uses: ``t in N_h(s)`` iff ``N_i(s)``
+    and ``N_j(t)`` share an atom for any ``i + j = h``, so per seed block a
+    ball grows from EACH end over the same plan — TWO bitmaps alive at
+    once — one expansion at a time, forward first, through the step
+    :func:`bfs_pull` and :func:`path_match` run (:func:`_expand`; each
+    side's first by the rule read against the restricted plan, later ones
+    dense, ended by ``_ball_update``), and after every expansion one meet
+    test (``_meet``: per column, does any row hold the bit in both
+    bitmaps). A column's length is the depth ``i + j`` of the first test it
+    passes; ``s == t`` is answered on the host. A column is EXHAUSTED
+    when an expansion of either ball gained no row in it — that ball is
+    its whole component, which the other end is not in: -1. The block ends
+    when every real column is met or exhausted, or at depth ``max_hops``
+    (an odd cap: the forward side has the extra hop) — the hop count
+    follows the data. Pad columns (K not a multiple of 32: both ends on
+    the dummy row) are never met.
+
+    Memory: two ``(N_pad, k_block/32)`` bitmaps a block beside the stage
+    buffers — at 10M atoms and 4096 pairs a quarter family fits one v5e
+    (12.5 of 16.9 GB) and every link does not: lower ``k_block``. Seed
+    blocks and the gather's choice are ``bfs_pull``'s.
+    """
+    _check_k_block(k_block)
+    if max_hops < 0:
+        raise ValueError(f"max_hops must be >= 0; got {max_hops}")
+    sources = np.asarray(sources, dtype=np.int32)
+    targets = np.asarray(targets, dtype=np.int32)
+    if sources.shape != targets.shape or sources.ndim != 1:
+        raise ValueError("sources and targets are two (K,) arrays, an end "
+                         f"each of K pairs; got {sources.shape} and "
+                         f"{targets.shape}")
+    if link_types is not None:
+        snap = restricted_for(snap, link_types)
+    if not snap.n_edges_tgt:  # no link to follow: s == t or nothing
+        max_hops = 0
+    K = len(sources)
+    K_pad = _ceil_to(max(K, WORD), WORD)
+    pad = np.full(K_pad - K, snap.num_atoms, dtype=np.int32)
+    sources, targets = (np.concatenate([e, pad]) for e in (sources, targets))
+    hop = _hop_over(snap) if max_hops else None
+    n_pad = _n_pad(snap.num_atoms)
+    default_registry().counter("bfs.pairs.batches").inc()
+    dist, expansions = np.empty(K_pad, dtype=np.int32), 0
+    for s in range(0, K_pad, k_block):
+        ends = sources[s: s + k_block], targets[s: s + k_block]
+        dist[s: s + k_block], ran = _pair_block(
+            hop, snap.num_atoms, n_pad, ends, max_hops, chunk,
+            use_pallas=_kernel_gathers(len(ends[0])))
+        expansions += ran
+    return PairDistResult(dist[:K], expansions)
+
+
+def _pair_block(hop: Optional[_Hop], n_atoms: int, n_pad: int, ends: tuple,
+                max_hops: int, chunk: int,
+                use_pallas: bool) -> tuple[np.ndarray, int]:
+    """One seed block of :func:`pair_distances`: ``ends`` its (sources,
+    targets), each (K,) with K % 32 == 0. Returns (dist, expansions)."""
+    reg = default_registry()
+    src, dst = ends
+    real = (src < n_atoms) & (dst < n_atoms)
+    dist = np.where(real & (src == dst), 0, -1).astype(np.int32)
+    # neither met nor exhausted: the columns the search is still for
+    wanted = real & (src != dst)
+    depth = 0
+    if max_hops and wanted.any():
+        n_atoms_dev = jnp.int32(n_atoms)
+        # both zero fills go out before the rule looks at either end, and
+        # BOTH sides' sparse pairs are made beside them: made when its turn
+        # comes, the backward side's would keep the device waiting
+        balls = [_bitmap_of(e, n_atoms_dev, n_pad) for e in ends]
+        first = [_first_hop_rule(hop, e) for e in ends]
+        first = [None if sl is None
+                 else _seed_pairs(hop.snap, e, sl, own_bits=False)
+                 for e, sl in zip(ends, first)]
+        while depth < max_hops and wanted.any():
+            side = depth % 2  # forward first: an odd cap's extra hop is its
+            sl, first[side] = first[side], None
+            balls[side], grew = _expand(
+                balls[side], hop, sl, _ball_update, seeds=ends[side],
+                n_atoms=n_atoms_dev, chunk=chunk, use_pallas=use_pallas)
+            depth += 1
+            reg.counter("bfs.pairs.expansions."
+                        + ("dense" if sl is None else "sparse")).inc()
+            # once a test, around the dispatch, the read of 4 Kw bytes
+            # and the host's decision: the device waits for it, so a
+            # profile charges the gap between two expansions here
+            with phase("hg.bfs.pairs.meet"):
+                met = _columns(_meet(*balls)) & wanted
+                reg.counter("bfs.pairs.meet_tests").inc()
+                dist[met] = depth
+                wanted &= ~met & grew
+    if depth < max_hops:
+        reg.counter("bfs.pairs.early_exits").inc()
+    return dist, depth
 
 
 def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:

@@ -282,6 +282,11 @@ STAGE_PROGRAMS = {
                         _UPDATE_ARGS, {}),
     "_frontier_replace": ("hg_bfs_frontier_replace",
                           ("hg.bfs.frontier_replace",), _UPDATE_ARGS, {}),
+    # a pair search's update: the traversal's fold, under its scope
+    "_ball_update": ("hg_bfs_ball_update", ("hg.bfs.visited_update",),
+                     _UPDATE_ARGS, {}),
+    "_meet": ("hg_bfs_meet", ("hg.bfs.meet",),
+              (_u32(64, 1), _u32(64, 1)), {}),
     "_reach_counts": ("hg_bfs_reach_counts", ("hg.bfs.reach_counts",),
                       (_u32(64, 1),), {}),
 }
@@ -302,6 +307,43 @@ def test_stage_program_carries_its_names(attr):
              for ln in text.splitlines() if 'op_name="jit(' in ln]
     assert paths and all(
         any(f"/{s}/" in p for s in scopes) for p in paths), paths[:5]
+
+
+PAIR_COUNTERS = ("bfs.pairs.batches", "bfs.pairs.expansions.sparse",
+                 "bfs.pairs.expansions.dense", "bfs.pairs.meet_tests",
+                 "bfs.pairs.early_exits")
+
+
+def test_pair_distances_leaves_its_phase_and_its_five_counters():
+    """Phase ``hg.bfs.pairs.meet`` once a test; the chain's own hop phases
+    once an expansion, as once a hop; the five ``bfs.pairs.*`` counters
+    from numbers the host holds."""
+    def counters():
+        got = [obs.default_registry().get(n) for n in PAIR_COUNTERS]
+        return [0 if c is None else c.value for c in got]
+
+    names = HOP_PHASES + (SPARSE_PHASE, DEG_SUM_PHASE, "hg.bfs.pairs.meet",
+                          "hg.bfs.seeds_upload", "hg.bfs.reach_counts")
+    snap = _small_snapshot(13)
+    r = np.random.default_rng(13)
+    sources = r.integers(0, 300, size=16).astype(np.int32)
+    targets = (sources + 1 + r.integers(0, 298, size=16)) % 300  # s != t
+    before, c0 = {n: _hist(n)["count"] for n in names}, counters()
+    res = eb.pair_distances(snap, sources, targets, 5)
+    # few pairs: either side's first hop is sparse, the rest dense
+    dense = res.expansions - 2
+    assert dense >= 1
+    grew = {n: _hist(n)["count"] - before[n] for n in names}
+    assert grew == {**{n: dense for n in HOP_PHASES}, SPARSE_PHASE: 2,
+                    DEG_SUM_PHASE: 0, "hg.bfs.pairs.meet": res.expansions,
+                    "hg.bfs.seeds_upload": 2, "hg.bfs.reach_counts": 0}
+    assert _hist("hg.bfs.pairs.meet")["total"] > 0.0
+    assert [now - was for now, was in zip(counters(), c0)] == \
+        [1, 2, dense, res.expansions, int(res.expansions < 5)]
+    # nothing to search for: a batch and an early exit, no expansion
+    c0 = counters()
+    eb.pair_distances(snap, sources[:2], sources[:2], 5)  # s == t: no work
+    assert [now - was for now, was in zip(counters(), c0)] == [1, 0, 0, 0, 1]
 
 
 def test_pallas_gather_kernel_is_named():

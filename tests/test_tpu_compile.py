@@ -346,7 +346,7 @@ def _staged_step(step: str):
                 (visited, _sds((2, eb.SPARSE_BLOCK), "int32"),
                  _sds((), "int32")),
                 {}, 4 * (sum(_L1) + sum(_L2)))
-    if step in ("_visited_update", "_frontier_replace"):
+    if step in ("_visited_update", "_frontier_replace", "_ball_update"):
         # a hop ends here (a match's step: three steps' plans lie beside
         # it); the block list has a slot a row block of the bitmap
         listed = eb._UpdateRows(
@@ -356,7 +356,8 @@ def _staged_step(step: str):
         return (getattr(eb, step),
                 (visited, _sds((rows(_L2, _W2) + 1, _KW), "uint32"),
                  listed, _sds((), "int32")),
-                {}, 3 * 4 * (sum(_L1) + sum(_L2)))
+                {}, 3 * 4 * (sum(_L1) + sum(_L2))
+                + (bitmap if step == "_ball_update" else 0))  # the other ball
     if step == "_stage":
         return (eb._stage,
                 (visited, ints(_L1)),
@@ -394,7 +395,8 @@ def test_the_cell_shape_is_the_modules_classes():
 
 @pytest.mark.parametrize("step", ["_sparse_hop", "_stage",
                                   "_stage_lvl0_consume", "_stage_upper",
-                                  "_visited_update", "_frontier_replace"])
+                                  "_visited_update", "_frontier_replace",
+                                  "_ball_update"])
 def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
                                                           no_compile_cache):
     """Each host-sequenced step of ``ellbfs._bfs_pull_device`` at the
@@ -409,7 +411,9 @@ def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
     pairs) updates the donated bitmap where it lies, and the two updates
     fold the listed row blocks into the donated state — a loop whose trip
     count the compiler cannot see carries the alias as a counted one did:
-    no second bitmap, and nothing of a bitmap's size beside it."""
+    no second bitmap, and nothing of a bitmap's size beside it. A pair
+    search's ``_ball_update`` is the traversal's update with a row of
+    words beside it, and the OTHER ball resident."""
     fn, args, statics, resident = _staged_step(step)
     mem = fn.lower(*_place(args, one_chip), **statics).compile() \
         .memory_analysis()
@@ -419,7 +423,8 @@ def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
     # (``_stage_lvl0_consume``: the bitmap, stage 1's 8.0M-row buffer and
     # stage 2's 2.1M rows), where one width-8 level 0 a stage held 15.5
     assert total < 0.75 * HBM_USABLE, (step, total)
-    if step in ("_sparse_hop", "_visited_update", "_frontier_replace"):
+    if step in ("_sparse_hop", "_visited_update", "_frontier_replace",
+                "_ball_update"):
         assert mem.alias_size_in_bytes >= _N_PAD * _KW * 4
         assert mem.temp_size_in_bytes < 2**30
 
@@ -443,3 +448,21 @@ def test_counting_pass_keeps_its_unpacked_bits_on_the_chip(
             else (visited,))
     compiled = getattr(eb, program).lower(*_place(args, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes <= 64 * 2**20
+
+
+def test_meet_test_reads_two_bitmaps_and_keeps_nothing(one_chip,
+                                                       no_compile_cache):
+    """``_meet`` at the pair cell's shapes — two bitmaps of 10,000,072 rows
+    x 128 words, 4096 pairs: the AND and the OR-fold are fused over the
+    row blocks, so what the program holds besides its arguments stays far
+    under 1 GiB (whole, unfused, ``fwd & bwd`` would be a third bitmap of
+    5.12 GB), nothing is donated, and the answer is a row of words."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    ball = _sds((_N_PAD, _KW), "uint32")
+    compiled = eb._meet.lower(*_place((ball, ball), one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2**30
+    assert mem.alias_size_in_bytes == 0
+    assert mem.output_size_in_bytes <= 4096  # (128,) uint32, tiled
+    assert compiled.as_text().startswith("HloModule jit_hg_bfs_meet,")

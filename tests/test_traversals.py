@@ -11,6 +11,7 @@ from hypergraphdb_tpu.algorithms.traversals import (
     SimpleALGenerator,
     dijkstra,
     has_cycles,
+    shortest_path_length,
 )
 from hypergraphdb_tpu.query import dsl as hg
 
@@ -159,3 +160,65 @@ def test_bfs_condition_intersects(chain):
     g, (a, b, c, d), links = chain
     res = g.find_all(hg.and_(hg.bfs(a), hg.eq("c")))
     assert res == [c]
+
+
+# ------------------------------------------------- shortest_path_length
+
+
+@pytest.fixture
+def web(graph):
+    """Two components: a ring of six with a chord and a hyperedge of four,
+    and a pair apart from it; one atom in no link."""
+    g = graph
+    ring = [g.add(f"r{i}") for i in range(6)]
+    for i in range(6):
+        g.add_link((ring[i], ring[(i + 1) % 6]), value="ring")
+    g.add_link((ring[0], ring[2]), value="chord")
+    far = [g.add(f"f{i}") for i in range(3)]
+    g.add_link((ring[3], *far), value="hyper")
+    pair = (g.add("p"), g.add("q"))
+    g.add_link(pair, value="apart")
+    return g, ring + far + list(pair) + [g.add("alone")]
+
+
+@pytest.mark.parametrize("cap", [None, 0, 1, 2, 3, 5])
+def test_shortest_path_length_is_dijkstra_at_unit_weights(web, cap):
+    g, atoms = web
+    for s in atoms:
+        for t in atoms:
+            path = dijkstra(g, s, t)
+            whole = -1 if path is None else len(path) - 1
+            want = whole if cap is None or whole <= cap else -1
+            assert shortest_path_length(g, s, t, max_distance=cap) == want
+
+
+@pytest.mark.parametrize("cap", [0, 1, 2, 4])
+def test_shortest_path_length_is_the_first_cap_at_which_bfs_yields_the_goal(
+        web, cap):
+    g, atoms = web
+    for s in atoms:
+        within = [{s} | {a for _, a in HGBreadthFirstTraversal(
+            g, s, max_distance=h)} for h in range(cap + 1)]
+        for t in atoms:
+            first = next((h for h, ball in enumerate(within) if t in ball), -1)
+            assert shortest_path_length(g, s, t, max_distance=cap) == first
+
+
+def test_shortest_path_length_under_a_link_predicate(web):
+    """Without the chord the ring's 0 and 2 are two apart; without the ring
+    they are one apart and 0 and 1 have no path."""
+    g, atoms = web
+
+    def valued(*values):
+        return DefaultALGenerator(
+            g, link_predicate=lambda gr, link: gr.get(link).value in values)
+
+    r0, r1, r2 = atoms[0], atoms[1], atoms[2]
+    assert shortest_path_length(g, r0, r2) == 1
+    assert shortest_path_length(g, r0, r2, valued("ring")) == 2
+    assert shortest_path_length(g, r0, r1, valued("chord")) == -1
+    assert shortest_path_length(g, r0, r0, valued()) == 0
+    assert shortest_path_length(g, r0, atoms[6], valued("ring", "hyper"),
+                                max_distance=3) == -1
+    assert shortest_path_length(g, r0, atoms[6], valued("ring", "hyper"),
+                                max_distance=4) == 4
