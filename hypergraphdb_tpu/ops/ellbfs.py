@@ -1762,8 +1762,25 @@ def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:
             # the first stage needs them all: waiting here moves no work,
             # it puts the upload's seconds under the upload's name
             jax.block_until_ready(cache)
+        # outside the upload's phase: on a TPU the first call probes the
+        # kernel (the first 4096-seed block would otherwise)
+        default_registry().gauge("bfs.gather.tile_share").set(
+            _tile_share(plans) if _pg.pallas_ok() else 0.0)
         object.__setattr__(snap, "_pull_device", cache)
     return cache
+
+
+def _tile_share(plans: PullBFSPlans) -> float:
+    """Percent of the plan's level-0 indices, both stages', in a class
+    whose width ``hg_gather_or`` serves at its one row width (4096-seed
+    blocks; a narrower block takes the XLA gather whatever its plan)."""
+    lvl0 = [(len(l), w) for levels, widths, n in (
+        (plans.stage1.levels, plans.stage1.widths, plans.stage1.n_lvl0),
+        (plans.stage2_levels, plans.stage2_widths, plans.stage2_n_lvl0))
+        for l, w in zip(levels[:n], widths[:n])]
+    served = sum(n for n, w in lvl0
+                 if _pg.declined(w, _pg.ROW_WORDS) is None)
+    return 100.0 * served / max(1, sum(n for n, _ in lvl0))
 
 
 def visited_rows(res, n_atoms: int) -> list[np.ndarray]:

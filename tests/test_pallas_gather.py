@@ -37,6 +37,136 @@ def test_gather_or_matches_xla(w, n_out):
     assert np.array_equal(np.asarray(out), _ref(values, idx, w))
 
 
+#: ``dma_start`` equations the traced chunk kernel held at each class
+#: width before the kernel reduced a tile a step — ``(slots + 1) * w`` with
+#: the slots of ``w`` copies it kept then (16 at w = 2 … 4 from w = 8): the
+#: trace budget, written as numbers
+CHUNK_KERNEL_STARTS = {2: 34, 4: 36, 6: 54, 8: 40, 10: 50, 14: 70, 20: 100,
+                       28: 140, 40: 200, 56: 280}
+#: and every equation of that traced call, nested bodies included: what
+#: tracing costs follows it (a traced ``x + 3`` is a millisecond of a
+#: loaded host's time, and a stage program traces two calls a class)
+CHUNK_KERNEL_EQUATIONS = {2: 175, 4: 165, 6: 227, 8: 181, 10: 219, 14: 295,
+                          20: 409, 28: 561, 40: 789, 56: 1093}
+
+
+def _equations(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _equations(sub)
+    return n
+
+
+@pytest.mark.parametrize("w", CLASS_WIDTHS)
+def test_traced_kernel_stays_inside_the_trace_budget(w):
+    """What a kernel body's TEXT costs is paid on every run, cache or no
+    cache (a run re-traces and re-lowers every program before it can look
+    one up, and compiles the stage programs whose shapes follow the
+    seed's plan), twice a width class a stage program: the traced kernel
+    holds ONE copy-issue loop — a step's ``written_out(w)`` chunks of
+    ``w`` copy starts — and never more than the chunk kernel held at that
+    width. Needs no chip: the jaxpr is the same whatever compiles it."""
+    assert set(CHUNK_KERNEL_STARTS) == set(CLASS_WIDTHS)
+    jaxpr = jax.make_jaxpr(lambda v, i: pg.gather_or(v, i, w))(
+        jax.ShapeDtypeStruct((1 << 12, 128), jnp.uint32),
+        jax.ShapeDtypeStruct((pg._seg(w),), jnp.int32))
+    text = str(jaxpr)
+    assert text.count("pallas_call") == 1
+    starts = text.count("dma_start")
+    assert starts == pg.written_out(w) * w <= CHUNK_KERNEL_STARTS[w]
+    assert text.count("dma_wait") == 1  # one wait a tile, in the text once
+    assert _equations(jaxpr.jaxpr) <= CHUNK_KERNEL_EQUATIONS[w]
+
+
+def _tile_cases():
+    """Index layouts a tile of eight chunks must get right: every chunk of
+    a tile reading the zero row (a plan's pads: rows of a class that end a
+    section); one row fetched by every copy of a tile, and by two chunks
+    of it (the same HBM row in flight into eight sublanes at once); a tile
+    whose chunks differ only in their LAST index (each sublane keeps its
+    own chunk's rows)."""
+    S = 300
+
+    def zero_tiles(r, n, w):
+        idx = r.integers(0, S - 1, size=(n, w))
+        idx[8:16] = S - 1          # the second tile of the first grid step
+        idx[n - 8:] = S - 1        # and the last tile of the last
+        return idx
+
+    def repeats(r, n, w):
+        idx = r.integers(0, S - 1, size=(n, w))
+        idx[0:8] = 7               # a whole tile fetches one row
+        idx[16:24] = idx[16]       # eight chunks, the same w rows
+        idx[24:32, :] = idx[24, 0]  # every copy of a chunk the same row
+        return idx
+
+    def last_differs(r, n, w):
+        idx = np.broadcast_to(r.integers(0, S - 1, size=(1, w)), (n, w)).copy()
+        idx[:, -1] = r.integers(0, S - 1, size=n)
+        return idx
+
+    return S, {"zero_tiles": zero_tiles, "repeats": repeats,
+               "last_differs": last_differs}
+
+
+@pytest.mark.parametrize("w", [2, 8, 14, 56])
+@pytest.mark.parametrize("layout", sorted(_tile_cases()[1]))
+def test_gather_or_tile_layouts(layout, w):
+    S, layouts = _tile_cases()
+    r = np.random.default_rng([w, 35])
+    values = r.integers(0, 2**32, size=(S, 128), dtype=np.uint64) \
+        .astype(np.uint32)
+    values[S - 1] = 0              # the zero row, last as a plan's is
+    values = jnp.asarray(values)
+    n_out = pg.G                   # one grid step exactly: no pad chunk
+    idx = jnp.asarray(layouts[layout](r, n_out, w).reshape(-1)
+                      .astype(np.int32))
+    out = np.asarray(pg.gather_or(values, idx, w, interpret=True))
+    assert out.shape == (n_out, 128)
+    assert np.array_equal(out, _ref(values, idx, w))
+    if layout == "zero_tiles":
+        assert not out[8:16].any() and not out[-8:].any()
+
+
+@pytest.mark.parametrize("w", [4, 20])
+def test_gather_or_holds_indices_to_the_table(w):
+    """The kernel is compiled without Mosaic's per-copy bounds checks, so
+    the call clamps what it is handed: an index outside the table reads
+    the table's first or last row, as the XLA gather's does."""
+    r = np.random.default_rng([w, 37])
+    S = 100
+    values = jnp.asarray(
+        r.integers(0, 2**32, size=(S, 128), dtype=np.uint64).astype(np.uint32)
+    )
+    idx = r.integers(0, S, size=pg.G * w).astype(np.int32)
+    idx[::7] = S + r.integers(0, 1 << 20, size=len(idx[::7]))
+    idx[3::11] = -1 - r.integers(0, 1 << 20, size=len(idx[3::11]))
+    out = pg.gather_or(values, jnp.asarray(idx), w, interpret=True)
+    assert np.array_equal(np.asarray(out),
+                          _ref(values, np.clip(idx, 0, S - 1), w))
+
+
+@pytest.mark.parametrize("w", [6, 10, 20, 28, 40])
+def test_gather_or_one_grid_step_exactly(w):
+    """``n_out`` of one grid step at the widths the first case leaves out
+    (it holds 4 and 8): 32 tiles, no pad chunk, no scan."""
+    r = np.random.default_rng([w, 36])
+    S = 200
+    values = jnp.asarray(
+        r.integers(0, 2**32, size=(S, 128), dtype=np.uint64).astype(np.uint32)
+    )
+    idx = jnp.asarray(r.integers(0, S, size=pg.G * w).astype(np.int32))
+    out = pg.gather_or(values, idx, w, interpret=True)
+    assert out.shape == (pg.G, 128)
+    assert np.array_equal(np.asarray(out), _ref(values, idx, w))
+
+
 @pytest.mark.parametrize("w,seg", [(8, pg.G * 8 * 2), (24, pg.G * 8 * 7)])
 def test_gather_or_multi_segment(w, seg, monkeypatch):
     # shrink SEG so the lax.scan path runs in-test: two grid steps a
@@ -56,25 +186,34 @@ def test_gather_or_multi_segment(w, seg, monkeypatch):
 
 @pytest.mark.parametrize("w", WIDTHS)
 def test_kernel_geometry_follows_the_width(w):
-    """At every width the gate admits: the power of two of slots that
-    keeps at least ``IN_FLIGHT`` copies outstanding and no more than twice
-    that, never fewer than ``MIN_SLOTS`` (the kernel is bound by the
-    copies it issues: more slots only lengthen a grid step's fill and
-    drain, PERF.md section 6, PR 30), never more than a grid step's
-    chunks, whole grid steps a segment, and a working set inside the VMEM
-    budget."""
+    """At every width the gate admits: a slot holds a TILE of eight chunks
+    (``TILE * w`` copies); the power of two of slots that keeps at least
+    ``IN_FLIGHT`` copies outstanding and no more than twice that, never
+    fewer than ``MIN_SLOTS`` (a tile lands while others are reduced: two
+    slots read 8-60% slower than four at every width up to 14), never
+    more than a grid step's tiles; a step of the issue loop writes out
+    the power of two of chunks whose copies number at most
+    ``STEP_COPIES`` — one chunk where a chunk alone is more, never more
+    than a tile; whole grid steps a segment; a working set inside the
+    VMEM budget."""
     assert pg.declined(w, pg.ROW_WORDS) is None
     d = pg.slots(w)
-    assert pg.MIN_SLOTS <= d <= pg.G and d & (d - 1) == 0
-    assert d * w >= pg.IN_FLIGHT
-    assert d * w < 2 * pg.IN_FLIGHT or d == pg.MIN_SLOTS
-    assert (pg.slots(2), pg.slots(8), pg.slots(56)) == (16, 4, 4)
+    assert pg.MIN_SLOTS <= d <= pg.G // pg.TILE and d & (d - 1) == 0
+    assert d * pg.TILE * w >= pg.IN_FLIGHT
+    assert d * pg.TILE * w < 2 * pg.IN_FLIGHT or d == pg.MIN_SLOTS
+    assert [pg.slots(x) for x in (2, 4, 6, 8, 56)] == [16, 8, 8, 4, 4]
+    p = pg.written_out(w)
+    assert 1 <= p <= pg.TILE and pg.TILE % p == 0
+    assert p * w <= pg.STEP_COPIES or p == 1
+    assert 2 * p * w > pg.STEP_COPIES or p == pg.TILE
+    assert [pg.written_out(x) for x in (2, 4, 6, 8, 10, 14, 20, 28, 56)] \
+        == [8, 8, 8, 4, 4, 2, 2, 1, 1]
     seg = pg._seg(w)
     assert seg % (pg.G * w) == 0 and pg.SEG - pg.G * w < seg <= pg.SEG
     assert pg.whole_segments(4 * pg.SEG, w) == 4 * pg.SEG // seg * seg
     assert pg.whole_segments(seg - 1, w) == seg - 1
     assert pg._vmem_bytes(w, pg.ROW_WORDS) == \
-        4 * pg.ROW_WORDS * (2 * pg.G + d * w) <= pg.VMEM_BUDGET
+        4 * pg.ROW_WORDS * (2 * pg.G + d * w * pg.TILE) <= pg.VMEM_BUDGET
 
 
 def test_gate_declines_a_chunk_wider_than_a_segment():
@@ -136,3 +275,46 @@ def test_bfs_pull_wide_block_cpu_fallback(graph):
     rn = visited_rows(narrow, snap.num_atoms)
     for a, b in zip(rw[: len(seeds)], rn[: len(seeds)]):
         assert np.array_equal(a, b)
+
+
+def test_tile_share_gauge_says_which_gather_serves(graph, monkeypatch):
+    """``bfs.gather.tile_share``, set where a plan's device arrays are
+    made: 0 where the XLA gather serves (this CPU), the share of the
+    plan's level-0 indices in a class the kernel's gate admits where the
+    kernel does."""
+    from tests.conftest import make_random_hypergraph
+    from hypergraphdb_tpu.obs.registry import default_registry
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    make_random_hypergraph(graph, n_nodes=200, n_links=500, seed=5)
+    gauge = default_registry().gauge("bfs.gather.tile_share")
+    gauge.set(-1.0)
+    snap = graph.snapshot()
+    eb._device_plans(snap, eb.plans_for(snap))
+    assert gauge.value == 0.0
+
+    monkeypatch.setattr(pg, "pallas_ok", lambda: True)
+    object.__setattr__(snap, "_pull_device", None)   # upload again
+    plans = eb.plans_for(snap)
+    eb._device_plans(snap, plans)
+    assert gauge.value == 100.0
+    eb._device_plans(snap, plans)       # a memo hit sets nothing
+    gauge.set(-1.0)
+    eb._device_plans(snap, plans)
+    assert gauge.value == -1.0
+
+    # a class the gate declines leaves its indices to the XLA gather
+    s1 = plans.stage1
+    w0, n0 = s1.widths[0], len(s1.levels[0])
+    lvl0 = sum(len(l) for l in s1.levels[:s1.n_lvl0]) + sum(
+        len(l) for l in plans.stage2_levels[:plans.stage2_n_lvl0])
+    in_w0 = n0 + sum(len(l) for l, w in zip(
+        plans.stage2_levels[:plans.stage2_n_lvl0], plans.stage2_widths)
+        if w == w0)
+    admits = pg.declined
+    monkeypatch.setattr(
+        pg, "declined",
+        lambda w, Kw: "not this one" if w == w0 else admits(w, Kw))
+    assert eb._tile_share(plans) == pytest.approx(
+        100.0 * (lvl0 - in_w0) / lvl0)
+    assert 0.0 < eb._tile_share(plans) < 100.0

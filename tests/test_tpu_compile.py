@@ -238,7 +238,12 @@ def test_wide_rows_gate_agrees_with_compiler(one_chip, no_compile_cache):
 def test_every_class_width_compiles_for_v5e(w, one_chip, no_compile_cache):
     """Each level-0 class width of the pull plan (``ellbfs.CLASS_WIDTHS``,
     held at import to what the gate admits) is a kernel the v5e compiler
-    accepts at 128-word rows, with the slots that width holds in flight."""
+    accepts at 128-word rows: a sublane tile of eight chunks a loop step,
+    with the slots that width holds in flight and the chunks its issue
+    loop writes out a step (seconds to lower and compile live in
+    ``benchmarks/tests/gather_tile_probe.py --describe``, the length of
+    the traced text in ``tests/test_pallas_gather.py``: no wall clock is
+    asserted under ``-n 6``)."""
     from hypergraphdb_tpu.ops import pallas_gather as pg
 
     assert pg.declined(w, 128) is None
