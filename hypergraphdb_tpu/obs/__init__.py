@@ -17,7 +17,18 @@ this repro had faithfully reproduced as ``utils.metrics.Metrics`` vs
 - **device timing** (:mod:`~hypergraphdb_tpu.obs.device`): opt-in
   launch→ready wall deltas, per-dispatch profiler annotations, a gated
   ``jax.profiler`` session, and ``phase`` for coarse synced host steps
-  (seconds in the registry, a host span on the device trace's clock);
+  (seconds in the registry, a host span on the device trace's clock).
+  Every phase instance also keeps a RECORD in ``phase_log()`` (a
+  ``FlightRecorder`` of its own): who called it (``parent``, and ``op``,
+  the outermost phase: ``hg.bfs.pull`` / ``.match`` / ``.pairs`` around an
+  operation), wall beside thread CPU, switches and page faults, and its
+  STEPS — ``ph.step(sub)`` / ``ph.wait(x)`` on the handle ``phase``
+  yields: where the dispatch ends and the wait begins. One
+  ``jax.monitoring`` LISTENER, registered at the first phase entry, puts
+  JAX's trace / lower / compile / cache-load seconds on the phase that
+  paid them (histograms ``jit.*``, records of kind ``jit``); an instance
+  far over its name's median that compiled nothing is counted
+  (``obs.phase.stalls``) and logged once, as a JSON line;
 - **export** (:mod:`~hypergraphdb_tpu.obs.export`): Prometheus text and
   schema-versioned JSONL traces;
 - **flight recorder** (:mod:`~hypergraphdb_tpu.obs.flight`): an
@@ -70,6 +81,7 @@ from hypergraphdb_tpu.obs.device import (
     annotate,
     block_timed,
     phase,
+    phase_log,
     profile,
     profiling,
 )
@@ -175,6 +187,7 @@ __all__ = [
     "parse_traces_jsonl",
     "perf",
     "phase",
+    "phase_log",
     "profile",
     "profiling",
     "prometheus_text",

@@ -1285,43 +1285,49 @@ def _expand(
     if sl is not None:
         # around expansion, upload, dispatch and sync: its count beside
         # hg.bfs.hop.stage1's says how often the rule took this side
-        with phase("hg.bfs.hop.sparse"):
-            pairs = (sl if isinstance(sl, _SeedPairs)
-                     else _seed_pairs(hop.snap, seeds, sl, own_bits))
-            state = _sparse_first_hop(state, pairs, n_atoms)
-            jax.block_until_ready(state)
+        with phase("hg.bfs.hop.sparse") as ph:
+            with ph.step("expand"):
+                pairs = (sl if isinstance(sl, _SeedPairs)
+                         else _seed_pairs(hop.snap, seeds, sl, own_bits))
+            with ph.step("place"):  # the upload and the dispatches
+                state = _sparse_first_hop(state, pairs, n_atoms)
+            ph.wait(state)
         return state, pairs.placed
     _, plans, dev = hop
     s1 = plans.stage1
     levels1, levels2 = dev["levels1"], dev["levels2"]
     widths2, n2 = plans.stage2_widths, plans.stage2_n_lvl0
     n2_last = len(plans.stage2_levels[n2 - 1]) // widths2[n2 - 1]
-    with phase("hg.bfs.hop.stage1"):
-        live = _stage(state, levels1, s1.widths, s1.n_lvl0, chunk,
-                      use_pallas)
-        jax.block_until_ready(live)
-    with phase("hg.bfs.hop.stage2_lvl0"):
-        lvl0b = _stage_lvl0_consume(live, levels2[:n2], widths2[:n2],
-                                    chunk, use_pallas)
+    with phase("hg.bfs.hop.stage1") as ph:
+        with ph.step("dispatch"):
+            live = _stage(state, levels1, s1.widths, s1.n_lvl0, chunk,
+                          use_pallas)
+        ph.wait(live)
+    with phase("hg.bfs.hop.stage2_lvl0") as ph:
+        with ph.step("dispatch"):
+            lvl0b = _stage_lvl0_consume(live, levels2[:n2], widths2[:n2],
+                                        chunk, use_pallas)
         # the donations can't alias (shapes differ), so the host ref
         # is what keeps each dead buffer resident — drop it AND sync
         # before the next dispatch: async dispatch would let the
         # allocator grab stage-upper's buffers while the consume step
         # (and therefore `live`'s 4.1 GB) is still in flight. The sync
         # costs one RTT per hop against multi-second hops.
-        del live
-        jax.block_until_ready(lvl0b)
-    with phase("hg.bfs.hop.stage2_upper_update"):
-        reach_chunks = _stage_upper(lvl0b, levels2[n2:], widths2[n2:],
-                                    n2_last, chunk)
-        del lvl0b
-        if listed is None:
-            listed, rows = dev["blocks"], dev["rows"]
-        else:
-            rows = _listed(dev["out_map"], listed)
-        out = update(state, reach_chunks, rows, n_atoms)
-        del reach_chunks
-        jax.block_until_ready(out)
+        with ph.step("free"):
+            del live
+        ph.wait(lvl0b)
+    with phase("hg.bfs.hop.stage2_upper_update") as ph:
+        with ph.step("dispatch"):
+            reach_chunks = _stage_upper(lvl0b, levels2[n2:], widths2[n2:],
+                                        n2_last, chunk)
+            del lvl0b
+            if listed is None:
+                listed, rows = dev["blocks"], dev["rows"]
+            else:
+                rows = _listed(dev["out_map"], listed)
+            out = update(state, reach_chunks, rows, n_atoms)
+            del reach_chunks
+        ph.wait(out)
         # what the update's loop folded beside the whole bitmap: their
         # ratio says how far the plan's block list engages
         n_pad = state.shape[0]
@@ -1391,9 +1397,10 @@ def _bfs_pull_device(
     for i, hop in enumerate(hops):
         if count_edges and i == len(hops) - 1:
             # the degree sum once a block, a phase of its own
-            with phase("hg.bfs.hop.deg_sum"):
-                s_ins.append(_deg_sum(visited, hop.dev["inc_deg"]))
-                jax.block_until_ready(s_ins[-1])
+            with phase("hg.bfs.hop.deg_sum") as ph:
+                with ph.step("dispatch"):
+                    s_ins.append(_deg_sum(visited, hop.dev["inc_deg"]))
+                ph.wait(s_ins[-1])
         listed = None
         if not grows:
             listed, held = hop.dev["blocks"] | held, hop.dev["blocks"]
@@ -1466,6 +1473,9 @@ def _seed_blocks(hops: Sequence[_Hop], snap: CSRSnapshot,
     return blocks, K
 
 
+# an operation's own phase, once a CALL: every phase below carries its id
+# as ``op``, and its self time is its wall less its direct children's
+@phase("hg.bfs.pull")
 def bfs_pull(
     snap: CSRSnapshot,
     seeds: np.ndarray,
@@ -1557,6 +1567,7 @@ class PathMatchResult(NamedTuple):
     match_counts: jax.Array  # (K,) int32 — |X_H[k]|
 
 
+@phase("hg.bfs.match")
 def path_match(
     snap: CSRSnapshot,
     seeds: np.ndarray,
@@ -1615,6 +1626,7 @@ class PairDistResult(NamedTuple):
     expansions: int    # ball expansions the batch ran, over its blocks
 
 
+@phase("hg.bfs.pairs")
 def pair_distances(
     snap: CSRSnapshot,
     sources: np.ndarray,
@@ -1733,11 +1745,16 @@ def _pair_block(hop: Optional[_Hop], n_atoms: int, n_pad: int, ends: tuple,
             # once a test, around the dispatch, the read of 4 Kw bytes
             # and the host's decision: the device waits for it, so a
             # profile charges the gap between two expansions here
-            with phase("hg.bfs.pairs.meet"):
-                met = _columns(_meet(*balls)) & wanted
-                reg.counter("bfs.pairs.meet_tests").inc()
-                dist[met] = depth
-                wanted &= ~met & grew
+            with phase("hg.bfs.pairs.meet") as ph:
+                with ph.step("dispatch"):
+                    words = _meet(*balls)
+                with ph.step("wait"):  # the read of its 4 Kw bytes
+                    words = np.asarray(words)
+                with ph.step("decide"):
+                    met = _columns(words) & wanted
+                    reg.counter("bfs.pairs.meet_tests").inc()
+                    dist[met] = depth
+                    wanted &= ~met & grew
     if depth < max_hops:
         reg.counter("bfs.pairs.early_exits").inc()
     return dist, depth
@@ -1746,22 +1763,23 @@ def _pair_block(hop: Optional[_Hop], n_atoms: int, n_pad: int, ends: tuple,
 def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:
     cache = getattr(snap, "_pull_device", None)
     if cache is None:
-        with phase("hg.bfs.plan.upload"):
-            cache = {
-                "levels1": tuple(jnp.asarray(l)
-                                 for l in plans.stage1.levels),
-                "levels2": tuple(jnp.asarray(l)
-                                 for l in plans.stage2_levels),
-                "out_map": jnp.asarray(plans.out_map),
-                "inc_deg": jnp.asarray(plans.inc_deg),
-                # the row blocks a hop over this plan can reach, on the
-                # host: derived here and not kept in the sidecar
-                "blocks": _active_blocks(plans),
-            }
-            cache["rows"] = _listed(cache["out_map"], cache["blocks"])
+        with phase("hg.bfs.plan.upload") as ph:
+            with ph.step("upload"):
+                cache = {
+                    "levels1": tuple(jnp.asarray(l)
+                                     for l in plans.stage1.levels),
+                    "levels2": tuple(jnp.asarray(l)
+                                     for l in plans.stage2_levels),
+                    "out_map": jnp.asarray(plans.out_map),
+                    "inc_deg": jnp.asarray(plans.inc_deg),
+                    # the row blocks a hop over this plan can reach, on
+                    # the host: derived here and not kept in the sidecar
+                    "blocks": _active_blocks(plans),
+                }
+                cache["rows"] = _listed(cache["out_map"], cache["blocks"])
             # the first stage needs them all: waiting here moves no work,
             # it puts the upload's seconds under the upload's name
-            jax.block_until_ready(cache)
+            ph.wait(cache)
         # outside the upload's phase: on a TPU the first call probes the
         # kernel (the first 4096-seed block would otherwise)
         default_registry().gauge("bfs.gather.tile_share").set(

@@ -7,12 +7,22 @@ is open, a child span under the thread's current trace. The staged BFS
 ``jax.named_scope`` and its eight programs' XLA modules ``jit_hg_bfs_*``;
 ``benchmarks/harness/scope_reduce.py`` reads those scopes back out of a
 profiler trace. ``PERF.md`` section 3 lists every name and its reader.
+
+Since PR 36 every phase INSTANCE also leaves a record in ``obs.phase_log()``
+(who called it, steps, thread CPU, JAX's trace / lower / compile / load
+seconds, a stall flagged as it happens), and ``benchmarks/harness/
+phase_log.py`` cuts the ring into a run's operations for nine readers.
 """
 
 from __future__ import annotations
 
 import importlib.util
+import json
+import logging
 import os
+import sys
+import threading
+import time
 from contextlib import nullcontext
 
 import jax
@@ -143,6 +153,221 @@ def test_phase_records_when_the_body_raises(global_tracing):
     assert _hist("hg.test.raises")["count"] == before + 1
     (tr,) = [t for t in global_tracing.drain() if t.name == "embedded.call"]
     assert tr.find("hg.test.raises").t1 is not None
+
+
+# ------------------------------------------- a record per phase instance
+
+
+def _records(kind: str = "phase") -> list:
+    return [f for _, k, f in obs.phase_log().records() if k == kind]
+
+
+def _last(name: str) -> dict:
+    return [r for r in _records() if r["name"] == name][-1]
+
+
+def test_phase_leaves_one_record_with_its_fields():
+    n = len(_records())
+    with obs.phase("hg.test.record") as ph:
+        pass
+    (rec,) = _records()[n:]
+    assert rec["name"] == "hg.test.record" and rec["id"] == ph.id > 0
+    assert rec["parent"] == 0 and rec["op"] == rec["id"]
+    assert rec["t1"] >= rec["t0"] and rec["cpu_s"] >= 0.0
+    if obs_device._RUSAGE_THREAD is not None:
+        assert all(isinstance(rec[k], int) and rec[k] >= 0
+                   for k in ("nivcsw", "minflt", "majflt"))
+    # scalars only: the ring's JSONL contract
+    assert all(isinstance(v, (bool, int, float, str)) for v in rec.values())
+    ring = obs.phase_log()
+    assert isinstance(ring, obs.FlightRecorder)
+    assert ring.capacity == 16_384 and ring is not obs.global_flight()
+
+
+def test_op_and_parent_chain_over_nested_phases():
+    with obs.phase("hg.test.op") as op:
+        with obs.phase("hg.test.child") as child:
+            with obs.phase("hg.test.grandchild") as grandchild:
+                pass
+        with obs.phase("hg.test.sibling") as sibling:
+            pass
+    with obs.phase("hg.test.op") as again:
+        pass
+    got = {r["id"]: (r["parent"], r["op"]) for r in _records()[-5:]}
+    assert got == {grandchild.id: (child.id, op.id),
+                   child.id: (op.id, op.id), sibling.id: (op.id, op.id),
+                   op.id: (0, op.id), again.id: (0, again.id)}
+    # a child's record is written before its parent's: exit order
+    assert [r["id"] for r in _records()[-5:]] == [
+        grandchild.id, child.id, sibling.id, op.id, again.id]
+
+
+def test_two_threads_keep_their_own_chains():
+    inside, release = threading.Barrier(3), threading.Event()
+    seen: dict = {}
+
+    def work(tag: str) -> None:
+        with obs.phase(f"hg.test.thread.{tag}") as op:
+            inside.wait(timeout=10)   # both operations are open at once
+            with obs.phase(f"hg.test.thread.{tag}.child") as child:
+                release.wait(timeout=10)
+            seen[tag] = (op.id, child.id)
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in "ab"]
+    for t in threads:
+        t.start()
+    inside.wait(timeout=10)
+    release.set()
+    for t in threads:
+        t.join(timeout=10)
+        assert not t.is_alive()
+    for tag in "ab":
+        op_id, child_id = seen[tag]
+        assert _last(f"hg.test.thread.{tag}")["parent"] == 0
+        child = _last(f"hg.test.thread.{tag}.child")
+        assert (child["id"], child["parent"], child["op"]) == \
+            (child_id, op_id, op_id)
+
+
+def test_steps_sum_to_no_more_than_the_wall():
+    with obs.phase("hg.test.steps") as ph:
+        with ph.step("dispatch"):
+            time.sleep(0.002)
+        with ph.step("free"):
+            pass
+        with ph.step("dispatch"):   # a step taken twice adds up
+            time.sleep(0.002)
+    rec = _last("hg.test.steps")
+    steps = {k: v for k, v in rec.items() if k.startswith("step.")}
+    assert sorted(steps) == ["step.dispatch", "step.free"]
+    assert steps["step.dispatch"] >= 0.004
+    assert sum(steps.values()) <= rec["t1"] - rec["t0"]
+
+
+def test_wait_is_block_until_ready_as_step_wait(monkeypatch):
+    blocked: list = []
+    monkeypatch.setattr(jax, "block_until_ready", blocked.append)
+    x = jnp.arange(4)
+    with obs.phase("hg.test.wait") as ph:
+        assert ph.wait(x) is x
+    assert blocked == [x]
+    rec = _last("hg.test.wait")
+    assert [k for k in rec if k.startswith("step.")] == ["step.wait"]
+
+
+def test_step_annotates_only_inside_a_profile_session(monkeypatch):
+    seen = _Annotations(monkeypatch)
+    with obs.phase("hg.test.quiet") as ph, ph.step("dispatch"):
+        pass
+    assert seen.names == []
+    monkeypatch.setattr(obs_device, "_PROFILING", True)
+    with obs.phase("hg.test.loud") as ph:
+        with ph.step("dispatch"):
+            pass
+        ph.wait(jnp.arange(4))
+    assert seen.names == ["hg.test.loud", "hg.test.loud.dispatch",
+                          "hg.test.loud.wait"]
+
+
+def test_a_fresh_jit_lands_on_the_phase_that_called_it():
+    def hg_test_fresh(x):   # its jnp calls are traced jits of their own
+        return jnp.sum(jnp.where(x > 1, x + 1, x * 2))
+
+    fn = jax.jit(hg_test_fresh)
+    x = jnp.arange(8)   # made out here: an eager op is a program too
+    hists = [obs.default_registry().histogram(f"jit.{h}")
+             for h in ("trace", "lower", "compile")]
+    counts = [h.count for h in hists]
+    n_jit = len(_records("jit"))
+    with obs.phase("hg.test.jit.outer") as outer:
+        with obs.phase("hg.test.jit.first"):
+            fn(x).block_until_ready()
+        with obs.phase("hg.test.jit.second"):
+            fn(x).block_until_ready()
+    first, second = _last("hg.test.jit.first"), _last("hg.test.jit.second")
+    assert all(first[f"jit.{k}"] > 0.0
+               for k in ("trace_s", "lower_s", "compile_s"))
+    assert not [k for k in second if k.startswith("jit.")]
+    assert not [k for k in _last("hg.test.jit.outer")
+                if k.startswith("jit.")]   # the innermost phase paid
+    mine = _records("jit")[n_jit:]
+    # ONE trace record: the nested jnp traces lie inside its seconds
+    assert [(r["stage"], r["fun_name"]) for r in mine] == [
+        ("trace_s", "hg_test_fresh"), ("lower_s", "jit(hg_test_fresh)"),
+        ("compile_s", "jit(hg_test_fresh)")]
+    assert all(r["op"] == outer.id and r["secs"] > 0.0 for r in mine)
+    assert [h.count - was for h, was in zip(hists, counts)] == [1, 1, 1]
+    assert first["jit.trace_s"] == mine[0]["secs"]
+    assert sum(first[f"jit.{k}"] for k in ("trace_s", "lower_s",
+                                            "compile_s")) \
+        <= first["t1"] - first["t0"]
+
+
+def test_the_ring_stays_bounded(monkeypatch):
+    from hypergraphdb_tpu.obs.flight import FlightRecorder
+
+    small = FlightRecorder(capacity=32, clock=time.perf_counter)
+    monkeypatch.setattr(obs_device, "_PHASE_LOG", small)
+    for _ in range(100):
+        with obs.phase("hg.test.bounded"):
+            pass
+    assert obs.phase_log() is small and len(small.records()) == 32
+    assert all(f["name"] == "hg.test.bounded"
+               for _, _, f in small.records())
+
+
+def _stalls() -> int:
+    return obs.default_registry().counter("obs.phase.stalls").value
+
+
+def test_a_planted_stall_is_counted_and_logged_once(caplog):
+    before = _stalls()
+    with caplog.at_level(logging.WARNING, logger="hypergraphdb_tpu.obs"):
+        with obs.phase("hg.test.stall.op"):
+            for i in range(12):
+                with obs.phase("hg.test.stall.hop") as ph:
+                    with ph.step("dispatch"):
+                        pass
+                    with ph.step("wait"):
+                        if i == 8:   # the ninth instance
+                            time.sleep(0.3)
+    assert _stalls() == before + 1
+    (line,) = [r.getMessage() for r in caplog.records]
+    said = json.loads(line.split("obs.phase stall ", 1)[1])
+    assert said["name"] == "hg.test.stall.hop" and said["stall"] is True
+    assert said["op_name"] == "hg.test.stall.op" and said["op_index"] >= 0
+    assert said["step.wait"] >= 0.3 > said["step.dispatch"]
+    assert said["wall_s"] >= 0.3 > said["median_s"] and "cpu_s" in said
+    if obs_device._RUSAGE_THREAD is not None:
+        assert {"nivcsw", "minflt", "majflt"} <= set(said)
+    assert set(said["memory"]) in (set(), {
+        "bytes_in_use", "peak_bytes_in_use", "largest_free_block_bytes",
+        "num_allocs"})
+    flagged = [r for r in _records() if r.get("stall")
+               and r["name"] == "hg.test.stall.hop"]
+    assert len(flagged) == 1 and flagged[0]["op"] == said["op"]
+
+
+@pytest.mark.parametrize("why", ["it_compiled", "too_few_samples",
+                                 "under_a_quarter_second"])
+def test_a_slow_instance_that_is_no_stall(why, caplog):
+    """Slow for a reason on record, too early to have a median, or under
+    the one compare the normal path pays."""
+    name = f"hg.test.nostall.{why}"
+    before = _stalls()
+    slow_at = 2 if why == "too_few_samples" else 8
+    fn, x = jax.jit(lambda x: x * 3 + 1), jnp.arange(3)
+    with caplog.at_level(logging.WARNING, logger="hypergraphdb_tpu.obs"):
+        for i in range(10):
+            with obs.phase(name):
+                if i == slow_at:
+                    time.sleep(0.2 if why == "under_a_quarter_second"
+                               else 0.3)
+                    if why == "it_compiled":
+                        fn(x)
+    assert _stalls() == before and not caplog.records
+    assert not [r for r in _records() if r["name"] == name
+                and r.get("stall")]
 
 
 # ------------------------------------------------------ the traversal path
@@ -371,6 +596,125 @@ def test_dispatch_thread_annotations_are_off_the_unprofiled_path(
                        "hg.serve.launch"):
         pass
     assert seen.names == ["hg.serve.park", "hg.serve.launch"]
+
+
+# ------------------------------------------------ an operation's own span
+
+
+def _operate(operator: str, snap) -> None:
+    seeds = np.arange(16, dtype=np.int32)
+    if operator == "bfs_pull":
+        eb.bfs_pull(snap, seeds, 3)
+    elif operator == "path_match":
+        eb.path_match(snap, seeds, [None] * 3)
+    else:
+        eb.pair_distances(snap, seeds, (seeds + 7) % 300, 4)
+
+
+OPERATIONS = {"bfs_pull": "hg.bfs.pull", "path_match": "hg.bfs.match",
+              "pair_distances": "hg.bfs.pairs"}
+
+
+@pytest.mark.parametrize("operator", sorted(OPERATIONS))
+def test_an_operation_leaves_one_record_whose_children_carry_its_id(
+        operator):
+    snap = _small_snapshot(17)
+    n = len(_records())
+    _operate(operator, snap)
+    mine = _records()[n:]
+    (op,) = [r for r in mine if r["name"] in OPERATIONS.values()]
+    assert op["name"] == OPERATIONS[operator] and op["parent"] == 0
+    assert mine[-1] is op and len(mine) > 5
+    assert {r["op"] for r in mine} == {op["id"]}
+    # every sync inside a phase is a step, and the table's steps are there
+    steps = {r["name"]: sorted(k[5:] for k in r if k.startswith("step."))
+             for r in mine}
+    assert steps["hg.bfs.hop.sparse"] == ["expand", "place", "wait"]
+    assert steps["hg.bfs.hop.stage1"] == ["dispatch", "wait"]
+    assert steps["hg.bfs.hop.stage2_lvl0"] == ["dispatch", "free", "wait"]
+    assert steps["hg.bfs.hop.stage2_upper_update"] == ["dispatch", "wait"]
+    assert steps["hg.bfs.plan.upload"] == ["upload", "wait"]
+    assert steps["hg.bfs.seeds_upload"] == steps["hg.bfs.plan"] == []
+    if operator == "pair_distances":
+        assert steps["hg.bfs.pairs.meet"] == ["decide", "dispatch", "wait"]
+    if operator == "bfs_pull":
+        assert steps["hg.bfs.hop.deg_sum"] == ["dispatch", "wait"]
+    # the direct children lie inside the operation, one after another
+    children = [r for r in mine if r["parent"] == op["id"]]
+    assert all(op["t0"] <= r["t0"] <= r["t1"] <= op["t1"] for r in children)
+    assert sum(r["t1"] - r["t0"] for r in children) <= op["t1"] - op["t0"]
+
+
+# ------------------------------------------- the readers of the records
+
+NEW_READERS = ("traverse_host_s.unwaited", "traverse_host_s.sparse_expand",
+               "traverse_host_s.self", "traverse_stall_s", "warm_trace_s",
+               "warm_lower_s", "warm_compile_s", "warm_cache_load_s",
+               "warm_other_s")
+
+
+def _reader(name: str):
+    bench = os.path.join(ROOT, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    spec = importlib.util.spec_from_file_location(
+        f"layer_metrics.{name}",
+        os.path.join(bench, "layer_metrics", f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.fixture
+def a_run(monkeypatch):
+    """A ring of its own holding what a run of the benchmark leaves: a
+    warm-up operation on a fresh snapshot (it compiles or loads every
+    program), then a window of three."""
+    from hypergraphdb_tpu.obs.flight import FlightRecorder
+
+    monkeypatch.setattr(obs_device, "_PHASE_LOG", FlightRecorder(
+        capacity=obs_device.PHASE_LOG_CAPACITY, clock=time.perf_counter))
+    jax.clear_caches()          # the warm-up traces and lowers anew
+    snap = _small_snapshot(19)  # built before the clock, as a builder does
+    t0 = time.perf_counter()
+    _operate("bfs_pull", snap)
+    warm_s = time.perf_counter() - t0
+    for _ in range(3):
+        _operate("bfs_pull", snap)
+    return {"window": {"attempted": 3}, "setup": {"warm_s": warm_s}}
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_a_new_reader_returns_a_number_and_none_on_an_emptied_ring(
+        name, a_run):
+    value = _reader(name)(a_run)
+    assert isinstance(value, float) and value >= 0.0
+    assert (value == 0.0) == (name in ("traverse_stall_s",
+                                       "warm_cache_load_s"))
+    obs.phase_log().reset()
+    assert _reader(name)(a_run) is None
+
+
+def test_the_readers_add_up(a_run):
+    from harness import phase_log  # benchmarks/ is on the path by now
+
+    read = {name: _reader(name)(a_run) for name in NEW_READERS}
+    # the warm-up by stage and the rest: warm_s, exactly
+    assert sum(read[n] for n in NEW_READERS[4:]) == \
+        pytest.approx(a_run["setup"]["warm_s"], abs=1e-12)
+    assert 0.0 < read["warm_other_s"] < a_run["setup"]["warm_s"]
+    # self + the children's seconds = the operations' wall, exactly
+    window = phase_log.window_of(a_run)
+    assert len(window.ops) == 3 and len(phase_log.warm_of(a_run).ops) == 1
+    walls = sum(map(phase_log.wall, window.ops))
+    assert read["traverse_host_s.self"] * 3 + sum(
+        map(phase_log.wall, window.children())) == pytest.approx(
+            walls, abs=1e-12)
+    assert read["traverse_host_s.sparse_expand"] \
+        <= read["traverse_host_s.unwaited"] <= walls / 3
+    # a window longer than the ring reaches back to: nothing to read
+    assert _reader("traverse_host_s.self")(
+        dict(a_run, window={"attempted": 5})) is None
 
 
 # ------------------------------------------------- the reader of the scopes
