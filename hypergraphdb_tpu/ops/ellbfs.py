@@ -53,9 +53,11 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   blocks the hop's plan can reach and no other (``_active_blocks``: a row
   is reached only if its atom has an incidence set, so a store that lays
   entities out before links folds the entities' blocks alone).
-- per-seed edge counts (the benchmark numerator) are one exact pass over
-  the bitmap a seed block (``_deg_sum``: bit-unpack, weight by degree and
-  sum in ``int32``, fused on the vector unit) — no gathers.
+- per-seed edge counts (the benchmark numerator) are one exact pass a
+  seed block (``_deg_sum``: bit-unpack, weight by degree and sum in
+  ``int32``, fused on the vector unit) — no gathers — over the row blocks
+  in which the state can hold a bit and no other, as ``_reach_counts`` is
+  (``_bitdot``: the plan's active blocks, and the seeds' own).
 - a LINK PREDICATE (``bfs_pull(..., link_types=F)``: follow a link only if
   its type atom is in ``F`` — ``DefaultALGenerator``'s ``linkPredicate``,
   ``DefaultALGenerator.java:73``, for a family of link types) is not a
@@ -739,52 +741,6 @@ def _build_or_load_plans(snap: CSRSnapshot) -> PullBFSPlans:
 # ------------------------------------------------------------------ kernel
 
 
-# Rows of the bitmap a step of `_bitdot`'s loop counts. The chip keeps
-# nothing of a block's size (tests/test_tpu_compile.py) and hardly cares: a
-# pass over the 10M-atom x 4096-seed bitmap read 47.8 / 44.9 / 44.5 ms at
-# 2^12 / 2^15 / 2^17 rows (PERF.md section 6, PR 28). The CPU backend does
-# write a block's unpacked bits out, block x K x 4 bytes: at 4096 seeds the
-# 0.5 GB it held before, less at every narrower block.
-BITDOT_ROWS = 1 << 15
-
-
-def _bitdot(packed_t: jax.Array, vec: jax.Array,
-            block_rows: int = BITDOT_ROWS) -> jax.Array:
-    """Σ_v vec[v] · bit(v, k) for every seed column k, exactly.
-
-    ``packed_t (R, Kw) uint32``, ``vec (R,) int32`` → ``(K,) int32``; the
-    caller bounds the sums below 2^31. The bits are unpacked shift-major,
-    ``(block_rows, 32, Kw)`` with the words still in the lanes, so that XLA
-    fuses unpack, weight and sum into one loop over the slice and the
-    unpacked bits never reach HBM (unpacked word-major and reshaped to
-    ``(block_rows, K)`` they did: 0.5 GB a block, PERF.md section 6,
-    PR 28); one ``(32, Kw) → K`` transpose after the loop restores the
-    column order ``word * 32 + bit``.
-    """
-    R, Kw = packed_t.shape
-    block_rows = min(block_rows, R)
-    n_blocks = -(-R // block_rows)
-    shifts = jnp.arange(WORD, dtype=jnp.uint32)[None, :, None]
-
-    # fori + clamped dynamic slices instead of pad-and-reshape: the pad
-    # path CONCATENATED (= copied) the whole packed array, a second
-    # visited-bitmap's worth of HBM at 10M atoms × 4096 seeds. The last
-    # block's clamped start overlaps the previous block; the row mask
-    # zeroes the already-counted rows.
-    def body(i, acc):
-        start = jnp.minimum(i * block_rows, R - block_rows)
-        sl = jax.lax.dynamic_slice(packed_t, (start, 0), (block_rows, Kw))
-        w = jax.lax.dynamic_slice(vec, (start,), (block_rows,))
-        fresh = (start + jnp.arange(block_rows)) >= i * block_rows
-        w = jnp.where(fresh, w, 0)
-        bits = ((sl[:, None, :] >> shifts) & 1).astype(jnp.int32)
-        return acc + jnp.sum(bits * w[:, None, None], axis=0)
-
-    acc = jax.lax.fori_loop(0, n_blocks, body,
-                            jnp.zeros((WORD, Kw), jnp.int32))
-    return acc.T.reshape(Kw * WORD)
-
-
 def _program(module: str, scope: Optional[str] = None):
     """Names that survive a refactor, for one stage program (the innermost
     decorator, under ``jax.jit``). ``module``: the XLA module is named
@@ -841,18 +797,6 @@ def _seed_bitmap(seeds: jax.Array, n_atoms: jax.Array, n_pad: int):
     onehot = jnp.zeros((K, Kw), dtype=jnp.uint32).at[k, k >> 5].set(bit)
     visited = jnp.zeros((n_pad, Kw), dtype=jnp.uint32).at[seeds].add(onehot)
     return visited.at[n_atoms].set(jnp.uint32(0))  # dummy row stays zero
-
-
-@hgverify.entry(
-    shapes=lambda: (hgverify.sds((64, 1), "uint32"),
-                    hgverify.sds((64,), "int32")),
-)
-@jax.jit
-@_program("hg_bfs_deg_sum", "hg.bfs.deg_sum")
-def _deg_sum(visited: jax.Array, inc_deg: jax.Array) -> jax.Array:
-    """S = Σ_v visited[v]·deg(v) per seed, exact: bounded by E_inc < 2^31,
-    so int32 cannot wrap."""
-    return _bitdot(visited, inc_deg)
 
 
 @hgverify.entry(
@@ -947,15 +891,24 @@ def _blocks_of(rows: np.ndarray, n_pad: int) -> np.ndarray:
     return blocks
 
 
+def _block_starts(
+    blocks: np.ndarray, n_pad: int, block_rows: int = UPDATE_ROWS,
+) -> tuple[jax.Array, jax.Array]:
+    """The row blocks ``blocks`` marks as a loop reads them, on the device:
+    ``(blocks,) int32`` with the marked blocks' first rows first, and how
+    many they are."""
+    first = np.flatnonzero(blocks) * _block_rows(n_pad, block_rows)
+    starts = np.zeros(len(blocks), dtype=np.int32)
+    starts[: len(first)] = first
+    return jnp.asarray(starts), jnp.asarray(np.int32(len(first)))
+
+
 def _listed(out_map: jax.Array, blocks: np.ndarray,
             block_rows: int = UPDATE_ROWS) -> _UpdateRows:
     """The update's argument for ``out_map`` over the row blocks ``blocks``
     marks."""
-    first = np.flatnonzero(blocks) * _block_rows(out_map.shape[0], block_rows)
-    starts = np.zeros(len(blocks), dtype=np.int32)
-    starts[: len(first)] = first
-    return _UpdateRows(out_map, jnp.asarray(starts),
-                       jnp.asarray(np.int32(len(first))))
+    return _UpdateRows(
+        out_map, *_block_starts(blocks, out_map.shape[0], block_rows))
 
 
 def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
@@ -1127,11 +1080,76 @@ def _sparse_hop(visited, pairs, n_atoms):
     return visited.at[n_atoms].set(jnp.uint32(0))
 
 
-@hgverify.entry(shapes=lambda: (hgverify.sds((64, 1), "uint32"),))
+def _bitdot(packed_t: jax.Array, vec: jax.Array, starts: jax.Array,
+            n_listed: jax.Array, block_rows: int = UPDATE_ROWS) -> jax.Array:
+    """Σ_v vec[v] · bit(v, k) for every seed column k, exactly, over the
+    rows of the LISTED row blocks and no other: ``starts[:n_listed]`` are
+    their first rows (the list an update is handed, ``_UpdateRows``: a slot
+    a block of the bitmap, so the program's shapes follow ``R`` alone), and
+    the caller lists every block in which the state can hold a bit.
+
+    ``packed_t (R, Kw) uint32``, ``vec (R,) int32`` → ``(K,) int32``; the
+    caller bounds the sums below 2^31. The bits are unpacked shift-major,
+    ``(block_rows, 32, Kw)`` with the words still in the lanes, so that XLA
+    fuses unpack, weight and sum into one loop over the slice and the
+    unpacked bits never reach HBM (unpacked word-major and reshaped to
+    ``(block_rows, K)`` they did: 0.5 GB a block, PERF.md section 6,
+    PR 28); one ``(32, Kw) → K`` transpose after the loop restores the
+    column order ``word * 32 + bit``. The chip keeps nothing of a block's
+    size (tests/test_tpu_compile.py); the CPU backend does write a block's
+    unpacked bits out, block x K x 4 bytes.
+    """
+    R, Kw = packed_t.shape
+    ub = _block_rows(R, block_rows)
+    shifts = jnp.arange(WORD, dtype=jnp.uint32)[None, :, None]
+
+    # clamped dynamic slices instead of pad-and-reshape: the pad path
+    # CONCATENATED (= copied) the whole packed array, a second bitmap's
+    # worth of HBM at 10M atoms × 4096 seeds. The ragged last block is
+    # sliced from ``R - ub`` and shares rows with the block before it; a
+    # sum is not idempotent as an update's fold is, so the row mask keeps
+    # the block to its own rows (all of them, in every other block) and a
+    # shared row is counted once when both are listed.
+    def body(i, acc):
+        first = starts[i]
+        start = jnp.minimum(first, R - ub)
+        sl = jax.lax.dynamic_slice(packed_t, (start, 0), (ub, Kw))
+        w = jax.lax.dynamic_slice(vec, (start,), (ub,))
+        own = (start + jnp.arange(ub)) >= first
+        w = jnp.where(own, w, 0)
+        bits = ((sl[:, None, :] >> shifts) & 1).astype(jnp.int32)
+        return acc + jnp.sum(bits * w[:, None, None], axis=0)
+
+    acc = jax.lax.fori_loop(0, n_listed, body,
+                            jnp.zeros((WORD, Kw), jnp.int32))
+    return acc.T.reshape(Kw * WORD)
+
+
+@hgverify.entry(shapes=lambda: (hgverify.sds((64, 1), "uint32"),
+                                hgverify.sds((64,), "int32"),
+                                hgverify.sds((1,), "int32"),
+                                hgverify.sds((), "int32")))
+@jax.jit
+@_program("hg_bfs_deg_sum", "hg.bfs.deg_sum")
+def _deg_sum(visited: jax.Array, inc_deg: jax.Array, starts: jax.Array,
+             n_listed: jax.Array) -> jax.Array:
+    """S = Σ_v visited[v]·deg(v) per seed, exact: bounded by E_inc < 2^31,
+    so int32 cannot wrap. Over the plan's active blocks: a row outside
+    them has no incidence set under the plan, a degree of 0."""
+    return _bitdot(visited, inc_deg, starts, n_listed)
+
+
+@hgverify.entry(shapes=lambda: (hgverify.sds((64, 1), "uint32"),
+                                hgverify.sds((1,), "int32"),
+                                hgverify.sds((), "int32")))
 @jax.jit
 @_program("hg_bfs_reach_counts", "hg.bfs.reach_counts")
-def _reach_counts(visited: jax.Array) -> jax.Array:
-    return _bitdot(visited, jnp.ones((visited.shape[0],), jnp.int32))
+def _reach_counts(visited: jax.Array, starts: jax.Array,
+                  n_listed: jax.Array) -> jax.Array:
+    """The rows that hold bit k, per seed k, over the blocks in which the
+    state can hold a bit at all (``_bfs_pull_device`` keeps that account)."""
+    return _bitdot(visited, jnp.ones((visited.shape[0],), jnp.int32),
+                   starts, n_listed)
 
 
 # The rule that sends a block's first hop the sparse way (module docstring),
@@ -1362,7 +1380,12 @@ def _bfs_pull_device(
     blocks in which the state it replaces can hold a bit (``held``): the
     seeds' own after no step, step 1's plan's active blocks after a sparse
     first step (its pairs are targets of admitted links, rows with an
-    incidence set under ``F_1``), the step's plan's after a dense one."""
+    incidence set under ``F_1``), the step's plan's after a dense one.
+
+    The counting passes fold the blocks in which the state they count can
+    hold a bit: ``_deg_sum`` the last hop's plan's active blocks,
+    ``_reach_counts`` a match's ``held`` as the last step leaves it, a
+    traversal's plan's active blocks beside the seeds' own."""
     grows = update is _visited_update
     n_atoms_dev = jnp.int32(n_atoms)
     expand = partial(_expand, update=update, seeds=seeds, n_atoms=n_atoms_dev,
@@ -1389,6 +1412,7 @@ def _bfs_pull_device(
     # reads (it telescopes over the hops before): one entry, or none where
     # nothing counts edges or no hop runs
     s_ins: list = []
+    last = hops[-1] if hops else None  # the chain's last plan, if any
     if sl is not None:
         visited, _ = expand(visited, hops[0], sl, own_bits=not grows)
         hops = hops[1:]
@@ -1396,18 +1420,45 @@ def _bfs_pull_device(
             s_ins.append(sl.deg)  # S_0 = deg(seed), which the host holds
     for i, hop in enumerate(hops):
         if count_edges and i == len(hops) - 1:
-            # the degree sum once a block, a phase of its own
+            # the degree sum once a block, a phase of its own; a seed
+            # outside the plan's active blocks has a degree of 0 under it
             with phase("hg.bfs.hop.deg_sum") as ph:
                 with ph.step("dispatch"):
-                    s_ins.append(_deg_sum(visited, hop.dev["inc_deg"]))
+                    s_ins.append(_deg_sum(
+                        visited, hop.dev["inc_deg"],
+                        *_count_list(hop.dev["blocks"], hop, n_pad)))
                 ph.wait(s_ins[-1])
         listed = None
         if not grows:
             listed, held = hop.dev["blocks"] | held, hop.dev["blocks"]
         visited, _ = expand(visited, hop, None, listed=listed)
     with phase("hg.bfs.reach_counts"):  # the dispatch: nothing syncs here
-        reach = _reach_counts(visited)
+        if grows:
+            # a visited set holds what its plan can reach, and every seed's
+            # own bit where the seed lies — a link atom, an atom no
+            # admitted link touches
+            held = _blocks_of(seeds[seeds < n_atoms], n_pad)
+            if last is not None:
+                held |= last.dev["blocks"]
+        reach = _reach_counts(visited, *_count_list(held, last, n_pad))
     return visited, s_ins, reach
+
+
+def _count_list(blocks: np.ndarray, hop: Optional[_Hop],
+                n_pad: int) -> tuple[jax.Array, jax.Array]:
+    """What a counting pass is handed beside the bitmap: the row blocks
+    ``blocks`` marks, every block in which the state it counts can hold a
+    bit. Where they ARE ``hop``'s plan's active blocks — seeds that are
+    entities, a match after a step — the list went up with the plan and
+    nothing is uploaded. The two counters say how far the list engages,
+    once a counting dispatch."""
+    reg = default_registry()
+    reg.counter("bfs.count.rows_visited").inc(
+        int(blocks.sum()) * _block_rows(n_pad))
+    reg.counter("bfs.count.rows_total").inc(n_pad)
+    if hop is not None and np.array_equal(blocks, hop.dev["blocks"]):
+        return hop.dev["rows"].starts, hop.dev["rows"].n_listed
+    return _block_starts(blocks, n_pad)
 
 
 def _bitmap_of(seeds: np.ndarray, n_atoms: jax.Array,

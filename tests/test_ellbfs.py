@@ -812,18 +812,35 @@ def test_one_restriction_and_plan_per_family(typed_graph):
 def _bitdot_definition(packed, vec):
     """Σ_r vec[r] · bit(r, k), column k = word * 32 + bit, in int64."""
     bits = (packed[:, :, None] >> np.arange(32, dtype=np.uint32)) & 1
-    return (bits.reshape(len(packed), -1).astype(np.int64)
+    return (bits.reshape(len(packed), 32 * packed.shape[1]).astype(np.int64)
             * vec[:, None].astype(np.int64)).sum(axis=0)
 
 
+#: which row blocks a pass is handed, from the bitmap's number of blocks
+#: (one where the rows are fewer than a block), in the order listed
+BITDOT_LISTS = {
+    "every": lambda n: list(range(n)),
+    "subset": lambda n: [b for b in (2, 0) if b < n - 1],  # never the last
+    "last_alone": lambda n: [n - 1],
+    "last_then_the_one_before": lambda n: [n - 1] + [n - 2] * (n > 1),
+}
+
+
+@pytest.mark.parametrize("listed", list(BITDOT_LISTS))
 @pytest.mark.parametrize("weights", ["ones", "degrees"])
 @pytest.mark.parametrize("rows", ["short", "multiple", "ragged"])
 @pytest.mark.parametrize("kw", [1, 4, 32, 128])
-def test_bitdot_is_exact(kw, rows, weights):
+def test_bitdot_is_exact(kw, rows, weights, listed):
     """Rows fewer than a block (one narrower block), an exact multiple,
     and a ragged last block (its clamped start overlaps the block before;
-    the ``fresh`` mask counts each row once). Degrees run to 800,000 and
-    the column sums past 2^24, where a ``float32`` sum rounds."""
+    the row mask keeps it to its own rows). Degrees run to 800,000 and
+    the column sums past 2^24, where a ``float32`` sum rounds.
+
+    The pass counts the rows of the blocks it is handed and no other:
+    every block listed it is the definition; a strict subset, the
+    definition over those rows alone; the clamped last block alone counts
+    none of the rows its slice shares with the block before, and listed
+    with that block — in either order — each of them once."""
     block = 64
     R = {"short": 37, "multiple": 4 * block, "ragged": 3 * block + 29}[rows]
     r = np.random.default_rng(kw * 1000 + R)
@@ -831,14 +848,26 @@ def test_bitdot_is_exact(kw, rows, weights):
     packed[:, 0] |= np.uint32(1)  # a column every row counts in
     vec = (np.ones(R, np.int32) if weights == "ones"
            else r.integers(600_000, 800_001, size=R).astype(np.int32))
-    want = _bitdot_definition(packed, vec)
-    if weights == "degrees":
+    n_blocks = -(-R // min(block, R))
+    blocks = np.isin(np.arange(n_blocks), BITDOT_LISTS[listed](n_blocks))
+    counted = np.repeat(blocks, block)[:R]
+    want = _bitdot_definition(packed[counted], vec[counted])
+    if weights == "degrees" and listed == "every":
         assert want[0] > 1 << 24 and want.max() < 1 << 31
-    got = np.asarray(eb._bitdot(packed, vec, block))
+    # column 0 holds every row: a strict subset counts fewer of them
+    assert (want[0] == vec.sum()) == bool(blocks.all())
+    # in the order the case lists them: it is no part of the answer
+    order = np.asarray(BITDOT_LISTS[listed](n_blocks), np.int32)
+    starts = np.zeros(n_blocks, np.int32)
+    starts[: len(order)] = order * min(block, R)
+    got = np.asarray(eb._bitdot(packed, vec, jnp.asarray(starts),
+                                jnp.int32(len(order)), block))
     assert got.dtype == np.int32 and got.shape == (kw * 32,)
     assert np.array_equal(got, want)
-    # the block size is no part of the answer
-    assert np.array_equal(np.asarray(eb._bitdot(packed, vec)), want)
+    if listed == "every":
+        # nor is the block size: at the module's own the bitmap is one block
+        whole = eb._bitdot(packed, vec, *eb._block_starts(blocks[:1], R))
+        assert np.array_equal(np.asarray(whole), want)
 
 
 @pytest.mark.parametrize("count_edges", [True, False])
@@ -1245,6 +1274,9 @@ def test_plan_block_list_covers_every_row_a_hop_can_reach(graph, spread):
         assert blocks.tolist() == [True, False, True, False]
     if graph == "one_family":
         assert blocks.tolist() == [False, False, True, False]
+    # a row of an unlisted block has no incidence set under the plan: the
+    # degree sum may skip it, a seed's own bit there weighs nothing
+    assert not plans.inc_deg[np.repeat(~blocks, ub)[: plans.n_pad]].any()
     dev = eb._device_plans(snap, plans)
     assert np.array_equal(dev["blocks"], blocks)
     n = int(dev["rows"].n_listed)
@@ -1357,3 +1389,105 @@ def test_path_match_where_the_active_blocks_differ_by_step(
     assert rows.total == n_dense * plans_for(snap).n_pad
     bitmap = np.asarray(res.frontier_t)
     assert not bitmap[np.repeat(~held, eb.UPDATE_ROWS)[: len(bitmap)]].any()
+
+
+# ------------------------------------------ the counting passes' row blocks
+#
+# ``_deg_sum`` and ``_reach_counts`` fold the row blocks in which the state
+# they count can hold a bit (``_bitdot``'s list) and no other: the plan's
+# active blocks, beside them the blocks of a traversal's seeds — a seed
+# keeps its own bit where nothing reaches it — and after a match's last
+# step what ``_frontier_replace`` leaves non-zero. ``spread_snapshot``'s
+# blocks: 0 holds A, 1 atoms nothing touches, 2 holds B, 3 the links.
+
+
+class _CountRowsCounted(_UpdateRowsCounted):
+    """The same of the counting passes dispatched inside the block."""
+
+    NAMES = ("bfs.count.rows_visited", "bfs.count.rows_total")
+
+
+def _count_seeds(spread, kind):
+    snap, a, b, links = spread
+    pad = [snap.num_atoms] * 3
+    return np.concatenate({
+        "entities_of_b": [b[:20], pad],
+        "a_link_too": [b[:20], links[5:6], pad],
+        # a link atom, an atom in a block no plan lists, the last atom
+        # before the dummy row, entities of both ranges, pad seeds
+        "every_kind": [a[:9], b[:9], links[:2], pad,
+                       [5, eb.UPDATE_ROWS + 7, links[-1]]],
+    }[kind]).astype(np.int32)
+
+
+COUNT_SEED_BLOCKS = {"entities_of_b": [2], "a_link_too": [2, 3],
+                     "every_kind": [0, 1, 2, 3]}
+
+
+@pytest.mark.parametrize("count_edges", [True, False])
+@pytest.mark.parametrize("kind", list(COUNT_SEED_BLOCKS))
+@pytest.mark.parametrize("typed", [False, True])
+@pytest.mark.parametrize("first_hop", ["sparse", "dense"])
+@pytest.mark.parametrize("hops", [0, 1, 3])
+def test_traversal_counts_fold_the_blocks_that_can_hold_a_bit(
+        spread, hops, first_hop, typed, kind, count_edges, monkeypatch):
+    """``reach_counts`` and ``edges_touched`` are the host's whatever the
+    seeds are, and the counters say which blocks each pass folded: the
+    degree sum the plan's active blocks (a seed elsewhere has no admitted
+    link), the reach count those and the seeds' own, the seeds' own alone
+    where no hop runs."""
+    snap = spread[0]
+    fam = (2,) if typed else None
+    seeds = _count_seeds(spread, kind)
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_hop == "sparse" else 1 << 62)
+    with _Sides() as ran, _CountRowsCounted() as rows:
+        res = bfs_pull(snap, seeds, hops, link_types=fam,
+                       count_edges=count_edges)
+    assert ran.dense == max(0, hops - (first_hop == "sparse"))
+    active = np.zeros(4, dtype=bool)
+    if hops:
+        active[[2] if typed else [0, 2]] = True
+    held = active.copy()
+    held[COUNT_SEED_BLOCKS[kind]] = True
+    deg_sums = int(count_edges and ran.dense > 0)
+    assert rows.visited == \
+        (deg_sums * int(active.sum()) + int(held.sum())) * eb.UPDATE_ROWS
+    assert rows.total == (deg_sums + 1) * plans_for(snap).n_pad
+    reach = np.asarray(res.reach_counts)
+    assert reach.dtype == np.int32 and reach.shape == (len(seeds),)
+    for k, s in enumerate(seeds.tolist()):
+        want, edges = host_bfs(snap, s, hops, family=fam)
+        if s == snap.num_atoms:
+            want, edges = set(), 0
+        assert reach[k] == len(want), f"seed {s} (column {k})"
+        assert res.edges_touched[k] == (edges if count_edges else 0)
+
+
+@pytest.mark.parametrize("kind", list(COUNT_SEED_BLOCKS))
+@pytest.mark.parametrize("first_step", ["sparse", "dense"])
+@pytest.mark.parametrize("path", ["no_step", "moves", "shrinks", "grows",
+                                  "every_link_then_b"])
+def test_match_counts_fold_the_blocks_the_last_step_leaves(
+        spread, path, first_step, kind, monkeypatch):
+    """``match_counts`` is ``match_path``'s, and its one pass folds what
+    the state holds after the last step: that step's plan's active blocks
+    — whatever blocks the seeds lie in, the replaced frontier is zero
+    there — and the seeds' own where no step runs."""
+    snap = spread[0]
+    steps = SPREAD_PATHS.get(path, [])
+    seeds = _count_seeds(spread, kind)
+    monkeypatch.setattr(eb, "SPARSE_SHARE",
+                        1 if first_step == "sparse" else 1 << 62)
+    with _CountRowsCounted() as rows:
+        res = eb.path_match(snap, seeds, steps)
+    _assert_matches_reference(_SnapshotGraph(snap), snap.num_atoms, seeds,
+                              steps, res)
+    if steps:
+        last = steps[-1]
+        sub = snap if last is None else eb.restricted_for(snap, last)
+        held = eb._active_blocks(plans_for(sub))
+    else:
+        held = np.isin(np.arange(4), COUNT_SEED_BLOCKS[kind])
+    assert rows.visited == int(held.sum()) * eb.UPDATE_ROWS
+    assert rows.total == plans_for(snap).n_pad

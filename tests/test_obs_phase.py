@@ -487,8 +487,9 @@ _UPDATE_ARGS = (_u32(64, 1), _u32(9, 1),
 STAGE_PROGRAMS = {
     "_seed_bitmap": ("hg_bfs_seed_bitmap", ("hg.bfs.seed_bitmap",),
                      (_i32(32), _i32()), {"n_pad": 64}),
+    # a counting pass: the bitmap, and the row blocks it folds
     "_deg_sum": ("hg_bfs_deg_sum", ("hg.bfs.deg_sum",),
-                 (_u32(64, 1), _i32(64)), {}),
+                 (_u32(64, 1), _i32(64), _i32(1), _i32()), {}),
     "_sparse_hop": ("hg_bfs_sparse_hop", ("hg.bfs.sparse_hop",),
                     (_u32(64, 1), _i32(2, 16), _i32()), {}),
     "_stage": ("hg_bfs_stage1",
@@ -513,7 +514,7 @@ STAGE_PROGRAMS = {
     "_meet": ("hg_bfs_meet", ("hg.bfs.meet",),
               (_u32(64, 1), _u32(64, 1)), {}),
     "_reach_counts": ("hg_bfs_reach_counts", ("hg.bfs.reach_counts",),
-                      (_u32(64, 1),), {}),
+                      (_u32(64, 1), _i32(1), _i32()), {}),
 }
 
 

@@ -438,10 +438,10 @@ def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
 def test_counting_pass_keeps_its_unpacked_bits_on_the_chip(
         program, one_chip, no_compile_cache):
     """The two counting programs at the cells' bitmap (``n_pad`` 10,000,072
-    rows x 128 words): whatever ``_bitdot`` holds besides its arguments
-    stays far below a block of unpacked bits. Unpacked word-major and
-    reshaped to ``(block_rows, K)`` — across the lane tiling, where XLA
-    cannot fuse the unpack into the sum — each program kept
+    rows x 128 words, the list of its 153 row blocks): whatever ``_bitdot``
+    holds besides its arguments stays far below a block of unpacked bits.
+    Unpacked word-major and reshaped to ``(block_rows, K)`` — across the
+    lane tiling, where XLA cannot fuse the unpack into the sum — each kept
     ``u32[32768,128,32]``, 537,000,448 bytes, and wrote and read it once a
     block: 0.98 TB a traversal to count a bitmap of 5.12 GB (PERF.md
     section 6, PR 28). A profile shows that only on the chip; this is the
@@ -449,8 +449,11 @@ def test_counting_pass_keeps_its_unpacked_bits_on_the_chip(
     from hypergraphdb_tpu.ops import ellbfs as eb
 
     visited = _sds((_N_PAD, _KW), "uint32")
+    # the row blocks to fold: a slot a block of the bitmap, and how many
+    listed = (_sds((-(-_N_PAD // eb.UPDATE_ROWS),), "int32"),
+              _sds((), "int32"))
     args = ((visited, _sds((_N_PAD,), "int32")) if program == "_deg_sum"
-            else (visited,))
+            else (visited,)) + listed
     compiled = getattr(eb, program).lower(*_place(args, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes <= 64 * 2**20
 
