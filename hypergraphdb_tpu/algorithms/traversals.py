@@ -297,6 +297,32 @@ def dijkstra(
     return None
 
 
+def connected_components(graph, generator: Optional[HGALGenerator] = None
+                         ) -> dict[int, int]:
+    """Which atoms hang together: ``{atom: label}`` for every atom of
+    ``graph.atoms()``, the label being the least atom id of the atom's
+    component under ``generator``'s adjacency (:class:`SimpleALGenerator`
+    when None; a :class:`DefaultALGenerator` with a ``link_predicate`` for
+    a link family) — LDBC Graphalytics' WCC. The atoms are taken in id
+    order, and from each one not labelled yet a
+    :class:`HGBreadthFirstTraversal` runs to exhaustion: the start and
+    everything it yields take the start's id, which is the least of its
+    component because every smaller atom came first. The adjacency is
+    symmetric (two atoms share a link), so the traversal finds the whole
+    component. The plain reference of ``ops.ellbfs.connected_components``,
+    independent of it."""
+    gen = generator or SimpleALGenerator(graph)
+    label: dict[int, int] = {}
+    for atom in graph.atoms():
+        atom = int(atom)
+        if atom in label:
+            continue
+        label[atom] = atom
+        for _, nbr in HGBreadthFirstTraversal(graph, atom, gen):
+            label[nbr] = atom
+    return label
+
+
 def has_cycles(graph, start: HGHandle, generator: Optional[HGALGenerator] = None) -> bool:
     """Cycle detection from a start atom (``GraphClassics.hasCycles`` :40),
     treating generated adjacency as directed edges."""

@@ -9,10 +9,12 @@ from hypergraphdb_tpu.ops.bitfrontier import (
     unpack_visited,
 )
 from hypergraphdb_tpu.ops.ellbfs import (
+    ComponentsResult,
     PairDistResult,
     PathMatchResult,
     PullBFSResult,
     bfs_pull,
+    connected_components,
     pair_distances,
     path_match,
     visited_rows,
@@ -41,6 +43,7 @@ from hypergraphdb_tpu.ops.checkpoint import (
 __all__ = [
     "AOTCache",
     "CSRSnapshot",
+    "ComponentsResult",
     "DeviceSnapshot",
     "PairDistResult",
     "PathMatchResult",
@@ -53,6 +56,7 @@ __all__ = [
     "bfs_levels",
     "bfs_pull",
     "collect_pattern",
+    "connected_components",
     "execute_pattern",
     "pair_distances",
     "path_match",

@@ -78,6 +78,11 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   column by column) after every expansion — HOW FAR apart, a length per
   pair (``GraphClassics.dijkstra`` at unit weights), where the hop count
   follows the data: a batch ends when its last pair is met or exhausted.
+- one WHOLE-GRAPH operator runs the same plan: :func:`connected_components`,
+  min-label rounds until a fixpoint — the pyramid and the fold reduce with
+  the state's reduction (``_reduction``: OR over uint32 seed words, min over
+  a flat int32 vector of labels, the buffer's zero row and every padded
+  index holding its identity), the gather the XLA one of 4-byte scalars.
 
 Geometry note: each gather row is ``Kw = K/32`` uint32 words (32 lanes for
 K=1024). Gathers remain the dominant cost and are bound by the indices
@@ -91,7 +96,7 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass
 from functools import partial
-from typing import NamedTuple, Optional, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +109,51 @@ from hypergraphdb_tpu.ops import pallas_gather as _pg
 from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 
 WORD = 32
+
+#: The identity of the min over labels: what the buffer's zero row and every
+#: padded index of a label pyramid read, and a label no atom has.
+INT32_MAX = int(np.iinfo(np.int32).max)
+
+
+class _Reduction(NamedTuple):
+    """What a pyramid and a fold reduce with: ``combine`` is elementwise,
+    associative, commutative and idempotent (a row folded twice, a padded
+    index, say the same), and ``identity`` is what the buffer's zero row
+    and every padded index hold."""
+
+    combine: Callable
+    identity: int
+
+
+#: OR over ``(S, Kw)`` uint32 rows of seed bits: a traversal, a match, a
+#: pair search.
+_OR_WORDS = _Reduction(jnp.bitwise_or, 0)
+#: min over a flat ``(S,)`` int32 vector of labels:
+#: :func:`connected_components`.
+_MIN_LABELS = _Reduction(jnp.minimum, INT32_MAX)
+
+
+def _reduction(state) -> _Reduction:
+    """The reduction of a state, where it enters a pyramid or a fold (the
+    values of ``_apply_plan``, the buffer of ``_reduce_into``, the state of
+    ``_fold_rows``), passed down from there: ``(S, Kw)`` uint32 seed words
+    take the OR, a flat ``(S,)`` int32 label vector the min, and any other
+    state is an error, never a default. One pyramid and one fold serve
+    both; the bitmap programs lower to the text they had before the labels
+    came."""
+    if state.ndim == 2 and state.dtype == jnp.uint32:
+        return _OR_WORDS
+    if state.ndim == 1 and state.dtype == jnp.int32:
+        return _MIN_LABELS
+    raise TypeError(f"no reduction for a {state.dtype} state of shape "
+                    f"{state.shape}: (S, Kw) uint32 words or (S,) int32 "
+                    f"labels")
+
+
+def _at(row, x) -> tuple:
+    """The start index of row ``row`` of ``x`` for a dynamic (update) slice,
+    whatever the rank: ``(row, 0)`` a bitmap's, ``(row,)`` a vector's."""
+    return (row,) + (0,) * (x.ndim - 1)
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -278,23 +328,25 @@ def _segmented_ranges(starts: np.ndarray, reps: np.ndarray) -> np.ndarray:
 
 
 def _reduce_level(
-    values: jax.Array,  # (S, Kw) uint32 value rows; padding rows index S-1.. caller
+    values: jax.Array,  # (S, Kw) uint32 rows, or (S,) int32 labels
     idx: jax.Array,     # (E,) int32, multiple of w
     w: int,
     chunk: int,
     use_pallas: bool = False,
+    red: _Reduction = _OR_WORDS,
 ) -> jax.Array:
-    """gather + fixed-width OR-reduce, streamed in ``chunk``-row slices to
-    bound the gather transient: returns (E//w, Kw) uint32."""
+    """gather + fixed-width reduction by ``red``, streamed in ``chunk``-row
+    slices to bound the gather transient: returns (E//w,) + a value row's
+    shape. The kernel serves the OR alone."""
     E = idx.shape[0]
-    Kw = values.shape[1]
+    row = values.shape[1:]  # (Kw,) seed words; () a label
     n_out = E // w
-    if (use_pallas and E >= _pg.MIN_INDICES
-            and _pg.declined(w, Kw) is None):
+    if (use_pallas and red is _OR_WORDS and E >= _pg.MIN_INDICES
+            and _pg.declined(w, values.shape[1]) is None):
         return _pg.gather_or(values, idx, w)
     if E <= chunk * w:
         g = values[idx]
-        return _or_fold(g.reshape(n_out, w, Kw))
+        return _fold(g.reshape(n_out, w, *row), red)
     # pad out rows to a multiple of chunk for the scan
     n_blocks = -(-n_out // chunk)
     pad_rows = n_blocks * chunk - n_out
@@ -306,29 +358,30 @@ def _reduce_level(
 
     def body(_, ib):
         g = values[ib]
-        return None, _or_fold(g.reshape(chunk, w, Kw))
+        return None, _fold(g.reshape(chunk, w, *row), red)
 
     _, out = jax.lax.scan(body, None, idx_b)
-    out = out.reshape(n_blocks * chunk, Kw)
+    out = out.reshape(n_blocks * chunk, *row)
     return out[:n_out] if pad_rows else out
 
 
-def _or_fold(x: jax.Array) -> jax.Array:
-    """(R, w, Kw) → (R, Kw) OR over axis 1 as a log-depth fold."""
+def _fold(x: jax.Array, red: _Reduction) -> jax.Array:
+    """(R, w, ...) → (R, ...): ``red`` over axis 1 as a log-depth fold; an
+    odd width is padded with its identity."""
     w = x.shape[1]
     while w > 1:
         if w % 2:
             x = jnp.concatenate(
-                [x, jnp.zeros_like(x[:, :1])], axis=1
+                [x, jnp.full_like(x[:, :1], red.identity)], axis=1
             )
             w += 1
-        x = x[:, 0::2] | x[:, 1::2]
+        x = red.combine(x[:, 0::2], x[:, 1::2])
         w //= 2
     return x[:, 0]
 
 
 def _apply_plan(
-    values: jax.Array,            # (S, Kw) uint32 — level-0 value rows
+    values: jax.Array,            # (S, Kw) uint32 rows, or (S,) int32 labels
     levels: Sequence[jax.Array],
     widths: Sequence[int],
     n_lvl0: int,
@@ -352,13 +405,16 @@ def _apply_plan(
     global zero row); their outputs are small enough to materialize.
 
     ``scopes`` names the device operations of (level 0, the upper levels)
-    for a profile, as ``jax.named_scope`` components of their ``op_name``."""
-    Kw = values.shape[1]
+    for a profile, as ``jax.named_scope`` components of their ``op_name``.
+    The reduction is the values' (``_reduction``, strict): the buffer
+    starts as its identity, so the zero row and every padded index read
+    it."""
     sizes = [lvl.shape[0] // w for lvl, w in zip(levels, widths)]
     total = sum(sizes) + 1  # + global zero row at index `sum(sizes)`
     lvl0_scope, upper_scope = map(jax.named_scope, scopes)
     with lvl0_scope:
-        buf = jnp.zeros((total, Kw), dtype=values.dtype)
+        buf = jnp.full((total, *values.shape[1:]),
+                       _reduction(values).identity, dtype=values.dtype)
         buf = _reduce_classes(buf, values, levels[:n_lvl0], widths[:n_lvl0],
                               chunk, use_pallas)
     if n_lvl0 == len(levels):  # no row above the widest class
@@ -432,11 +488,13 @@ def _reduce_into(
     chunk: int,
     use_pallas: bool,
 ) -> jax.Array:
-    """OR-reduce ``values`` rows over ``idx`` groups of ``w``, writing the
+    """Reduce ``values`` rows over ``idx`` groups of ``w`` by the buffer's
+    reduction (``_reduction``, strict), writing the
     ``len(idx)//w`` output rows into ``buf[off:]`` in place: full blocks of
     ``chunk`` outputs stream through a scan (carry = buf, aliased by XLA),
     the ragged tail lands with one final update. ``values=None`` gathers
     from ``buf`` itself (the rows read must lie outside ``buf[off:]``)."""
+    red = _reduction(buf)
     E = idx.shape[0]
     n_out = E // w
     n_full = n_out // chunk
@@ -446,9 +504,9 @@ def _reduce_into(
         def body(b, ib_i):
             ib, i = ib_i
             out = _reduce_level(b if values is None else values, ib, w,
-                                chunk, use_pallas)
+                                chunk, use_pallas, red)
             return jax.lax.dynamic_update_slice(
-                b, out, (off + i * chunk, 0)
+                b, out, _at(off + i * chunk, b)
             ), None
 
         buf, _ = jax.lax.scan(
@@ -458,10 +516,10 @@ def _reduce_into(
     if tail:
         out = _reduce_level(
             buf if values is None else values,
-            idx[n_full * chunk * w :], w, chunk, use_pallas
+            idx[n_full * chunk * w :], w, chunk, use_pallas, red
         )
         buf = jax.lax.dynamic_update_slice(
-            buf, out, (off + n_full * chunk, 0)
+            buf, out, _at(off + n_full * chunk, buf)
         )
     return buf
 
@@ -911,42 +969,69 @@ def _listed(out_map: jax.Array, blocks: np.ndarray,
         out_map, *_block_starts(blocks, out_map.shape[0], block_rows))
 
 
+class _Gain(NamedTuple):
+    """What a fold counts beside the state, from the two operands it holds
+    anyway (the state's block and what the stage buffer sends it): no pass
+    of its own. ``init(row)`` starts the count, a value row's shape given;
+    ``step(count, cur, reached)`` adds a block's."""
+
+    init: Callable
+    step: Callable
+
+
+#: The columns that GREW (``_ball_update``): ``(Kw,) uint32``, the OR over
+#: the folded rows of the bits the stage buffer holds and the state did not
+#: — bit k of word w says column ``32 w + k`` gained a row.
+_GREW = _Gain(lambda row: jnp.zeros(row, jnp.uint32),
+              lambda new, cur, reached: new | _or_rows(reached & ~cur))
+
+#: The rows a round LOWERED (``_wcc_round``): ``() int32``, the folded rows
+#: whose label the buffer's is below. A row folded twice (the ragged last
+#: block) is lowered the first time only.
+_LOWERED = _Gain(lambda row: jnp.int32(0),
+                 lambda n, cur, reached: n + jnp.sum(reached < cur,
+                                                     dtype=jnp.int32))
+
+
 def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
-               block_rows: int = UPDATE_ROWS, grew: bool = False):
+               block_rows: int = UPDATE_ROWS, gain: Optional[_Gain] = None):
     """``state[v] = combine(state[v], reach_chunks[out_map[v]])`` for every
     row of the LISTED row blocks and no other, folded a block at a time so
-    no second (n_pad, Kw) array materializes while the stage buffer is
-    alive (the loop's carry aliases in place, whatever its trip count);
-    the dummy row (``n_atoms``) is zeroed last. The bitmap's ragged last
+    no second state array materializes while the stage buffer is alive
+    (the loop's carry aliases in place, whatever its trip count); the
+    dummy row (``n_atoms``) is set to the state's identity (``_reduction``:
+    a bitmap's 0, a label's ``INT32_MAX``) last. The state's ragged last
     block is folded from ``n_pad - block_rows``: the rows it shares with
-    the block before are folded twice in one pass at most, and both
-    ``combine``s give the same row both times.
+    the block before are folded twice in one pass at most, and every
+    ``combine`` gives the same row both times. The state is ``(n_pad, Kw)``
+    uint32 words or ``(n_pad,)`` int32 labels.
 
-    ``grew``: also return ``(Kw,) uint32``, the OR over the folded rows of
-    the bits the stage buffer holds and the state did not — bit k of word
-    w says column ``32 w + k`` gained a row. Both operands are at hand in
-    the fold, so it costs no pass of its own; without it the carry is the
-    state alone and the program is what it was."""
-    n_pad, Kw = state.shape
+    ``gain``: also return what it counts over the folded rows (``_GREW``,
+    ``_LOWERED``). Both operands are at hand in the fold, so it costs no
+    pass of its own; without it the carry is the state alone and the
+    program is what it was."""
+    n_pad, row = state.shape[0], state.shape[1:]
+    identity = _reduction(state).identity
     ub = _block_rows(n_pad, block_rows)
 
     def fold(i, carry):
-        nxt, new = carry
+        nxt, count = carry
         start = jnp.minimum(rows.starts[i], n_pad - ub)
-        cur = jax.lax.dynamic_slice(nxt, (start, 0), (ub, Kw))
+        cur = jax.lax.dynamic_slice(nxt, _at(start, nxt), (ub, *row))
         sl = jax.lax.dynamic_slice(rows.out_map, (start,), (ub,))
         reached = reach_chunks[sl]
-        if grew:
-            new = new | _or_rows(reached & ~cur)
+        if gain is not None:
+            count = gain.step(count, cur, reached)
         return jax.lax.dynamic_update_slice(
-            nxt, combine(cur, reached), (start, 0)
-        ), new
+            nxt, combine(cur, reached), _at(start, nxt)
+        ), count
 
-    nxt, new = jax.lax.fori_loop(
+    nxt, count = jax.lax.fori_loop(
         0, rows.n_listed, fold,
-        (state, jnp.zeros((Kw,), jnp.uint32) if grew else None))
-    nxt = nxt.at[n_atoms].set(jnp.uint32(0))
-    return (nxt, new) if grew else nxt
+        (state, None if gain is None else gain.init(row)))
+    nxt = nxt.at[n_atoms].set(
+        jnp.asarray(identity, state.dtype))
+    return nxt if gain is None else (nxt, count)
 
 
 def _or_rows(x: jax.Array) -> jax.Array:
@@ -1001,7 +1086,7 @@ def _ball_update(ball, reach_chunks, rows, n_atoms):
     (:func:`pair_distances`' exhaustion). A program of its own because the
     traversal's update returns the bitmap alone and is left as it is."""
     return _fold_rows(ball, reach_chunks, rows, n_atoms,
-                      lambda cur, reached: cur | reached, grew=True)
+                      lambda cur, reached: cur | reached, gain=_GREW)
 
 
 #: Rows of the two bitmaps a step of the meet test's loop folds: one AND
@@ -1809,6 +1894,145 @@ def _pair_block(hop: Optional[_Hop], n_atoms: int, n_pad: int, ends: tuple,
     if depth < max_hops:
         reg.counter("bfs.pairs.early_exits").inc()
     return dist, depth
+
+
+# ------------------------------------------------- connected components
+
+
+@hgverify.entry(shapes=lambda: (hgverify.sds((), "int32"),),
+                statics={"n_pad": 64})
+@partial(jax.jit, static_argnames=("n_pad",))
+@_program("hg_wcc_init", "hg.wcc.init")
+def _wcc_init(n_atoms: jax.Array, n_pad: int) -> jax.Array:
+    """``label[a] = a`` for every atom; the dummy row and the pad rows hold
+    ``INT32_MAX``, the min's identity, which no link ever lowers."""
+    ids = jnp.arange(n_pad, dtype=jnp.int32)
+    return jnp.where(ids < n_atoms, ids, INT32_MAX)
+
+
+def _wcc_round_shapes():
+    i32 = partial(hgverify.sds, dtype="int32")
+    return (i32((64,)), (i32((32,)), i32((64,))), (i32((32,)),),
+            _UpdateRows(i32((64,)), i32((1,)), i32(())), i32(()))
+
+
+@hgverify.entry(shapes=_wcc_round_shapes, donate=True,
+                statics={"widths1": (2, 8), "n1": 2, "widths2": (2,),
+                         "n2": 1, "chunk": 4})
+@partial(jax.jit, static_argnames=("widths1", "n1", "widths2", "n2", "chunk"),
+         donate_argnums=(0,))  # the labels alias the output
+@_program("hg_wcc_round")
+def _wcc_round(labels, levels1, levels2, rows, n_atoms, widths1, n1,
+               widths2, n2, chunk):
+    """One synchronous min-label round over a pull plan, ONE program: stage
+    1 (each link's min over its targets' labels), stage 2 (each atom's min
+    over its incident links' — level 0 composed through stage 1's chunks,
+    then its upper levels) and the fold ``label[v] = min(label[v],
+    buf[out_map[v]])`` over the plan's active row blocks, which returns
+    beside the labels the rows it LOWERED, ``() int32``. The pyramids are
+    ``bfs_pull``'s with the labels' reduction (``_reduction``: min,
+    identity ``INT32_MAX``) and the XLA gather of 4-byte scalars; the
+    stage buffers are a few tens of MB at 10M atoms, so one program holds
+    the round where a 4096-seed hop needs four."""
+    live = _apply_plan(labels, levels1, widths1, n1, chunk, False,
+                       scopes=("hg.wcc.stage1", "hg.wcc.stage1"))
+    reach = _apply_plan(live, levels2, widths2, n2, chunk, False,
+                        scopes=("hg.wcc.stage2", "hg.wcc.stage2"))
+    with jax.named_scope("hg.wcc.fold"):
+        return _fold_rows(labels, reach, rows, n_atoms, jnp.minimum,
+                          gain=_LOWERED)
+
+
+@hgverify.entry(shapes=lambda: (hgverify.sds((64,), "int32"),))
+@jax.jit
+@_program("hg_wcc_count", "hg.wcc.count")
+def _wcc_count(labels: jax.Array) -> jax.Array:
+    """The atoms that are their own label: one a component. The dummy and
+    pad rows hold ``INT32_MAX``, no row's index."""
+    return jnp.sum(labels == jnp.arange(labels.shape[0], dtype=jnp.int32),
+                   dtype=jnp.int32)
+
+
+class ComponentsResult(NamedTuple):
+    labels: jax.Array    # (N_pad,) int32 on the device; dummy row INT32_MAX
+    n_components: int    # host: the atoms whose label is their own id
+    rounds: int          # host: rounds run, the last of them quiet
+
+
+def connected_components(snap: CSRSnapshot, link_types=None, *,
+                         chunk: int = 1 << 16) -> ComponentsResult:
+    """Which atoms hang together: the weakly connected components of the
+    hypergraph under a link family — LDBC Graphalytics' WCC, and what
+    ``HGBreadthFirstTraversal(start, DefaultALGenerator(linkPredicate))``
+    yields run to exhaustion, ``start`` added (the plain reference is
+    ``algorithms/traversals.connected_components``). Two atoms are
+    ADJACENT iff some link of a type in ``link_types`` (``None``: every
+    link) holds both among its targets — :func:`bfs_pull`'s adjacency: a
+    link atom is adjacent to the co-targets of the links that hold it, not
+    to its own targets. Then
+
+        label[a] = min {b : b reachable from a} ∪ {a}
+
+    so an atom in no admitted link (a type atom, a link of another family,
+    an isolated entity) is its own component, and ``n_components`` counts
+    the atoms with ``label[a] == a``. Departure from the reference: its
+    link-as-node form (``HyperTraversal``, a link in its targets'
+    component) is not built — it would need each link's label kept
+    between the stages, where the plan composes them away.
+
+    Synchronous min-label propagation: ``label_0[a] = a``, and a round is
+    ``label_{r+1}[a] = min(label_r[a], min over the neighbours b of
+    label_r[b])`` — after r rounds ``label_r[a]`` is the least id within r
+    hops of ``a``, so the fixpoint is ``label``. A round is ONE program
+    (``_wcc_round``: :func:`bfs_pull`'s two pyramids over the family's
+    restricted plan, with the min in place of the OR, and the fold over
+    the plan's active row blocks, which counts the rows it lowered); the
+    host reads that count, 4 bytes, and stops after the first round that
+    lowered none. So the rounds follow the data — the longest shortest
+    path from an atom to its component's least id, plus the quiet one —
+    and none is skipped: a round after the fixpoint's is as dear as the
+    first. Nothing is kept between calls; a second call on the same
+    inputs runs every round again. A family that admits no link runs no
+    round: every atom is its own label.
+
+    Memory: the labels, ``(N_pad,)`` int32 — 4 bytes an atom, 40 MB at 10M
+    atoms, flat so that no tile pads a label to a row — donated from
+    round to round, beside the plan and two stage buffers of one int32 a
+    chunk. Returns ``ComponentsResult(labels, n_components, rounds)``:
+    the labels on the device (rows ``0 … n_atoms-1``; the dummy row
+    holds ``INT32_MAX``), the other two on the host. ``chunk``: the scan
+    grain of the pyramids' level 0 (``chunk * STEP_WIDTH`` indices a
+    step)."""
+    reg = default_registry()
+    with phase("hg.wcc") as op:
+        if link_types is not None:
+            snap = restricted_for(snap, link_types)
+        n_pad = _n_pad(snap.num_atoms)
+        n_atoms = jnp.int32(snap.num_atoms)
+        reg.counter("wcc.runs").inc()
+        labels = _wcc_init(n_atoms, n_pad)
+        rounds = 0
+        if snap.n_edges_tgt:  # else no link to follow: every atom alone
+            _, plans, dev = _hop_over(snap)
+            s1, rows = plans.stage1, dev["rows"]
+            folded = int(dev["blocks"].sum()) * _block_rows(n_pad)
+            lowered = 1
+            while lowered:
+                with phase("hg.wcc.round") as ph:
+                    with ph.step("dispatch"):
+                        labels, lowered = _wcc_round(
+                            labels, dev["levels1"], dev["levels2"], rows,
+                            n_atoms, s1.widths, s1.n_lvl0,
+                            plans.stage2_widths, plans.stage2_n_lvl0, chunk)
+                    with ph.step("wait"):  # the read of its 4 bytes
+                        lowered = int(lowered)
+                rounds += 1
+                reg.counter("wcc.rounds").inc()
+                reg.counter("wcc.rows_lowered").inc(lowered)
+                reg.counter("wcc.rows_folded").inc(folded)
+        with op.step("count"):
+            n_components = int(_wcc_count(labels))
+    return ComponentsResult(labels, n_components, rounds)
 
 
 def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:

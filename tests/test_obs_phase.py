@@ -515,6 +515,18 @@ STAGE_PROGRAMS = {
               (_u32(64, 1), _u32(64, 1)), {}),
     "_reach_counts": ("hg_bfs_reach_counts", ("hg.bfs.reach_counts",),
                       (_u32(64, 1), _i32(1), _i32()), {}),
+    # connected components: one program a round, its three scopes at its
+    # top level, and the two around it
+    "_wcc_init": ("hg_wcc_init", ("hg.wcc.init",), (_i32(),),
+                  {"n_pad": 64}),
+    "_wcc_round": ("hg_wcc_round",
+                   ("hg.wcc.stage1", "hg.wcc.stage2", "hg.wcc.fold"),
+                   (_i32(64), (_i32(32), _i32(64), _i32(16)),
+                    (_i32(32), _i32(16)),
+                    eb._UpdateRows(_i32(64), _i32(1), _i32()), _i32()),
+                   {"widths1": (2, 8, 8), "n1": 2, "widths2": (2, 8),
+                    "n2": 1, "chunk": 4}),
+    "_wcc_count": ("hg_wcc_count", ("hg.wcc.count",), (_i32(64),), {}),
 }
 
 
@@ -597,6 +609,44 @@ def test_dispatch_thread_annotations_are_off_the_unprofiled_path(
                        "hg.serve.launch"):
         pass
     assert seen.names == ["hg.serve.park", "hg.serve.launch"]
+
+
+WCC_COUNTERS = ("wcc.runs", "wcc.rounds", "wcc.rows_lowered",
+                "wcc.rows_folded")
+
+
+def test_connected_components_leaves_its_phases_and_its_four_counters():
+    """Phase ``hg.wcc`` once a call (with its ``count`` step), phase
+    ``hg.wcc.round`` once a round (``dispatch`` / ``wait``), the bitmap
+    chain's hop phases never; the four ``wcc.*`` counters from numbers the
+    host holds: runs, rounds, rows lowered (the rounds' own counts) and
+    rows folded (the plan's listed rows a round)."""
+    def counters():
+        got = [obs.default_registry().get(n) for n in WCC_COUNTERS]
+        return [0 if c is None else c.value for c in got]
+
+    names = ("hg.wcc", "hg.wcc.round") + HOP_PHASES
+    snap = _small_snapshot(23)
+    before, c0 = {n: _hist(n)["count"] for n in names}, counters()
+    n = len(_records())
+    res = eb.connected_components(snap)
+    grew = {n: _hist(n)["count"] - before[n] for n in names}
+    assert grew == {"hg.wcc": 1, "hg.wcc.round": res.rounds,
+                    **{n: 0 for n in HOP_PHASES}}
+    mine = _records()[n:]
+    (op,) = [r for r in mine if r["name"] == "hg.wcc"]
+    rounds = [r for r in mine if r["name"] == "hg.wcc.round"]
+    assert all(r["parent"] == op["id"] and r["op"] == op["id"]
+               and sorted(k for k in r if k.startswith("step.")) ==
+               ["step.dispatch", "step.wait"] for r in rounds)
+    assert "step.count" in op
+    n_pad = eb.plans_for(snap).n_pad  # one row block: every row listed
+    labels = np.asarray(res.labels)[: snap.num_atoms]
+    moved = int(np.count_nonzero(labels != np.arange(snap.num_atoms)))
+    runs, rounds, lowered, folded = (
+        now - was for now, was in zip(counters(), c0))
+    assert (runs, rounds, folded) == (1, res.rounds, res.rounds * n_pad)
+    assert lowered >= moved  # a label not its own fell once at least
 
 
 # ------------------------------------------------ an operation's own span

@@ -474,3 +474,53 @@ def test_meet_test_reads_two_bitmaps_and_keeps_nothing(one_chip,
     assert mem.alias_size_in_bytes == 0
     assert mem.output_size_in_bytes <= 4096  # (128,) uint32, tiled
     assert compiled.as_text().startswith("HloModule jit_hg_bfs_meet,")
+
+
+# ------------------- the label round at the connected-components cell's shapes
+
+
+#: The restricted plan of ``wcc10m.family16`` (``dbpedia10m-wcc`` at seed
+#: 4021: 11,992,322 admitted entries; read on the chip by
+#: ``benchmarks/tests/wcc_gather_probe.py --plan-seed 4021``, PR 38): stage
+#: 1's five classes hold every admitted link, stage 2's ten classes, then
+#: the pyramid of its rows above ``W_MAX``; 31 of the 153 row blocks active.
+_T1 = (443_484, 1_781_784, 2_658_372, 3_555_640, 4_442_050)
+_TW1 = (2, 4, 6, 8, 10)
+_T2 = (376_440, 2_249_292, 3_879_924, 3_112_576, 1_457_570, 670_404,
+       95_820, 51_184, 46_640, 1_219_456, 29_640, 3_368, 392, 40)
+_TW2 = (2, 4, 6, 8, 10, 14, 20, 28, 40, 56, 8, 8, 8, 8)
+_TN2 = 10
+
+
+def test_label_round_fits_one_chip_with_a_four_byte_label(one_chip,
+                                                          no_compile_cache):
+    """``_wcc_round`` — both min pyramids and the fold in ONE program — at
+    the cell's plan: the labels are ``(n_pad,)`` int32, 4 bytes a row on the
+    chip (a ``(n_pad, 1)`` column would be tiled to 512 bytes a row, 5.1 GB
+    an argument), donated into the output; what the program holds besides
+    its arguments — two stage buffers of an int32 a chunk and the scan's
+    gather transients — stays under 1 GiB."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
+    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
+    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
+                          _sds((blocks,), "int32"), _sds((), "int32"))
+    args = (_sds((_N_PAD,), "int32"), ints(_T1), ints(_T2), rows,
+            _sds((), "int32"))
+    compiled = eb._wcc_round.lower(
+        *_place(args, one_chip), widths1=_TW1, n1=len(_T1), widths2=_TW2,
+        n2=_TN2, chunk=_CHUNK).compile()
+    mem = compiled.memory_analysis()
+    assert compiled.as_text().startswith("HloModule jit_hg_wcc_round,")
+    assert mem.temp_size_in_bytes < 2**30
+    assert mem.alias_size_in_bytes >= 4 * _N_PAD
+    # the labels, the plan, out_map and the block list at 4 bytes an entry
+    flat = 4 * (2 * _N_PAD + sum(_T1) + sum(_T2) + blocks + 1)
+    assert mem.argument_size_in_bytes < 1.01 * flat
+
+
+def test_the_new_programs_carry_their_hgverify_entries():
+    names = set(hgverify.REGISTRY.names())
+    assert {"ops.ellbfs._wcc_init", "ops.ellbfs._wcc_round",
+            "ops.ellbfs._wcc_count"} <= names

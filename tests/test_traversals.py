@@ -9,6 +9,7 @@ from hypergraphdb_tpu.algorithms.traversals import (
     HGDepthFirstTraversal,
     HyperTraversal,
     SimpleALGenerator,
+    connected_components,
     dijkstra,
     has_cycles,
     shortest_path_length,
@@ -222,3 +223,24 @@ def test_shortest_path_length_under_a_link_predicate(web):
                                 max_distance=3) == -1
     assert shortest_path_length(g, r0, atoms[6], valued("ring", "hyper"),
                                 max_distance=4) == 4
+
+
+# ------------------------------------------------- connected_components
+
+
+@pytest.mark.parametrize("values", [None, ("ring",), ("ring", "hyper"),
+                                    ("chord", "apart"), ()])
+def test_connected_components_is_the_traversal_from_every_atom(web, values):
+    """Every atom's label is the least id of what ``HGBreadthFirstTraversal``
+    reaches from it run to exhaustion (itself added), under each link
+    family — and every atom of the graph has one, links and type atoms
+    among them."""
+    g, _ = web
+    gen = None if values is None else DefaultALGenerator(
+        g, link_predicate=lambda gr, link: gr.get(link).value in values)
+    label = connected_components(g, gen)
+    assert sorted(label) == sorted(int(a) for a in g.atoms())
+    for a in g.atoms():
+        reach = {int(a)} | {int(b) for _, b in HGBreadthFirstTraversal(
+            g, a, gen or SimpleALGenerator(g))}
+        assert label[int(a)] == min(reach), a
