@@ -52,7 +52,9 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   (``_visited_update``, a match's ``_frontier_replace``), over the row
   blocks the hop's plan can reach and no other (``_active_blocks``: a row
   is reached only if its atom has an incidence set, so a store that lays
-  entities out before links folds the entities' blocks alone).
+  entities out before links folds the entities' blocks alone); where the
+  stages gather on ``hg_gather_or``, so does the update's row fetch, at
+  width 1 (``_update_on_kernel``).
 - per-seed edge counts (the benchmark numerator) are one exact pass a
   seed block (``_deg_sum``: bit-unpack, weight by degree and sum in
   ``int32``, fused on the vector unit) — no gathers — over the row blocks
@@ -993,8 +995,22 @@ _LOWERED = _Gain(lambda row: jnp.int32(0),
                                                      dtype=jnp.int32))
 
 
+def _update_on_kernel(state, use_pallas: bool,
+                      block_rows: int = UPDATE_ROWS) -> bool:
+    """Does ``_fold_rows`` fetch a block's rows through ``hg_gather_or`` at
+    width 1? Where the caller's gathers take the kernel (``use_pallas``: a
+    4096-seed block on a TPU, ``_kernel_gathers``), the state is rows of
+    the kernel's width and a block is as many indices as the kernel is
+    worth (``_reduce_level``'s rule). A flat label state and a narrower
+    bitmap keep the XLA gather."""
+    return (use_pallas and state.ndim == 2 and state.dtype == jnp.uint32
+            and _pg.declined(1, state.shape[1]) is None
+            and _block_rows(state.shape[0], block_rows) >= _pg.MIN_INDICES)
+
+
 def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
-               block_rows: int = UPDATE_ROWS, gain: Optional[_Gain] = None):
+               block_rows: int = UPDATE_ROWS, gain: Optional[_Gain] = None,
+               use_pallas: bool = False):
     """``state[v] = combine(state[v], reach_chunks[out_map[v]])`` for every
     row of the LISTED row blocks and no other, folded a block at a time so
     no second state array materializes while the stage buffer is alive
@@ -1009,17 +1025,23 @@ def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
     ``gain``: also return what it counts over the folded rows (``_GREW``,
     ``_LOWERED``). Both operands are at hand in the fold, so it costs no
     pass of its own; without it the carry is the state alone and the
-    program is what it was."""
+    program is what it was.
+
+    A block's rows are fetched through ``hg_gather_or`` at width 1 (one
+    call of ``block_rows`` indices) where ``_update_on_kernel`` says so,
+    else through the XLA gather; the combine is XLA's either way."""
     n_pad, row = state.shape[0], state.shape[1:]
     identity = _reduction(state).identity
     ub = _block_rows(n_pad, block_rows)
+    kernel = _update_on_kernel(state, use_pallas, block_rows)
 
     def fold(i, carry):
         nxt, count = carry
         start = jnp.minimum(rows.starts[i], n_pad - ub)
         cur = jax.lax.dynamic_slice(nxt, _at(start, nxt), (ub, *row))
         sl = jax.lax.dynamic_slice(rows.out_map, (start,), (ub,))
-        reached = reach_chunks[sl]
+        reached = (_pg.gather_or(reach_chunks, sl, 1) if kernel
+                   else reach_chunks[sl])
         if gain is not None:
             count = gain.step(count, cur, reached)
         return jax.lax.dynamic_update_slice(
@@ -1048,21 +1070,26 @@ def _update_shapes():
 
 
 @hgverify.entry(shapes=_update_shapes, donate=True)
-@partial(jax.jit, donate_argnums=(0,))  # visited aliases the output
+@partial(jax.jit, static_argnames=("use_pallas",),
+         donate_argnums=(0,))  # visited aliases the output
 @_program("hg_bfs_visited_update", "hg.bfs.visited_update")
-def _visited_update(visited, reach_chunks, rows, n_atoms):
+def _visited_update(visited, reach_chunks, rows, n_atoms, use_pallas=False):
     """A traversal's hop ends here: visited | reach_chunks[out_map], over
     the hop's plan's active blocks (``rows``). A row outside them would OR
     in the zero row: what it holds — a seed's own bit, an earlier hop's
-    bits — is left where it is."""
+    bits — is left where it is. ``use_pallas``: the stage programs'
+    (``_fold_rows`` fetches on the kernel where it serves the state)."""
     return _fold_rows(visited, reach_chunks, rows, n_atoms,
-                      lambda cur, reached: cur | reached)
+                      lambda cur, reached: cur | reached,
+                      use_pallas=use_pallas)
 
 
 @hgverify.entry(shapes=_update_shapes, donate=True)
-@partial(jax.jit, donate_argnums=(0,))  # the old frontier's buffer is reused
+@partial(jax.jit, static_argnames=("use_pallas",),
+         donate_argnums=(0,))  # the old frontier's buffer is reused
 @_program("hg_bfs_frontier_replace", "hg.bfs.frontier_replace")
-def _frontier_replace(frontier, reach_chunks, rows, n_atoms):
+def _frontier_replace(frontier, reach_chunks, rows, n_atoms,
+                      use_pallas=False):
     """A match's step ends here: the new state IS reach_chunks[out_map],
     written into the donated old frontier, of which no bit is read.
 
@@ -1071,22 +1098,25 @@ def _frontier_replace(frontier, reach_chunks, rows, n_atoms):
     bit beside the step's plan's active blocks (``_bfs_pull_device`` keeps
     that account). Inside a listed block a row nothing reaches reads the
     zero row and is cleared; an unlisted block is neither read nor
-    written, and was zero."""
+    written, and was zero. ``use_pallas`` as ``_visited_update``'s."""
     return _fold_rows(frontier, reach_chunks, rows, n_atoms,
-                      lambda cur, reached: reached)
+                      lambda cur, reached: reached, use_pallas=use_pallas)
 
 
 @hgverify.entry(shapes=_update_shapes, donate=True)
-@partial(jax.jit, donate_argnums=(0,))  # the ball aliases the output
+@partial(jax.jit, static_argnames=("use_pallas",),
+         donate_argnums=(0,))  # the ball aliases the output
 @_program("hg_bfs_ball_update", "hg.bfs.visited_update")
-def _ball_update(ball, reach_chunks, rows, n_atoms):
+def _ball_update(ball, reach_chunks, rows, n_atoms, use_pallas=False):
     """A pair search's expansion ends here: ``_visited_update`` (the same
     fold, under the same scope), and beside the grown ball the columns that
     GREW, ``(Kw,) uint32`` — a column that did not has its whole component
     (:func:`pair_distances`' exhaustion). A program of its own because the
-    traversal's update returns the bitmap alone and is left as it is."""
+    traversal's update returns the bitmap alone and is left as it is.
+    ``use_pallas`` as ``_visited_update``'s."""
     return _fold_rows(ball, reach_chunks, rows, n_atoms,
-                      lambda cur, reached: cur | reached, gain=_GREW)
+                      lambda cur, reached: cur | reached, gain=_GREW,
+                      use_pallas=use_pallas)
 
 
 #: Rows of the two bitmaps a step of the meet test's loop folds: one AND
@@ -1428,15 +1458,21 @@ def _expand(
                 listed, rows = dev["blocks"], dev["rows"]
             else:
                 rows = _listed(dev["out_map"], listed)
-            out = update(state, reach_chunks, rows, n_atoms)
+            # the XLA route is the four-argument call it always was, which
+            # an update standing in for the operator's may be written to
+            out = update(state, reach_chunks, rows, n_atoms,
+                         **({"use_pallas": True} if use_pallas else {}))
             del reach_chunks
         ph.wait(out)
-        # what the update's loop folded beside the whole bitmap: their
-        # ratio says how far the plan's block list engages
+        # what the update's loop folded beside the whole bitmap, and of
+        # it what the kernel fetched: their ratios say how far the plan's
+        # block list and the kernel's route engage
         n_pad = state.shape[0]
+        visited = int(listed.sum()) * _block_rows(n_pad)
         reg = default_registry()
-        reg.counter("bfs.update.rows_visited").inc(
-            int(listed.sum()) * _block_rows(n_pad))
+        reg.counter("bfs.update.rows_visited").inc(visited)
+        reg.counter("bfs.update.rows_kernel").inc(
+            visited if _update_on_kernel(state, use_pallas) else 0)
         reg.counter("bfs.update.rows_total").inc(n_pad)
     if isinstance(out, tuple):  # the state, and the words of what grew
         return out[0], _columns(out[1])

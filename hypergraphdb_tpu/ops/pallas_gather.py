@@ -26,6 +26,13 @@ all static  13.5   9.0    8.9    8.1    8.4    8.3    8.0    8.1    7.9    8.0
 this        5.0    4.4    4.7    4.6    4.9    4.9    4.7    4.6    4.5    4.5
 ==========  =====  =====  =====  =====  =====  =====  =====  =====  =====  =====
 
+w = 1 has one caller, the row fetch of the update that ends a hop
+(``ellbfs._fold_rows``: a gather, no OR; no class is that narrow): over the
+cells' 2,031,616 listed rows, in calls of 2^16 indices, 10.6 ns an index
+with a 256-chunk grid step and 6.5 with :func:`grid_chunks`' 4096, where
+the XLA gather reads 10.3 (``benchmarks/tests/update_kernel_probe.py``,
+PERF.md section 6).
+
 ``before`` is the kernel until PR 35: a CHUNK a loop step (``w`` loads of
 one sublane, ``w - 1`` ORs on registers an eighth full, a one-row store,
 a wait). It was read as "bound by the copies it issues, 14.3 ns an index
@@ -128,8 +135,10 @@ if SEG * 4 > SMEM_BUDGET // 2:
         "pallas_gather.SEG: scalar-prefetch segment exceeds half the "
         "SMEM budget"
     )
-#: output chunks per grid step
+#: output chunks per grid step (see :func:`grid_chunks`)
 G = 256
+#: and at width 1
+G_W1 = 4096
 #: chunks a loop step reduces: one sublane tile of ``uint32`` rows
 TILE = 8
 #: single-row copies the kernel keeps outstanding, at least, and the slots
@@ -182,10 +191,27 @@ def written_out(w: int) -> int:
     return min(TILE, 1 << max(1, STEP_COPIES // w).bit_length() - 1)
 
 
+def grid_chunks(w: int) -> int:
+    """Output chunks one grid step writes: ``G``, and ``G_W1`` at w = 1
+    (the update's row fetch, ``ellbfs._fold_rows``: a gather, no OR). The
+    kernel's loop starts :func:`slots` tiles of copies before its first
+    wait and waits for as many after its last start, once a grid step; at
+    w = 1 that is the whole of a ``G``-chunk step (``slots(1)`` = 32 tiles
+    = ``G / TILE``), so no loop step both waits and starts copies. A
+    longer step spreads the fill and the drain over more copies. ns an
+    index at w = 1 over the cells' 2,031,616 listed rows, one call a block
+    of 2^16, at 8 / 16 / 32 / 64 slots: G = 256: 8.8 / 8.6 / 10.6 / 15.8;
+    1024: 7.7 / 7.1 / 7.2 / 8.5; 2048: 7.5 / 6.8 / 6.8 / 7.4; 4096: 7.4 /
+    6.7 / 6.5 / 6.8 (one v5e chip; PERF.md section 6). The plan's classes
+    (w >= 2) keep ``G``: their copies in flight are at most half a step."""
+    return G_W1 if w == 1 else G
+
+
 def _seg(w: int) -> int:
-    """Indices one ``pallas_call`` takes at width ``w``: the whole
-    ``G``-chunk grid steps that fit ``SEG``."""
-    return SEG // (G * w) * (G * w)
+    """Indices one ``pallas_call`` takes at width ``w``: the whole grid
+    steps (:func:`grid_chunks`) that fit ``SEG``."""
+    step = grid_chunks(w) * w
+    return SEG // step * step
 
 
 def whole_segments(n: int, w: int) -> int:
@@ -198,13 +224,14 @@ def whole_segments(n: int, w: int) -> int:
 
 
 def _vmem_bytes(w: int, Kw: int) -> int:
-    """Static VMEM working set of one ``_call``: the (G, Kw) uint32 output
-    window double-buffered across grid steps + the (slots(w) * w, TILE, Kw)
-    uint32 DMA row scratch. ``w``/``Kw`` are runtime-chosen, so hglint HG502
-    cannot fold this bound — this guard enforces it instead (the kernel
-    would otherwise die in Mosaic allocation with an opaque error, or only
-    on hardware while CPU interpret tests pass)."""
-    return 4 * Kw * (2 * G + slots(w) * w * TILE)
+    """Static VMEM working set of one ``_call``: the (grid_chunks(w), Kw)
+    uint32 output window double-buffered across grid steps + the
+    (slots(w) * w, TILE, Kw) uint32 DMA row scratch. ``w``/``Kw`` are
+    runtime-chosen, so hglint HG502 cannot fold this bound — this guard
+    enforces it instead (the kernel would otherwise die in Mosaic
+    allocation with an opaque error, or only on hardware while CPU
+    interpret tests pass)."""
+    return 4 * Kw * (2 * grid_chunks(w) + slots(w) * w * TILE)
 
 
 def declined(w: int, Kw: int) -> str | None:
@@ -218,10 +245,11 @@ def declined(w: int, Kw: int) -> str | None:
                 f"only at {ROW_WORDS}-word rows (narrower VMEM blocks fail "
                 f"to compile; wider is refused with 'Slice shape along "
                 f"dimension 0 must be aligned to tiling (8), but is 1')")
-    if not 1 <= w <= SEG // G:
-        # a segment is whole G-chunk grid steps; a chunk wider than
-        # SEG / G would leave the grid empty and the buffer unwritten
-        return f"w={w}: a grid step of {G} chunks must fit SEG={SEG}"
+    if w < 1 or grid_chunks(w) * w > SEG:
+        # a segment is whole grid steps; a chunk wider than SEG over a
+        # step's chunks would leave the grid empty and the buffer unwritten
+        return (f"w={w}: a grid step of {grid_chunks(w)} chunks must fit "
+                f"SEG={SEG}")
     if _vmem_bytes(w, Kw) > VMEM_BUDGET:
         return (f"VMEM working set {_vmem_bytes(w, Kw)} B (w={w}, "
                 f"Kw={Kw}) exceeds the {VMEM_BUDGET} B per-core budget")
@@ -229,7 +257,7 @@ def declined(w: int, Kw: int) -> str | None:
 
 
 def _kernel(idx_ref, values, out_ref, rows, sems, *, w):
-    """One grid step: ``G`` output chunks as ``G // TILE`` tiles. Copy
+    """One grid step: :func:`grid_chunks` output chunks as tiles. Copy
     ``j`` of chunk ``i`` of a tile lands in ``rows[slot * w + j, i]``, so
     ``rows[slot * w + j]`` is one full (TILE, Kw) register tile — the
     ``j``-th source row of eight chunks. Loop step ``s`` reduces tile ``s - D``
@@ -242,7 +270,8 @@ def _kernel(idx_ref, values, out_ref, rows, sems, *, w):
     g = pl.program_id(0)
     D = slots(w)
     P = written_out(w)
-    NT = G // TILE
+    GS = grid_chunks(w)
+    NT = GS // TILE
 
     def body(s, _):
         slot = jax.lax.rem(s, D)
@@ -264,7 +293,7 @@ def _kernel(idx_ref, values, out_ref, rows, sems, *, w):
 
         @pl.when(s < NT)
         def _():
-            base = (g * G + s * TILE) * w
+            base = (g * GS + s * TILE) * w
 
             def chunks(i0, _):
                 i, at = i0 * P, base + i0 * (P * w)
@@ -291,7 +320,7 @@ def _call(seg_idx: jax.Array, values: jax.Array, w: int,
           interpret: bool) -> jax.Array:
     Kw = values.shape[1]
     n_out = seg_idx.shape[0] // w
-    D = slots(w)
+    D, GS = slots(w), grid_chunks(w)
     # the kernel runs without Mosaic's per-copy bounds checks (the module
     # docstring): where a copy LANDS is the loop's own arithmetic, where
     # it READS is held to the table here, as the XLA gather clamps — one
@@ -302,9 +331,9 @@ def _call(seg_idx: jax.Array, values: jax.Array, w: int,
         functools.partial(_kernel, w=w),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_out // G,),
+            grid=(n_out // GS,),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((G, Kw), lambda i, s: (i, 0),
+            out_specs=pl.BlockSpec((GS, Kw), lambda i, s: (i, 0),
                                    memory_space=pltpu.VMEM),
             scratch_shapes=[pltpu.VMEM((D * w, TILE, Kw), jnp.uint32),
                             pltpu.SemaphoreType.DMA((D,))],
@@ -335,9 +364,9 @@ def gather_or(values: jax.Array, idx: jax.Array, w: int,
     if why is not None:
         raise ValueError(f"gather_or: {why}")
     n_out = E // w
-    # pad to whole G-chunk blocks (pad chunks gather row 0 and are sliced
+    # pad to whole grid steps (pad chunks gather row 0 and are sliced
     # off — chunks are independent, so garbage rows never mix in)
-    seg = min(_seg(w), _ceil(E, G * w))
+    seg = min(_seg(w), _ceil(E, grid_chunks(w) * w))
     E_pad = _ceil(E, seg)
     if E_pad != E:
         idx = jnp.concatenate(

@@ -2,6 +2,9 @@
 pure-numpy host BFS, on random hypergraphs (the correctness oracle pattern
 from SURVEY §7 M4)."""
 
+from functools import partial
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -471,6 +474,69 @@ def test_update_folds_the_listed_blocks_and_no_other(update, blocks):
     assert np.array_equal(got, want)
 
 
+#: ``_fold_rows``' cases by route: (state words a row — 0 a flat label
+#: state —, combine, gain, does the fetch take the kernel)
+FOLD_ROUTES = {
+    "or": (128, lambda cur, reached: cur | reached, None, True),
+    "replace": (128, lambda cur, reached: reached, None, True),
+    "or_grew": (128, lambda cur, reached: cur | reached, eb._GREW, True),
+    "labels": (0, jnp.minimum, eb._LOWERED, False),
+    "narrow_rows": (32, lambda cur, reached: cur | reached, None, False),
+}
+
+
+@pytest.mark.parametrize("case", list(FOLD_ROUTES))
+def test_fold_rows_fetches_on_the_kernel_where_it_serves_the_state(
+        case, monkeypatch):
+    """``_fold_rows`` with ``use_pallas`` over four blocks of 256 rows and
+    a ragged fifth, the list holding the ragged last block, a block twice
+    and the dummy row's block, against the same fold on the XLA gather:
+    bit-equal state (the dummy row the identity) and count. A 128-word
+    bitmap fetches through ``hg_gather_or`` at width 1 (the Pallas
+    interpreter here); a flat label state and a 32-word bitmap trace no
+    ``pallas_call`` — the XLA route alone."""
+    kw, combine, gain, kernel = FOLD_ROUTES[case]
+    gather, calls = eb._pg.gather_or, []
+    monkeypatch.setattr(
+        eb._pg, "gather_or",
+        lambda v, i, w: calls.append(w) or gather(v, i, w, interpret=True))
+    ub = 256
+    monkeypatch.setattr(eb._pg, "MIN_INDICES", ub)
+    r = np.random.default_rng(sorted(FOLD_ROUTES).index(case))
+    n_pad, n_chunks = 4 * ub + 40, 300
+    n_atoms = n_pad - 3
+    if kw:
+        reach = r.integers(0, 1 << 32, size=(n_chunks + 1, kw),
+                           dtype=np.uint32)
+        reach[n_chunks] = 0
+        state = r.integers(0, 1 << 32, size=(n_pad, kw), dtype=np.uint32)
+    else:
+        reach = r.integers(0, n_pad, size=n_chunks + 1).astype(np.int32)
+        reach[n_chunks] = eb.INT32_MAX
+        state = np.arange(n_pad, dtype=np.int32)
+    out_map = r.integers(0, n_chunks + 1, size=n_pad).astype(np.int32)
+    listed = [4 * ub, 0, 2 * ub, 2 * ub, 3 * ub]  # ragged last, 2 twice
+    rows = eb._UpdateRows(jnp.asarray(out_map),
+                          jnp.asarray(np.asarray(listed + [0], np.int32)),
+                          jnp.int32(len(listed)))
+    args = (jnp.asarray(state), jnp.asarray(reach), rows, jnp.int32(n_atoms))
+    fold = {k: jax.jit(partial(eb._fold_rows, combine=combine, block_rows=ub,
+                               gain=gain, use_pallas=k))
+            for k in (False, True)}
+    assert ("pallas_call" in str(jax.make_jaxpr(fold[True])(*args))) \
+        == kernel
+    assert "pallas_call" not in str(jax.make_jaxpr(fold[False])(*args))
+    assert bool(calls) == kernel and set(calls) <= {1}
+    want, got = (jax.tree_util.tree_leaves(fold[k](*args))
+                 for k in (False, True))
+    for w, g in zip(want, got):
+        assert np.array_equal(np.asarray(w), np.asarray(g))
+    assert np.asarray(got[0])[n_atoms].tolist() == (
+        [0] * kw if kw else eb.INT32_MAX)
+    # the listed rows were folded: the fold did something to compare
+    assert not np.array_equal(np.asarray(got[0]), state)
+
+
 # ------------------------------------------------------ the sparse first hop
 #
 # Which side of the rule ran is read off the phase counts, as an operator
@@ -641,9 +707,16 @@ def test_hub_above_w_max_squared_matches_host(hub_graph, k, typed,
         # the long classes alone: each width the interpreter runs is
         # seconds of compile here
         monkeypatch.setattr(eb._pg, "MIN_INDICES", 1 << 13)
+        fetched = [_counter(n) for n in ("bfs.update.rows_visited",
+                                         "bfs.update.rows_kernel")]
         kernel = bfs_pull(snap, seeds, 2, chunk=1 << 12, k_block=k,
                           link_types=family)
-        assert set(calls) >= {4, eb.W_MAX}
+        # the update that ends the dense hops fetches on the kernel too,
+        # every row it folds (width 1)
+        assert set(calls) >= {1, 4, eb.W_MAX}
+        visited, by_kernel = (_counter(n) - t for n, t in zip(
+            ("bfs.update.rows_visited", "bfs.update.rows_kernel"), fetched))
+        assert by_kernel == visited > 0
         assert np.array_equal(np.asarray(res.visited_t),
                               np.asarray(kernel.visited_t))
         assert np.array_equal(res.edges_touched, kernel.edges_touched)

@@ -445,15 +445,17 @@ def test_bfs_pull_leaves_every_phase_with_the_right_counts():
     assert grew[DEG_SUM_PHASE] == 3 and grew["hg.bfs.edges_to_host"] == 4
 
 
-UPDATE_COUNTERS = ("bfs.update.rows_visited", "bfs.update.rows_total")
+UPDATE_COUNTERS = ("bfs.update.rows_visited", "bfs.update.rows_total",
+                   "bfs.update.rows_kernel")
 
 
 @pytest.mark.parametrize("operator", ["bfs_pull", "path_match"])
 def test_update_counters_grow_once_a_dense_hop(operator):
-    """Beside the phases: what an update's loop folded and the bitmap's
-    rows, from numbers the host holds, once an update dispatch — a sparse
-    first hop dispatches none. On a graph of one row block the two are
-    the bitmap's rows both."""
+    """Beside the phases: what an update's loop folded, the bitmap's rows
+    and what of the folded rows the kernel fetched, from numbers the host
+    holds, once an update dispatch — a sparse first hop dispatches none.
+    On a graph of one row block the first two are the bitmap's rows both;
+    on the CPU the kernel fetches none (the counter is there, at 0)."""
     def read():
         got = [obs.default_registry().get(n) for n in UPDATE_COUNTERS]
         return [0 if c is None else c.value for c in got]
@@ -468,7 +470,8 @@ def test_update_counters_grow_once_a_dense_hop(operator):
     else:
         eb.path_match(snap, seeds, [None] * 3)
     assert [now - was for now, was in zip(read(), before)] == \
-        [2 * n_pad, 2 * n_pad]
+        [2 * n_pad, 2 * n_pad, 0]
+    assert obs.default_registry().get("bfs.update.rows_kernel") is not None
 
 
 def _u32(*shape):

@@ -24,7 +24,8 @@ WIDTHS = sorted({*CLASS_WIDTHS, 4, 8, 24})
 
 
 @pytest.mark.parametrize("w,n_out", [(4, pg.G), (8, pg.G)] + [
-    (w, pg.G * 3 + 17) for w in WIDTHS])  # whole grid steps; a ragged tail
+    (w, pg.G * 3 + 17) for w in WIDTHS]  # whole grid steps; a ragged tail
+    + [(1, pg.G_W1 + 17)])  # the update's row fetch: a step and a tail
 def test_gather_or_matches_xla(w, n_out):
     r = np.random.default_rng(0)
     S = 500
@@ -184,7 +185,7 @@ def test_gather_or_multi_segment(w, seg, monkeypatch):
     assert np.array_equal(np.asarray(out), _ref(values, idx, w))
 
 
-@pytest.mark.parametrize("w", WIDTHS)
+@pytest.mark.parametrize("w", [1, *WIDTHS])
 def test_kernel_geometry_follows_the_width(w):
     """At every width the gate admits: a slot holds a TILE of eight chunks
     (``TILE * w`` copies); the power of two of slots that keeps at least
@@ -194,11 +195,14 @@ def test_kernel_geometry_follows_the_width(w):
     more than a grid step's tiles; a step of the issue loop writes out
     the power of two of chunks whose copies number at most
     ``STEP_COPIES`` — one chunk where a chunk alone is more, never more
-    than a tile; whole grid steps a segment; a working set inside the
-    VMEM budget."""
+    than a tile; whole grid steps a segment (``G`` chunks a step, ``G_W1``
+    at w = 1, whose copies in flight are a whole ``G``-chunk step); a
+    working set inside the VMEM budget."""
     assert pg.declined(w, pg.ROW_WORDS) is None
     d = pg.slots(w)
-    assert pg.MIN_SLOTS <= d <= pg.G // pg.TILE and d & (d - 1) == 0
+    g = pg.grid_chunks(w)
+    assert g == (pg.G_W1 if w == 1 else pg.G)
+    assert pg.MIN_SLOTS <= d <= g // pg.TILE and d & (d - 1) == 0
     assert d * pg.TILE * w >= pg.IN_FLIGHT
     assert d * pg.TILE * w < 2 * pg.IN_FLIGHT or d == pg.MIN_SLOTS
     assert [pg.slots(x) for x in (2, 4, 6, 8, 56)] == [16, 8, 8, 4, 4]
@@ -209,11 +213,11 @@ def test_kernel_geometry_follows_the_width(w):
     assert [pg.written_out(x) for x in (2, 4, 6, 8, 10, 14, 20, 28, 56)] \
         == [8, 8, 8, 4, 4, 2, 2, 1, 1]
     seg = pg._seg(w)
-    assert seg % (pg.G * w) == 0 and pg.SEG - pg.G * w < seg <= pg.SEG
+    assert seg % (g * w) == 0 and pg.SEG - g * w < seg <= pg.SEG
     assert pg.whole_segments(4 * pg.SEG, w) == 4 * pg.SEG // seg * seg
     assert pg.whole_segments(seg - 1, w) == seg - 1
     assert pg._vmem_bytes(w, pg.ROW_WORDS) == \
-        4 * pg.ROW_WORDS * (2 * pg.G + d * w * pg.TILE) <= pg.VMEM_BUDGET
+        4 * pg.ROW_WORDS * (2 * g + d * w * pg.TILE) <= pg.VMEM_BUDGET
 
 
 def test_gate_declines_a_chunk_wider_than_a_segment():

@@ -338,8 +338,10 @@ _N2 = 9  # stage 2's level-0 classes
 _N_PAD, _KW, _CHUNK = 10_000_072, 128, 1 << 16
 
 
-def _staged_step(step: str):
-    """(jitted step, exemplar args, statics, bytes resident beside it)."""
+def _staged_step(step: str, kernel: bool = False):
+    """(jitted step, exemplar args, statics, bytes resident beside it);
+    ``kernel``: an update's fetch on ``hg_gather_or``, as a 4096-seed
+    block on a TPU runs it."""
     from hypergraphdb_tpu.ops import ellbfs as eb
 
     rows = lambda ls, ws: sum(n // w for n, w in zip(ls, ws))  # noqa: E731
@@ -361,7 +363,8 @@ def _staged_step(step: str):
         return (getattr(eb, step),
                 (visited, _sds((rows(_L2, _W2) + 1, _KW), "uint32"),
                  listed, _sds((), "int32")),
-                {}, 3 * 4 * (sum(_L1) + sum(_L2))
+                {"use_pallas": True} if kernel else {},
+                3 * 4 * (sum(_L1) + sum(_L2))
                 + (bitmap if step == "_ball_update" else 0))  # the other ball
     if step == "_stage":
         return (eb._stage,
@@ -398,11 +401,17 @@ def test_the_cell_shape_is_the_modules_classes():
     assert sum(_L2[_N2:]) == 158_696  # the upper pyramid's indices a hop
 
 
-@pytest.mark.parametrize("step", ["_sparse_hop", "_stage",
-                                  "_stage_lvl0_consume", "_stage_upper",
-                                  "_visited_update", "_frontier_replace",
-                                  "_ball_update"])
-def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
+_UPDATES = ("_visited_update", "_frontier_replace", "_ball_update")
+
+
+@pytest.mark.parametrize(
+    "step,kernel",
+    [pytest.param(s, False, id=s)
+     for s in ("_sparse_hop", "_stage", "_stage_lvl0_consume",
+               "_stage_upper") + _UPDATES]
+    + [pytest.param(s, True, id=f"{s}-kernel") for s in _UPDATES])
+def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, kernel,
+                                                          one_chip,
                                                           no_compile_cache):
     """Each host-sequenced step of ``ellbfs._bfs_pull_device`` at the
     benchmark graph, a 4096-seed block (128-word rows — narrower rows are
@@ -418,20 +427,23 @@ def test_staged_hop_fits_one_chip_at_10m_atoms_4096_seeds(step, one_chip,
     count the compiler cannot see carries the alias as a counted one did:
     no second bitmap, and nothing of a bitmap's size beside it. A pair
     search's ``_ball_update`` is the traversal's update with a row of
-    words beside it, and the OTHER ball resident."""
-    fn, args, statics, resident = _staged_step(step)
-    mem = fn.lower(*_place(args, one_chip), **statics).compile() \
-        .memory_analysis()
+    words beside it, and the OTHER ball resident. Each update also with
+    its fetch on the kernel (``-kernel``): ``hg_gather_or`` at width 1
+    inside the loop, the alias and the temporaries as on the XLA route."""
+    fn, args, statics, resident = _staged_step(step, kernel)
+    compiled = fn.lower(*_place(args, one_chip), **statics).compile()
+    mem = compiled.memory_analysis()
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes + resident)
     # with level 0 in width classes the widest step holds 11.2 GB
     # (``_stage_lvl0_consume``: the bitmap, stage 1's 8.0M-row buffer and
     # stage 2's 2.1M rows), where one width-8 level 0 a stage held 15.5
     assert total < 0.75 * HBM_USABLE, (step, total)
-    if step in ("_sparse_hop", "_visited_update", "_frontier_replace",
-                "_ball_update"):
+    if step in ("_sparse_hop",) + _UPDATES:
         assert mem.alias_size_in_bytes >= _N_PAD * _KW * 4
         assert mem.temp_size_in_bytes < 2**30
+    if kernel:
+        assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("program", ["_deg_sum", "_reach_counts"])
