@@ -323,6 +323,52 @@ def connected_components(graph, generator: Optional[HGALGenerator] = None
     return label
 
 
+def pagerank(snap, link_types=None, *, damping: float = 0.85,
+             iterations: int = 10):
+    """How important each atom is: ``(num_atoms,)`` float64 ranks, LDBC
+    Graphalytics' PageRank with every atom a vertex, over the walk of
+    ``ops.ellbfs.pagerank`` under a link family (``link_types``; None:
+    every link): from ``u`` pick one of the target slots ``u`` holds in a
+    link of two or more DISTINCT targets, uniformly, then one of that
+    link's other distinct atoms, uniformly. ``PR_0 = 1/N`` and each of
+    ``iterations`` rounds is
+
+        PR'(v) = (1 - d)/N + d · Σ_u P(u, v) PR(u) + (d/N) · Σ_{dangling} PR
+
+    straight from the snapshot's TARGET relation (``tgt_offsets`` /
+    ``tgt_flat``, links filtered by ``type_of``), in numpy: the (link,
+    atom) pairs are deduplicated here, never read from the incidence
+    relation or a plan. The plain reference of ``ops.ellbfs.pagerank``,
+    independent of it."""
+    import numpy as np
+
+    n = int(snap.num_atoms)
+    lens = np.diff(np.asarray(snap.tgt_offsets[: n + 1], dtype=np.int64))
+    link = np.repeat(np.arange(n, dtype=np.int64), lens)
+    atom = np.asarray(snap.tgt_flat[: len(link)], dtype=np.int64)
+    if link_types is not None:
+        family = np.fromiter((int(t) for t in link_types), dtype=np.int64)
+        keep = np.isin(np.asarray(snap.type_of[:n])[link], family)
+        link, atom = link[keep], atom[keep]
+    pair_link, pair_atom = np.divmod(np.unique(link * n + atom), n)
+    distinct = np.bincount(pair_link, minlength=n)
+    w = np.zeros(n)
+    w[distinct >= 2] = 1.0 / (distinct[distinct >= 2] - 1)
+    slot_w = w[link]
+    d = np.bincount(atom, weights=slot_w > 0, minlength=n)
+    c = np.bincount(atom, weights=slot_w, minlength=n)
+    dangling = d == 0
+    inv_d = np.divide(1.0, d, out=np.zeros(n), where=~dangling)
+    rank = np.full(n, 1.0 / n)
+    for _ in range(iterations):
+        x = rank * inv_d
+        s = np.bincount(link, weights=x[atom], minlength=n)
+        y = np.bincount(pair_atom, weights=(w * s)[pair_link], minlength=n)
+        rank = ((1.0 - damping) / n + damping * (y - c * x)
+                + damping * rank[dangling].sum() / n)
+    return rank
+
+
 def has_cycles(graph, start: HGHandle, generator: Optional[HGALGenerator] = None) -> bool:
     """Cycle detection from a start atom (``GraphClassics.hasCycles`` :40),
     treating generated adjacency as directed edges."""

@@ -10,11 +10,13 @@ from hypergraphdb_tpu.ops.bitfrontier import (
 )
 from hypergraphdb_tpu.ops.ellbfs import (
     ComponentsResult,
+    PageRankResult,
     PairDistResult,
     PathMatchResult,
     PullBFSResult,
     bfs_pull,
     connected_components,
+    pagerank,
     pair_distances,
     path_match,
     visited_rows,
@@ -45,6 +47,7 @@ __all__ = [
     "CSRSnapshot",
     "ComponentsResult",
     "DeviceSnapshot",
+    "PageRankResult",
     "PairDistResult",
     "PathMatchResult",
     "PinnedView",
@@ -58,6 +61,7 @@ __all__ = [
     "collect_pattern",
     "connected_components",
     "execute_pattern",
+    "pagerank",
     "pair_distances",
     "path_match",
     "plan_pattern",

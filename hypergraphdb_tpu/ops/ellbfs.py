@@ -84,7 +84,10 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   min-label rounds until a fixpoint — the pyramid and the fold reduce with
   the state's reduction (``_reduction``: OR over uint32 seed words, min over
   a flat int32 vector of labels, the buffer's zero row and every padded
-  index holding its identity), the gather the XLA one of 4-byte scalars.
+  index holding its identity), the gather the XLA one of 4-byte scalars;
+  and :func:`pagerank`, a fixed count of iterations that SUM float32 ranks
+  through the same two pyramids (the first reduction that is not
+  idempotent: every entry covered once, the fold replacing).
 
 Geometry note: each gather row is ``Kw = K/32`` uint32 words (32 lanes for
 K=1024). Gathers remain the dominant cost and are bound by the indices
@@ -119,12 +122,20 @@ INT32_MAX = int(np.iinfo(np.int32).max)
 
 class _Reduction(NamedTuple):
     """What a pyramid and a fold reduce with: ``combine`` is elementwise,
-    associative, commutative and idempotent (a row folded twice, a padded
-    index, say the same), and ``identity`` is what the buffer's zero row
-    and every padded index hold."""
+    associative and commutative, and ``identity`` is what the buffer's
+    zero row and every padded index hold.
+
+    The invariant the pyramid keeps is EXACT coverage: every CSR entry is
+    gathered once, into the one chunk of its row, and every padded index
+    reads the identity — so a combine need not be idempotent, and a sum
+    is as exact as an OR. The one place that visits a row twice is
+    ``_fold_rows``' clamped last block: a fold's own combine must give the
+    same row both times. The OR and the min do, and so does a fold that
+    REPLACES the state's row; an accumulating add does not (it would count
+    the overlap twice), so a sum is folded by replacement."""
 
     combine: Callable
-    identity: int
+    identity: int | float
 
 
 #: OR over ``(S, Kw)`` uint32 rows of seed bits: a traversal, a match, a
@@ -133,23 +144,27 @@ _OR_WORDS = _Reduction(jnp.bitwise_or, 0)
 #: min over a flat ``(S,)`` int32 vector of labels:
 #: :func:`connected_components`.
 _MIN_LABELS = _Reduction(jnp.minimum, INT32_MAX)
+#: sum over a flat ``(S,)`` float32 vector of rank shares: :func:`pagerank`.
+_SUM_FLOATS = _Reduction(jnp.add, 0.0)
 
 
 def _reduction(state) -> _Reduction:
     """The reduction of a state, where it enters a pyramid or a fold (the
     values of ``_apply_plan``, the buffer of ``_reduce_into``, the state of
     ``_fold_rows``), passed down from there: ``(S, Kw)`` uint32 seed words
-    take the OR, a flat ``(S,)`` int32 label vector the min, and any other
-    state is an error, never a default. One pyramid and one fold serve
-    both; the bitmap programs lower to the text they had before the labels
-    came."""
+    take the OR, a flat ``(S,)`` int32 label vector the min, a flat
+    ``(S,)`` float32 vector the sum, and any other state is an error, never
+    a default. One pyramid and one fold serve all three; the bitmap and
+    label programs lower to the text they had before the sum came."""
     if state.ndim == 2 and state.dtype == jnp.uint32:
         return _OR_WORDS
     if state.ndim == 1 and state.dtype == jnp.int32:
         return _MIN_LABELS
+    if state.ndim == 1 and state.dtype == jnp.float32:
+        return _SUM_FLOATS
     raise TypeError(f"no reduction for a {state.dtype} state of shape "
-                    f"{state.shape}: (S, Kw) uint32 words or (S,) int32 "
-                    f"labels")
+                    f"{state.shape}: (S, Kw) uint32 words, (S,) int32 "
+                    f"labels or (S,) float32 sums")
 
 
 def _at(row, x) -> tuple:
@@ -330,7 +345,7 @@ def _segmented_ranges(starts: np.ndarray, reps: np.ndarray) -> np.ndarray:
 
 
 def _reduce_level(
-    values: jax.Array,  # (S, Kw) uint32 rows, or (S,) int32 labels
+    values: jax.Array,  # (S, Kw) uint32 rows, (S,) int32 or float32
     idx: jax.Array,     # (E,) int32, multiple of w
     w: int,
     chunk: int,
@@ -383,7 +398,7 @@ def _fold(x: jax.Array, red: _Reduction) -> jax.Array:
 
 
 def _apply_plan(
-    values: jax.Array,            # (S, Kw) uint32 rows, or (S,) int32 labels
+    values: jax.Array,            # (S, Kw) uint32 rows, (S,) int32 or float32
     levels: Sequence[jax.Array],
     widths: Sequence[int],
     n_lvl0: int,
@@ -495,25 +510,41 @@ def _reduce_into(
     ``len(idx)//w`` output rows into ``buf[off:]`` in place: full blocks of
     ``chunk`` outputs stream through a scan (carry = buf, aliased by XLA),
     the ragged tail lands with one final update. ``values=None`` gathers
-    from ``buf`` itself (the rows read must lie outside ``buf[off:]``)."""
+    from ``buf`` itself (the rows read must lie outside ``buf[off:]``).
+
+    The sum slices a block's indices out of ``idx`` in the loop's body;
+    the OR and the min scan over ``idx`` reshaped to ``(blocks, chunk *
+    w)``, which the TPU compiler lays out anew in loops of its own that
+    carry no ``op_name`` — at the untyped plan's classes 80M indices an
+    iteration (PERF.md section 6) — and keep the lowered text their
+    programs had."""
     red = _reduction(buf)
     E = idx.shape[0]
     n_out = E // w
     n_full = n_out // chunk
     if n_full:
-        xs = idx[: n_full * chunk * w].reshape(n_full, chunk * w)
+        step = chunk * w
+        if red is _SUM_FLOATS:
+            def block(i):
+                return jax.lax.dynamic_slice_in_dim(idx, i * step, step), i
 
-        def body(b, ib_i):
-            ib, i = ib_i
+            xs = jnp.arange(n_full, dtype=jnp.int32)
+        else:
+            def block(ib_i):
+                return ib_i
+
+            xs = (idx[: n_full * step].reshape(n_full, step),
+                  jnp.arange(n_full, dtype=jnp.int32))
+
+        def body(b, x):
+            ib, i = block(x)
             out = _reduce_level(b if values is None else values, ib, w,
                                 chunk, use_pallas, red)
             return jax.lax.dynamic_update_slice(
                 b, out, _at(off + i * chunk, b)
             ), None
 
-        buf, _ = jax.lax.scan(
-            body, buf, (xs, jnp.arange(n_full, dtype=jnp.int32))
-        )
+        buf, _ = jax.lax.scan(body, buf, xs)
     tail = n_out - n_full * chunk
     if tail:
         out = _reduce_level(
@@ -1016,11 +1047,15 @@ def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
     no second state array materializes while the stage buffer is alive
     (the loop's carry aliases in place, whatever its trip count); the
     dummy row (``n_atoms``) is set to the state's identity (``_reduction``:
-    a bitmap's 0, a label's ``INT32_MAX``) last. The state's ragged last
-    block is folded from ``n_pad - block_rows``: the rows it shares with
-    the block before are folded twice in one pass at most, and every
-    ``combine`` gives the same row both times. The state is ``(n_pad, Kw)``
-    uint32 words or ``(n_pad,)`` int32 labels.
+    a bitmap's 0, a label's ``INT32_MAX``, a sum's 0.0) last. The state's
+    ragged last block is folded from ``n_pad - block_rows``: the rows it
+    shares with the block before are folded twice in one pass at most, so
+    ``combine`` must give the same row both times — the OR, the min and a
+    replacement (``lambda cur, reached: reached``) do; an accumulating add
+    would count those rows twice, so a sum (:func:`pagerank`) is folded by
+    replacement into a state that starts at zero. The state is
+    ``(n_pad, Kw)`` uint32 words, ``(n_pad,)`` int32 labels or
+    ``(n_pad,)`` float32 sums.
 
     ``gain``: also return what it counts over the folded rows (``_GREW``,
     ``_LOWERED``). Both operands are at hand in the fold, so it costs no
@@ -2069,6 +2104,179 @@ def connected_components(snap: CSRSnapshot, link_types=None, *,
         with op.step("count"):
             n_components = int(_wcc_count(labels))
     return ComponentsResult(labels, n_components, rounds)
+
+
+# ------------------------------------------------------------- pagerank
+
+
+class _PRWeights(NamedTuple):
+    """What a PageRank iteration reads of a plan beside its indices, built
+    on the host once a plan (:func:`_pr_weights`), float32 on the device."""
+
+    inv_d: jax.Array  # (n_pad,): 1/d(u); 0 where d(u) = 0, dummy and pad rows
+    w: jax.Array      # (stage 1's buffer,): w_e at link e's chunk, else 0
+    c: jax.Array      # (n_pad,): Σ w_e over the target slots v holds
+
+
+@hgverify.entry(shapes=lambda: (hgverify.sds((), "int32"),),
+                statics={"n_pad": 64})
+@partial(jax.jit, static_argnames=("n_pad",))
+@_program("hg_pr_init", "hg.pr.init")
+def _pr_init(n_atoms: jax.Array, n_pad: int) -> tuple[jax.Array, jax.Array]:
+    """``rank[a] = 1/N`` on every atom, 0 on the dummy and the pad rows;
+    and the ranks' sum, ``() float32``."""
+    ids = jnp.arange(n_pad, dtype=jnp.int32)
+    ranks = jnp.where(ids < n_atoms,
+                      jnp.float32(1.0) / n_atoms.astype(jnp.float32),
+                      jnp.float32(0.0))
+    return ranks, jnp.sum(ranks)
+
+
+def _pr_iter_shapes():
+    i32 = partial(hgverify.sds, dtype="int32")
+    f32 = partial(hgverify.sds, dtype="float32")
+    # stage 1's buffer: 16 + 8 chunks and the zero row
+    return (f32((64,)), (i32((32,)), i32((64,))), (i32((32,)),),
+            _PRWeights(f32((64,)), f32((25,)), f32((64,))),
+            _UpdateRows(i32((64,)), i32((1,)), i32(())), i32(()), f32(()))
+
+
+@hgverify.entry(shapes=_pr_iter_shapes, donate=True,
+                statics={"widths1": (2, 8), "n1": 2, "widths2": (2,),
+                         "n2": 1, "chunk": 4})
+@partial(jax.jit, static_argnames=("widths1", "n1", "widths2", "n2", "chunk"),
+         donate_argnums=(0,))  # the ranks alias the output
+@_program("hg_pr_iter")
+def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
+             n1, widths2, n2, chunk):
+    """One PageRank iteration over a pull plan, ONE program, returning the
+    new ranks and their sum. Stage 1 sums each link's target shares ``x =
+    rank · inv_d`` (a slot a share); ``w`` scales each link's sum where
+    stage 2's composed level 0 reads it; stage 2 sums those over each
+    atom's incident links; the fold REPLACES ``y[v] = buf[out_map[v]]``
+    into zeros over the plan's active row blocks (a row outside them has
+    no incident link); the rest is elementwise over the whole vector:
+
+        rank'[v] = (1 - d)/N + d · (y[v] - c[v] · x[v]) + (d/N) · dangling
+
+    ``dangling`` the ranks of the atoms with ``inv_d == 0``. The pyramids
+    are :func:`bfs_pull`'s with the sum (``_reduction``: identity 0.0) and
+    the XLA gather of 4-byte scalars."""
+    with jax.named_scope("hg.pr.stage1"):
+        x = ranks * pw.inv_d
+    s = _apply_plan(x, levels1, widths1, n1, chunk, False,
+                    scopes=("hg.pr.stage1", "hg.pr.stage1"))
+    with jax.named_scope("hg.pr.stage2"):
+        s = s * pw.w
+    y = _apply_plan(s, levels2, widths2, n2, chunk, False,
+                    scopes=("hg.pr.stage2", "hg.pr.stage2"))
+    with jax.named_scope("hg.pr.update"):
+        y = _fold_rows(jnp.zeros_like(ranks), y, rows, n_atoms,
+                       lambda cur, reached: reached)
+        n = n_atoms.astype(jnp.float32)
+        real = jnp.arange(ranks.shape[0], dtype=jnp.int32) < n_atoms
+        new = jnp.where(real, (1.0 - damping) / n + damping * (y - pw.c * x)
+                        + damping * _dangling(ranks, pw.inv_d) / n, 0.0)
+        return new, jnp.sum(new)
+
+
+def _dangling(ranks: jax.Array, inv_d: jax.Array) -> jax.Array:
+    """The rank the dangling atoms hold, ``() float32``: the walk spreads it
+    over every atom. The dummy and pad rows hold 0."""
+    return jnp.sum(jnp.where(inv_d == 0, ranks, 0.0))
+
+
+def _pr_weights(snap: CSRSnapshot, plans: PullBFSPlans) -> _PRWeights:
+    """The walk's weights over ``snap``'s relations, on the device; kept on
+    the snapshot beside ``_device_plans``' dict, not in it, so that no other
+    operator uploads them. A link of ``δ'`` DISTINCT targets (its incidence
+    entries: the relation holds a (link, atom) pair once) steps with ``w_e
+    = 1/(δ' - 1)``, a link of fewer is no step; ``d(u)`` counts the target
+    SLOTS ``u`` holds in links that step, ``c_v`` their ``w_e``, a slot
+    each."""
+    cache = getattr(snap, "_pull_pagerank", None)
+    if cache is None:
+        N, n_pad = snap.num_atoms, plans.n_pad
+        distinct = np.bincount(snap.inc_links[: snap.n_edges_inc],
+                               minlength=N + 1)[: N + 1]
+        w_row = np.zeros(N + 1, dtype=np.float64)
+        steps = distinct >= 2
+        w_row[steps] = 1.0 / (distinct[steps] - 1)
+        tgt = snap.tgt_flat[: snap.n_edges_tgt]
+        slot_w = w_row[snap.tgt_src[: snap.n_edges_tgt]]
+        d = np.bincount(tgt, weights=slot_w > 0, minlength=n_pad)
+        c = np.bincount(tgt, weights=slot_w, minlength=n_pad)
+        inv_d = np.zeros(n_pad, dtype=np.float64)
+        np.divide(1.0, d, out=inv_d, where=d > 0)
+        # stage 1's concat space: a row's chunk, empty rows on the zero row
+        w = np.zeros(plans.stage1.concat_size + 1, dtype=np.float32)
+        w[plans.stage1.out_map] = w_row
+        cache = _PRWeights(*(jnp.asarray(a, dtype=jnp.float32)
+                             for a in (inv_d, w, c)))
+        object.__setattr__(snap, "_pull_pagerank", cache)
+    return cache
+
+
+class PageRankResult(NamedTuple):
+    ranks: jax.Array  # (N_pad,) float32 on the device; dummy and pad rows 0
+    mass: float       # host: Σ rank, read once after the last iteration
+    iterations: int   # host: iterations run
+
+
+def pagerank(snap: CSRSnapshot, link_types=None, *, damping: float = 0.85,
+             iterations: int = 10, chunk: int = 1 << 16) -> PageRankResult:
+    """How important each atom is: LDBC Graphalytics' PageRank, every atom
+    a vertex, over the hypergraph's walk under a link family
+    (``link_types``; None: every link). From ``u`` the walk picks one of
+    the target slots ``u`` holds in a link of two or more distinct targets,
+    uniformly, then one of that link's OTHER distinct atoms, uniformly — so
+    a link atom steps to the co-targets of the links that hold it
+    (:func:`bfs_pull`'s adjacency), and on a graph of two-target links it
+    is Graphalytics' undirected PR, parallel links counted. ``PR_0 = 1/N``
+    and
+
+        PR'(v) = (1 - d)/N + d · Σ_{u→v} P(u, v) · PR(u)
+                 + (d/N) · Σ_{w dangling} PR(w)
+
+    an atom with no step out dangling. In pull form (:func:`_pr_iter`): ``x
+    = PR/d(u)``, a link's sum of its slots' ``x``, each atom's sum over its
+    links of ``w_e`` times that, less its own ``c_v · x_v`` — stage 1 and
+    stage 2 of the plan :func:`bfs_pull` runs, summing where it ORs. The
+    plain reference is ``algorithms/traversals.pagerank``.
+
+    ``iterations`` programs are dispatched back to back with no host read
+    between them, the ranks donated from one to the next; the host reads
+    their sum (4 bytes) once, after the last. Nothing is kept between
+    calls but the plan and the walk's weights (``_pr_weights``). Memory:
+    the ranks, ``(N_pad,)`` float32 — 40 MB at 10M atoms — beside the plan,
+    two stage buffers of a float a chunk and the weights. ``chunk``: the
+    scan grain of the pyramids' level 0 (``chunk * STEP_WIDTH`` indices a
+    step)."""
+    reg = default_registry()
+    with phase("hg.pr") as op:
+        if link_types is not None:
+            snap = restricted_for(snap, link_types)
+        n_pad = _n_pad(snap.num_atoms)
+        n_atoms = jnp.int32(snap.num_atoms)
+        reg.counter("pr.runs").inc()
+        ranks, mass = _pr_init(n_atoms, n_pad)
+        if iterations:
+            _, plans, dev = _hop_over(snap)
+            s1, pw = plans.stage1, _pr_weights(snap, plans)
+            folded = int(dev["blocks"].sum()) * _block_rows(n_pad)
+            d = jnp.float32(damping)
+            for _ in range(iterations):
+                with phase("hg.pr.iter") as ph:
+                    with ph.step("dispatch"):
+                        ranks, mass = _pr_iter(
+                            ranks, dev["levels1"], dev["levels2"], pw,
+                            dev["rows"], n_atoms, d, s1.widths, s1.n_lvl0,
+                            plans.stage2_widths, plans.stage2_n_lvl0, chunk)
+                reg.counter("pr.iterations").inc()
+                reg.counter("pr.rows_folded").inc(folded)
+        with op.step("mass"):
+            mass = float(mass)
+    return PageRankResult(ranks, mass, iterations)
 
 
 def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:

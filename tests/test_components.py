@@ -303,12 +303,18 @@ def test_the_counting_fold_lowers_and_counts_like_numpy(blocks):
     (np.zeros((5,), np.uint32), None),     # a flat vector of words
     (np.zeros((5, 1), np.int32), None),    # a lane-padded label
     (np.zeros((5, 2), np.uint8), None),
+    (np.zeros((5,), np.float32), "sum"),   # PageRank's shares
+    (np.zeros((5,), np.float16), None),
+    (np.zeros((5, 2), np.float32), None),  # a float bitmap
+    (np.zeros((5, 1), np.float32), None),  # a lane-padded rank
+    (np.zeros((), np.float32), None),      # a scalar
 ])
 def test_the_reduction_is_the_states_and_no_other_state_has_one(state,
                                                                 want):
     """``_reduction`` is strict: the OR for ``(S, Kw)`` uint32 words, the
-    min for ``(S,)`` int32 labels, an error for anything else — so neither
-    pyramid nor fold reduces a state by a reduction picked by default."""
+    min for ``(S,)`` int32 labels, the sum (identity 0.0) for ``(S,)``
+    float32 shares, an error for anything else — so neither pyramid nor
+    fold reduces a state by a reduction picked by default."""
     if want is None:
         with pytest.raises(TypeError, match="no reduction"):
             eb._reduction(jnp.asarray(state))
@@ -317,4 +323,7 @@ def test_the_reduction_is_the_states_and_no_other_state_has_one(state,
                            (2,), 1, 4, False, scopes=("hg.t", "hg.t"))
     else:
         assert eb._reduction(jnp.asarray(state)) is {
-            "or": eb._OR_WORDS, "min": eb._MIN_LABELS}[want]
+            "or": eb._OR_WORDS, "min": eb._MIN_LABELS,
+            "sum": eb._SUM_FLOATS}[want]
+    assert (eb._SUM_FLOATS.combine, eb._SUM_FLOATS.identity) == (jnp.add,
+                                                                 0.0)

@@ -482,6 +482,10 @@ def _i32(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32)
 
 
+def _f32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
 #: either update's: a bitmap of one row block, listed or not
 _UPDATE_ARGS = (_u32(64, 1), _u32(9, 1),
                 eb._UpdateRows(_i32(64), _i32(1), _i32()), _i32())
@@ -530,6 +534,18 @@ STAGE_PROGRAMS = {
                    {"widths1": (2, 8, 8), "n1": 2, "widths2": (2, 8),
                     "n2": 1, "chunk": 4}),
     "_wcc_count": ("hg_wcc_count", ("hg.wcc.count",), (_i32(64),), {}),
+    # PageRank: one program an iteration, its three scopes at its top level
+    # (stage 1's buffer: 16 + 8 + 2 chunks and the zero row), and its init
+    "_pr_init": ("hg_pr_init", ("hg.pr.init",), (_i32(),), {"n_pad": 64}),
+    "_pr_iter": ("hg_pr_iter",
+                 ("hg.pr.stage1", "hg.pr.stage2", "hg.pr.update"),
+                 (_f32(64), (_i32(32), _i32(64), _i32(16)),
+                  (_i32(32), _i32(16)),
+                  eb._PRWeights(_f32(64), _f32(27), _f32(64)),
+                  eb._UpdateRows(_i32(64), _i32(1), _i32()), _i32(),
+                  _f32()),
+                 {"widths1": (2, 8, 8), "n1": 2, "widths2": (2, 8),
+                  "n2": 1, "chunk": 4}),
 }
 
 
@@ -789,3 +805,37 @@ def test_scope_reduce(check):
     counts once, an unscoped child inherits, coverage; the recorded TPU
     fixture), run here so that tier-1 holds them."""
     getattr(_check_scope_reduce(), check)()
+
+
+PR_COUNTERS = ("pr.runs", "pr.iterations", "pr.rows_folded")
+
+
+def test_pagerank_leaves_its_phases_and_its_three_counters():
+    """Phase ``hg.pr`` once a call (with its ``mass`` step: the one read),
+    phase ``hg.pr.iter`` once an iteration with a ``dispatch`` step and no
+    ``wait`` (nothing is read between iterations), the bitmap chain's hop
+    phases never; the three ``pr.*`` counters from numbers the host holds:
+    runs, iterations and the plan's listed rows an iteration."""
+    def counters():
+        got = [obs.default_registry().get(n) for n in PR_COUNTERS]
+        return [0 if c is None else c.value for c in got]
+
+    names = ("hg.pr", "hg.pr.iter") + HOP_PHASES
+    snap = _small_snapshot(29)
+    before, c0 = {n: _hist(n)["count"] for n in names}, counters()
+    n = len(_records())
+    res = eb.pagerank(snap, iterations=4)
+    grew = {n: _hist(n)["count"] - before[n] for n in names}
+    assert grew == {"hg.pr": 1, "hg.pr.iter": 4,
+                    **{n: 0 for n in HOP_PHASES}}
+    mine = _records()[n:]
+    (op,) = [r for r in mine if r["name"] == "hg.pr"]
+    iters = [r for r in mine if r["name"] == "hg.pr.iter"]
+    assert len(iters) == 4 and all(
+        r["parent"] == op["id"] and r["op"] == op["id"]
+        and sorted(k for k in r if k.startswith("step.")) ==
+        ["step.dispatch"] for r in iters)
+    assert "step.mass" in op and abs(res.mass - 1.0) < 1e-5
+    n_pad = eb.plans_for(snap).n_pad  # one row block: every row listed
+    assert [now - was for now, was in zip(counters(), c0)] == \
+        [1, 4, 4 * n_pad]

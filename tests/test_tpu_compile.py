@@ -536,3 +536,45 @@ def test_the_new_programs_carry_their_hgverify_entries():
     names = set(hgverify.REGISTRY.names())
     assert {"ops.ellbfs._wcc_init", "ops.ellbfs._wcc_round",
             "ops.ellbfs._wcc_count"} <= names
+
+
+# -------------------- a PageRank iteration at the untyped cell's plan shapes
+
+
+def test_pagerank_iteration_fits_one_chip_with_a_four_byte_rank(
+        one_chip, no_compile_cache):
+    """``_pr_iter`` — both sum pyramids, the link weights between them, the
+    replacing fold and the elementwise update in ONE program — at the
+    untyped plan (``_L1`` / ``_L2``: ``pagerank10m.iter10`` runs
+    ``embedded10m.traverse3``'s plan): the ranks are ``(n_pad,)`` float32,
+    4 bytes a row, donated into the output; what the program holds besides
+    its arguments — two stage buffers of a float a chunk, the fold's zeros
+    and the scan's gather transients — stays under 1 GiB."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
+    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
+    chunks1 = sum(n // w for n, w in zip(_L1, _W1)) + 1
+    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
+                          _sds((blocks,), "int32"), _sds((), "int32"))
+    weights = eb._PRWeights(_sds((_N_PAD,), "float32"),
+                            _sds((chunks1,), "float32"),
+                            _sds((_N_PAD,), "float32"))
+    args = (_sds((_N_PAD,), "float32"), ints(_L1), ints(_L2), weights, rows,
+            _sds((), "int32"), _sds((), "float32"))
+    compiled = eb._pr_iter.lower(
+        *_place(args, one_chip), widths1=_W1, n1=len(_L1), widths2=_W2,
+        n2=_N2, chunk=_CHUNK).compile()
+    mem = compiled.memory_analysis()
+    assert compiled.as_text().startswith("HloModule jit_hg_pr_iter,")
+    assert mem.temp_size_in_bytes < 2**30
+    assert mem.alias_size_in_bytes >= 4 * _N_PAD
+    # the ranks, the plan, the weights, out_map and the block list at 4
+    # bytes an entry
+    flat = 4 * (4 * _N_PAD + sum(_L1) + sum(_L2) + chunks1 + blocks + 1)
+    assert mem.argument_size_in_bytes < 1.01 * flat
+
+
+def test_the_pagerank_programs_carry_their_hgverify_entries():
+    names = set(hgverify.REGISTRY.names())
+    assert {"ops.ellbfs._pr_init", "ops.ellbfs._pr_iter"} <= names
