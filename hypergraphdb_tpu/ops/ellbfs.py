@@ -84,10 +84,15 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   min-label rounds until a fixpoint — the pyramid and the fold reduce with
   the state's reduction (``_reduction``: OR over uint32 seed words, min over
   a flat int32 vector of labels, the buffer's zero row and every padded
-  index holding its identity), the gather the XLA one of 4-byte scalars;
-  and :func:`pagerank`, a fixed count of iterations that SUM float32 ranks
-  through the same two pyramids (the first reduction that is not
-  idempotent: every entry covered once, the fold replacing).
+  index holding its identity); and :func:`pagerank`, a fixed count of
+  iterations that SUM float32 ranks through the same two pyramids (the
+  first reduction that is not idempotent: every entry covered once, the
+  fold replacing). On a TPU their level 0 gathers on the row-gather
+  kernel's scalar form (``pallas_gather.gather_reduce``: the state whole
+  in VMEM as rows of 128, a row loaded an index and its one lane kept),
+  chosen, as the OR's kernel is, from what the code observes
+  (``_kernel_of``: the state's shape and dtype, the gate); the upper
+  levels and the fold's fetch keep the XLA gather.
 
 Geometry note: each gather row is ``Kw = K/32`` uint32 words (32 lanes for
 K=1024). Gathers remain the dominant cost and are bound by the indices
@@ -136,6 +141,9 @@ class _Reduction(NamedTuple):
 
     combine: Callable
     identity: int | float
+    #: the scalar form of the row-gather kernel's name for it
+    #: (``pallas_gather.SCALAR_OPS``), where a flat state takes the kernel
+    scalar_op: Optional[str] = None
 
 
 #: OR over ``(S, Kw)`` uint32 rows of seed bits: a traversal, a match, a
@@ -143,9 +151,9 @@ class _Reduction(NamedTuple):
 _OR_WORDS = _Reduction(jnp.bitwise_or, 0)
 #: min over a flat ``(S,)`` int32 vector of labels:
 #: :func:`connected_components`.
-_MIN_LABELS = _Reduction(jnp.minimum, INT32_MAX)
+_MIN_LABELS = _Reduction(jnp.minimum, INT32_MAX, "min")
 #: sum over a flat ``(S,)`` float32 vector of rank shares: :func:`pagerank`.
-_SUM_FLOATS = _Reduction(jnp.add, 0.0)
+_SUM_FLOATS = _Reduction(jnp.add, 0.0, "sum")
 
 
 def _reduction(state) -> _Reduction:
@@ -344,6 +352,22 @@ def _segmented_ranges(starts: np.ndarray, reps: np.ndarray) -> np.ndarray:
 # ------------------------------------------------------------------ device ops
 
 
+def _kernel_of(values, red: _Reduction, w: int) -> Optional[str]:
+    """Which form of the row-gather kernel serves a class of width ``w``
+    over ``values`` where the caller's gathers take the kernel: ``"or"``
+    (``hg_gather_or``: ``(S, 128)`` uint32 rows), the reduction's
+    ``scalar_op`` (``hg_gather_scalar``: a flat int32 or float32 state), or
+    None where the gate declines it (the XLA gather). The one rule
+    ``_reduce_classes``, ``_reduce_level`` and ``_scalar_indices`` route
+    by; ``values`` needs a shape and a dtype alone."""
+    if red is _OR_WORDS:
+        return "or" if _pg.declined(w, values.shape[1]) is None else None
+    if (red.scalar_op and values.ndim == 1
+            and _pg.declined_scalar(w, values.dtype, values.shape[0]) is None):
+        return red.scalar_op
+    return None
+
+
 def _reduce_level(
     values: jax.Array,  # (S, Kw) uint32 rows, (S,) int32 or float32
     idx: jax.Array,     # (E,) int32, multiple of w
@@ -354,13 +378,18 @@ def _reduce_level(
 ) -> jax.Array:
     """gather + fixed-width reduction by ``red``, streamed in ``chunk``-row
     slices to bound the gather transient: returns (E//w,) + a value row's
-    shape. The kernel serves the OR alone."""
+    shape. A call of ``MIN_INDICES`` or more takes the kernel where
+    ``_kernel_of`` names one: the OR's seed words or a flat state's
+    scalars, a 128-lane row fetched an index either way."""
     E = idx.shape[0]
     row = values.shape[1:]  # (Kw,) seed words; () a label
     n_out = E // w
-    if (use_pallas and red is _OR_WORDS and E >= _pg.MIN_INDICES
-            and _pg.declined(w, values.shape[1]) is None):
+    kernel = (_kernel_of(values, red, w)
+              if use_pallas and E >= _pg.MIN_INDICES else None)
+    if kernel == "or":
         return _pg.gather_or(values, idx, w)
+    if kernel:
+        return _pg.gather_reduce(values, idx, w, kernel)
     if E <= chunk * w:
         g = values[idx]
         return _fold(g.reshape(n_out, w, *row), red)
@@ -429,9 +458,17 @@ def _apply_plan(
     sizes = [lvl.shape[0] // w for lvl, w in zip(levels, widths)]
     total = sum(sizes) + 1  # + global zero row at index `sum(sizes)`
     lvl0_scope, upper_scope = map(jax.named_scope, scopes)
+    red = _reduction(values)
     with lvl0_scope:
-        buf = jnp.full((total, *values.shape[1:]),
-                       _reduction(values).identity, dtype=values.dtype)
+        if use_pallas and red.scalar_op and any(
+                _kernel_of(values, red, w) for w in widths[:n_lvl0]):
+            # the scalar kernel reads a flat table as rows of 128: padded
+            # with the identity once here, not at every call, and the
+            # buffer (the next stage's table) allocated whole
+            values = _pg.scalar_table(values, red.identity)
+            total = _ceil_to(total, _pg.G_SCALAR)
+        buf = jnp.full((total, *values.shape[1:]), red.identity,
+                       dtype=values.dtype)
         buf = _reduce_classes(buf, values, levels[:n_lvl0], widths[:n_lvl0],
                               chunk, use_pallas)
     if n_lvl0 == len(levels):  # no row above the widest class
@@ -451,13 +488,54 @@ def _reduce_classes(buf, values, levels, widths, chunk, use_pallas):
     would pad every step otherwise)."""
     off = 0
     for idx, w in zip(levels, widths):
-        step = chunk * STEP_WIDTH
-        if use_pallas and _pg.declined(w, values.shape[1]) is None:
-            step = _pg.whole_segments(step, w)
-        buf = _reduce_into(buf, off, values, idx, w, max(1, step // w),
+        buf = _reduce_into(buf, off, values, idx, w,
+                           _class_rows(values, w, chunk, use_pallas),
                            use_pallas)
         off += idx.shape[0] // w
     return buf
+
+
+def _class_rows(values, w: int, chunk: int, use_pallas: bool) -> int:
+    """Output rows a scan step of a level-0 class of width ``w`` moves (see
+    ``_reduce_classes``): ``chunk * STEP_WIDTH`` indices, cut to the whole
+    segments of the kernel's form that serves the class."""
+    step = chunk * STEP_WIDTH
+    kernel = _kernel_of(values, _reduction(values), w) if use_pallas else None
+    if kernel == "or":
+        step = _pg.whole_segments(step, w)
+    elif kernel:
+        step = _pg.whole_scalar_steps(step, w)
+    return max(1, step // w)
+
+
+def _scalar_indices(plans: "PullBFSPlans", dtype, chunk: int,
+                    use_pallas: bool) -> tuple[int, int]:
+    """``(level-0 indices, those the kernel gathers)`` of one program over
+    both stages of ``plans`` for a flat ``dtype`` state — what a whole-graph
+    operator counts a dispatch (``scalar.gather.indices`` and
+    ``.indices_kernel``), from the plan's lengths on the host, by the rule
+    the program routes by (``_class_rows``, ``_reduce_into``'s blocks and
+    tail, ``_reduce_level``'s ``MIN_INDICES``), over each stage's table:
+    the state, then stage 1's buffer."""
+    s1 = plans.stage1
+    total = kernel = 0
+    for table, levels, widths, n in (
+            (plans.n_pad, s1.levels, s1.widths, s1.n_lvl0),
+            (s1.concat_size + 1, plans.stage2_levels, plans.stage2_widths,
+             plans.stage2_n_lvl0)):
+        values = jax.ShapeDtypeStruct((table,), dtype)
+        red = _reduction(values)
+        for idx, w in zip(levels[:n], widths[:n]):
+            E = len(idx)
+            total += E
+            if not (use_pallas and _kernel_of(values, red, w)):
+                continue
+            rows = _class_rows(values, w, chunk, use_pallas)
+            n_out = E // w
+            block, tail = rows * w, (n_out - n_out // rows * rows) * w
+            kernel += ((n_out // rows) * block if block >= _pg.MIN_INDICES
+                       else 0) + (tail if tail >= _pg.MIN_INDICES else 0)
+    return total, kernel
 
 
 def _upper_levels(
@@ -1990,11 +2068,12 @@ def _wcc_round_shapes():
 @hgverify.entry(shapes=_wcc_round_shapes, donate=True,
                 statics={"widths1": (2, 8), "n1": 2, "widths2": (2,),
                          "n2": 1, "chunk": 4})
-@partial(jax.jit, static_argnames=("widths1", "n1", "widths2", "n2", "chunk"),
+@partial(jax.jit, static_argnames=("widths1", "n1", "widths2", "n2", "chunk",
+                                   "use_pallas"),
          donate_argnums=(0,))  # the labels alias the output
 @_program("hg_wcc_round")
 def _wcc_round(labels, levels1, levels2, rows, n_atoms, widths1, n1,
-               widths2, n2, chunk):
+               widths2, n2, chunk, use_pallas=False):
     """One synchronous min-label round over a pull plan, ONE program: stage
     1 (each link's min over its targets' labels), stage 2 (each atom's min
     over its incident links' — level 0 composed through stage 1's chunks,
@@ -2002,12 +2081,14 @@ def _wcc_round(labels, levels1, levels2, rows, n_atoms, widths1, n1,
     buf[out_map[v]])`` over the plan's active row blocks, which returns
     beside the labels the rows it LOWERED, ``() int32``. The pyramids are
     ``bfs_pull``'s with the labels' reduction (``_reduction``: min,
-    identity ``INT32_MAX``) and the XLA gather of 4-byte scalars; the
-    stage buffers are a few tens of MB at 10M atoms, so one program holds
-    the round where a 4096-seed hop needs four."""
-    live = _apply_plan(labels, levels1, widths1, n1, chunk, False,
+    identity ``INT32_MAX``); their level 0 gathers its 4-byte scalars on
+    the kernel's scalar form where ``use_pallas`` (``pallas_ok()``), else
+    through the XLA gather. The stage buffers are a few tens of MB at 10M
+    atoms, so one program holds the round where a 4096-seed hop needs
+    four."""
+    live = _apply_plan(labels, levels1, widths1, n1, chunk, use_pallas,
                        scopes=("hg.wcc.stage1", "hg.wcc.stage1"))
-    reach = _apply_plan(live, levels2, widths2, n2, chunk, False,
+    reach = _apply_plan(live, levels2, widths2, n2, chunk, use_pallas,
                         scopes=("hg.wcc.stage2", "hg.wcc.stage2"))
     with jax.named_scope("hg.wcc.fold"):
         return _fold_rows(labels, reach, rows, n_atoms, jnp.minimum,
@@ -2087,6 +2168,8 @@ def connected_components(snap: CSRSnapshot, link_types=None, *,
             _, plans, dev = _hop_over(snap)
             s1, rows = plans.stage1, dev["rows"]
             folded = int(dev["blocks"].sum()) * _block_rows(n_pad)
+            kernel = _pg.pallas_ok()
+            gathered = _scalar_indices(plans, jnp.int32, chunk, kernel)
             lowered = 1
             while lowered:
                 with phase("hg.wcc.round") as ph:
@@ -2094,13 +2177,15 @@ def connected_components(snap: CSRSnapshot, link_types=None, *,
                         labels, lowered = _wcc_round(
                             labels, dev["levels1"], dev["levels2"], rows,
                             n_atoms, s1.widths, s1.n_lvl0,
-                            plans.stage2_widths, plans.stage2_n_lvl0, chunk)
+                            plans.stage2_widths, plans.stage2_n_lvl0, chunk,
+                            **({"use_pallas": True} if kernel else {}))
                     with ph.step("wait"):  # the read of its 4 bytes
                         lowered = int(lowered)
                 rounds += 1
                 reg.counter("wcc.rounds").inc()
                 reg.counter("wcc.rows_lowered").inc(lowered)
                 reg.counter("wcc.rows_folded").inc(folded)
+                _count_scalar_gathers(reg, gathered)
         with op.step("count"):
             n_components = int(_wcc_count(labels))
     return ComponentsResult(labels, n_components, rounds)
@@ -2144,11 +2229,12 @@ def _pr_iter_shapes():
 @hgverify.entry(shapes=_pr_iter_shapes, donate=True,
                 statics={"widths1": (2, 8), "n1": 2, "widths2": (2,),
                          "n2": 1, "chunk": 4})
-@partial(jax.jit, static_argnames=("widths1", "n1", "widths2", "n2", "chunk"),
+@partial(jax.jit, static_argnames=("widths1", "n1", "widths2", "n2", "chunk",
+                                   "use_pallas"),
          donate_argnums=(0,))  # the ranks alias the output
 @_program("hg_pr_iter")
 def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
-             n1, widths2, n2, chunk):
+             n1, widths2, n2, chunk, use_pallas=False):
     """One PageRank iteration over a pull plan, ONE program, returning the
     new ranks and their sum. Stage 1 sums each link's target shares ``x =
     rank · inv_d`` (a slot a share); ``w`` scales each link's sum where
@@ -2160,15 +2246,18 @@ def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
         rank'[v] = (1 - d)/N + d · (y[v] - c[v] · x[v]) + (d/N) · dangling
 
     ``dangling`` the ranks of the atoms with ``inv_d == 0``. The pyramids
-    are :func:`bfs_pull`'s with the sum (``_reduction``: identity 0.0) and
-    the XLA gather of 4-byte scalars."""
+    are :func:`bfs_pull`'s with the sum (``_reduction``: identity 0.0);
+    their level 0 gathers on the kernel's scalar form where
+    ``use_pallas``, as :func:`_wcc_round`'s does, whose buffers come
+    padded to whole rows of the kernel's table (``w``'s pad is 0)."""
     with jax.named_scope("hg.pr.stage1"):
         x = ranks * pw.inv_d
-    s = _apply_plan(x, levels1, widths1, n1, chunk, False,
+    s = _apply_plan(x, levels1, widths1, n1, chunk, use_pallas,
                     scopes=("hg.pr.stage1", "hg.pr.stage1"))
     with jax.named_scope("hg.pr.stage2"):
-        s = s * pw.w
-    y = _apply_plan(s, levels2, widths2, n2, chunk, False,
+        pad = s.shape[0] - pw.w.shape[0]
+        s = s * (jnp.pad(pw.w, (0, pad)) if pad else pw.w)
+    y = _apply_plan(s, levels2, widths2, n2, chunk, use_pallas,
                     scopes=("hg.pr.stage2", "hg.pr.stage2"))
     with jax.named_scope("hg.pr.update"):
         y = _fold_rows(jnp.zeros_like(ranks), y, rows, n_atoms,
@@ -2178,6 +2267,13 @@ def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
         new = jnp.where(real, (1.0 - damping) / n + damping * (y - pw.c * x)
                         + damping * _dangling(ranks, pw.inv_d) / n, 0.0)
         return new, jnp.sum(new)
+
+
+def _count_scalar_gathers(reg, gathered: tuple[int, int]) -> None:
+    """A whole-graph dispatch's level-0 indices and those the kernel
+    gathered (``_scalar_indices``): reader ``scalar_gather_kernel_share``."""
+    reg.counter("scalar.gather.indices").inc(gathered[0])
+    reg.counter("scalar.gather.indices_kernel").inc(gathered[1])
 
 
 def _dangling(ranks: jax.Array, inv_d: jax.Array) -> jax.Array:
@@ -2264,6 +2360,8 @@ def pagerank(snap: CSRSnapshot, link_types=None, *, damping: float = 0.85,
             _, plans, dev = _hop_over(snap)
             s1, pw = plans.stage1, _pr_weights(snap, plans)
             folded = int(dev["blocks"].sum()) * _block_rows(n_pad)
+            kernel = _pg.pallas_ok()
+            gathered = _scalar_indices(plans, jnp.float32, chunk, kernel)
             d = jnp.float32(damping)
             for _ in range(iterations):
                 with phase("hg.pr.iter") as ph:
@@ -2271,9 +2369,11 @@ def pagerank(snap: CSRSnapshot, link_types=None, *, damping: float = 0.85,
                         ranks, mass = _pr_iter(
                             ranks, dev["levels1"], dev["levels2"], pw,
                             dev["rows"], n_atoms, d, s1.widths, s1.n_lvl0,
-                            plans.stage2_widths, plans.stage2_n_lvl0, chunk)
+                            plans.stage2_widths, plans.stage2_n_lvl0, chunk,
+                            **({"use_pallas": True} if kernel else {}))
                 reg.counter("pr.iterations").inc()
                 reg.counter("pr.rows_folded").inc(folded)
+                _count_scalar_gathers(reg, gathered)
         with op.step("mass"):
             mass = float(mass)
     return PageRankResult(ranks, mass, iterations)

@@ -1,4 +1,7 @@
-"""Pallas TPU kernel: double-buffered HBM row gather + fixed-width OR.
+"""Pallas TPU kernels: a row gather reduced over fixed-width chunks — the OR
+of 128-word bitmap rows (``hg_gather_or``, double-buffered HBM row copies)
+and the sum or min of a flat state's 4-byte scalars (``hg_gather_scalar``,
+:func:`gather_reduce`: rows of 128 loaded from VMEM, one lane kept).
 
 The pull-BFS reduction (:mod:`hypergraphdb_tpu.ops.ellbfs`) spends its time
 gathering Kw-word rows of the transposed visited bitmap through CSR index
@@ -100,6 +103,40 @@ visited-only state to fit wide blocks in HBM. A pad index costs what a
 real one does (a fetch of the zero row is a fetch). The kernel is the
 TPU path at supported widths, with the XLA gather as the fallback
 everywhere else.
+
+THE SCALAR FORM (:func:`gather_reduce`) serves a whole-graph operator's
+pyramids (``ellbfs.connected_components``' int32 labels, min;
+``ellbfs.pagerank``'s float32 shares, sum): its table is the flat state
+viewed as rows of 128, held WHOLE in VMEM (up to ``SCALAR_TABLE_BYTES``;
+the cells' states are 40 MB), and an index ``i`` is one vector load of
+row ``i >> 7`` from a scalar address, lane ``i & 127`` kept and the
+identity elsewhere, combined into its chunk's row; 128 chunks' rows are
+transposed and folded into one lane-dense row of results. No copy is
+issued: the loop is bound by the scalar core (an SMEM load, a shift and
+an address an index; 2.5 bundles an index on a described v5e). ns an
+index (one v5e chip, one class alone over a 10,000,072-value table, 8.4M
+random indices a width, through ``ellbfs._reduce_classes`` either way;
+``benchmarks/tests/scalar_gather_probe.py``, PERF.md section 6;
+in the cells the XLA gather read 7.26-7.66):
+
+==============  =====  =====  =====  =====  =====  =====  =====  =====  =====  =====
+w               2      4      6      8      10     14     20     28     40     56
+==============  =====  =====  =====  =====  =====  =====  =====  =====  =====  =====
+f32 sum, this   2.23   2.34   2.29   2.20   2.24   2.16   2.14   2.12   2.16   2.11
+f32 sum, XLA    14.44  13.72  13.62  13.50  13.53  13.50  13.41  13.40  13.40  13.37
+i32 min, this   2.31   2.35   2.39   2.21   2.33   2.18   2.16   2.13   2.24   2.11
+i32 min, XLA    8.52   13.85  13.85  13.68  7.68   7.62   7.56   7.52   7.51   7.49
+row DMA         9.24   8.46   8.31   8.47   8.40   8.44   8.33   8.37   8.25   8.20
+==============  =====  =====  =====  =====  =====  =====  =====  =====  =====  =====
+
+``row DMA`` (f32 sum) is the form first built, ``hg_gather_or``'s
+pipeline with a 128-lane row COPIED from an HBM table an index and the
+lane picked after the tile's wait: 8.2-9.3 ns at every width, no faster
+than the XLA gather's min, and a table XLA places in VMEM (the cells'
+stage buffers are) turns each of its copies into a load and a store in
+series. The table is copied into VMEM once a call (40 MB, ~50 us), so a
+call takes a scan block of the pyramid's level 0 (``chunk * 8``
+indices), its indices a grid step at a time through SMEM.
 
 Constraints (Mosaic, this toolchain): rows must be exactly 128 lanes
 (``ROW_WORDS`` — narrower VMEM blocks fail to compile, and at 256+ the
@@ -385,6 +422,201 @@ def gather_or(values: jax.Array, idx: jax.Array, w: int,
 
 def _ceil(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+# ------------------------------------------------------ the scalar form
+
+#: output chunks a grid step of the scalar form writes: one (8, 128) block
+#: of 4-byte results, lane-dense
+G_SCALAR = TILE * ROW_WORDS
+#: what the scalar form reduces with (see :func:`_scalar_fns`)
+SCALAR_OPS = ("sum", "min")
+#: the state dtypes it serves: 4-byte scalars, 128 to a row of the table
+SCALAR_DTYPES = (jnp.dtype(jnp.float32), jnp.dtype(jnp.int32))
+#: indices of each of a tile's eight chunks one step of the scalar form's
+#: loop loads: two, so that sixteen loads stand in a step for the
+#: scheduler to overlap (2.5 bundles an index on a described v5e, where
+#: one reads 3.1 and four 2.2 with twice the text)
+SCALAR_STEP = 2
+#: the most table bytes the scalar form holds in VMEM (v5e: 128 MiB a
+#: core, beside what XLA keeps there): a 16.7M-value state and its pad
+SCALAR_TABLE_BYTES = 64 << 20
+
+
+def _scalar_fns(op: str):
+    """``op``'s combine of a chunk's rows and its fold of a transposed
+    block's sublanes."""
+    return (jnp.add, jnp.sum) if op == "sum" else (jnp.minimum, jnp.min)
+
+
+def whole_scalar_steps(n: int, w: int) -> int:
+    """The most indices up to ``n`` that :func:`gather_reduce` takes at
+    width ``w`` without a pad chunk: whole grid steps of ``G_SCALAR``
+    chunks, or ``n`` itself where it is less than one."""
+    step = G_SCALAR * w
+    return n // step * step if n >= step else n
+
+
+def _vmem_bytes_scalar(n_values: int) -> int:
+    """VMEM working set of one ``_call_scalar``: the table whole, the
+    (G_SCALAR, 128) block of picked rows and the (8, 128) output block
+    double-buffered, 4-byte (a grid step's indices are in SMEM)."""
+    return 4 * (_ceil(n_values, G_SCALAR)
+                + ROW_WORDS * (G_SCALAR + 2 * TILE))
+
+
+def declined_scalar(w: int, dtype, n_values: int) -> str | None:
+    """None when :func:`gather_reduce` serves ``w``-wide chunks of a flat
+    ``dtype`` state of ``n_values``; otherwise the reason callers must
+    take the XLA gather. The scalar form's ONE gate, as :func:`declined`
+    is the OR's: ``gather_reduce`` raises it, ``ellbfs._reduce_level``
+    routes on it, and ``tests/test_tpu_compile.py`` holds it to what the
+    v5e compiler accepts."""
+    if jnp.dtype(dtype) not in SCALAR_DTYPES:
+        return (f"a {jnp.dtype(dtype)} state: the scalar form reads "
+                f"4-byte float32 or int32 values")
+    if w < 1 or 2 * G_SCALAR * w * 4 > SMEM_BUDGET // 2:
+        # a grid step's indices, double-buffered, beside Mosaic's own
+        return (f"w={w}: two grid steps of {G_SCALAR} chunks of indices "
+                f"must fit half the {SMEM_BUDGET} B of SMEM")
+    if 4 * _ceil(n_values, G_SCALAR) > SCALAR_TABLE_BYTES:
+        return (f"a table of {n_values} values: the scalar form holds at "
+                f"most {SCALAR_TABLE_BYTES} B in VMEM")
+    return None
+
+
+def _scalar_kernel(idx_ref, table, out_ref, picked, *, w, op, identity):
+    """One grid step of the scalar form: ``G_SCALAR`` chunks of ``w``
+    indices, the step's indices in SMEM, the table whole in VMEM as rows
+    of 128. A tile of eight chunks is eight chains of one (1, 128) row
+    each; a step of the loop over ``j`` loads, for each chunk, the row
+    ``i >> 7`` that holds its ``j``-th index ``i`` — one vector load from a
+    scalar address — keeps lane ``i & 127`` and the identity elsewhere,
+    and combines it by ``op`` into the chunk's row. The tile's rows are
+    stored to ``picked``; after the loop each 128 chunks' rows are
+    transposed and folded over their sublanes: a lane-dense (1, 128) row
+    of results, 4 bytes a chunk. The text is one loop over the tiles
+    around one loop over ``j``, eight loads in each step, whatever ``w``."""
+    combine, fold = _scalar_fns(op)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, ROW_WORDS), 1)
+    # 127 in every lane, not a constant: the lane number is taken on the
+    # vector unit, and the scalar core, which bounds the loop, is spared
+    # an operation an index
+    low = lane | (ROW_WORDS - 1)
+
+    def tile(t, _):
+        first = t * (TILE * w)
+
+        def step(j, rows):
+            out = list(rows)
+            at = first + j
+            for i in range(TILE):
+                v = idx_ref[at + i * w if i else at]
+                out[i] = combine(out[i], jnp.where(
+                    lane == (jnp.full((1, ROW_WORDS), v) & low),
+                    table[pl.ds(v >> 7, 1), :], identity))
+            return tuple(out)
+
+        def steps(j0, rows):
+            for u in range(SCALAR_STEP):
+                rows = step(j0 * SCALAR_STEP + u, rows)
+            return rows
+
+        rows = jax.lax.fori_loop(
+            0, w // SCALAR_STEP, steps,
+            (jnp.full((1, ROW_WORDS), identity, table.dtype),) * TILE)
+        for j in range(w - w % SCALAR_STEP, w):
+            rows = step(j, rows)
+        for i in range(TILE):
+            picked[pl.ds(t * TILE + i, 1), :] = rows[i]
+        return 0
+
+    jax.lax.fori_loop(0, G_SCALAR // TILE, tile, 0)
+    for q in range(G_SCALAR // ROW_WORDS):
+        block = picked[q * ROW_WORDS:(q + 1) * ROW_WORDS, :]
+        out_ref[q:q + 1, :] = fold(block.T, axis=0, keepdims=True)
+
+
+def _call_scalar(idx: jax.Array, table: jax.Array, n_valid: int, w: int,
+                 op: str, identity, interpret: bool) -> jax.Array:
+    n_out = idx.shape[0] // w
+    step = G_SCALAR * w
+    # where a load READS is held to the table's values here, as the XLA
+    # gather clamps (the kernel is compiled without bounds checks, as
+    # _call's is): one fused elementwise pass over the indices
+    idx = jnp.clip(idx, 0, n_valid - 1)
+    # budget enforced by gather_reduce's declined_scalar guard
+    return pl.pallas_call(  # hglint: disable=HG502
+        functools.partial(_scalar_kernel, w=w, op=op, identity=identity),
+        grid=(n_out // G_SCALAR,),
+        in_specs=[pl.BlockSpec((step,), lambda i: (i,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((G_SCALAR // ROW_WORDS, ROW_WORDS),
+                               lambda i: (i, 0), memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((G_SCALAR, ROW_WORDS), table.dtype)],
+        out_shape=jax.ShapeDtypeStruct((n_out // ROW_WORDS, ROW_WORDS),
+                                       table.dtype),
+        compiler_params=pltpu.CompilerParams(
+            disable_bounds_checks=True,
+            vmem_limit_bytes=_vmem_bytes_scalar(table.size) + (4 << 20)),
+        interpret=interpret,
+        name="hg_gather_scalar",  # the kernel's name in a profile
+    )(idx, table)
+
+
+def scalar_identity(op: str, dtype):
+    """What a pad of the table and every lane not picked hold."""
+    if op == "sum":
+        return 0
+    dtype = jnp.dtype(dtype)
+    return (jnp.iinfo(dtype).max if jnp.issubdtype(dtype, jnp.integer)
+            else float("inf"))
+
+
+def scalar_table(values: jax.Array, identity) -> jax.Array:
+    """``values`` padded with ``identity`` to whole (8, 128) tiles of rows
+    — what :func:`gather_reduce` reads as rows of 128 with a reshape and
+    no copy; ``values`` itself where it is whole already."""
+    pad = -values.shape[0] % G_SCALAR
+    return (values if not pad
+            else jnp.pad(values, (0, pad), constant_values=identity))
+
+
+@hgverify.entry(
+    shapes=lambda: (hgverify.sds((1000,), "float32"),
+                    hgverify.sds((8192,), "int32")),
+    statics={"w": 8, "op": "sum", "interpret": True},
+)
+def gather_reduce(values: jax.Array, idx: jax.Array, w: int, op: str,
+                  interpret: bool = False) -> jax.Array:
+    """``op`` over groups of ``w`` of a FLAT 4-byte state: returns
+    ``(len(idx)//w,)`` of ``values.dtype`` where entry c = ``op`` over
+    ``values[idx[c*w : (c+1)*w]]`` (``op``: ``"sum"`` or ``"min"``; an
+    index outside the table reads its nearest end, as the XLA gather's).
+    The table is ``values`` viewed as rows of 128 — a reshape where its
+    length is whole (8, 128) tiles, else padded to them with the
+    identity (a copy: a caller that gathers from one table many times
+    pads it once, :func:`scalar_table`). ``len(idx) % w == 0`` and a
+    shape :func:`declined_scalar` admits required. Trace-safe."""
+    E, S = idx.shape[0], values.shape[0]
+    if values.ndim != 1 or op not in SCALAR_OPS:
+        raise ValueError(f"gather_reduce: a flat state and an op of "
+                         f"{sorted(SCALAR_OPS)}, got {values.shape} {op!r}")
+    if E % w:
+        raise ValueError(f"gather_reduce: need len(idx) % {w} == 0, "
+                         f"got E={E}")
+    why = declined_scalar(w, values.dtype, S)
+    if why is not None:
+        raise ValueError(f"gather_reduce: {why}")
+    identity = scalar_identity(op, values.dtype)
+    table = scalar_table(values, identity).reshape(-1, ROW_WORDS)
+    E_pad = _ceil(E, G_SCALAR * w)
+    if E_pad != E:  # pad chunks read the table's first value, sliced off
+        idx = jnp.concatenate([idx, jnp.zeros((E_pad - E,), idx.dtype)])
+    out = _call_scalar(idx, table, S, w, op, identity,
+                       interpret).reshape(E_pad // w)
+    return out[: E // w] if E_pad != E else out
 
 
 #: backends whose probe has passed (a failed probe raises and is never

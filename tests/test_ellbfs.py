@@ -485,6 +485,53 @@ FOLD_ROUTES = {
 }
 
 
+def scalar_kernel_route(monkeypatch, min_indices: int = 64) -> list:
+    """Send a whole-graph operator's level-0 gathers through the scalar form
+    of the row-gather kernel on the CPU: ``pallas_ok`` says yes, the Pallas
+    interpreter stands in for the chip, and ``MIN_INDICES`` comes down so
+    that a small graph's classes engage it. Returns the widths of the calls
+    the program traced, as ``gather_reduce`` records them."""
+    calls, real = [], eb._pg.gather_reduce
+    monkeypatch.setattr(eb._pg, "pallas_ok", lambda: True)
+    monkeypatch.setattr(eb._pg, "MIN_INDICES", min_indices)
+    monkeypatch.setattr(
+        eb._pg, "gather_reduce",
+        lambda v, i, w, op: calls.append((w, op)) or real(
+            v, i, w, op, interpret=True))
+    return calls
+
+
+@pytest.mark.parametrize("chunk", [8, 1 << 16])
+def test_wcc_round_on_the_kernel_gives_the_xla_routes_labels(chunk,
+                                                              monkeypatch):
+    """``connected_components`` with its level-0 gathers on the kernel's
+    scalar form against the same rounds on the XLA gather: the labels bit
+    for bit, as many rounds, and the counters' share of kernel indices
+    over 0 and up to 100% (a class's ragged tail under ``MIN_INDICES``
+    keeps the XLA gather; at chunk 8 a scan block is 64 indices)."""
+    from hypergraphdb_tpu.ops import connected_components
+    from tests.test_pair_distances import linked_snapshot
+
+    snap = linked_snapshot(700, 800, 11, n_types=4)
+    want = connected_components(snap, (1, 3), chunk=chunk)
+    calls = scalar_kernel_route(monkeypatch)
+    reg = obs.default_registry()
+    names = ("scalar.gather.indices", "scalar.gather.indices_kernel")
+    before = [0 if reg.get(n) is None else reg.get(n).value for n in names]
+    got = connected_components(snap, (1, 3), chunk=chunk)
+    np.testing.assert_array_equal(np.asarray(got.labels),
+                                  np.asarray(want.labels))
+    assert (got.rounds, got.n_components) == (want.rounds, want.n_components)
+    total, kernel = (reg.get(n).value - b for n, b in zip(names, before))
+    sub = eb.restricted_for(snap, (1, 3))
+    plans = eb.plans_for(sub)
+    assert total == got.rounds * sum(
+        len(l) for l in plans.stage1.levels[:plans.stage1.n_lvl0]
+        + plans.stage2_levels[:plans.stage2_n_lvl0])
+    assert bool(calls) == (kernel > 0) and {op for _, op in calls} <= {"min"}
+    assert 0 < kernel <= total
+
+
 @pytest.mark.parametrize("case", list(FOLD_ROUTES))
 def test_fold_rows_fetches_on_the_kernel_where_it_serves_the_state(
         case, monkeypatch):

@@ -25,6 +25,7 @@ from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 from tests.test_components import _wide
 from tests.test_ellbfs import (  # noqa: F401  (typed_graph: a fixture)
     FAMILIES,
+    scalar_kernel_route,
     typed_graph,
 )
 from tests.test_pair_distances import linked_snapshot
@@ -301,6 +302,27 @@ def test_the_sum_pyramid_is_numpy_at_every_width_class(width):
     want = np.asarray([values[flat[offsets[i]:offsets[i + 1]]].sum()
                        for i in range(n_rows)])
     np.testing.assert_array_equal(buf[plan.out_map], want)
+
+
+@pytest.mark.parametrize("typed", [False, True])
+def test_pr_iter_on_the_kernel_gives_the_xla_routes_ranks(typed, monkeypatch):
+    """``pagerank`` with its level-0 gathers on the kernel's scalar form
+    (the Pallas interpreter standing in for the chip) against the same
+    iterations on the XLA gather: every rank within ``RTOL`` (a sum in
+    another order), the mass, both the reference, and most of the plan's
+    level-0 indices on the kernel."""
+    snap = linked_snapshot(700, 800, 12, n_types=4)
+    family = (1, 3) if typed else None
+    want = pagerank(snap, family, iterations=3, chunk=1 << 16)
+    calls = scalar_kernel_route(monkeypatch)
+    got = pagerank(snap, family, iterations=3, chunk=1 << 16)
+    np.testing.assert_allclose(np.asarray(got.ranks), np.asarray(want.ranks),
+                               rtol=RTOL, atol=0)
+    assert abs(got.mass - want.mass) < 1e-6
+    _assert_is_the_reference(snap, family, got, iterations=3)
+    assert calls and {op for _, op in calls} == {"sum"}
+    share = obs.default_registry().get("scalar.gather.indices_kernel").value
+    assert share > 0
 
 
 @pytest.mark.parametrize("blocks", ["all", "some", "ragged_last"])

@@ -322,3 +322,132 @@ def test_tile_share_gauge_says_which_gather_serves(graph, monkeypatch):
     assert eb._tile_share(plans) == pytest.approx(
         100.0 * (lvl0 - in_w0) / lvl0)
     assert 0.0 < eb._tile_share(plans) < 100.0
+
+
+# ------------------------------------------------------ the scalar form
+
+#: a table whose length is no multiple of 128: the last row is padded
+SCALAR_S = 3005
+
+
+def _scalar_values(r, op, S=SCALAR_S):
+    """Small whole numbers as float32 (every sum exact, in any order) or
+    int32 labels over the whole range."""
+    if op == "sum":
+        return r.integers(0, 64, size=S).astype(np.float32)
+    return r.integers(-2**31, 2**31 - 1, size=S, dtype=np.int64) \
+        .astype(np.int32)
+
+
+def _scalar_ref(values, idx, w, op):
+    g = np.asarray(values)[np.asarray(idx)].reshape(-1, w)
+    return g.sum(axis=1) if op == "sum" else g.min(axis=1)
+
+
+@pytest.mark.parametrize("op", ["sum", "min"])
+@pytest.mark.parametrize("w", CLASS_WIDTHS)
+def test_gather_reduce_matches_numpy(w, op):
+    """Every class width, a float32 sum and an int32 min: one whole grid
+    step of ``G_SCALAR`` chunks and a ragged tail of 17 (padded to a
+    second step and sliced off), over a table of 3005 values, every one
+    of them reachable."""
+    r = np.random.default_rng([w, 41, op == "sum"])
+    values = _scalar_values(r, op)
+    n_out = pg.G_SCALAR + 17
+    idx = r.integers(0, SCALAR_S, size=n_out * w).astype(np.int32)
+    out = np.asarray(pg.gather_reduce(jnp.asarray(values), jnp.asarray(idx),
+                                      w, op, interpret=True))
+    assert out.shape == (n_out,) and out.dtype == values.dtype
+    assert np.array_equal(out, _scalar_ref(values, idx, w, op))
+
+
+@pytest.mark.parametrize("op", ["sum", "min"])
+def test_gather_reduce_holds_indices_to_the_table(op):
+    """The last value is read where the table's last row is a pad, and an
+    index outside the table reads its nearest end, as the XLA gather's
+    does — the pad past the last value is never read."""
+    r = np.random.default_rng([op == "sum", 42])
+    values = _scalar_values(r, op)
+    w = 6
+    idx = r.integers(0, SCALAR_S, size=pg.G_SCALAR * w).astype(np.int32)
+    idx[::5] = SCALAR_S - 1
+    idx[1::7] = SCALAR_S + r.integers(0, 1 << 20, size=len(idx[1::7]))
+    idx[3::11] = -1 - r.integers(0, 1 << 20, size=len(idx[3::11]))
+    out = pg.gather_reduce(jnp.asarray(values), jnp.asarray(idx), w, op,
+                           interpret=True)
+    assert np.array_equal(np.asarray(out), _scalar_ref(
+        values, np.clip(idx, 0, SCALAR_S - 1), w, op))
+
+
+@pytest.mark.parametrize("op", ["sum", "min"])
+def test_gather_reduce_pad_indices_read_the_identity(op):
+    """A plan pads a chunk with the index of its zero row, which holds the
+    reduction's identity: a chunk of pads alone reads the identity, and a
+    chunk of one value and pads reads that value, exactly."""
+    r = np.random.default_rng([op == "sum", 43])
+    values = _scalar_values(r, op)
+    ident = pg.scalar_identity(op, values.dtype)
+    zero = SCALAR_S - 1
+    values[zero] = ident
+    w = 8
+    idx = np.full((pg.G_SCALAR, w), zero, dtype=np.int32)
+    idx[1::2, 0] = r.integers(0, zero, size=pg.G_SCALAR // 2)
+    out = np.asarray(pg.gather_reduce(jnp.asarray(values),
+                                      jnp.asarray(idx.reshape(-1)), w, op,
+                                      interpret=True))
+    assert (out[0::2] == ident).all()
+    assert np.array_equal(out[1::2], values[idx[1::2, 0]])
+
+
+def test_scalar_gate_says_what_the_form_serves():
+    """4-byte float32 and int32 states, a grid step's indices inside half
+    the SMEM, a table inside ``SCALAR_TABLE_BYTES``; anything else is
+    declined with its reason, and ``gather_reduce`` raises it."""
+    widest = pg.SMEM_BUDGET // 2 // (8 * pg.G_SCALAR)  # two steps' indices
+    for w in (*CLASS_WIDTHS, 1, widest):
+        for dt in ("float32", "int32"):
+            assert pg.declined_scalar(w, dt, 10_000_072) is None
+    assert "4-byte" in pg.declined_scalar(8, "uint32", 100)
+    assert "4-byte" in pg.declined_scalar(8, "float16", 100)
+    assert "SMEM" in pg.declined_scalar(widest + 1, "int32", 100)
+    most = pg.SCALAR_TABLE_BYTES // 4
+    assert pg.declined_scalar(8, "int32", most) is None
+    assert "VMEM" in pg.declined_scalar(8, "int32", most + 1)
+    with pytest.raises(ValueError, match="4-byte"):
+        pg.gather_reduce(jnp.zeros((8,), jnp.uint32),
+                         jnp.zeros((64,), jnp.int32), 8, "min")
+    with pytest.raises(ValueError, match="% 8"):
+        pg.gather_reduce(jnp.zeros((8,), jnp.int32),
+                         jnp.zeros((63,), jnp.int32), 8, "min")
+    with pytest.raises(ValueError, match="flat"):
+        pg.gather_reduce(jnp.zeros((8, 128), jnp.int32),
+                         jnp.zeros((64,), jnp.int32), 8, "min")
+    with pytest.raises(ValueError, match="flat"):
+        pg.gather_reduce(jnp.zeros((8,), jnp.int32),
+                         jnp.zeros((64,), jnp.int32), 8, "max")
+
+
+def test_scalar_table_pads_to_whole_tiles_only_where_it_must():
+    x = jnp.arange(pg.G_SCALAR * 3, dtype=jnp.float32)
+    assert pg.scalar_table(x, 0) is x
+    y = np.asarray(pg.scalar_table(jnp.arange(5, dtype=jnp.int32), 7))
+    assert y.shape == (pg.G_SCALAR,) and (y[5:] == 7).all()
+    assert y[:5].tolist() == list(range(5))
+
+
+@pytest.mark.parametrize("w", CLASS_WIDTHS)
+def test_traced_scalar_kernel_text_does_not_follow_the_width(w):
+    """The scalar form's text is one loop over the tiles around one loop
+    over a chunk's indices, two of them a step: the same equations at
+    every even width (every class width is even), one pallas_call, no
+    copies of its own."""
+    def equations(width):
+        jaxpr = jax.make_jaxpr(lambda v, i: pg.gather_reduce(
+            v, i, width, "sum"))(
+            jax.ShapeDtypeStruct((1 << 12,), jnp.float32),
+            jax.ShapeDtypeStruct((pg.G_SCALAR * width,), jnp.int32))
+        text = str(jaxpr)
+        assert text.count("pallas_call") == 1 and "dma_start" not in text
+        return _equations(jaxpr.jaxpr)
+
+    assert equations(w) == equations(2) <= 300

@@ -253,6 +253,59 @@ def test_every_class_width_compiles_for_v5e(w, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _case_gather_reduce(place, w, op, n_values=None):
+    from hypergraphdb_tpu.ops import pallas_gather as pg
+
+    n_values = _N_PAD if n_values is None else n_values
+    fn = jax.jit(partial(pg.gather_reduce, w=w, op=op))
+    dtype = "float32" if op == "sum" else "int32"
+    # a scan block of the pyramid's level 0 (_class_rows: chunk * 8
+    # indices, whole grid steps of the scalar form)
+    n = pg.whole_scalar_steps(_CHUNK * 8, w)
+    return fn, place((_sds((n_values,), dtype), _sds((n,), "int32"))), {}
+
+
+@pytest.mark.parametrize("op", ["sum", "min"])
+@pytest.mark.parametrize("w", CLASS_WIDTHS)
+def test_scalar_gate_agrees_with_compiler_at_every_class_width(
+        w, op, one_chip, no_compile_cache):
+    """The scalar form at each level-0 class width, a float32 sum and an
+    int32 min over the cells' 10,000,072-value state (padded to whole
+    tiles in the call): the gate admits it and the v5e compiler accepts
+    it, the table whole in VMEM beside a block of indices in SMEM; and a
+    table past ``SCALAR_TABLE_BYTES`` is declined at trace time, with
+    the reason, before the compiler is asked."""
+    from hypergraphdb_tpu.ops import pallas_gather as pg
+
+    dtype = "float32" if op == "sum" else "int32"
+    assert pg.declined_scalar(w, dtype, _N_PAD) is None
+    place = partial(_place, sharding=one_chip)
+    fn, args, kwargs = _case_gather_reduce(place, w, op)
+    compiled = fn.lower(*args, **kwargs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    most = pg.SCALAR_TABLE_BYTES // 4
+    fn, args, kwargs = _case_gather_reduce(place, w, op, most + 1)
+    with pytest.raises(ValueError, match="VMEM"):
+        fn.lower(*args, **kwargs)
+
+
+def test_scalar_gate_largest_table_compiles(one_chip, no_compile_cache):
+    """The largest table the gate admits, ``SCALAR_TABLE_BYTES`` of int32,
+    compiles at the narrowest class and at the widest chunk the gate
+    admits (two grid steps of its indices in half the SMEM)."""
+    from hypergraphdb_tpu.ops import pallas_gather as pg
+
+    widest = pg.SMEM_BUDGET // 2 // (8 * pg.G_SCALAR)
+    assert pg.declined_scalar(widest, "int32", 1) is None
+    assert pg.declined_scalar(widest + 1, "int32", 1)
+    for w in (CLASS_WIDTHS[0], widest):
+        fn, args, kwargs = _case_gather_reduce(
+            partial(_place, sharding=one_chip), w, "min",
+            pg.SCALAR_TABLE_BYTES // 4)
+        assert "tpu_custom_call" in fn.lower(*args, **kwargs) \
+            .compile().as_text()
+
+
 @pytest.mark.slow  # ~25 s to be refused; the fix is guarded above
 def test_whole_row_top_k_is_what_the_compiler_refuses(one_chip,
                                                       no_compile_cache):
@@ -532,6 +585,33 @@ def test_label_round_fits_one_chip_with_a_four_byte_label(one_chip,
     assert mem.argument_size_in_bytes < 1.01 * flat
 
 
+def test_label_round_on_the_kernel_fits_one_chip(one_chip,
+                                                 no_compile_cache):
+    """``_wcc_round`` with its level-0 gathers on the scalar form (what a
+    TPU runs: ``use_pallas`` from ``pallas_ok()``), at the cell's plan:
+    every class of both stages calls the kernel, and what the program
+    holds besides its arguments stays under 256 MiB — no (chunks, 128)
+    transient of a fetched row a chunk (4 bytes a chunk, as on the XLA
+    route), the tables padded by under a grid step's 4 KB."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
+    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
+    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
+                          _sds((blocks,), "int32"), _sds((), "int32"))
+    args = (_sds((_N_PAD,), "int32"), ints(_T1), ints(_T2), rows,
+            _sds((), "int32"))
+    compiled = eb._wcc_round.lower(
+        *_place(args, one_chip), widths1=_TW1, n1=len(_T1), widths2=_TW2,
+        n2=_TN2, chunk=_CHUNK, use_pallas=True).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_hg_wcc_round,")
+    assert text.count("hg_gather_scalar") >= len(_T1) + _TN2
+    assert mem.temp_size_in_bytes < 2**28
+    assert mem.alias_size_in_bytes >= 4 * _N_PAD
+
+
 def test_the_new_programs_carry_their_hgverify_entries():
     names = set(hgverify.REGISTRY.names())
     assert {"ops.ellbfs._wcc_init", "ops.ellbfs._wcc_round",
@@ -573,6 +653,35 @@ def test_pagerank_iteration_fits_one_chip_with_a_four_byte_rank(
     # bytes an entry
     flat = 4 * (4 * _N_PAD + sum(_L1) + sum(_L2) + chunks1 + blocks + 1)
     assert mem.argument_size_in_bytes < 1.01 * flat
+
+
+def test_pagerank_iteration_on_the_kernel_fits_one_chip(one_chip,
+                                                        no_compile_cache):
+    """``_pr_iter`` with its level-0 gathers on the scalar form, at the
+    untyped plan: every class of both stages calls the kernel, the link
+    weights meet stage 1's padded buffer, and what the program holds
+    besides its arguments stays under 256 MiB."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
+    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
+    chunks1 = sum(n // w for n, w in zip(_L1, _W1)) + 1
+    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
+                          _sds((blocks,), "int32"), _sds((), "int32"))
+    weights = eb._PRWeights(_sds((_N_PAD,), "float32"),
+                            _sds((chunks1,), "float32"),
+                            _sds((_N_PAD,), "float32"))
+    args = (_sds((_N_PAD,), "float32"), ints(_L1), ints(_L2), weights, rows,
+            _sds((), "int32"), _sds((), "float32"))
+    compiled = eb._pr_iter.lower(
+        *_place(args, one_chip), widths1=_W1, n1=len(_L1), widths2=_W2,
+        n2=_N2, chunk=_CHUNK, use_pallas=True).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_hg_pr_iter,")
+    assert text.count("hg_gather_scalar") >= len(_L1) + _N2
+    assert mem.temp_size_in_bytes < 2**28
+    assert mem.alias_size_in_bytes >= 4 * _N_PAD
 
 
 def test_the_pagerank_programs_carry_their_hgverify_entries():
