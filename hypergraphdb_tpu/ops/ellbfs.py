@@ -53,8 +53,9 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   blocks the hop's plan can reach and no other (``_active_blocks``: a row
   is reached only if its atom has an incidence set, so a store that lays
   entities out before links folds the entities' blocks alone); where the
-  stages gather on ``hg_gather_or``, so does the update's row fetch, at
-  width 1 (``_update_on_kernel``).
+  stages gather on the kernel, so does the update's row fetch, at width 1
+  (``_update_on_kernel``): ``hg_gather_or`` for a bitmap,
+  ``hg_gather_scalar`` for a whole-graph operator's flat state.
 - per-seed edge counts (the benchmark numerator) are one exact pass a
   seed block (``_deg_sum``: bit-unpack, weight by degree and sum in
   ``int32``, fused on the vector unit) — no gathers — over the row blocks
@@ -1105,16 +1106,25 @@ _LOWERED = _Gain(lambda row: jnp.int32(0),
 
 
 def _update_on_kernel(state, use_pallas: bool,
-                      block_rows: int = UPDATE_ROWS) -> bool:
-    """Does ``_fold_rows`` fetch a block's rows through ``hg_gather_or`` at
-    width 1? Where the caller's gathers take the kernel (``use_pallas``: a
-    4096-seed block on a TPU, ``_kernel_gathers``), the state is rows of
-    the kernel's width and a block is as many indices as the kernel is
-    worth (``_reduce_level``'s rule). A flat label state and a narrower
-    bitmap keep the XLA gather."""
-    return (use_pallas and state.ndim == 2 and state.dtype == jnp.uint32
-            and _pg.declined(1, state.shape[1]) is None
-            and _block_rows(state.shape[0], block_rows) >= _pg.MIN_INDICES)
+                      block_rows: int = UPDATE_ROWS,
+                      table=None) -> Optional[str]:
+    """Which form of the row-gather kernel ``_fold_rows`` fetches a block's
+    rows through at width 1, or None for the XLA gather. Where the
+    caller's gathers take the kernel (``use_pallas``: a 4096-seed block or
+    a whole-graph operator on a TPU) and a block is as many indices as the
+    kernel is worth (``_reduce_level``'s rule), ``_kernel_of`` decides over
+    the TABLE the fetch reads — the stage buffer ``table``, the state
+    where none is given — by the state's reduction: ``"or"``
+    (``hg_gather_or``) for ``(S, 128)`` uint32 rows, the reduction's
+    ``scalar_op`` (``hg_gather_scalar``) for a flat int32 or float32 state
+    whose table the scalar gate admits. A narrower bitmap, a table past
+    ``SCALAR_TABLE_BYTES`` and a short block keep the XLA gather. Only
+    shapes and dtypes are read: the host counts by it."""
+    if not (use_pallas
+            and _block_rows(state.shape[0], block_rows) >= _pg.MIN_INDICES):
+        return None
+    return _kernel_of(state if table is None else table, _reduction(state),
+                      1)
 
 
 def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
@@ -1140,21 +1150,33 @@ def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
     pass of its own; without it the carry is the state alone and the
     program is what it was.
 
-    A block's rows are fetched through ``hg_gather_or`` at width 1 (one
-    call of ``block_rows`` indices) where ``_update_on_kernel`` says so,
-    else through the XLA gather; the combine is XLA's either way."""
+    A block's rows are fetched by the row-gather kernel at width 1 (one
+    call of ``block_rows`` indices) where ``_update_on_kernel`` names a
+    form: ``hg_gather_or`` for a bitmap, ``hg_gather_scalar`` for a flat
+    state, whose stage buffer it reads as rows of 128 — whole (8, 128)
+    tiles where the stage's pyramid ran on the kernel, else padded here
+    once, outside the loop; elsewhere through the XLA gather. The combine
+    is XLA's either way."""
     n_pad, row = state.shape[0], state.shape[1:]
     identity = _reduction(state).identity
     ub = _block_rows(n_pad, block_rows)
-    kernel = _update_on_kernel(state, use_pallas, block_rows)
+    kernel = _update_on_kernel(state, use_pallas, block_rows, reach_chunks)
+    if kernel and kernel != "or":
+        reach_chunks = _pg.scalar_table(reach_chunks, identity)
+
+    def fetch(sl):
+        if kernel == "or":
+            return _pg.gather_or(reach_chunks, sl, 1)
+        if kernel:
+            return _pg.gather_reduce(reach_chunks, sl, 1, kernel)
+        return reach_chunks[sl]
 
     def fold(i, carry):
         nxt, count = carry
         start = jnp.minimum(rows.starts[i], n_pad - ub)
         cur = jax.lax.dynamic_slice(nxt, _at(start, nxt), (ub, *row))
         sl = jax.lax.dynamic_slice(rows.out_map, (start,), (ub,))
-        reached = (_pg.gather_or(reach_chunks, sl, 1) if kernel
-                   else reach_chunks[sl])
+        reached = fetch(sl)
         if gain is not None:
             count = gain.step(count, cur, reached)
         return jax.lax.dynamic_update_slice(
@@ -2082,17 +2104,17 @@ def _wcc_round(labels, levels1, levels2, rows, n_atoms, widths1, n1,
     beside the labels the rows it LOWERED, ``() int32``. The pyramids are
     ``bfs_pull``'s with the labels' reduction (``_reduction``: min,
     identity ``INT32_MAX``); their level 0 gathers its 4-byte scalars on
-    the kernel's scalar form where ``use_pallas`` (``pallas_ok()``), else
-    through the XLA gather. The stage buffers are a few tens of MB at 10M
-    atoms, so one program holds the round where a 4096-seed hop needs
-    four."""
+    the kernel's scalar form where ``use_pallas`` (``pallas_ok()``), and
+    the fold fetches its rows on it at width 1, else both through the XLA
+    gather. The stage buffers are a few tens of MB at 10M atoms, so one
+    program holds the round where a 4096-seed hop needs four."""
     live = _apply_plan(labels, levels1, widths1, n1, chunk, use_pallas,
                        scopes=("hg.wcc.stage1", "hg.wcc.stage1"))
     reach = _apply_plan(live, levels2, widths2, n2, chunk, use_pallas,
                         scopes=("hg.wcc.stage2", "hg.wcc.stage2"))
     with jax.named_scope("hg.wcc.fold"):
         return _fold_rows(labels, reach, rows, n_atoms, jnp.minimum,
-                          gain=_LOWERED)
+                          gain=_LOWERED, use_pallas=use_pallas)
 
 
 @hgverify.entry(shapes=lambda: (hgverify.sds((64,), "int32"),))
@@ -2170,6 +2192,7 @@ def connected_components(snap: CSRSnapshot, link_types=None, *,
             folded = int(dev["blocks"].sum()) * _block_rows(n_pad)
             kernel = _pg.pallas_ok()
             gathered = _scalar_indices(plans, jnp.int32, chunk, kernel)
+            fetched = _scalar_fold_rows(plans, jnp.int32, folded, kernel)
             lowered = 1
             while lowered:
                 with phase("hg.wcc.round") as ph:
@@ -2185,7 +2208,7 @@ def connected_components(snap: CSRSnapshot, link_types=None, *,
                 reg.counter("wcc.rounds").inc()
                 reg.counter("wcc.rows_lowered").inc(lowered)
                 reg.counter("wcc.rows_folded").inc(folded)
-                _count_scalar_gathers(reg, gathered)
+                _count_scalar_gathers(reg, gathered, fetched)
         with op.step("count"):
             n_components = int(_wcc_count(labels))
     return ComponentsResult(labels, n_components, rounds)
@@ -2248,8 +2271,9 @@ def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
     ``dangling`` the ranks of the atoms with ``inv_d == 0``. The pyramids
     are :func:`bfs_pull`'s with the sum (``_reduction``: identity 0.0);
     their level 0 gathers on the kernel's scalar form where
-    ``use_pallas``, as :func:`_wcc_round`'s does, whose buffers come
-    padded to whole rows of the kernel's table (``w``'s pad is 0)."""
+    ``use_pallas``, and so does the fold's fetch at width 1, as in
+    :func:`_wcc_round`, whose buffers come padded to whole rows of the
+    kernel's table (``w``'s pad is 0)."""
     with jax.named_scope("hg.pr.stage1"):
         x = ranks * pw.inv_d
     s = _apply_plan(x, levels1, widths1, n1, chunk, use_pallas,
@@ -2261,7 +2285,7 @@ def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
                     scopes=("hg.pr.stage2", "hg.pr.stage2"))
     with jax.named_scope("hg.pr.update"):
         y = _fold_rows(jnp.zeros_like(ranks), y, rows, n_atoms,
-                       lambda cur, reached: reached)
+                       lambda cur, reached: reached, use_pallas=use_pallas)
         n = n_atoms.astype(jnp.float32)
         real = jnp.arange(ranks.shape[0], dtype=jnp.int32) < n_atoms
         new = jnp.where(real, (1.0 - damping) / n + damping * (y - pw.c * x)
@@ -2269,11 +2293,31 @@ def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
         return new, jnp.sum(new)
 
 
-def _count_scalar_gathers(reg, gathered: tuple[int, int]) -> None:
+def _scalar_fold_rows(plans: "PullBFSPlans", dtype, folded: int,
+                      use_pallas: bool) -> tuple[int, int]:
+    """``(rows a whole-graph dispatch's fold visits, those whose fetch
+    takes the kernel)``: ``folded`` (the plan's listed rows), and all or
+    none of them by ``_update_on_kernel``'s rule over a flat ``dtype``
+    state and the stage-2 buffer the fold reads, from their shapes on the
+    host (the buffer's pad to whole tiles moves no gate)."""
+    buf = sum(len(l) // w for l, w in zip(plans.stage2_levels,
+                                          plans.stage2_widths)) + 1
+    kernel = _update_on_kernel(
+        jax.ShapeDtypeStruct((plans.n_pad,), dtype), use_pallas,
+        table=jax.ShapeDtypeStruct((buf,), dtype))
+    return folded, folded if kernel else 0
+
+
+def _count_scalar_gathers(reg, gathered: tuple[int, int],
+                          fetched: tuple[int, int]) -> None:
     """A whole-graph dispatch's level-0 indices and those the kernel
-    gathered (``_scalar_indices``): reader ``scalar_gather_kernel_share``."""
+    gathered (``_scalar_indices``), its fold's rows and those the kernel
+    fetched (``_scalar_fold_rows``): readers ``scalar_gather_kernel_share``
+    and ``scalar_fold_kernel_share``."""
     reg.counter("scalar.gather.indices").inc(gathered[0])
     reg.counter("scalar.gather.indices_kernel").inc(gathered[1])
+    reg.counter("scalar.fold.rows").inc(fetched[0])
+    reg.counter("scalar.fold.rows_kernel").inc(fetched[1])
 
 
 def _dangling(ranks: jax.Array, inv_d: jax.Array) -> jax.Array:
@@ -2362,6 +2406,7 @@ def pagerank(snap: CSRSnapshot, link_types=None, *, damping: float = 0.85,
             folded = int(dev["blocks"].sum()) * _block_rows(n_pad)
             kernel = _pg.pallas_ok()
             gathered = _scalar_indices(plans, jnp.float32, chunk, kernel)
+            fetched = _scalar_fold_rows(plans, jnp.float32, folded, kernel)
             d = jnp.float32(damping)
             for _ in range(iterations):
                 with phase("hg.pr.iter") as ph:
@@ -2373,7 +2418,7 @@ def pagerank(snap: CSRSnapshot, link_types=None, *, damping: float = 0.85,
                             **({"use_pallas": True} if kernel else {}))
                 reg.counter("pr.iterations").inc()
                 reg.counter("pr.rows_folded").inc(folded)
-                _count_scalar_gathers(reg, gathered)
+                _count_scalar_gathers(reg, gathered, fetched)
         with op.step("mass"):
             mass = float(mass)
     return PageRankResult(ranks, mass, iterations)

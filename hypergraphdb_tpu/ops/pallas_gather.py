@@ -129,6 +129,17 @@ i32 min, XLA    8.52   13.85  13.85  13.68  7.68   7.62   7.56   7.52   7.51   7
 row DMA         9.24   8.46   8.31   8.47   8.40   8.44   8.33   8.37   8.25   8.20
 ==============  =====  =====  =====  =====  =====  =====  =====  =====  =====  =====
 
+w = 1 has one caller, the fetch of a whole-graph operator's fold
+(``ellbfs._fold_rows`` over a flat state: a gather, the lane kept and no
+chunk to reduce), one call of 2^16 indices a listed block: over the cells'
+2,031,616 listed rows through the plan's ``out_map``, a 2.1M-value stage
+buffer, i32 / f32 the fetch alone 3.21 / 3.68 ns an index and the whole
+fold 3.53 / 3.37 ns a row, where the XLA gather read 17.5 / 17.6 and its
+fold 20.8 / 21.2 beside it (in the cells' programs ≈ 7 a row); with a
+grid step of its own at w = 1, 2,048-16,384 chunks, the fold read 0.04-0.16
+ns a row slower, so the form keeps one step at every width
+(``benchmarks/tests/scalar_fold_probe.py``, PERF.md section 6).
+
 ``row DMA`` (f32 sum) is the form first built, ``hg_gather_or``'s
 pipeline with a 128-lane row COPIED from an HBM table an index and the
 lane picked after the tile's wait: 8.2-9.3 ns at every width, no faster

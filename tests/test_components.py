@@ -9,6 +9,7 @@ untouched."""
 
 from collections import deque
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -22,6 +23,7 @@ from hypergraphdb_tpu.ops.snapshot import CSRSnapshot
 from tests.test_ellbfs import (  # noqa: F401  (typed_graph: a fixture)
     FAMILIES,
     _SnapshotGraph,
+    scalar_kernel_route,
     typed_graph,
 )
 from tests.test_pair_distances import linked_snapshot
@@ -223,6 +225,50 @@ def test_two_calls_both_run_every_round():
     final = np.asarray(a.labels)[: snap.num_atoms]
     assert 0 < one[2] and (final <= np.arange(snap.num_atoms)).all()
     np.testing.assert_array_equal(np.asarray(b.labels), np.asarray(a.labels))
+
+
+#: the fold's two counters, once a dispatch (``_scalar_fold_rows``)
+FOLD_COUNTERS = ("scalar.fold.rows", "scalar.fold.rows_kernel")
+
+
+def _fold_counted():
+    got = [obs.default_registry().get(n) for n in FOLD_COUNTERS]
+    return np.asarray([0 if c is None else int(c.value) for c in got])
+
+
+@pytest.fixture
+def retraced(monkeypatch):
+    """``_wcc_round`` traced anew under a patched helper (JAX keeps a trace
+    by the function it traced): its caches cleared before the patch and
+    again after it, when no trace of the patched program may be left."""
+    jax.clear_caches()
+    yield monkeypatch
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel", "short_blocks"])
+def test_the_fold_counters_add_up_the_listed_rows(route, retraced):
+    """``scalar.fold.rows`` grows by the plan's listed rows a round — what
+    ``wcc.rows_folded`` counts — and ``.rows_kernel`` by as many where the
+    fold's fetch takes the kernel (the interpreter standing in), by none
+    on the XLA route and where a block is under ``MIN_INDICES``; the
+    labels are the reference's on every route."""
+    snap = linked_snapshot(700, 800, 11, n_types=4)
+    plans = eb.plans_for(eb.restricted_for(snap, (1, 3)))
+    listed = (int(eb._active_blocks(plans).sum())
+              * eb._block_rows(plans.n_pad))
+    calls = []
+    if route != "xla":
+        calls = scalar_kernel_route(
+            retraced, 64 if route == "kernel" else plans.n_pad + 1)
+    before, folded = _fold_counted(), _counted()[3]
+    res = connected_components(snap, (1, 3))
+    rows, kernel = _fold_counted() - before
+    assert rows == res.rounds * listed == _counted()[3] - folded > 0
+    assert kernel == (rows if route == "kernel" else 0)
+    assert ((1, "min") in calls) == (route == "kernel")
+    _assert_is_the_reference(snap, (1, 3), res)
 
 
 # ------------------------------------------- the pyramid and the fold

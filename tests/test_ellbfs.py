@@ -474,14 +474,18 @@ def test_update_folds_the_listed_blocks_and_no_other(update, blocks):
     assert np.array_equal(got, want)
 
 
-#: ``_fold_rows``' cases by route: (state words a row — 0 a flat label
-#: state —, combine, gain, does the fetch take the kernel)
+#: ``_fold_rows``' cases by route: (state words a row — 0 a flat state —,
+#: the state's dtype, combine, gain, the kernel form that fetches — None
+#: the XLA gather)
 FOLD_ROUTES = {
-    "or": (128, lambda cur, reached: cur | reached, None, True),
-    "replace": (128, lambda cur, reached: reached, None, True),
-    "or_grew": (128, lambda cur, reached: cur | reached, eb._GREW, True),
-    "labels": (0, jnp.minimum, eb._LOWERED, False),
-    "narrow_rows": (32, lambda cur, reached: cur | reached, None, False),
+    "or": (128, np.uint32, lambda cur, reached: cur | reached, None, "or"),
+    "replace": (128, np.uint32, lambda cur, reached: reached, None, "or"),
+    "or_grew": (128, np.uint32, lambda cur, reached: cur | reached,
+                eb._GREW, "or"),
+    "labels": (0, np.int32, jnp.minimum, eb._LOWERED, "min"),
+    "ranks": (0, np.float32, lambda cur, reached: reached, None, "sum"),
+    "narrow_rows": (32, np.uint32, lambda cur, reached: cur | reached, None,
+                    None),
 }
 
 
@@ -532,6 +536,36 @@ def test_wcc_round_on_the_kernel_gives_the_xla_routes_labels(chunk,
     assert 0 < kernel <= total
 
 
+def _kernel_fetches(monkeypatch) -> list:
+    """Both forms of the row-gather kernel in the Pallas interpreter, each
+    call's (form, width) recorded."""
+    calls = []
+    gather_or, gather_reduce = eb._pg.gather_or, eb._pg.gather_reduce
+    monkeypatch.setattr(
+        eb._pg, "gather_or", lambda v, i, w: calls.append(("or", w))
+        or gather_or(v, i, w, interpret=True))
+    monkeypatch.setattr(
+        eb._pg, "gather_reduce", lambda v, i, w, op: calls.append((op, w))
+        or gather_reduce(v, i, w, op, interpret=True))
+    return calls
+
+
+def _fold_case(case, r, n_pad, n_chunks):
+    """A state of ``n_pad`` rows and a stage buffer of ``n_chunks`` rows and
+    its zero row (the reduction's identity) for ``FOLD_ROUTES[case]``."""
+    kw, dtype = FOLD_ROUTES[case][:2]
+    if kw:
+        reach = r.integers(0, 1 << 32, size=(n_chunks + 1, kw), dtype=dtype)
+        return r.integers(0, 1 << 32, size=(n_pad, kw), dtype=dtype), reach
+    if dtype == np.int32:
+        reach = r.integers(0, n_pad, size=n_chunks + 1).astype(dtype)
+        reach[n_chunks] = eb.INT32_MAX
+        return np.arange(n_pad, dtype=dtype), reach
+    reach = r.integers(1, 1 << 20, size=n_chunks + 1).astype(dtype)
+    reach[n_chunks] = 0.0
+    return np.zeros(n_pad, dtype=dtype), reach
+
+
 @pytest.mark.parametrize("case", list(FOLD_ROUTES))
 def test_fold_rows_fetches_on_the_kernel_where_it_serves_the_state(
         case, monkeypatch):
@@ -539,28 +573,18 @@ def test_fold_rows_fetches_on_the_kernel_where_it_serves_the_state(
     a ragged fifth, the list holding the ragged last block, a block twice
     and the dummy row's block, against the same fold on the XLA gather:
     bit-equal state (the dummy row the identity) and count. A 128-word
-    bitmap fetches through ``hg_gather_or`` at width 1 (the Pallas
-    interpreter here); a flat label state and a 32-word bitmap trace no
-    ``pallas_call`` — the XLA route alone."""
-    kw, combine, gain, kernel = FOLD_ROUTES[case]
-    gather, calls = eb._pg.gather_or, []
-    monkeypatch.setattr(
-        eb._pg, "gather_or",
-        lambda v, i, w: calls.append(w) or gather(v, i, w, interpret=True))
+    bitmap fetches through ``hg_gather_or`` at width 1, a flat int32 or
+    float32 state through ``hg_gather_scalar`` (the Pallas interpreter
+    here); a 32-word bitmap traces no ``pallas_call`` — the XLA route
+    alone."""
+    kw, _, combine, gain, form = FOLD_ROUTES[case]
+    calls = _kernel_fetches(monkeypatch)
     ub = 256
     monkeypatch.setattr(eb._pg, "MIN_INDICES", ub)
     r = np.random.default_rng(sorted(FOLD_ROUTES).index(case))
     n_pad, n_chunks = 4 * ub + 40, 300
     n_atoms = n_pad - 3
-    if kw:
-        reach = r.integers(0, 1 << 32, size=(n_chunks + 1, kw),
-                           dtype=np.uint32)
-        reach[n_chunks] = 0
-        state = r.integers(0, 1 << 32, size=(n_pad, kw), dtype=np.uint32)
-    else:
-        reach = r.integers(0, n_pad, size=n_chunks + 1).astype(np.int32)
-        reach[n_chunks] = eb.INT32_MAX
-        state = np.arange(n_pad, dtype=np.int32)
+    state, reach = _fold_case(case, r, n_pad, n_chunks)
     out_map = r.integers(0, n_chunks + 1, size=n_pad).astype(np.int32)
     listed = [4 * ub, 0, 2 * ub, 2 * ub, 3 * ub]  # ragged last, 2 twice
     rows = eb._UpdateRows(jnp.asarray(out_map),
@@ -571,17 +595,106 @@ def test_fold_rows_fetches_on_the_kernel_where_it_serves_the_state(
                                gain=gain, use_pallas=k))
             for k in (False, True)}
     assert ("pallas_call" in str(jax.make_jaxpr(fold[True])(*args))) \
-        == kernel
+        == (form is not None)
     assert "pallas_call" not in str(jax.make_jaxpr(fold[False])(*args))
-    assert bool(calls) == kernel and set(calls) <= {1}
+    assert set(calls) == ({(form, 1)} if form else set())
     want, got = (jax.tree_util.tree_leaves(fold[k](*args))
                  for k in (False, True))
     for w, g in zip(want, got):
         assert np.array_equal(np.asarray(w), np.asarray(g))
     assert np.asarray(got[0])[n_atoms].tolist() == (
-        [0] * kw if kw else eb.INT32_MAX)
+        [0] * kw if kw else reach[n_chunks])
     # the listed rows were folded: the fold did something to compare
     assert not np.array_equal(np.asarray(got[0]), state)
+
+
+#: block lists of the scalar fold over five blocks of 256 rows, the last
+#: ragged: which blocks a round lists (each folded from its clamped start)
+SCALAR_FOLD_LISTS = {
+    "skips": [1, 3],
+    "ragged_twice": [3, 4],
+    "all_dummy_last": [0, 1, 2, 3, 4],
+}
+
+
+@pytest.mark.parametrize("blocks", list(SCALAR_FOLD_LISTS))
+@pytest.mark.parametrize("case", ["labels", "ranks"])
+def test_scalar_fold_on_the_kernel_is_numpy(case, blocks, monkeypatch):
+    """The fold's scalar route (``hg_gather_scalar`` at width 1 in the
+    Pallas interpreter) against numpy: the min with ``_LOWERED``'s count
+    and the replacement into zeros, over a list that skips blocks, the
+    ragged last block beside the one it overlaps (those rows folded twice,
+    lowered once) and every block; the dummy row set to the identity, the
+    unlisted rows left as they were. The stage buffer is not whole (8,
+    128) tiles: the fold pads it once, outside its loop."""
+    _, _, combine, gain, form = FOLD_ROUTES[case]
+    calls = _kernel_fetches(monkeypatch)
+    ub = 256
+    monkeypatch.setattr(eb._pg, "MIN_INDICES", ub)
+    r = np.random.default_rng([len(blocks), case == "ranks"])
+    n_pad, n_chunks = 4 * ub + 40, 300
+    n_atoms = n_pad - 3
+    state, reach = _fold_case(case, r, n_pad, n_chunks)
+    out_map = r.integers(0, n_chunks, size=n_pad).astype(np.int32)
+    out_map[n_atoms] = n_chunks
+    listed = SCALAR_FOLD_LISTS[blocks]
+    rows = eb._listed(jnp.asarray(out_map), np.isin(np.arange(5), listed),
+                      block_rows=ub)
+    fn = jax.jit(partial(eb._fold_rows, combine=combine, block_rows=ub,
+                         gain=gain, use_pallas=True))
+    out = fn(jnp.asarray(state), jnp.asarray(reach), rows,
+             jnp.int32(n_atoms))
+    got = np.asarray(out[0] if gain else out)
+    assert calls == [(form, 1)]
+    folded = np.zeros(n_pad, dtype=bool)
+    for b in listed:
+        start = min(b * ub, n_pad - ub)
+        folded[start:start + ub] = True
+    fetched = reach[out_map]
+    want = np.where(folded, np.minimum(state, fetched) if gain else fetched,
+                    state)
+    want[n_atoms] = reach[n_chunks]
+    np.testing.assert_array_equal(got, want)
+    if gain:
+        assert int(out[1]) == int(np.count_nonzero(folded
+                                                   & (fetched < state)))
+
+
+_UPDATE_TABLES = {
+    # state, table, use_pallas, block rows: the form, or None (XLA)
+    "bitmap": ((70_000, 128), "uint32", (9_000, 128), True, 1 << 16, "or"),
+    "narrow_bitmap": ((70_000, 32), "uint32", (9_000, 32), True, 1 << 16,
+                      None),
+    "labels": ((70_000,), "int32", (9_000,), True, 1 << 16, "min"),
+    "ranks": ((70_000,), "float32", (9_000,), True, 1 << 16, "sum"),
+    "labels_whole_graph": ((10_000_072,), "int32", (2_108_462,), True,
+                           1 << 16, "min"),
+    "table_past_the_bound": ((70_000,), "int32",
+                             (eb._pg.SCALAR_TABLE_BYTES // 4 + 1,), True,
+                             1 << 16, None),
+    "short_block": ((70_000,), "float32", (9_000,), True,
+                    eb._pg.MIN_INDICES // 2, None),
+    "xla_caller": ((70_000,), "int32", (9_000,), False, 1 << 16, None),
+    "bitmap_xla_caller": ((70_000, 128), "uint32", (9_000, 128), False,
+                          1 << 16, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_UPDATE_TABLES))
+def test_update_on_kernel_routes_by_state_and_table(case):
+    """``_update_on_kernel``, the fold's one gate, as a table: 128-word
+    uint32 rows take ``hg_gather_or`` as before; a flat int32 or float32
+    state whose stage buffer the scalar gate admits takes its reduction's
+    scalar form; a narrower bitmap, a buffer past ``SCALAR_TABLE_BYTES``
+    (the state itself small), a block under ``MIN_INDICES`` or a caller
+    whose gathers take XLA keep the XLA gather. It reads shapes alone."""
+    shape, dtype, table, use_pallas, block_rows, form = _UPDATE_TABLES[case]
+    state = jax.ShapeDtypeStruct(shape, dtype)
+    buf = jax.ShapeDtypeStruct(table, dtype)
+    assert eb._update_on_kernel(state, use_pallas, block_rows,
+                                table=buf) == form
+    if len(shape) == 2:  # a bitmap's rows are the table's: the same answer
+        assert eb._update_on_kernel(state, use_pallas, block_rows) == form
 
 
 # ------------------------------------------------------ the sparse first hop

@@ -325,6 +325,36 @@ def test_pr_iter_on_the_kernel_gives_the_xla_routes_ranks(typed, monkeypatch):
     assert share > 0
 
 
+@pytest.mark.parametrize("route", ["xla", "kernel", "short_blocks"])
+def test_the_fold_counters_add_up_the_listed_rows(route, retraced):
+    """``scalar.fold.rows`` grows by the plan's listed rows an iteration —
+    what ``pr.rows_folded`` counts — and ``.rows_kernel`` by as many where
+    the replacing fold's fetch takes the kernel (the interpreter standing
+    in), by none on the XLA route and where a block is under
+    ``MIN_INDICES``; the ranks are the reference's on every route."""
+    names = ("scalar.fold.rows", "scalar.fold.rows_kernel")
+
+    def counted():
+        got = [obs.default_registry().get(n) for n in names]
+        return np.asarray([0 if c is None else int(c.value) for c in got])
+
+    snap = linked_snapshot(700, 800, 12, n_types=4)
+    plans = eb.plans_for(snap)
+    listed = (int(eb._active_blocks(plans).sum())
+              * eb._block_rows(plans.n_pad))
+    calls = []
+    if route != "xla":
+        calls = scalar_kernel_route(
+            retraced, 64 if route == "kernel" else plans.n_pad + 1)
+    before, folded = counted(), _counted()[2]
+    res = pagerank(snap, iterations=3)
+    rows, kernel = counted() - before
+    assert rows == 3 * listed == _counted()[2] - folded > 0
+    assert kernel == (rows if route == "kernel" else 0)
+    assert ((1, "sum") in calls) == (route == "kernel")
+    _assert_is_the_reference(snap, None, res, iterations=3)
+
+
 @pytest.mark.parametrize("blocks", ["all", "some", "ragged_last"])
 def test_the_replacing_fold_is_numpy_and_an_add_is_not(blocks):
     """``_fold_rows`` replacing a zero state over listed blocks of 16 rows of

@@ -19,6 +19,8 @@ around them (a compile for a described chip cannot be read back).
 
 from __future__ import annotations
 
+import hashlib
+import re
 from functools import partial
 
 import jax
@@ -585,14 +587,24 @@ def test_label_round_fits_one_chip_with_a_four_byte_label(one_chip,
     assert mem.argument_size_in_bytes < 1.01 * flat
 
 
+def _kernel_calls_under(text: str, scope: str) -> int:
+    """The scalar form's calls in a compiled program's text whose op_name
+    lies under the named scope ``scope``."""
+    return sum(1 for line in text.splitlines()
+               if "custom-call" in line and "hg_gather_scalar" in line
+               and f"/{scope}/" in line)
+
+
 def test_label_round_on_the_kernel_fits_one_chip(one_chip,
                                                  no_compile_cache):
     """``_wcc_round`` with its level-0 gathers on the scalar form (what a
     TPU runs: ``use_pallas`` from ``pallas_ok()``), at the cell's plan:
-    every class of both stages calls the kernel, and what the program
-    holds besides its arguments stays under 256 MiB — no (chunks, 128)
-    transient of a fetched row a chunk (4 bytes a chunk, as on the XLA
-    route), the tables padded by under a grid step's 4 KB."""
+    every class of both stages calls the kernel, and so does the fold's
+    fetch (width 1, a block of ``UPDATE_ROWS`` a call inside its loop,
+    under scope ``hg.wcc.fold``); what the program holds besides its
+    arguments stays under 256 MiB — no (chunks, 128) transient of a
+    fetched row a chunk (4 bytes a chunk, as on the XLA route), the
+    tables padded by under a grid step's 4 KB."""
     from hypergraphdb_tpu.ops import ellbfs as eb
 
     ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
@@ -607,7 +619,8 @@ def test_label_round_on_the_kernel_fits_one_chip(one_chip,
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_hg_wcc_round,")
-    assert text.count("hg_gather_scalar") >= len(_T1) + _TN2
+    assert text.count("hg_gather_scalar") >= len(_T1) + _TN2 + 1
+    assert _kernel_calls_under(text, "hg.wcc.fold") >= 1
     assert mem.temp_size_in_bytes < 2**28
     assert mem.alias_size_in_bytes >= 4 * _N_PAD
 
@@ -658,8 +671,9 @@ def test_pagerank_iteration_fits_one_chip_with_a_four_byte_rank(
 def test_pagerank_iteration_on_the_kernel_fits_one_chip(one_chip,
                                                         no_compile_cache):
     """``_pr_iter`` with its level-0 gathers on the scalar form, at the
-    untyped plan: every class of both stages calls the kernel, the link
-    weights meet stage 1's padded buffer, and what the program holds
+    untyped plan: every class of both stages calls the kernel, and so
+    does the replacing fold's fetch (under scope ``hg.pr.update``), the
+    link weights meet stage 1's padded buffer, and what the program holds
     besides its arguments stays under 256 MiB."""
     from hypergraphdb_tpu.ops import ellbfs as eb
 
@@ -679,7 +693,8 @@ def test_pagerank_iteration_on_the_kernel_fits_one_chip(one_chip,
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_hg_pr_iter,")
-    assert text.count("hg_gather_scalar") >= len(_L1) + _N2
+    assert text.count("hg_gather_scalar") >= len(_L1) + _N2 + 1
+    assert _kernel_calls_under(text, "hg.pr.update") >= 1
     assert mem.temp_size_in_bytes < 2**28
     assert mem.alias_size_in_bytes >= 4 * _N_PAD
 
@@ -687,3 +702,43 @@ def test_pagerank_iteration_on_the_kernel_fits_one_chip(one_chip,
 def test_the_pagerank_programs_carry_their_hgverify_entries():
     names = set(hgverify.REGISTRY.names())
     assert {"ops.ellbfs._pr_init", "ops.ellbfs._pr_iter"} <= names
+
+
+# ------------- the bitmap programs' text, which the whole-graph fold shares
+
+
+#: sha256 (its first 32 digits) of each bitmap program's
+#: ``lower(...).as_text()`` at ``_staged_step``'s exemplars for a described
+#: v5e, by (program, fetch on the kernel): the text these programs had
+#: before the whole-graph folds took the scalar form — what the compile
+#: cache keys on. A kernel route's text is hashed with the Mosaic body
+#: stripped (it names the checkout's files and lines). Regenerate from
+#: ``_text_hash`` only for a change meant to move them, or a new JAX.
+_BITMAP_TEXT = {
+    ("_stage", True): "5368e3a966290112d05ca0660fc3268b",
+    ("_stage_lvl0_consume", True): "5efb3d21e8a00d405a6841fb29faa52a",
+    ("_stage_upper", False): "0e491a9e7a66c0ec56b0657cf0a97cc6",
+    ("_visited_update", False): "b751848da9544bb0d5eb07368376c65c",
+    ("_visited_update", True): "d3bb4f5619ca1f36951cb85ea452c148",
+    ("_frontier_replace", False): "7c40cc4311917d9ad8f6a9a3a6347f4b",
+    ("_frontier_replace", True): "684e595274bc80ecc8323715fb313178",
+    ("_ball_update", False): "639dfb0a39487baecd70fd6312cbb82b",
+    ("_ball_update", True): "0f68b95bba0584178f3d139a3a3bdd16",
+}
+
+
+def _text_hash(step: str, kernel: bool, sharding) -> str:
+    fn, args, statics, _ = _staged_step(step, kernel)
+    text = fn.lower(*_place(args, sharding), **statics).as_text()
+    return hashlib.sha256(re.sub(r'backend_config = "[^"]*"',
+                                 'backend_config = ""', text)
+                          .encode()).hexdigest()[:32]
+
+
+@pytest.mark.parametrize("step,kernel", list(_BITMAP_TEXT))
+def test_the_bitmap_programs_lower_to_the_text_they_had(step, kernel,
+                                                        one_chip):
+    """``_fold_rows`` routes a flat state's fetch to the scalar form; the
+    six bitmap programs — the three stages and the three updates, each
+    update by both routes of its fetch — lower to the text they had."""
+    assert _text_hash(step, kernel, one_chip) == _BITMAP_TEXT[(step, kernel)]
