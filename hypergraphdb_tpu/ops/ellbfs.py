@@ -53,9 +53,12 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   blocks the hop's plan can reach and no other (``_active_blocks``: a row
   is reached only if its atom has an incidence set, so a store that lays
   entities out before links folds the entities' blocks alone); where the
-  stages gather on the kernel, so does the update's row fetch, at width 1
-  (``_update_on_kernel``): ``hg_gather_or`` for a bitmap,
-  ``hg_gather_scalar`` for a whole-graph operator's flat state.
+  stages gather on the kernel, so does the update's row fetch, at width 1:
+  ``hg_gather_or`` for a bitmap, ``hg_gather_scalar`` for a whole-graph
+  operator's flat state.
+- which gather serves each call, a form of the row-gather kernel or the
+  XLA gather, is decided ONCE on the host (:func:`_route`): the programs
+  take that route as a static, and the counters count from it.
 - per-seed edge counts (the benchmark numerator) are one exact pass a
   seed block (``_deg_sum``: bit-unpack, weight by degree and sum in
   ``int32``, fused on the vector unit) — no gathers — over the row blocks
@@ -88,12 +91,12 @@ expensive primitive is **one row gather per edge**, not K probes per edge:
   index holding its identity); and :func:`pagerank`, a fixed count of
   iterations that SUM float32 ranks through the same two pyramids (the
   first reduction that is not idempotent: every entry covered once, the
-  fold replacing). On a TPU their level 0 gathers on the row-gather
-  kernel's scalar form (``pallas_gather.gather_reduce``: the state whole
-  in VMEM as rows of 128, a row loaded an index and its one lane kept),
-  chosen, as the OR's kernel is, from what the code observes
-  (``_kernel_of``: the state's shape and dtype, the gate); the upper
-  levels and the fold's fetch keep the XLA gather.
+  fold replacing). On a TPU their level 0 and the fold's fetch gather on
+  the row-gather kernel's scalar form (``pallas_gather.gather_reduce``:
+  the state whole in VMEM as rows of 128, a row loaded an index and its
+  one lane kept), routed, as the OR's kernel is, from what the code
+  observes (:func:`_route`: the state's shape and dtype, the gate); the
+  upper levels keep the XLA gather.
 
 Geometry note: each gather row is ``Kw = K/32`` uint32 words (32 lanes for
 K=1024). Gathers remain the dominant cost and are bound by the indices
@@ -105,7 +108,7 @@ K=1024.
 from __future__ import annotations
 
 import os
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from functools import partial
 from typing import Callable, NamedTuple, Optional, Sequence
 
@@ -142,14 +145,14 @@ class _Reduction(NamedTuple):
 
     combine: Callable
     identity: int | float
-    #: the scalar form of the row-gather kernel's name for it
-    #: (``pallas_gather.SCALAR_OPS``), where a flat state takes the kernel
-    scalar_op: Optional[str] = None
+    #: the row-gather kernel's form for it: ``"or"`` (``hg_gather_or``),
+    #: or the scalar form's name (``pallas_gather.SCALAR_OPS``)
+    form: str
 
 
 #: OR over ``(S, Kw)`` uint32 rows of seed bits: a traversal, a match, a
 #: pair search.
-_OR_WORDS = _Reduction(jnp.bitwise_or, 0)
+_OR_WORDS = _Reduction(jnp.bitwise_or, 0, "or")
 #: min over a flat ``(S,)`` int32 vector of labels:
 #: :func:`connected_components`.
 _MIN_LABELS = _Reduction(jnp.minimum, INT32_MAX, "min")
@@ -350,23 +353,144 @@ def _segmented_ranges(starts: np.ndarray, reps: np.ndarray) -> np.ndarray:
     return np.cumsum(delta)
 
 
+# ------------------------------------------------------------------ the route
+
+
+class _Class(NamedTuple):
+    """One width class of a stage's level 0 as its program runs it: ``n``
+    indices in chunks of ``w``, ``rows`` output rows a scan step, ``form``
+    the kernel's form the gate admits (``"or"``, ``"min"``, ``"sum"``;
+    None: the XLA gather), and the forms of a full scan block and of the
+    ragged tail: ``form`` where the call is ``MIN_INDICES`` or more."""
+
+    w: int
+    n: int
+    rows: int
+    form: Optional[str]
+    block: Optional[str]
+    tail: Optional[str]
+
+    @property
+    def on_kernel(self) -> int:
+        """The indices the kernel gathers, the full blocks' and the tail's."""
+        tail = self.n % (self.rows * self.w)
+        return (self.n - tail) * bool(self.block) + tail * bool(self.tail)
+
+
+class _Stage(NamedTuple):
+    """How one stage's pyramid gathers: level 0's ``classes``, the upper
+    levels' widths (XLA, in scan blocks of ``chunk`` rows), and whether the
+    table and the buffer are padded to whole rows of the scalar form."""
+
+    classes: tuple[_Class, ...]
+    upper: tuple[int, ...]
+    chunk: int
+    padded: bool
+
+
+class _Route(NamedTuple):
+    """How one program over a plan gathers, decided once on the host
+    (:func:`_route`): the two stages' pyramids and the fold's row fetch at
+    width 1 (``fetch``; None: XLA). Hashable: the programs take it, or a
+    stage's part, as their one static; the counters count from it."""
+
+    stage1: _Stage
+    stage2: _Stage
+    fetch: Optional[str]
+
+    @property
+    def indices(self) -> tuple[int, int]:
+        """(level-0 indices, those the kernel gathers), both stages'."""
+        classes = self.stage1.classes + self.stage2.classes
+        return sum(c.n for c in classes), sum(c.on_kernel for c in classes)
+
+    @property
+    def tile_share(self) -> float:
+        """Percent of the level-0 indices in ``hg_gather_or``'s classes."""
+        classes = self.stage1.classes + self.stage2.classes
+        return 100.0 * sum(c.n for c in classes if c.form == "or") / max(
+            1, sum(c.n for c in classes))
+
+    def listed(self, rows: "_UpdateRows") -> "_UpdateRows":
+        """``rows`` handed to the fold with its fetch's form."""
+        return replace(rows, fetch=self.fetch)
+
+
+def _form(red: _Reduction, table, w: int) -> Optional[str]:
+    """The kernel's form for chunks of ``w`` rows of ``table`` (a shape
+    and a dtype) where its gate admits them, else None."""
+    why = (_pg.declined(w, table.shape[1]) if red is _OR_WORDS
+           else _pg.declined_scalar(w, table.dtype, table.shape[0]))
+    return None if why else red.form
+
+
+def _stage_route(lengths: Sequence[int], widths: Sequence[int], n_lvl0: int,
+                 table, chunk: int, kernel: bool) -> _Stage:
+    """The route of one stage's levels (index counts and widths, the first
+    ``n_lvl0`` level 0's classes) over ``table``, the state or the stage
+    before's buffer. Where the backend has the kernel, a class takes the
+    form its gate admits; a scan step moves ``chunk * STEP_WIDTH`` indices
+    whatever the width, so that the XLA gather's transient does not follow
+    the class — on the kernel its whole segments, a pad chunk costing what
+    a real one does — and a call under ``MIN_INDICES`` the XLA gather."""
+    red = _reduction(table)
+    classes = []
+    for n, w in zip(lengths[:n_lvl0], widths[:n_lvl0]):
+        form = _form(red, table, w) if kernel else None
+        step = chunk * STEP_WIDTH
+        if form == "or":
+            step = _pg.whole_segments(step, w)
+        elif form:
+            step = _pg.whole_scalar_steps(step, w)
+        rows = max(1, step // w)
+        tail = n % (rows * w)
+        classes.append(_Class(
+            w, n, rows, form,
+            form if rows * w >= _pg.MIN_INDICES else None,
+            form if tail >= _pg.MIN_INDICES else None))
+    padded = red is not _OR_WORDS and any(c.form for c in classes)
+    return _Stage(tuple(classes), tuple(widths[n_lvl0:]), chunk, padded)
+
+
+def _route(stage1: tuple, stage2: tuple, state, chunk: int,
+           kernel: bool) -> _Route:
+    """Which gather serves each call of one program over a plan's two
+    stages (each its level lengths, widths and level-0 classes) for
+    ``state`` (a shape and a dtype: ``(n_pad, Kw)`` uint32, ``(n_pad,)``
+    int32 or float32) scanned in ``chunk``s: the ONE place the kernel's
+    forms and the XLA gather are chosen between. Stage 1 reads the state,
+    stage 2 stage 1's buffer, the fold's fetch stage 2's, at width 1 in
+    blocks of ``UPDATE_ROWS``."""
+    row, dtype = state.shape[1:], state.dtype
+    tables, stages = [state], []
+    for lengths, widths, n_lvl0 in (stage1, stage2):
+        stages.append(_stage_route(lengths, widths, n_lvl0, tables[-1],
+                                   chunk, kernel))
+        tables.append(jax.ShapeDtypeStruct(
+            (sum(n // w for n, w in zip(lengths, widths)) + 1, *row), dtype))
+    fetch = (_form(_reduction(state), tables[-1], 1) if kernel and
+             _block_rows(state.shape[0]) >= _pg.MIN_INDICES else None)
+    return _Route(*stages, fetch)
+
+
+def _route_for(snap: CSRSnapshot, plans: "PullBFSPlans", state,
+               chunk: int) -> _Route:
+    """:func:`_route` over ``snap``'s plan, kept on the snapshot beside
+    ``_device_plans``' dict by the state's spec, ``chunk`` and whether the
+    backend serves the kernel (``pallas_ok``, read here alone)."""
+    kernel = _pg.pallas_ok()
+    key = (state.shape, jnp.dtype(state.dtype), chunk, kernel)
+    routes = vars(snap).setdefault("_pull_routes", {})
+    if key not in routes:
+        s1 = plans.stage1
+        routes[key] = _route(
+            (tuple(map(len, s1.levels)), s1.widths, s1.n_lvl0),
+            (tuple(map(len, plans.stage2_levels)), plans.stage2_widths,
+             plans.stage2_n_lvl0), state, chunk, kernel)
+    return routes[key]
+
+
 # ------------------------------------------------------------------ device ops
-
-
-def _kernel_of(values, red: _Reduction, w: int) -> Optional[str]:
-    """Which form of the row-gather kernel serves a class of width ``w``
-    over ``values`` where the caller's gathers take the kernel: ``"or"``
-    (``hg_gather_or``: ``(S, 128)`` uint32 rows), the reduction's
-    ``scalar_op`` (``hg_gather_scalar``: a flat int32 or float32 state), or
-    None where the gate declines it (the XLA gather). The one rule
-    ``_reduce_classes``, ``_reduce_level`` and ``_scalar_indices`` route
-    by; ``values`` needs a shape and a dtype alone."""
-    if red is _OR_WORDS:
-        return "or" if _pg.declined(w, values.shape[1]) is None else None
-    if (red.scalar_op and values.ndim == 1
-            and _pg.declined_scalar(w, values.dtype, values.shape[0]) is None):
-        return red.scalar_op
-    return None
 
 
 def _reduce_level(
@@ -374,23 +498,21 @@ def _reduce_level(
     idx: jax.Array,     # (E,) int32, multiple of w
     w: int,
     chunk: int,
-    use_pallas: bool = False,
+    form: Optional[str] = None,
     red: _Reduction = _OR_WORDS,
 ) -> jax.Array:
     """gather + fixed-width reduction by ``red``, streamed in ``chunk``-row
     slices to bound the gather transient: returns (E//w,) + a value row's
-    shape. A call of ``MIN_INDICES`` or more takes the kernel where
-    ``_kernel_of`` names one: the OR's seed words or a flat state's
-    scalars, a 128-lane row fetched an index either way."""
+    shape. ``form``: the row-gather kernel's form that serves the call
+    (the OR's seed words or a flat state's scalars, a 128-lane row fetched
+    an index either way), None for the XLA gather."""
     E = idx.shape[0]
     row = values.shape[1:]  # (Kw,) seed words; () a label
     n_out = E // w
-    kernel = (_kernel_of(values, red, w)
-              if use_pallas and E >= _pg.MIN_INDICES else None)
-    if kernel == "or":
+    if form == "or":
         return _pg.gather_or(values, idx, w)
-    if kernel:
-        return _pg.gather_reduce(values, idx, w, kernel)
+    if form:
+        return _pg.gather_reduce(values, idx, w, form)
     if E <= chunk * w:
         g = values[idx]
         return _fold(g.reshape(n_out, w, *row), red)
@@ -430,10 +552,7 @@ def _fold(x: jax.Array, red: _Reduction) -> jax.Array:
 def _apply_plan(
     values: jax.Array,            # (S, Kw) uint32 rows, (S,) int32 or float32
     levels: Sequence[jax.Array],
-    widths: Sequence[int],
-    n_lvl0: int,
-    chunk: int,
-    use_pallas: bool,
+    route: _Stage,
     scopes: tuple[str, str],
 ) -> jax.Array:
     """Run the reduction pyramid; returns the CONCATENATION of every
@@ -451,18 +570,19 @@ def _apply_plan(
     host-local indices rebased on device (pad marker ``n_prev`` → the
     global zero row); their outputs are small enough to materialize.
 
-    ``scopes`` names the device operations of (level 0, the upper levels)
-    for a profile, as ``jax.named_scope`` components of their ``op_name``.
-    The reduction is the values' (``_reduction``, strict): the buffer
-    starts as its identity, so the zero row and every padded index read
-    it."""
+    ``route``: the stage's (``_stage_route``). ``scopes`` names the device
+    operations of (level 0, the upper levels) for a profile, as
+    ``jax.named_scope`` components of their ``op_name``. The reduction is
+    the values' (``_reduction``, strict): the buffer starts as its
+    identity, so the zero row and every padded index read it."""
+    n_lvl0 = len(route.classes)
+    widths = [c.w for c in route.classes] + list(route.upper)
     sizes = [lvl.shape[0] // w for lvl, w in zip(levels, widths)]
     total = sum(sizes) + 1  # + global zero row at index `sum(sizes)`
     lvl0_scope, upper_scope = map(jax.named_scope, scopes)
     red = _reduction(values)
     with lvl0_scope:
-        if use_pallas and red.scalar_op and any(
-                _kernel_of(values, red, w) for w in widths[:n_lvl0]):
+        if route.padded:
             # the scalar kernel reads a flat table as rows of 128: padded
             # with the identity once here, not at every call, and the
             # buffer (the next stage's table) allocated whole
@@ -470,73 +590,24 @@ def _apply_plan(
             total = _ceil_to(total, _pg.G_SCALAR)
         buf = jnp.full((total, *values.shape[1:]), red.identity,
                        dtype=values.dtype)
-        buf = _reduce_classes(buf, values, levels[:n_lvl0], widths[:n_lvl0],
-                              chunk, use_pallas)
+        buf = _reduce_classes(buf, values, levels[:n_lvl0], route.classes)
     if n_lvl0 == len(levels):  # no row above the widest class
         return buf
     with upper_scope:
-        return _upper_levels(buf, levels[n_lvl0:], widths[n_lvl0:],
-                             sizes[n_lvl0 - 1:], sum(sizes[:n_lvl0]), chunk)
+        return _upper_levels(buf, levels[n_lvl0:], route.upper,
+                             sizes[n_lvl0 - 1:], sum(sizes[:n_lvl0]),
+                             route.chunk)
 
 
-def _reduce_classes(buf, values, levels, widths, chunk, use_pallas):
+def _reduce_classes(buf, values, levels, classes: Sequence[_Class]):
     """Level 0: every width class of ``values`` rows into its section of
-    ``buf``, in order from row 0. A scan step moves ``chunk * STEP_WIDTH``
-    indices whatever the class — ``chunk`` output rows at width 8, fewer
-    wider ones, more narrower ones — so the XLA path's gather transient
-    (``chunk * STEP_WIDTH`` rows) does not follow the width; on the kernel
-    the whole segments of that (a width that does not divide its segment
-    would pad every step otherwise)."""
+    ``buf``, in order from row 0, each as its route says."""
     off = 0
-    for idx, w in zip(levels, widths):
-        buf = _reduce_into(buf, off, values, idx, w,
-                           _class_rows(values, w, chunk, use_pallas),
-                           use_pallas)
-        off += idx.shape[0] // w
+    for idx, c in zip(levels, classes):
+        buf = _reduce_into(buf, off, values, idx, c.w, c.rows, c.block,
+                           c.tail)
+        off += idx.shape[0] // c.w
     return buf
-
-
-def _class_rows(values, w: int, chunk: int, use_pallas: bool) -> int:
-    """Output rows a scan step of a level-0 class of width ``w`` moves (see
-    ``_reduce_classes``): ``chunk * STEP_WIDTH`` indices, cut to the whole
-    segments of the kernel's form that serves the class."""
-    step = chunk * STEP_WIDTH
-    kernel = _kernel_of(values, _reduction(values), w) if use_pallas else None
-    if kernel == "or":
-        step = _pg.whole_segments(step, w)
-    elif kernel:
-        step = _pg.whole_scalar_steps(step, w)
-    return max(1, step // w)
-
-
-def _scalar_indices(plans: "PullBFSPlans", dtype, chunk: int,
-                    use_pallas: bool) -> tuple[int, int]:
-    """``(level-0 indices, those the kernel gathers)`` of one program over
-    both stages of ``plans`` for a flat ``dtype`` state — what a whole-graph
-    operator counts a dispatch (``scalar.gather.indices`` and
-    ``.indices_kernel``), from the plan's lengths on the host, by the rule
-    the program routes by (``_class_rows``, ``_reduce_into``'s blocks and
-    tail, ``_reduce_level``'s ``MIN_INDICES``), over each stage's table:
-    the state, then stage 1's buffer."""
-    s1 = plans.stage1
-    total = kernel = 0
-    for table, levels, widths, n in (
-            (plans.n_pad, s1.levels, s1.widths, s1.n_lvl0),
-            (s1.concat_size + 1, plans.stage2_levels, plans.stage2_widths,
-             plans.stage2_n_lvl0)):
-        values = jax.ShapeDtypeStruct((table,), dtype)
-        red = _reduction(values)
-        for idx, w in zip(levels[:n], widths[:n]):
-            E = len(idx)
-            total += E
-            if not (use_pallas and _kernel_of(values, red, w)):
-                continue
-            rows = _class_rows(values, w, chunk, use_pallas)
-            n_out = E // w
-            block, tail = rows * w, (n_out - n_out // rows * rows) * w
-            kernel += ((n_out // rows) * block if block >= _pg.MIN_INDICES
-                       else 0) + (tail if tail >= _pg.MIN_INDICES else 0)
-    return total, kernel
 
 
 def _upper_levels(
@@ -570,7 +641,7 @@ def _upper_levels(
         # a separate level output is a padded scan result plus its trimmed
         # copy, ~2 GB of temps at 10M atoms x 4096 seeds that the hop's
         # widest step has no room for
-        buf = _reduce_into(buf, off, None, idx_g, w, chunk, False)
+        buf = _reduce_into(buf, off, None, idx_g, w, chunk)
         off += sizes[i + 1]
     return buf
 
@@ -582,14 +653,17 @@ def _reduce_into(
     idx: jax.Array,
     w: int,
     chunk: int,
-    use_pallas: bool,
+    block: Optional[str] = None,
+    tail: Optional[str] = None,
 ) -> jax.Array:
     """Reduce ``values`` rows over ``idx`` groups of ``w`` by the buffer's
     reduction (``_reduction``, strict), writing the
     ``len(idx)//w`` output rows into ``buf[off:]`` in place: full blocks of
     ``chunk`` outputs stream through a scan (carry = buf, aliased by XLA),
-    the ragged tail lands with one final update. ``values=None`` gathers
-    from ``buf`` itself (the rows read must lie outside ``buf[off:]``).
+    the ragged tail lands with one final update; ``block`` and ``tail``
+    are the kernel's forms that serve them (``_Class``), None the XLA
+    gather. ``values=None`` gathers from ``buf`` itself (the rows read
+    must lie outside ``buf[off:]``).
 
     The sum slices a block's indices out of ``idx`` in the loop's body;
     the OR and the min scan over ``idx`` reshaped to ``(blocks, chunk *
@@ -604,31 +678,30 @@ def _reduce_into(
     if n_full:
         step = chunk * w
         if red is _SUM_FLOATS:
-            def block(i):
+            def block_of(i):
                 return jax.lax.dynamic_slice_in_dim(idx, i * step, step), i
 
             xs = jnp.arange(n_full, dtype=jnp.int32)
         else:
-            def block(ib_i):
+            def block_of(ib_i):
                 return ib_i
 
             xs = (idx[: n_full * step].reshape(n_full, step),
                   jnp.arange(n_full, dtype=jnp.int32))
 
         def body(b, x):
-            ib, i = block(x)
+            ib, i = block_of(x)
             out = _reduce_level(b if values is None else values, ib, w,
-                                chunk, use_pallas, red)
+                                chunk, block, red)
             return jax.lax.dynamic_update_slice(
                 b, out, _at(off + i * chunk, b)
             ), None
 
         buf, _ = jax.lax.scan(body, buf, xs)
-    tail = n_out - n_full * chunk
-    if tail:
+    if n_out - n_full * chunk:
         out = _reduce_level(
             buf if values is None else values,
-            idx[n_full * chunk * w :], w, chunk, use_pallas, red
+            idx[n_full * chunk * w :], w, chunk, tail, red
         )
         buf = jax.lax.dynamic_update_slice(
             buf, out, _at(off + n_full * chunk, buf)
@@ -973,46 +1046,47 @@ def _seed_bitmap(seeds: jax.Array, n_atoms: jax.Array, n_pad: int):
     shapes=lambda: (hgverify.sds((64, 1), "uint32"),
                     (hgverify.sds((32,), "int32"),
                      hgverify.sds((64,), "int32"))),
-    statics={"widths": (2, 8), "n_lvl0": 2, "chunk": 1 << 19,
-             "use_pallas": False},
+    statics={"route": _stage_route((32, 64), (2, 8), 2,
+                                   hgverify.sds((64, 1), "uint32"), 1 << 19,
+                                   kernel=False)},
 )
-@partial(jax.jit,
-         static_argnames=("widths", "n_lvl0", "chunk", "use_pallas"))
+@partial(jax.jit, static_argnames=("route",))
 @_program("hg_bfs_stage1")
-def _stage(values, levels, widths, n_lvl0, chunk, use_pallas):
-    return _apply_plan(values, levels, widths, n_lvl0, chunk, use_pallas,
+def _stage(values, levels, route):
+    return _apply_plan(values, levels, route,
                        scopes=("hg.bfs.stage1.lvl0", "hg.bfs.stage1.upper"))
 
 
-@partial(jax.jit, static_argnames=("widths", "chunk", "use_pallas"))
+@partial(jax.jit, static_argnames=("route",))
 @_program("hg_bfs_stage2_lvl0", "hg.bfs.stage2.lvl0")
-def _stage_lvl0_consume(values, levels, widths, chunk, use_pallas):
-    """Level-0 chunks only (every width class), into an exact-size buffer.
+def _stage_lvl0_consume(values, levels, route):
+    """Level-0 chunks only (``route``'s classes), into an exact-size buffer.
     ``values`` (the previous stage's buffer, ~4.1 GB at benchmark scale)
     is genuinely dead once this jit returns; the caller drops its ref and
     syncs — splitting stage 2 here is what lets that buffer free before
     the full concat buffer allocates. (No donate: the shapes can never
     alias, donation would only warn.)"""
-    n0 = sum(idx.shape[0] // w for idx, w in zip(levels, widths))
+    n0 = sum(idx.shape[0] // c.w for idx, c in zip(levels, route.classes))
     buf = jnp.zeros((n0, values.shape[1]), dtype=values.dtype)
-    return _reduce_classes(buf, values, levels, widths, chunk, use_pallas)
+    return _reduce_classes(buf, values, levels, route.classes)
 
 
-@partial(jax.jit, static_argnames=("widths", "n_last", "chunk"))
+@partial(jax.jit, static_argnames=("route",))
 @_program("hg_bfs_stage2_upper", "hg.bfs.stage2.upper")
-def _stage_upper(lvl0, levels, widths, n_last, chunk):
+def _stage_upper(lvl0, levels, route):
     """Assemble the stage's concat buffer from the level-0 chunks, then
-    run the (small) upper levels on the XLA gather path. ``levels`` and
-    ``widths`` are the upper levels' alone (none where no row is above
-    ``W_MAX``: the buffer is then level 0 and the zero row); ``n_last``
-    is the chunk count of level 0's last class, whose section the first
-    upper level reads."""
+    run the (small) upper levels on the XLA gather path. ``levels`` are
+    the upper levels' alone (none where no row is above ``W_MAX``: the
+    buffer is then level 0 and the zero row); the first reads the section
+    of ``route``'s last class."""
     n0, Kw = lvl0.shape
-    sizes = [n_last] + [lvl.shape[0] // w for lvl, w in zip(levels, widths)]
+    last = route.classes[-1]
+    sizes = [last.n // last.w] + [
+        lvl.shape[0] // w for lvl, w in zip(levels, route.upper)]
     total = n0 + sum(sizes[1:]) + 1
     buf = jnp.zeros((total, Kw), dtype=lvl0.dtype)
     buf = jax.lax.dynamic_update_slice(buf, lvl0, (0, 0))
-    return _upper_levels(buf, levels, widths, sizes, n0, chunk)
+    return _upper_levels(buf, levels, route.upper, sizes, n0, route.chunk)
 
 
 #: Rows of the bitmap a step of an update's loop folds, and the grain at
@@ -1026,15 +1100,20 @@ def _stage_upper(lvl0, levels, widths, n_last, chunk):
 UPDATE_ROWS = 1 << 16
 
 
-class _UpdateRows(NamedTuple):
+@partial(jax.tree_util.register_dataclass,
+         data_fields=["out_map", "starts", "n_listed"], meta_fields=["fetch"])
+@dataclass(frozen=True, eq=False)
+class _UpdateRows:
     """What an update is handed of a plan, as one argument: where each row
     of the bitmap reads the stage buffer, and the row blocks worth folding.
     The list has one slot a block of the bitmap, so a program's shapes
-    follow ``n_pad`` alone and never the graph."""
+    follow ``n_pad`` alone and never the graph. ``fetch``, static: the
+    route's form for the fold's fetch (``_Route.listed``; None: XLA)."""
 
     out_map: jax.Array   # (n_pad,) int32 into reach_chunks, → its zero row
     starts: jax.Array    # (blocks,) int32: the listed blocks' first rows
     n_listed: jax.Array  # () int32: what follows them in starts is unread
+    fetch: Optional[str] = None
 
 
 def _block_rows(n_pad: int, block_rows: int = UPDATE_ROWS) -> int:
@@ -1105,31 +1184,8 @@ _LOWERED = _Gain(lambda row: jnp.int32(0),
                                                      dtype=jnp.int32))
 
 
-def _update_on_kernel(state, use_pallas: bool,
-                      block_rows: int = UPDATE_ROWS,
-                      table=None) -> Optional[str]:
-    """Which form of the row-gather kernel ``_fold_rows`` fetches a block's
-    rows through at width 1, or None for the XLA gather. Where the
-    caller's gathers take the kernel (``use_pallas``: a 4096-seed block or
-    a whole-graph operator on a TPU) and a block is as many indices as the
-    kernel is worth (``_reduce_level``'s rule), ``_kernel_of`` decides over
-    the TABLE the fetch reads — the stage buffer ``table``, the state
-    where none is given — by the state's reduction: ``"or"``
-    (``hg_gather_or``) for ``(S, 128)`` uint32 rows, the reduction's
-    ``scalar_op`` (``hg_gather_scalar``) for a flat int32 or float32 state
-    whose table the scalar gate admits. A narrower bitmap, a table past
-    ``SCALAR_TABLE_BYTES`` and a short block keep the XLA gather. Only
-    shapes and dtypes are read: the host counts by it."""
-    if not (use_pallas
-            and _block_rows(state.shape[0], block_rows) >= _pg.MIN_INDICES):
-        return None
-    return _kernel_of(state if table is None else table, _reduction(state),
-                      1)
-
-
 def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
-               block_rows: int = UPDATE_ROWS, gain: Optional[_Gain] = None,
-               use_pallas: bool = False):
+               block_rows: int = UPDATE_ROWS, gain: Optional[_Gain] = None):
     """``state[v] = combine(state[v], reach_chunks[out_map[v]])`` for every
     row of the LISTED row blocks and no other, folded a block at a time so
     no second state array materializes while the stage buffer is alive
@@ -1151,24 +1207,24 @@ def _fold_rows(state, reach_chunks, rows: _UpdateRows, n_atoms, combine,
     program is what it was.
 
     A block's rows are fetched by the row-gather kernel at width 1 (one
-    call of ``block_rows`` indices) where ``_update_on_kernel`` names a
-    form: ``hg_gather_or`` for a bitmap, ``hg_gather_scalar`` for a flat
-    state, whose stage buffer it reads as rows of 128 — whole (8, 128)
-    tiles where the stage's pyramid ran on the kernel, else padded here
-    once, outside the loop; elsewhere through the XLA gather. The combine
-    is XLA's either way."""
+    call of ``block_rows`` indices) where ``rows.fetch`` names a form:
+    ``hg_gather_or`` for a bitmap, ``hg_gather_scalar`` for a flat state,
+    whose stage buffer it reads as rows of 128 — whole (8, 128) tiles
+    where the stage's pyramid ran on the kernel, else padded here once,
+    outside the loop; elsewhere through the XLA gather. The combine is
+    XLA's either way."""
     n_pad, row = state.shape[0], state.shape[1:]
     identity = _reduction(state).identity
     ub = _block_rows(n_pad, block_rows)
-    kernel = _update_on_kernel(state, use_pallas, block_rows, reach_chunks)
-    if kernel and kernel != "or":
+    form = rows.fetch
+    if form and form != "or":
         reach_chunks = _pg.scalar_table(reach_chunks, identity)
 
     def fetch(sl):
-        if kernel == "or":
+        if form == "or":
             return _pg.gather_or(reach_chunks, sl, 1)
-        if kernel:
-            return _pg.gather_reduce(reach_chunks, sl, 1, kernel)
+        if form:
+            return _pg.gather_reduce(reach_chunks, sl, 1, form)
         return reach_chunks[sl]
 
     def fold(i, carry):
@@ -1205,26 +1261,21 @@ def _update_shapes():
 
 
 @hgverify.entry(shapes=_update_shapes, donate=True)
-@partial(jax.jit, static_argnames=("use_pallas",),
-         donate_argnums=(0,))  # visited aliases the output
+@partial(jax.jit, donate_argnums=(0,))  # visited aliases the output
 @_program("hg_bfs_visited_update", "hg.bfs.visited_update")
-def _visited_update(visited, reach_chunks, rows, n_atoms, use_pallas=False):
+def _visited_update(visited, reach_chunks, rows, n_atoms):
     """A traversal's hop ends here: visited | reach_chunks[out_map], over
-    the hop's plan's active blocks (``rows``). A row outside them would OR
-    in the zero row: what it holds — a seed's own bit, an earlier hop's
-    bits — is left where it is. ``use_pallas``: the stage programs'
-    (``_fold_rows`` fetches on the kernel where it serves the state)."""
+    the hop's plan's active blocks (``rows``, whose ``fetch`` is the
+    route's). A row outside them would OR in the zero row: what it holds —
+    a seed's own bit, an earlier hop's bits — is left where it is."""
     return _fold_rows(visited, reach_chunks, rows, n_atoms,
-                      lambda cur, reached: cur | reached,
-                      use_pallas=use_pallas)
+                      lambda cur, reached: cur | reached)
 
 
 @hgverify.entry(shapes=_update_shapes, donate=True)
-@partial(jax.jit, static_argnames=("use_pallas",),
-         donate_argnums=(0,))  # the old frontier's buffer is reused
+@partial(jax.jit, donate_argnums=(0,))  # the old frontier's buffer is reused
 @_program("hg_bfs_frontier_replace", "hg.bfs.frontier_replace")
-def _frontier_replace(frontier, reach_chunks, rows, n_atoms,
-                      use_pallas=False):
+def _frontier_replace(frontier, reach_chunks, rows, n_atoms):
     """A match's step ends here: the new state IS reach_chunks[out_map],
     written into the donated old frontier, of which no bit is read.
 
@@ -1233,25 +1284,22 @@ def _frontier_replace(frontier, reach_chunks, rows, n_atoms,
     bit beside the step's plan's active blocks (``_bfs_pull_device`` keeps
     that account). Inside a listed block a row nothing reaches reads the
     zero row and is cleared; an unlisted block is neither read nor
-    written, and was zero. ``use_pallas`` as ``_visited_update``'s."""
+    written, and was zero."""
     return _fold_rows(frontier, reach_chunks, rows, n_atoms,
-                      lambda cur, reached: reached, use_pallas=use_pallas)
+                      lambda cur, reached: reached)
 
 
 @hgverify.entry(shapes=_update_shapes, donate=True)
-@partial(jax.jit, static_argnames=("use_pallas",),
-         donate_argnums=(0,))  # the ball aliases the output
+@partial(jax.jit, donate_argnums=(0,))  # the ball aliases the output
 @_program("hg_bfs_ball_update", "hg.bfs.visited_update")
-def _ball_update(ball, reach_chunks, rows, n_atoms, use_pallas=False):
+def _ball_update(ball, reach_chunks, rows, n_atoms):
     """A pair search's expansion ends here: ``_visited_update`` (the same
     fold, under the same scope), and beside the grown ball the columns that
     GREW, ``(Kw,) uint32`` — a column that did not has its whole component
     (:func:`pair_distances`' exhaustion). A program of its own because the
-    traversal's update returns the bitmap alone and is left as it is.
-    ``use_pallas`` as ``_visited_update``'s."""
+    traversal's update returns the bitmap alone and is left as it is."""
     return _fold_rows(ball, reach_chunks, rows, n_atoms,
-                      lambda cur, reached: cur | reached, gain=_GREW,
-                      use_pallas=use_pallas)
+                      lambda cur, reached: cur | reached, gain=_GREW)
 
 
 #: Rows of the two bitmaps a step of the meet test's loop folds: one AND
@@ -1525,7 +1573,6 @@ def _expand(
     seeds: np.ndarray,           # the block's seeds (a sparse hop reads them)
     n_atoms: jax.Array,          # () int32, on the device
     chunk: int,
-    use_pallas: bool,
     own_bits: bool = False,      # a sparse hop onto an EMPTY bitmap
     listed: Optional[np.ndarray] = None,
 ) -> tuple[jax.Array, object]:
@@ -1537,7 +1584,8 @@ def _expand(
     ``_sparse_hop``; else the
     pull chain — stage 1, stage 2's level 0, its upper levels and
     ``update`` — each step synced and the dead stage buffer dropped before
-    the next is dispatched.
+    the next is dispatched, each gather as the route of ``state`` over the
+    hop's plan says (:func:`_route`).
 
     ``listed``: the row blocks ``update`` folds where they are not the
     hop's plan's own active blocks (a match's update, which has to clear
@@ -1561,20 +1609,18 @@ def _expand(
                 state = _sparse_first_hop(state, pairs, n_atoms)
             ph.wait(state)
         return state, pairs.placed
-    _, plans, dev = hop
-    s1 = plans.stage1
+    snap, plans, dev = hop
+    route = _route_for(snap, plans, state, chunk)
     levels1, levels2 = dev["levels1"], dev["levels2"]
-    widths2, n2 = plans.stage2_widths, plans.stage2_n_lvl0
-    n2_last = len(plans.stage2_levels[n2 - 1]) // widths2[n2 - 1]
+    n2 = len(route.stage2.classes)
     with phase("hg.bfs.hop.stage1") as ph:
         with ph.step("dispatch"):
-            live = _stage(state, levels1, s1.widths, s1.n_lvl0, chunk,
-                          use_pallas)
+            live = _stage(state, levels1, route=route.stage1)
         ph.wait(live)
     with phase("hg.bfs.hop.stage2_lvl0") as ph:
         with ph.step("dispatch"):
-            lvl0b = _stage_lvl0_consume(live, levels2[:n2], widths2[:n2],
-                                        chunk, use_pallas)
+            lvl0b = _stage_lvl0_consume(live, levels2[:n2],
+                                        route=route.stage2)
         # the donations can't alias (shapes differ), so the host ref
         # is what keeps each dead buffer resident — drop it AND sync
         # before the next dispatch: async dispatch would let the
@@ -1586,17 +1632,14 @@ def _expand(
         ph.wait(lvl0b)
     with phase("hg.bfs.hop.stage2_upper_update") as ph:
         with ph.step("dispatch"):
-            reach_chunks = _stage_upper(lvl0b, levels2[n2:], widths2[n2:],
-                                        n2_last, chunk)
+            reach_chunks = _stage_upper(lvl0b, levels2[n2:],
+                                        route=route.stage2)
             del lvl0b
             if listed is None:
                 listed, rows = dev["blocks"], dev["rows"]
             else:
                 rows = _listed(dev["out_map"], listed)
-            # the XLA route is the four-argument call it always was, which
-            # an update standing in for the operator's may be written to
-            out = update(state, reach_chunks, rows, n_atoms,
-                         **({"use_pallas": True} if use_pallas else {}))
+            out = update(state, reach_chunks, route.listed(rows), n_atoms)
             del reach_chunks
         ph.wait(out)
         # what the update's loop folded beside the whole bitmap, and of
@@ -1607,7 +1650,7 @@ def _expand(
         reg = default_registry()
         reg.counter("bfs.update.rows_visited").inc(visited)
         reg.counter("bfs.update.rows_kernel").inc(
-            visited if _update_on_kernel(state, use_pallas) else 0)
+            visited if route.fetch else 0)
         reg.counter("bfs.update.rows_total").inc(n_pad)
     if isinstance(out, tuple):  # the state, and the words of what grew
         return out[0], _columns(out[1])
@@ -1620,13 +1663,13 @@ def _bfs_pull_device(
     n_pad: int,
     seeds: np.ndarray,       # (K,) int32 — K % 32 == 0
     update,                  # what ends a hop: the operator's
+    grows: bool,             # whether the operator's state grows
     chunk: int = 1 << 19,
     count_edges: bool = True,
-    use_pallas: bool = False,
 ) -> tuple[jax.Array, list, jax.Array]:
     """The hop chain of one seed block: the state, the first hop's side and
     H calls of :func:`_expand`. ``update`` is ``_visited_update`` (a
-    traversal: the state is the visited set, and grows) or
+    traversal: the state is the visited set, and ``grows``) or
     ``_frontier_replace`` (a match: the state is the newest step's end
     points alone, and ``count_edges`` has no meaning).
 
@@ -1642,10 +1685,9 @@ def _bfs_pull_device(
     hold a bit: ``_deg_sum`` the last hop's plan's active blocks,
     ``_reach_counts`` a match's ``held`` as the last step leaves it, a
     traversal's plan's active blocks beside the seeds' own."""
-    grows = update is _visited_update
     n_atoms_dev = jnp.int32(n_atoms)
     expand = partial(_expand, update=update, seeds=seeds, n_atoms=n_atoms_dev,
-                     chunk=chunk, use_pallas=use_pallas)
+                     chunk=chunk)
 
     def rule() -> Optional[_SeedLinks]:  # it reads the FIRST hop's plan
         return _first_hop_rule(hops[0], seeds) if hops else None
@@ -1746,16 +1788,9 @@ def _check_k_block(k_block: int) -> None:
         )
 
 
-def _kernel_gathers(width: int) -> bool:
-    """4096-seed blocks (128-lane rows, the one width the kernel compiles
-    at) run the Pallas gather on a TPU; everything else keeps the XLA
-    gather (no width limits)."""
-    return width == _pg.ROW_WORDS * WORD and _pg.pallas_ok()
-
-
 def _seed_blocks(hops: Sequence[_Hop], snap: CSRSnapshot,
-                 seeds: np.ndarray, update, chunk: int, k_block: int,
-                 count_edges: bool) -> tuple[list, int]:
+                 seeds: np.ndarray, update, grows: bool, chunk: int,
+                 k_block: int, count_edges: bool) -> tuple[list, int]:
     """The chain over every seed block of ``seeds``: the blocks' (bitmap,
     S entering the last hop, counts) in order, and the seeds' real number
     (the last block's columns past it are pad seeds)."""
@@ -1772,9 +1807,8 @@ def _seed_blocks(hops: Sequence[_Hop], snap: CSRSnapshot,
         block = seeds[s : s + k_block]
         blocks.append(
             _bfs_pull_device(
-                hops, snap.num_atoms, n_pad, block, update,
+                hops, snap.num_atoms, n_pad, block, update, grows,
                 chunk=chunk, count_edges=count_edges,
-                use_pallas=_kernel_gathers(len(block)),
             )
         )
     return blocks, K
@@ -1839,8 +1873,8 @@ def bfs_pull(
         max_hops = 0
     # one plan for every hop: the chain is handed the same one H times
     blocks, K = _seed_blocks(
-        [_hop_over(snap)] * max_hops, snap, seeds, _visited_update, chunk,
-        k_block, count_edges)
+        [_hop_over(snap)] * max_hops, snap, seeds, _visited_update, True,
+        chunk, k_block, count_edges)
 
     # The device emits S_h (Σ deg over visited entering each hop);
     # frontiers partition visited, so the total over all hops telescopes
@@ -1923,7 +1957,7 @@ def path_match(
         # alone, and no plan goes up
         subs, seeds = [], np.full(len(seeds), snap.num_atoms, np.int32)
     blocks, K = _seed_blocks([_hop_over(sub) for sub in subs], snap, seeds,
-                             _frontier_replace, chunk, k_block,
+                             _frontier_replace, False, chunk, k_block,
                              count_edges=False)
     return PathMatchResult(*_joined(blocks, K))
 
@@ -2012,15 +2046,13 @@ def pair_distances(
     for s in range(0, K_pad, k_block):
         ends = sources[s: s + k_block], targets[s: s + k_block]
         dist[s: s + k_block], ran = _pair_block(
-            hop, snap.num_atoms, n_pad, ends, max_hops, chunk,
-            use_pallas=_kernel_gathers(len(ends[0])))
+            hop, snap.num_atoms, n_pad, ends, max_hops, chunk)
         expansions += ran
     return PairDistResult(dist[:K], expansions)
 
 
 def _pair_block(hop: Optional[_Hop], n_atoms: int, n_pad: int, ends: tuple,
-                max_hops: int, chunk: int,
-                use_pallas: bool) -> tuple[np.ndarray, int]:
+                max_hops: int, chunk: int) -> tuple[np.ndarray, int]:
     """One seed block of :func:`pair_distances`: ``ends`` its (sources,
     targets), each (K,) with K % 32 == 0. Returns (dist, expansions)."""
     reg = default_registry()
@@ -2045,7 +2077,7 @@ def _pair_block(hop: Optional[_Hop], n_atoms: int, n_pad: int, ends: tuple,
             sl, first[side] = first[side], None
             balls[side], grew = _expand(
                 balls[side], hop, sl, _ball_update, seeds=ends[side],
-                n_atoms=n_atoms_dev, chunk=chunk, use_pallas=use_pallas)
+                n_atoms=n_atoms_dev, chunk=chunk)
             depth += 1
             reg.counter("bfs.pairs.expansions."
                         + ("dense" if sl is None else "sparse")).inc()
@@ -2087,15 +2119,18 @@ def _wcc_round_shapes():
             _UpdateRows(i32((64,)), i32((1,)), i32(())), i32(()))
 
 
+#: the whole-graph programs' exemplar plan: two classes, then one
+_EXEMPLAR_STAGES = (((32, 64), (2, 8), 2), ((32,), (2,), 1))
+
+
 @hgverify.entry(shapes=_wcc_round_shapes, donate=True,
-                statics={"widths1": (2, 8), "n1": 2, "widths2": (2,),
-                         "n2": 1, "chunk": 4})
-@partial(jax.jit, static_argnames=("widths1", "n1", "widths2", "n2", "chunk",
-                                   "use_pallas"),
+                statics={"route": _route(*_EXEMPLAR_STAGES,
+                                         hgverify.sds((64,), "int32"), 4,
+                                         kernel=False)})
+@partial(jax.jit, static_argnames=("route",),
          donate_argnums=(0,))  # the labels alias the output
 @_program("hg_wcc_round")
-def _wcc_round(labels, levels1, levels2, rows, n_atoms, widths1, n1,
-               widths2, n2, chunk, use_pallas=False):
+def _wcc_round(labels, levels1, levels2, rows, n_atoms, route):
     """One synchronous min-label round over a pull plan, ONE program: stage
     1 (each link's min over its targets' labels), stage 2 (each atom's min
     over its incident links' — level 0 composed through stage 1's chunks,
@@ -2103,18 +2138,18 @@ def _wcc_round(labels, levels1, levels2, rows, n_atoms, widths1, n1,
     buf[out_map[v]])`` over the plan's active row blocks, which returns
     beside the labels the rows it LOWERED, ``() int32``. The pyramids are
     ``bfs_pull``'s with the labels' reduction (``_reduction``: min,
-    identity ``INT32_MAX``); their level 0 gathers its 4-byte scalars on
-    the kernel's scalar form where ``use_pallas`` (``pallas_ok()``), and
-    the fold fetches its rows on it at width 1, else both through the XLA
-    gather. The stage buffers are a few tens of MB at 10M atoms, so one
-    program holds the round where a 4096-seed hop needs four."""
-    live = _apply_plan(labels, levels1, widths1, n1, chunk, use_pallas,
+    identity ``INT32_MAX``); ``route`` (a ``_Route``) says which gathers
+    take the kernel's scalar form — level 0's 4-byte scalars, and the
+    fold's rows at width 1 — and which the XLA gather. The stage buffers
+    are a few tens of MB at 10M atoms, so one program holds the round
+    where a 4096-seed hop needs four."""
+    live = _apply_plan(labels, levels1, route.stage1,
                        scopes=("hg.wcc.stage1", "hg.wcc.stage1"))
-    reach = _apply_plan(live, levels2, widths2, n2, chunk, use_pallas,
+    reach = _apply_plan(live, levels2, route.stage2,
                         scopes=("hg.wcc.stage2", "hg.wcc.stage2"))
     with jax.named_scope("hg.wcc.fold"):
-        return _fold_rows(labels, reach, rows, n_atoms, jnp.minimum,
-                          gain=_LOWERED, use_pallas=use_pallas)
+        return _fold_rows(labels, reach, route.listed(rows), n_atoms,
+                          jnp.minimum, gain=_LOWERED)
 
 
 @hgverify.entry(shapes=lambda: (hgverify.sds((64,), "int32"),))
@@ -2188,27 +2223,22 @@ def connected_components(snap: CSRSnapshot, link_types=None, *,
         rounds = 0
         if snap.n_edges_tgt:  # else no link to follow: every atom alone
             _, plans, dev = _hop_over(snap)
-            s1, rows = plans.stage1, dev["rows"]
             folded = int(dev["blocks"].sum()) * _block_rows(n_pad)
-            kernel = _pg.pallas_ok()
-            gathered = _scalar_indices(plans, jnp.int32, chunk, kernel)
-            fetched = _scalar_fold_rows(plans, jnp.int32, folded, kernel)
+            route = _route_for(snap, plans, labels, chunk)
             lowered = 1
             while lowered:
                 with phase("hg.wcc.round") as ph:
                     with ph.step("dispatch"):
                         labels, lowered = _wcc_round(
-                            labels, dev["levels1"], dev["levels2"], rows,
-                            n_atoms, s1.widths, s1.n_lvl0,
-                            plans.stage2_widths, plans.stage2_n_lvl0, chunk,
-                            **({"use_pallas": True} if kernel else {}))
+                            labels, dev["levels1"], dev["levels2"],
+                            dev["rows"], n_atoms, route=route)
                     with ph.step("wait"):  # the read of its 4 bytes
                         lowered = int(lowered)
                 rounds += 1
                 reg.counter("wcc.rounds").inc()
                 reg.counter("wcc.rows_lowered").inc(lowered)
                 reg.counter("wcc.rows_folded").inc(folded)
-                _count_scalar_gathers(reg, gathered, fetched)
+                _count_scalar_gathers(reg, route, folded)
         with op.step("count"):
             n_components = int(_wcc_count(labels))
     return ComponentsResult(labels, n_components, rounds)
@@ -2250,14 +2280,13 @@ def _pr_iter_shapes():
 
 
 @hgverify.entry(shapes=_pr_iter_shapes, donate=True,
-                statics={"widths1": (2, 8), "n1": 2, "widths2": (2,),
-                         "n2": 1, "chunk": 4})
-@partial(jax.jit, static_argnames=("widths1", "n1", "widths2", "n2", "chunk",
-                                   "use_pallas"),
+                statics={"route": _route(*_EXEMPLAR_STAGES,
+                                         hgverify.sds((64,), "float32"), 4,
+                                         kernel=False)})
+@partial(jax.jit, static_argnames=("route",),
          donate_argnums=(0,))  # the ranks alias the output
 @_program("hg_pr_iter")
-def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
-             n1, widths2, n2, chunk, use_pallas=False):
+def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, route):
     """One PageRank iteration over a pull plan, ONE program, returning the
     new ranks and their sum. Stage 1 sums each link's target shares ``x =
     rank · inv_d`` (a slot a share); ``w`` scales each link's sum where
@@ -2269,23 +2298,22 @@ def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
         rank'[v] = (1 - d)/N + d · (y[v] - c[v] · x[v]) + (d/N) · dangling
 
     ``dangling`` the ranks of the atoms with ``inv_d == 0``. The pyramids
-    are :func:`bfs_pull`'s with the sum (``_reduction``: identity 0.0);
-    their level 0 gathers on the kernel's scalar form where
-    ``use_pallas``, and so does the fold's fetch at width 1, as in
-    :func:`_wcc_round`, whose buffers come padded to whole rows of the
-    kernel's table (``w``'s pad is 0)."""
+    are :func:`bfs_pull`'s with the sum (``_reduction``: identity 0.0),
+    gathering as ``route`` says, as in :func:`_wcc_round`; on the kernel
+    their buffers come padded to whole rows of its table (``w``'s pad is
+    0)."""
     with jax.named_scope("hg.pr.stage1"):
         x = ranks * pw.inv_d
-    s = _apply_plan(x, levels1, widths1, n1, chunk, use_pallas,
+    s = _apply_plan(x, levels1, route.stage1,
                     scopes=("hg.pr.stage1", "hg.pr.stage1"))
     with jax.named_scope("hg.pr.stage2"):
         pad = s.shape[0] - pw.w.shape[0]
         s = s * (jnp.pad(pw.w, (0, pad)) if pad else pw.w)
-    y = _apply_plan(s, levels2, widths2, n2, chunk, use_pallas,
+    y = _apply_plan(s, levels2, route.stage2,
                     scopes=("hg.pr.stage2", "hg.pr.stage2"))
     with jax.named_scope("hg.pr.update"):
-        y = _fold_rows(jnp.zeros_like(ranks), y, rows, n_atoms,
-                       lambda cur, reached: reached, use_pallas=use_pallas)
+        y = _fold_rows(jnp.zeros_like(ranks), y, route.listed(rows), n_atoms,
+                       lambda cur, reached: reached)
         n = n_atoms.astype(jnp.float32)
         real = jnp.arange(ranks.shape[0], dtype=jnp.int32) < n_atoms
         new = jnp.where(real, (1.0 - damping) / n + damping * (y - pw.c * x)
@@ -2293,31 +2321,16 @@ def _pr_iter(ranks, levels1, levels2, pw, rows, n_atoms, damping, widths1,
         return new, jnp.sum(new)
 
 
-def _scalar_fold_rows(plans: "PullBFSPlans", dtype, folded: int,
-                      use_pallas: bool) -> tuple[int, int]:
-    """``(rows a whole-graph dispatch's fold visits, those whose fetch
-    takes the kernel)``: ``folded`` (the plan's listed rows), and all or
-    none of them by ``_update_on_kernel``'s rule over a flat ``dtype``
-    state and the stage-2 buffer the fold reads, from their shapes on the
-    host (the buffer's pad to whole tiles moves no gate)."""
-    buf = sum(len(l) // w for l, w in zip(plans.stage2_levels,
-                                          plans.stage2_widths)) + 1
-    kernel = _update_on_kernel(
-        jax.ShapeDtypeStruct((plans.n_pad,), dtype), use_pallas,
-        table=jax.ShapeDtypeStruct((buf,), dtype))
-    return folded, folded if kernel else 0
-
-
-def _count_scalar_gathers(reg, gathered: tuple[int, int],
-                          fetched: tuple[int, int]) -> None:
+def _count_scalar_gathers(reg, route: _Route, folded: int) -> None:
     """A whole-graph dispatch's level-0 indices and those the kernel
-    gathered (``_scalar_indices``), its fold's rows and those the kernel
-    fetched (``_scalar_fold_rows``): readers ``scalar_gather_kernel_share``
-    and ``scalar_fold_kernel_share``."""
-    reg.counter("scalar.gather.indices").inc(gathered[0])
-    reg.counter("scalar.gather.indices_kernel").inc(gathered[1])
-    reg.counter("scalar.fold.rows").inc(fetched[0])
-    reg.counter("scalar.fold.rows_kernel").inc(fetched[1])
+    gathers, its fold's rows (``folded``, the plan's listed rows) and those
+    the kernel fetches, as its ``route`` says: readers
+    ``scalar_gather_kernel_share`` and ``scalar_fold_kernel_share``."""
+    gathered, on_kernel = route.indices
+    reg.counter("scalar.gather.indices").inc(gathered)
+    reg.counter("scalar.gather.indices_kernel").inc(on_kernel)
+    reg.counter("scalar.fold.rows").inc(folded)
+    reg.counter("scalar.fold.rows_kernel").inc(folded if route.fetch else 0)
 
 
 def _dangling(ranks: jax.Array, inv_d: jax.Array) -> jax.Array:
@@ -2402,23 +2415,19 @@ def pagerank(snap: CSRSnapshot, link_types=None, *, damping: float = 0.85,
         ranks, mass = _pr_init(n_atoms, n_pad)
         if iterations:
             _, plans, dev = _hop_over(snap)
-            s1, pw = plans.stage1, _pr_weights(snap, plans)
+            pw = _pr_weights(snap, plans)
             folded = int(dev["blocks"].sum()) * _block_rows(n_pad)
-            kernel = _pg.pallas_ok()
-            gathered = _scalar_indices(plans, jnp.float32, chunk, kernel)
-            fetched = _scalar_fold_rows(plans, jnp.float32, folded, kernel)
+            route = _route_for(snap, plans, ranks, chunk)
             d = jnp.float32(damping)
             for _ in range(iterations):
                 with phase("hg.pr.iter") as ph:
                     with ph.step("dispatch"):
                         ranks, mass = _pr_iter(
                             ranks, dev["levels1"], dev["levels2"], pw,
-                            dev["rows"], n_atoms, d, s1.widths, s1.n_lvl0,
-                            plans.stage2_widths, plans.stage2_n_lvl0, chunk,
-                            **({"use_pallas": True} if kernel else {}))
+                            dev["rows"], n_atoms, d, route=route)
                 reg.counter("pr.iterations").inc()
                 reg.counter("pr.rows_folded").inc(folded)
-                _count_scalar_gathers(reg, gathered, fetched)
+                _count_scalar_gathers(reg, route, folded)
         with op.step("mass"):
             mass = float(mass)
     return PageRankResult(ranks, mass, iterations)
@@ -2445,24 +2454,13 @@ def _device_plans(snap: CSRSnapshot, plans: PullBFSPlans) -> dict:
             # it puts the upload's seconds under the upload's name
             ph.wait(cache)
         # outside the upload's phase: on a TPU the first call probes the
-        # kernel (the first 4096-seed block would otherwise)
+        # kernel (the first 4096-seed block would otherwise). A 4096-seed
+        # block's route: its classes' forms do not follow the chunk
+        block = jax.ShapeDtypeStruct((plans.n_pad, _pg.ROW_WORDS), jnp.uint32)
         default_registry().gauge("bfs.gather.tile_share").set(
-            _tile_share(plans) if _pg.pallas_ok() else 0.0)
+            _route_for(snap, plans, block, 1 << 19).tile_share)
         object.__setattr__(snap, "_pull_device", cache)
     return cache
-
-
-def _tile_share(plans: PullBFSPlans) -> float:
-    """Percent of the plan's level-0 indices, both stages', in a class
-    whose width ``hg_gather_or`` serves at its one row width (4096-seed
-    blocks; a narrower block takes the XLA gather whatever its plan)."""
-    lvl0 = [(len(l), w) for levels, widths, n in (
-        (plans.stage1.levels, plans.stage1.widths, plans.stage1.n_lvl0),
-        (plans.stage2_levels, plans.stage2_widths, plans.stage2_n_lvl0))
-        for l, w in zip(levels[:n], widths[:n])]
-    served = sum(n for n, w in lvl0
-                 if _pg.declined(w, _pg.ROW_WORDS) is None)
-    return 100.0 * served / max(1, sum(n for n, _ in lvl0))
 
 
 def visited_rows(res, n_atoms: int) -> list[np.ndarray]:

@@ -285,7 +285,7 @@ def _vmem_bytes(w: int, Kw: int) -> int:
 def declined(w: int, Kw: int) -> str | None:
     """None when the kernel serves ``w``-wide chunks of ``Kw``-word rows;
     otherwise the reason callers must take the XLA gather. The ONE gate:
-    ``gather_or`` raises it, ``ellbfs._reduce_level`` routes on it, and
+    ``gather_or`` raises it, ``ellbfs._route`` routes on it, and
     ``tests/test_tpu_compile.py`` holds it to what the v5e compiler
     accepts."""
     if Kw != ROW_WORDS:
@@ -480,8 +480,8 @@ def declined_scalar(w: int, dtype, n_values: int) -> str | None:
     """None when :func:`gather_reduce` serves ``w``-wide chunks of a flat
     ``dtype`` state of ``n_values``; otherwise the reason callers must
     take the XLA gather. The scalar form's ONE gate, as :func:`declined`
-    is the OR's: ``gather_reduce`` raises it, ``ellbfs._reduce_level``
-    routes on it, and ``tests/test_tpu_compile.py`` holds it to what the
+    is the OR's: ``gather_reduce`` raises it, ``ellbfs._route`` routes
+    on it, and ``tests/test_tpu_compile.py`` holds it to what the
     v5e compiler accepts."""
     if jnp.dtype(dtype) not in SCALAR_DTYPES:
         return (f"a {jnp.dtype(dtype)} state: the scalar form reads "

@@ -227,7 +227,7 @@ def test_two_calls_both_run_every_round():
     np.testing.assert_array_equal(np.asarray(b.labels), np.asarray(a.labels))
 
 
-#: the fold's two counters, once a dispatch (``_scalar_fold_rows``)
+#: the fold's two counters, once a dispatch (from the route's fetch)
 FOLD_COUNTERS = ("scalar.fold.rows", "scalar.fold.rows_kernel")
 
 
@@ -301,9 +301,11 @@ def test_the_min_pyramid_is_numpy_at_every_width_class(width):
     plan = eb.build_reduce_plan(offsets, flat, n_rows, zero_row=n_values)
     values = r.integers(-50, 1 << 30, size=n_values + 1).astype(np.int32)
     values[n_values] = MAX  # the zero row a padded index reads
+    route = eb._stage_route(tuple(map(len, plan.levels)), plan.widths,
+                            plan.n_lvl0, values, 4, kernel=False)
     buf = np.asarray(eb._apply_plan(
-        jnp.asarray(values), tuple(map(jnp.asarray, plan.levels)),
-        plan.widths, plan.n_lvl0, 4, False, scopes=("hg.t", "hg.t")))
+        jnp.asarray(values), tuple(map(jnp.asarray, plan.levels)), route,
+        scopes=("hg.t", "hg.t")))
     assert buf.shape == (plan.concat_size + 1,) and buf[-1] == MAX
     want = np.asarray([values[flat[offsets[i]:offsets[i + 1]]].min()
                        if degrees[i] else MAX for i in range(n_rows)])
@@ -366,7 +368,8 @@ def test_the_reduction_is_the_states_and_no_other_state_has_one(state,
             eb._reduction(jnp.asarray(state))
         with pytest.raises(TypeError, match="no reduction"):
             eb._apply_plan(jnp.asarray(state), (jnp.zeros(4, jnp.int32),),
-                           (2,), 1, 4, False, scopes=("hg.t", "hg.t"))
+                           eb._Stage((eb._Class(2, 4, 4, None, None, None),),
+                                     (), 4, False), scopes=("hg.t", "hg.t"))
     else:
         assert eb._reduction(jnp.asarray(state)) is {
             "or": eb._OR_WORDS, "min": eb._MIN_LABELS,

@@ -221,21 +221,25 @@ def _segment_or(values, offsets, flat, deg):
     return want
 
 
+def _xla_route(plan, values, chunk):
+    """A ``ReducePlan``'s stage route over ``values`` on the XLA gather."""
+    return eb._stage_route(tuple(map(len, plan.levels)), plan.widths,
+                           plan.n_lvl0, values, chunk, kernel=False)
+
+
 def _run_stages(plan, values, chunk):
     """The plan through ``_stage`` (one program) and through
     ``_stage_lvl0_consume`` + ``_stage_upper`` (two): the same buffer."""
     n = plan.n_lvl0
     levels = tuple(np.asarray(l) for l in plan.levels)
-    whole = np.asarray(eb._stage(values, levels, plan.widths, n, chunk,
-                                 False))
+    route = _xla_route(plan, values, chunk)
+    whole = np.asarray(eb._stage(values, levels, route))
     assert whole.shape == (plan.concat_size + 1, values.shape[1])
     assert not whole[plan.concat_size].any()  # the global zero row
     sizes = [len(l) // w for l, w in zip(plan.levels, plan.widths)]
-    lvl0 = eb._stage_lvl0_consume(values, levels[:n], plan.widths[:n],
-                                  chunk, False)
+    lvl0 = eb._stage_lvl0_consume(values, levels[:n], route)
     assert lvl0.shape == (sum(sizes[:n]), values.shape[1])
-    split = np.asarray(eb._stage_upper(lvl0, levels[n:], plan.widths[n:],
-                                       sizes[n - 1], chunk))
+    split = np.asarray(eb._stage_upper(lvl0, levels[n:], route))
     assert np.array_equal(split, whole)
     return whole
 
@@ -353,8 +357,8 @@ def test_no_row_above_w_max_no_upper_level(top_degree):
         if lo < top_degree)
     assert plan.concat_size == np.count_nonzero(deg)
     text = eb._stage.lower(
-        values, tuple(np.asarray(l) for l in plan.levels), plan.widths,
-        plan.n_lvl0, 16, False).as_text(debug_info=True)
+        values, tuple(np.asarray(l) for l in plan.levels),
+        _xla_route(plan, values, 16)).as_text(debug_info=True)
     assert "hg.bfs.stage1.lvl0" in text
     assert "hg.bfs.stage1.upper" not in text
     whole = _run_stages(plan, values, 16)
@@ -362,29 +366,35 @@ def test_no_row_above_w_max_no_upper_level(top_degree):
                           _segment_or(values, offsets, flat, deg))
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("kernel", [False, True])
 @pytest.mark.parametrize("chunk", [8, 1 << 6, 1 << 16])
 def test_a_scan_step_moves_the_same_indices_at_every_class(
-        chunk, use_pallas, monkeypatch):
-    """``chunk * STEP_WIDTH`` indices a step whatever the class width (less
-    than one chunk short where the width does not divide it), so the XLA
-    gather's transient does not follow the class; on the kernel, the whole
-    segments of that, so that no step pads a segment."""
+        chunk, kernel, monkeypatch):
+    """The route's rows: ``chunk * STEP_WIDTH`` indices a step whatever
+    the class width (less than one chunk short where the width does not
+    divide it), so the XLA gather's transient does not follow the class;
+    on the kernel (a 128-word state), the whole segments of that, so that
+    no step pads a segment. Level 0 runs each class at its route's rows
+    and forms, its section after the one before."""
     steps = []
     monkeypatch.setattr(
         eb, "_reduce_into",
-        lambda buf, off, values, idx, w, rows, use_pallas:
-        steps.append((off, w, rows * w)) or buf)
+        lambda buf, off, values, idx, w, rows, block, tail:
+        steps.append((off, w, rows * w, block, tail)) or buf)
     levels = tuple(np.zeros(w * (3 + c), np.int32)
                    for c, w in enumerate(eb.CLASS_WIDTHS))
-    eb._reduce_classes(None, np.zeros((1, 128), np.uint32), levels,
-                       eb.CLASS_WIDTHS, chunk, use_pallas)
-    assert [w for _, w, _ in steps] == list(eb.CLASS_WIDTHS)
-    assert [off for off, _, _ in steps] == [
+    values = np.zeros((1, 128), np.uint32)
+    route = eb._stage_route(tuple(map(len, levels)), eb.CLASS_WIDTHS,
+                            len(levels), values, chunk, kernel)
+    eb._reduce_classes(None, values, levels, route.classes)
+    assert [s[1] for s in steps] == list(eb.CLASS_WIDTHS)
+    assert [s[0] for s in steps] == [
         sum(range(3, 3 + c)) for c in range(len(steps))]
+    assert [s[2:] for s in steps] == [(c.rows * c.w, c.block, c.tail)
+                                      for c in route.classes]
     whole = chunk * eb.STEP_WIDTH
-    for _, w, n in steps:
-        if use_pallas:
+    for _, w, n, _, _ in steps:
+        if kernel:
             assert n == eb._pg.whole_segments(whole, w) // w * w
             assert n % eb._pg._seg(w) == 0 or n < eb._pg._seg(w)
         assert whole - max(w, whole // 8) < n <= whole
@@ -538,16 +548,25 @@ def test_wcc_round_on_the_kernel_gives_the_xla_routes_labels(chunk,
 
 def _kernel_fetches(monkeypatch) -> list:
     """Both forms of the row-gather kernel in the Pallas interpreter, each
-    call's (form, width) recorded."""
+    call's (form, width, indices) recorded as it is traced."""
     calls = []
     gather_or, gather_reduce = eb._pg.gather_or, eb._pg.gather_reduce
     monkeypatch.setattr(
-        eb._pg, "gather_or", lambda v, i, w: calls.append(("or", w))
-        or gather_or(v, i, w, interpret=True))
+        eb._pg, "gather_or", lambda v, i, w: calls.append(
+            ("or", w, i.shape[0])) or gather_or(v, i, w, interpret=True))
     monkeypatch.setattr(
-        eb._pg, "gather_reduce", lambda v, i, w, op: calls.append((op, w))
-        or gather_reduce(v, i, w, op, interpret=True))
+        eb._pg, "gather_reduce", lambda v, i, w, op: calls.append(
+            (op, w, i.shape[0])) or gather_reduce(v, i, w, op,
+                                                  interpret=True))
     return calls
+
+
+def _fold_route(state, n_chunks: int, kernel: bool = True):
+    """The route of a plan whose stage 2 leaves a buffer of ``n_chunks``
+    rows and its zero row, for ``state`` (a shape and a dtype): the fold
+    reads that buffer."""
+    return eb._route(((8,), (8,), 1), ((n_chunks,), (1,), 1), state, 8,
+                     kernel)
 
 
 def _fold_case(case, r, n_pad, n_chunks):
@@ -569,14 +588,14 @@ def _fold_case(case, r, n_pad, n_chunks):
 @pytest.mark.parametrize("case", list(FOLD_ROUTES))
 def test_fold_rows_fetches_on_the_kernel_where_it_serves_the_state(
         case, monkeypatch):
-    """``_fold_rows`` with ``use_pallas`` over four blocks of 256 rows and
-    a ragged fifth, the list holding the ragged last block, a block twice
-    and the dummy row's block, against the same fold on the XLA gather:
-    bit-equal state (the dummy row the identity) and count. A 128-word
-    bitmap fetches through ``hg_gather_or`` at width 1, a flat int32 or
-    float32 state through ``hg_gather_scalar`` (the Pallas interpreter
-    here); a 32-word bitmap traces no ``pallas_call`` — the XLA route
-    alone."""
+    """``_fold_rows`` handed the route's fetch over four blocks of 256
+    rows and a ragged fifth, the list holding the ragged last block, a
+    block twice and the dummy row's block, against the same fold on the
+    XLA route: bit-equal state (the dummy row the identity) and count. A
+    128-word bitmap fetches through ``hg_gather_or`` at width 1, a flat
+    int32 or float32 state through ``hg_gather_scalar`` (the Pallas
+    interpreter here); a 32-word bitmap traces no ``pallas_call`` — the
+    XLA route alone."""
     kw, _, combine, gain, form = FOLD_ROUTES[case]
     calls = _kernel_fetches(monkeypatch)
     ub = 256
@@ -590,15 +609,17 @@ def test_fold_rows_fetches_on_the_kernel_where_it_serves_the_state(
     rows = eb._UpdateRows(jnp.asarray(out_map),
                           jnp.asarray(np.asarray(listed + [0], np.int32)),
                           jnp.int32(len(listed)))
-    args = (jnp.asarray(state), jnp.asarray(reach), rows, jnp.int32(n_atoms))
-    fold = {k: jax.jit(partial(eb._fold_rows, combine=combine, block_rows=ub,
-                               gain=gain, use_pallas=k))
-            for k in (False, True)}
-    assert ("pallas_call" in str(jax.make_jaxpr(fold[True])(*args))) \
+    args = {k: (jnp.asarray(state), jnp.asarray(reach),
+                _fold_route(state, n_chunks, k).listed(rows),
+                jnp.int32(n_atoms)) for k in (False, True)}
+    assert args[True][2].fetch == form and args[False][2].fetch is None
+    fold = jax.jit(partial(eb._fold_rows, combine=combine, block_rows=ub,
+                           gain=gain))
+    assert ("pallas_call" in str(jax.make_jaxpr(fold)(*args[True]))) \
         == (form is not None)
-    assert "pallas_call" not in str(jax.make_jaxpr(fold[False])(*args))
-    assert set(calls) == ({(form, 1)} if form else set())
-    want, got = (jax.tree_util.tree_leaves(fold[k](*args))
+    assert "pallas_call" not in str(jax.make_jaxpr(fold)(*args[False]))
+    assert set(calls) == ({(form, 1, ub)} if form else set())
+    want, got = (jax.tree_util.tree_leaves(fold(*args[k]))
                  for k in (False, True))
     for w, g in zip(want, got):
         assert np.array_equal(np.asarray(w), np.asarray(g))
@@ -638,14 +659,14 @@ def test_scalar_fold_on_the_kernel_is_numpy(case, blocks, monkeypatch):
     out_map = r.integers(0, n_chunks, size=n_pad).astype(np.int32)
     out_map[n_atoms] = n_chunks
     listed = SCALAR_FOLD_LISTS[blocks]
-    rows = eb._listed(jnp.asarray(out_map), np.isin(np.arange(5), listed),
-                      block_rows=ub)
+    rows = _fold_route(state, n_chunks).listed(eb._listed(
+        jnp.asarray(out_map), np.isin(np.arange(5), listed), block_rows=ub))
     fn = jax.jit(partial(eb._fold_rows, combine=combine, block_rows=ub,
-                         gain=gain, use_pallas=True))
+                         gain=gain))
     out = fn(jnp.asarray(state), jnp.asarray(reach), rows,
              jnp.int32(n_atoms))
     got = np.asarray(out[0] if gain else out)
-    assert calls == [(form, 1)]
+    assert calls == [(form, 1, ub)]
     folded = np.zeros(n_pad, dtype=bool)
     for b in listed:
         start = min(b * ub, n_pad - ub)
@@ -661,40 +682,109 @@ def test_scalar_fold_on_the_kernel_is_numpy(case, blocks, monkeypatch):
 
 
 _UPDATE_TABLES = {
-    # state, table, use_pallas, block rows: the form, or None (XLA)
-    "bitmap": ((70_000, 128), "uint32", (9_000, 128), True, 1 << 16, "or"),
-    "narrow_bitmap": ((70_000, 32), "uint32", (9_000, 32), True, 1 << 16,
-                      None),
-    "labels": ((70_000,), "int32", (9_000,), True, 1 << 16, "min"),
-    "ranks": ((70_000,), "float32", (9_000,), True, 1 << 16, "sum"),
+    # state, its stage-2 buffer, the backend has the kernel: the form, or
+    # None (XLA)
+    "bitmap": ((70_000, 128), "uint32", (9_000, 128), True, "or"),
+    "narrow_bitmap": ((70_000, 32), "uint32", (9_000, 32), True, None),
+    "labels": ((70_000,), "int32", (9_000,), True, "min"),
+    "ranks": ((70_000,), "float32", (9_000,), True, "sum"),
     "labels_whole_graph": ((10_000_072,), "int32", (2_108_462,), True,
-                           1 << 16, "min"),
+                           "min"),
     "table_past_the_bound": ((70_000,), "int32",
                              (eb._pg.SCALAR_TABLE_BYTES // 4 + 1,), True,
-                             1 << 16, None),
-    "short_block": ((70_000,), "float32", (9_000,), True,
-                    eb._pg.MIN_INDICES // 2, None),
-    "xla_caller": ((70_000,), "int32", (9_000,), False, 1 << 16, None),
+                             None),
+    "short_block": ((eb._pg.MIN_INDICES // 2,), "float32", (9_000,), True,
+                    None),
+    "xla_caller": ((70_000,), "int32", (9_000,), False, None),
     "bitmap_xla_caller": ((70_000, 128), "uint32", (9_000, 128), False,
-                          1 << 16, None),
+                          None),
 }
 
 
 @pytest.mark.parametrize("case", list(_UPDATE_TABLES))
 def test_update_on_kernel_routes_by_state_and_table(case):
-    """``_update_on_kernel``, the fold's one gate, as a table: 128-word
-    uint32 rows take ``hg_gather_or`` as before; a flat int32 or float32
-    state whose stage buffer the scalar gate admits takes its reduction's
-    scalar form; a narrower bitmap, a buffer past ``SCALAR_TABLE_BYTES``
-    (the state itself small), a block under ``MIN_INDICES`` or a caller
-    whose gathers take XLA keep the XLA gather. It reads shapes alone."""
-    shape, dtype, table, use_pallas, block_rows, form = _UPDATE_TABLES[case]
+    """The route's fold fetch as a table: 128-word uint32 rows take
+    ``hg_gather_or`` as before; a flat int32 or float32 state whose stage
+    buffer the scalar gate admits takes its reduction's scalar form; a
+    narrower bitmap, a buffer past ``SCALAR_TABLE_BYTES`` (the state
+    itself small), a state whose row block is under ``MIN_INDICES`` and a
+    backend without the kernel keep the XLA gather. It reads shapes
+    alone."""
+    shape, dtype, table, kernel, form = _UPDATE_TABLES[case]
     state = jax.ShapeDtypeStruct(shape, dtype)
-    buf = jax.ShapeDtypeStruct(table, dtype)
-    assert eb._update_on_kernel(state, use_pallas, block_rows,
-                                table=buf) == form
-    if len(shape) == 2:  # a bitmap's rows are the table's: the same answer
-        assert eb._update_on_kernel(state, use_pallas, block_rows) == form
+    assert _fold_route(state, table[0] - 1, kernel).fetch == form
+
+
+#: the counters a dispatch adds to, by operator
+ROUTE_COUNTERS = {
+    "hop": ("bfs.update.rows_visited", "bfs.update.rows_kernel"),
+    "wcc": ("scalar.gather.indices", "scalar.gather.indices_kernel",
+            "scalar.fold.rows", "scalar.fold.rows_kernel"),
+}
+ROUTE_COUNTERS["pagerank"] = ROUTE_COUNTERS["wcc"]
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+@pytest.mark.parametrize("operator", list(ROUTE_COUNTERS))
+def test_the_counters_agree_with_the_route(operator, route, monkeypatch):
+    """What a dispatch adds to the kernel counters is what the route it ran
+    says, and the program traces the kernel calls the route names and no
+    other: a 4096-seed hop (``bfs.update.rows_*`` from the fold's fetch,
+    the gauge ``bfs.gather.tile_share`` from the classes' forms), a WCC
+    round and a PageRank iteration (``scalar.gather.*`` from level 0's
+    blocks and tails, ``scalar.fold.*`` from the fetch). On the kernel
+    route (``pallas_ok`` patched, the interpreter, ``MIN_INDICES``
+    lowered) the fetch and some of level 0 take the kernel, a tail under
+    ``MIN_INDICES`` the XLA gather; on the XLA route no call does."""
+    from hypergraphdb_tpu.ops import connected_components, pagerank
+    from tests.test_pair_distances import linked_snapshot
+
+    jax.clear_caches()  # a kernel call is recorded as its program is traced
+    calls = _kernel_fetches(monkeypatch)
+    if route == "kernel":
+        monkeypatch.setattr(eb._pg, "pallas_ok", lambda: True)
+        monkeypatch.setattr(eb._pg, "MIN_INDICES", 256)
+    snap = linked_snapshot(700, 800, 11, n_types=4)
+    plans = eb.plans_for(snap)
+    dev = eb._device_plans(snap, plans)
+    chunk, n_pad = 1 << 10, plans.n_pad
+    listed = int(dev["blocks"].sum()) * eb._block_rows(n_pad)
+    before = [_counter(n) for n in ROUTE_COUNTERS[operator]]
+    if operator == "hop":
+        monkeypatch.setattr(eb, "SPARSE_SHARE", 1 << 62)  # the chain alone
+        seeds = np.arange(4096, dtype=np.int32) % snap.num_atoms
+        bfs_pull(snap, seeds, 1, chunk=chunk, k_block=4096)
+        state, dispatches = (n_pad, 128), 1
+    elif operator == "wcc":
+        state = (n_pad,)
+        dispatches = connected_components(snap, chunk=chunk).rounds
+    else:
+        state = (n_pad,)
+        dispatches = pagerank(snap, iterations=2, chunk=chunk).iterations
+    added = [_counter(n) - b for n, b in zip(ROUTE_COUNTERS[operator], before)]
+    dtype = {"hop": jnp.uint32, "wcc": jnp.int32}.get(operator, jnp.float32)
+    ran = eb._route_for(snap, plans, jax.ShapeDtypeStruct(state, dtype),
+                        chunk)
+    fetched = listed if ran.fetch else 0
+    if operator == "hop":
+        assert added == [listed, fetched]
+        assert obs.default_registry().get("bfs.gather.tile_share").value \
+            == ran.tile_share == (100.0 if route == "kernel" else 0.0)
+    else:
+        assert added == [dispatches * n for n in (*ran.indices, listed,
+                                                  fetched)]
+    classes = ran.stage1.classes + ran.stage2.classes
+    want = {(form, c.w, n) for c in classes for form, n in (
+        (c.block, c.n - c.n % (c.rows * c.w) and c.rows * c.w),
+        (c.tail, c.n % (c.rows * c.w))) if form and n}
+    if ran.fetch:
+        want.add((ran.fetch, 1, eb._block_rows(n_pad)))
+    assert set(calls) == want
+    if route == "kernel":
+        assert ran.fetch and 0 < ran.indices[1] < ran.indices[0]
+    else:
+        assert ran.indices[1] == 0 and ran.fetch is None and not calls
+    jax.clear_caches()
 
 
 # ------------------------------------------------------ the sparse first hop

@@ -490,6 +490,10 @@ def _f32(*shape):
 _UPDATE_ARGS = (_u32(64, 1), _u32(9, 1),
                 eb._UpdateRows(_i32(64), _i32(1), _i32()), _i32())
 
+#: a whole-graph program's plan: stage 1 two classes and an upper level,
+#: stage 2 one class and an upper level
+_WHOLE_GRAPH_STAGES = (((32, 64, 16), (2, 8, 8), 2), ((32, 16), (2, 8), 1))
+
 #: attribute -> (module name, scopes in its operations' paths, args, statics)
 STAGE_PROGRAMS = {
     "_seed_bitmap": ("hg_bfs_seed_bitmap", ("hg.bfs.seed_bitmap",),
@@ -502,15 +506,18 @@ STAGE_PROGRAMS = {
     "_stage": ("hg_bfs_stage1",
                ("hg.bfs.stage1.lvl0", "hg.bfs.stage1.upper"),
                (_u32(64, 1), (_i32(32), _i32(128), _i32(16))),
-               {"widths": (2, 8, 8), "n_lvl0": 2, "chunk": 4,
-                "use_pallas": False}),
+               {"route": eb._stage_route((32, 128, 16), (2, 8, 8), 2,
+                                         _u32(64, 1), 4, kernel=False)}),
     "_stage_lvl0_consume": ("hg_bfs_stage2_lvl0", ("hg.bfs.stage2.lvl0",),
                             (_u32(64, 1), (_i32(32), _i32(128))),
-                            {"widths": (2, 8), "chunk": 4,
-                             "use_pallas": False}),
+                            {"route": eb._stage_route(
+                                (32, 128), (2, 8), 2, _u32(64, 1), 4,
+                                kernel=False)}),
     "_stage_upper": ("hg_bfs_stage2_upper", ("hg.bfs.stage2.upper",),
                      (_u32(32, 1), (_i32(16),)),
-                     {"widths": (8,), "n_last": 16, "chunk": 4}),
+                     {"route": eb._stage_route((32, 16), (2, 8), 1,
+                                               _u32(64, 1), 4,
+                                               kernel=False)}),
     "_visited_update": ("hg_bfs_visited_update", ("hg.bfs.visited_update",),
                         _UPDATE_ARGS, {}),
     "_frontier_replace": ("hg_bfs_frontier_replace",
@@ -531,8 +538,8 @@ STAGE_PROGRAMS = {
                    (_i32(64), (_i32(32), _i32(64), _i32(16)),
                     (_i32(32), _i32(16)),
                     eb._UpdateRows(_i32(64), _i32(1), _i32()), _i32()),
-                   {"widths1": (2, 8, 8), "n1": 2, "widths2": (2, 8),
-                    "n2": 1, "chunk": 4}),
+                   {"route": eb._route(*_WHOLE_GRAPH_STAGES, _i32(64), 4,
+                                       kernel=False)}),
     "_wcc_count": ("hg_wcc_count", ("hg.wcc.count",), (_i32(64),), {}),
     # PageRank: one program an iteration, its three scopes at its top level
     # (stage 1's buffer: 16 + 8 + 2 chunks and the zero row), and its init
@@ -544,8 +551,8 @@ STAGE_PROGRAMS = {
                   eb._PRWeights(_f32(64), _f32(27), _f32(64)),
                   eb._UpdateRows(_i32(64), _i32(1), _i32()), _i32(),
                   _f32()),
-                 {"widths1": (2, 8, 8), "n1": 2, "widths2": (2, 8),
-                  "n2": 1, "chunk": 4}),
+                 {"route": eb._route(*_WHOLE_GRAPH_STAGES, _f32(64), 4,
+                                     kernel=False)}),
 }
 
 

@@ -294,9 +294,11 @@ def test_the_sum_pyramid_is_numpy_at_every_width_class(width):
     plan = eb.build_reduce_plan(offsets, flat, n_rows, zero_row=n_values)
     values = r.integers(1, 64, size=n_values + 1).astype(np.float32)
     values[n_values] = 0.0  # the zero row a padded index reads
+    route = eb._stage_route(tuple(map(len, plan.levels)), plan.widths,
+                            plan.n_lvl0, values, 4, kernel=False)
     buf = np.asarray(eb._apply_plan(
-        jnp.asarray(values), tuple(map(jnp.asarray, plan.levels)),
-        plan.widths, plan.n_lvl0, 4, False, scopes=("hg.t", "hg.t")))
+        jnp.asarray(values), tuple(map(jnp.asarray, plan.levels)), route,
+        scopes=("hg.t", "hg.t")))
     assert buf.shape == (plan.concat_size + 1,) and buf[-1] == 0.0
     # small whole numbers: float32 sums are exact
     want = np.asarray([values[flat[offsets[i]:offsets[i + 1]]].sum()

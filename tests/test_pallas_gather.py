@@ -283,9 +283,9 @@ def test_bfs_pull_wide_block_cpu_fallback(graph):
 
 def test_tile_share_gauge_says_which_gather_serves(graph, monkeypatch):
     """``bfs.gather.tile_share``, set where a plan's device arrays are
-    made: 0 where the XLA gather serves (this CPU), the share of the
-    plan's level-0 indices in a class the kernel's gate admits where the
-    kernel does."""
+    made, from a 4096-seed block's route: 0 where the XLA gather serves
+    (this CPU), the share of the plan's level-0 indices in a class whose
+    form is ``hg_gather_or``'s where the kernel does."""
     from tests.conftest import make_random_hypergraph
     from hypergraphdb_tpu.obs.registry import default_registry
     from hypergraphdb_tpu.ops import ellbfs as eb
@@ -319,9 +319,11 @@ def test_tile_share_gauge_says_which_gather_serves(graph, monkeypatch):
     monkeypatch.setattr(
         pg, "declined",
         lambda w, Kw: "not this one" if w == w0 else admits(w, Kw))
-    assert eb._tile_share(plans) == pytest.approx(
-        100.0 * (lvl0 - in_w0) / lvl0)
-    assert 0.0 < eb._tile_share(plans) < 100.0
+    object.__setattr__(snap, "_pull_device", None)   # upload and route
+    object.__setattr__(snap, "_pull_routes", {})     # anew
+    eb._device_plans(snap, plans)
+    assert gauge.value == pytest.approx(100.0 * (lvl0 - in_w0) / lvl0)
+    assert 0.0 < gauge.value < 100.0
 
 
 # ------------------------------------------------------ the scalar form

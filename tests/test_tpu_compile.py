@@ -408,34 +408,36 @@ def _staged_step(step: str, kernel: bool = False):
                 (visited, _sds((2, eb.SPARSE_BLOCK), "int32"),
                  _sds((), "int32")),
                 {}, 4 * (sum(_L1) + sum(_L2)))
+    stages = ((_L1, _W1, len(_L1)), (_L2, _W2, _N2))
     if step in ("_visited_update", "_frontier_replace", "_ball_update"):
         # a hop ends here (a match's step: three steps' plans lie beside
-        # it); the block list has a slot a row block of the bitmap
-        listed = eb._UpdateRows(
-            _sds((_N_PAD,), "int32"),
-            _sds((-(-_N_PAD // eb.UPDATE_ROWS),), "int32"),
-            _sds((), "int32"))
+        # it); the block list has a slot a row block of the bitmap, and
+        # the route's fetch
+        listed = eb._route(*stages, visited, _CHUNK, kernel).listed(
+            eb._UpdateRows(_sds((_N_PAD,), "int32"),
+                           _sds((-(-_N_PAD // eb.UPDATE_ROWS),), "int32"),
+                           _sds((), "int32")))
         return (getattr(eb, step),
                 (visited, _sds((rows(_L2, _W2) + 1, _KW), "uint32"),
                  listed, _sds((), "int32")),
-                {"use_pallas": True} if kernel else {},
+                {},
                 3 * 4 * (sum(_L1) + sum(_L2))
                 + (bitmap if step == "_ball_update" else 0))  # the other ball
+    # the stages gather on the kernel, as a 4096-seed block on a TPU does
+    route = eb._route(*stages, visited, _CHUNK, kernel=True)
     if step == "_stage":
         return (eb._stage,
                 (visited, ints(_L1)),
-                dict(widths=_W1, n_lvl0=len(_L1), chunk=_CHUNK,
-                     use_pallas=True),
+                {"route": route.stage1},
                 4 * sum(_L2))
     if step == "_stage_lvl0_consume":
         return (eb._stage_lvl0_consume,
                 (_sds((rows(_L1, _W1) + 1, _KW), "uint32"), ints(_L2[:_N2])),
-                dict(widths=_W2[:_N2], chunk=_CHUNK, use_pallas=True),
+                {"route": route.stage2},
                 bitmap + 4 * (sum(_L1) + sum(_L2[_N2:])))
     return (eb._stage_upper,
             (_sds((rows(_L2[:_N2], _W2), _KW), "uint32"), ints(_L2[_N2:])),
-            dict(widths=_W2[_N2:], n_last=_L2[_N2 - 1] // _W2[_N2 - 1],
-                 chunk=_CHUNK),
+            {"route": route.stage2},
             bitmap + 4 * (sum(_L1) + sum(_L2[:_N2])))
 
 
@@ -559,6 +561,38 @@ _TW2 = (2, 4, 6, 8, 10, 14, 20, 28, 40, 56, 8, 8, 8, 8)
 _TN2 = 10
 
 
+def _whole_graph_step(program: str, kernel: bool = False):
+    """(jitted program, exemplar args, statics) of a whole-graph operator at
+    its cell's plan: ``_wcc_round`` at ``wcc10m.family16``'s restricted plan
+    (``_T1`` / ``_T2``), ``_pr_iter`` at the untyped plan (``_L1`` /
+    ``_L2``: ``pagerank10m.iter10`` runs ``embedded10m.traverse3``'s);
+    ``kernel``: the level-0 gathers and the fold's fetch on the scalar
+    form, as a TPU runs them."""
+    from hypergraphdb_tpu.ops import ellbfs as eb
+
+    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
+    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
+    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
+                          _sds((blocks,), "int32"), _sds((), "int32"))
+    if program == "_wcc_round":
+        labels = _sds((_N_PAD,), "int32")
+        route = eb._route((_T1, _TW1, len(_T1)), (_T2, _TW2, _TN2),
+                          labels, _CHUNK, kernel)
+        return eb._wcc_round, (
+            labels, ints(_T1), ints(_T2), rows, _sds((), "int32")), {
+            "route": route}
+    chunks1 = sum(n // w for n, w in zip(_L1, _W1)) + 1
+    weights = eb._PRWeights(_sds((_N_PAD,), "float32"),
+                            _sds((chunks1,), "float32"),
+                            _sds((_N_PAD,), "float32"))
+    ranks = _sds((_N_PAD,), "float32")
+    route = eb._route((_L1, _W1, len(_L1)), (_L2, _W2, _N2), ranks,
+                      _CHUNK, kernel)
+    return eb._pr_iter, (
+        ranks, ints(_L1), ints(_L2), weights, rows, _sds((), "int32"),
+        _sds((), "float32")), {"route": route}
+
+
 def test_label_round_fits_one_chip_with_a_four_byte_label(one_chip,
                                                           no_compile_cache):
     """``_wcc_round`` — both min pyramids and the fold in ONE program — at
@@ -567,22 +601,14 @@ def test_label_round_fits_one_chip_with_a_four_byte_label(one_chip,
     an argument), donated into the output; what the program holds besides
     its arguments — two stage buffers of an int32 a chunk and the scan's
     gather transients — stays under 1 GiB."""
-    from hypergraphdb_tpu.ops import ellbfs as eb
-
-    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
-    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
-    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
-                          _sds((blocks,), "int32"), _sds((), "int32"))
-    args = (_sds((_N_PAD,), "int32"), ints(_T1), ints(_T2), rows,
-            _sds((), "int32"))
-    compiled = eb._wcc_round.lower(
-        *_place(args, one_chip), widths1=_TW1, n1=len(_T1), widths2=_TW2,
-        n2=_TN2, chunk=_CHUNK).compile()
+    fn, args, statics = _whole_graph_step("_wcc_round")
+    compiled = fn.lower(*_place(args, one_chip), **statics).compile()
     mem = compiled.memory_analysis()
     assert compiled.as_text().startswith("HloModule jit_hg_wcc_round,")
     assert mem.temp_size_in_bytes < 2**30
     assert mem.alias_size_in_bytes >= 4 * _N_PAD
     # the labels, the plan, out_map and the block list at 4 bytes an entry
+    blocks = args[3].starts.shape[0]
     flat = 4 * (2 * _N_PAD + sum(_T1) + sum(_T2) + blocks + 1)
     assert mem.argument_size_in_bytes < 1.01 * flat
 
@@ -598,24 +624,15 @@ def _kernel_calls_under(text: str, scope: str) -> int:
 def test_label_round_on_the_kernel_fits_one_chip(one_chip,
                                                  no_compile_cache):
     """``_wcc_round`` with its level-0 gathers on the scalar form (what a
-    TPU runs: ``use_pallas`` from ``pallas_ok()``), at the cell's plan:
+    TPU runs: the route's forms where ``pallas_ok()``), at the cell's plan:
     every class of both stages calls the kernel, and so does the fold's
     fetch (width 1, a block of ``UPDATE_ROWS`` a call inside its loop,
     under scope ``hg.wcc.fold``); what the program holds besides its
     arguments stays under 256 MiB — no (chunks, 128) transient of a
     fetched row a chunk (4 bytes a chunk, as on the XLA route), the
     tables padded by under a grid step's 4 KB."""
-    from hypergraphdb_tpu.ops import ellbfs as eb
-
-    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
-    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
-    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
-                          _sds((blocks,), "int32"), _sds((), "int32"))
-    args = (_sds((_N_PAD,), "int32"), ints(_T1), ints(_T2), rows,
-            _sds((), "int32"))
-    compiled = eb._wcc_round.lower(
-        *_place(args, one_chip), widths1=_TW1, n1=len(_T1), widths2=_TW2,
-        n2=_TN2, chunk=_CHUNK, use_pallas=True).compile()
+    fn, args, statics = _whole_graph_step("_wcc_round", kernel=True)
+    compiled = fn.lower(*_place(args, one_chip), **statics).compile()
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_hg_wcc_round,")
@@ -643,27 +660,15 @@ def test_pagerank_iteration_fits_one_chip_with_a_four_byte_rank(
     4 bytes a row, donated into the output; what the program holds besides
     its arguments — two stage buffers of a float a chunk, the fold's zeros
     and the scan's gather transients — stays under 1 GiB."""
-    from hypergraphdb_tpu.ops import ellbfs as eb
-
-    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
-    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
-    chunks1 = sum(n // w for n, w in zip(_L1, _W1)) + 1
-    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
-                          _sds((blocks,), "int32"), _sds((), "int32"))
-    weights = eb._PRWeights(_sds((_N_PAD,), "float32"),
-                            _sds((chunks1,), "float32"),
-                            _sds((_N_PAD,), "float32"))
-    args = (_sds((_N_PAD,), "float32"), ints(_L1), ints(_L2), weights, rows,
-            _sds((), "int32"), _sds((), "float32"))
-    compiled = eb._pr_iter.lower(
-        *_place(args, one_chip), widths1=_W1, n1=len(_L1), widths2=_W2,
-        n2=_N2, chunk=_CHUNK).compile()
+    fn, args, statics = _whole_graph_step("_pr_iter")
+    compiled = fn.lower(*_place(args, one_chip), **statics).compile()
     mem = compiled.memory_analysis()
     assert compiled.as_text().startswith("HloModule jit_hg_pr_iter,")
     assert mem.temp_size_in_bytes < 2**30
     assert mem.alias_size_in_bytes >= 4 * _N_PAD
     # the ranks, the plan, the weights, out_map and the block list at 4
     # bytes an entry
+    chunks1, blocks = args[3].w.shape[0], args[4].starts.shape[0]
     flat = 4 * (4 * _N_PAD + sum(_L1) + sum(_L2) + chunks1 + blocks + 1)
     assert mem.argument_size_in_bytes < 1.01 * flat
 
@@ -675,21 +680,8 @@ def test_pagerank_iteration_on_the_kernel_fits_one_chip(one_chip,
     does the replacing fold's fetch (under scope ``hg.pr.update``), the
     link weights meet stage 1's padded buffer, and what the program holds
     besides its arguments stays under 256 MiB."""
-    from hypergraphdb_tpu.ops import ellbfs as eb
-
-    ints = lambda ls: tuple(_sds((n,), "int32") for n in ls)  # noqa: E731
-    blocks = -(-_N_PAD // eb.UPDATE_ROWS)
-    chunks1 = sum(n // w for n, w in zip(_L1, _W1)) + 1
-    rows = eb._UpdateRows(_sds((_N_PAD,), "int32"),
-                          _sds((blocks,), "int32"), _sds((), "int32"))
-    weights = eb._PRWeights(_sds((_N_PAD,), "float32"),
-                            _sds((chunks1,), "float32"),
-                            _sds((_N_PAD,), "float32"))
-    args = (_sds((_N_PAD,), "float32"), ints(_L1), ints(_L2), weights, rows,
-            _sds((), "int32"), _sds((), "float32"))
-    compiled = eb._pr_iter.lower(
-        *_place(args, one_chip), widths1=_W1, n1=len(_L1), widths2=_W2,
-        n2=_N2, chunk=_CHUNK, use_pallas=True).compile()
+    fn, args, statics = _whole_graph_step("_pr_iter", kernel=True)
+    compiled = fn.lower(*_place(args, one_chip), **statics).compile()
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_hg_pr_iter,")
@@ -727,8 +719,7 @@ _BITMAP_TEXT = {
 }
 
 
-def _text_hash(step: str, kernel: bool, sharding) -> str:
-    fn, args, statics, _ = _staged_step(step, kernel)
+def _text_hash(fn, args, statics, sharding) -> str:
     text = fn.lower(*_place(args, sharding), **statics).as_text()
     return hashlib.sha256(re.sub(r'backend_config = "[^"]*"',
                                  'backend_config = ""', text)
@@ -741,4 +732,26 @@ def test_the_bitmap_programs_lower_to_the_text_they_had(step, kernel,
     """``_fold_rows`` routes a flat state's fetch to the scalar form; the
     six bitmap programs — the three stages and the three updates, each
     update by both routes of its fetch — lower to the text they had."""
-    assert _text_hash(step, kernel, one_chip) == _BITMAP_TEXT[(step, kernel)]
+    assert _text_hash(*_staged_step(step, kernel)[:3], one_chip) \
+        == _BITMAP_TEXT[(step, kernel)]
+
+
+#: The same for the two whole-graph programs at ``_whole_graph_step``'s
+#: exemplars, each by both routes (the kernel's text with the Mosaic body
+#: stripped): the text they had before the gather's route became their one
+#: static.
+_WHOLE_GRAPH_TEXT = {
+    ("_wcc_round", False): "6580bddf79ce011ccebf2af833768693",
+    ("_wcc_round", True): "913c47d687580ac34aa9e1e8ba2adb83",
+    ("_pr_iter", False): "f3e33c1ba82393ed7d76225f9b1e90e2",
+    ("_pr_iter", True): "23ced0c75ab480f9a4950da1c91f1f6b",
+}
+
+
+@pytest.mark.parametrize("program,kernel", list(_WHOLE_GRAPH_TEXT))
+def test_the_whole_graph_programs_lower_to_the_text_they_had(program, kernel,
+                                                             one_chip):
+    """A WCC round and a PageRank iteration, each by the XLA gather and by
+    the kernel's scalar form, lower to the text they had."""
+    assert _text_hash(*_whole_graph_step(program, kernel), one_chip) \
+        == _WHOLE_GRAPH_TEXT[(program, kernel)]
